@@ -13,9 +13,11 @@ RUN pip install --no-cache-dir "jax>=0.4.30" numpy pyyaml
 COPY coraza_kubernetes_operator_tpu/ coraza_kubernetes_operator_tpu/
 COPY native/ native/
 
-# Build the native host runtime if a toolchain is present (optional:
-# the Python fallback is used when the shared library is absent).
-RUN if command -v g++ >/dev/null 2>&1; then make -C native || true; fi
+# Build the native host runtime. A failed build fails the image: without
+# the library the sidecar serves on the Python tensorizer and says so
+# only in /waf/v1/stats (native.available).
+RUN apt-get update && apt-get install -y --no-install-recommends g++ make \
+    && rm -rf /var/lib/apt/lists/* && make -C native
 
 RUN useradd -u 65532 -m nonroot
 USER 65532

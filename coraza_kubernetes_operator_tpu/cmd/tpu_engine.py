@@ -231,8 +231,10 @@ def build_config(argv: list[str] | None = None) -> SidecarConfig:
         "--compile-cache-dir",
         default=None,
         help="persistent XLA compilation cache directory (default"
-        " $CKO_COMPILE_CACHE_DIR): cold sidecar starts warm-start their"
-        " executable compiles from disk; '0' disables",
+        " $CKO_COMPILE_CACHE_DIR, else .jax_bench_cache/ in the checkout):"
+        " cold sidecar starts warm-start their executable compiles from"
+        " disk; '0' disables. $JAX_COMPILATION_CACHE_DIR, when set, wins"
+        " over both",
     )
     p.add_argument(
         "--disable-rollout",
@@ -323,7 +325,7 @@ def build_config(argv: list[str] | None = None) -> SidecarConfig:
     # deserializes yesterday's executables instead of recompiling them.
     from ..engine.compile_cache import configure_persistent_cache
 
-    configure_persistent_cache(args.compile_cache_dir)
+    configure_persistent_cache(args.compile_cache_dir, default=True)
 
     cluster = args.cache_server_cluster
     if ":" in cluster:

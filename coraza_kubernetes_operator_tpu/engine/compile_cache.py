@@ -22,9 +22,10 @@ swapped into it. Three layers implement that here:
    unchanged signature performs zero XLA compiles. Hit/miss/compile-time
    counters back the ``cko_compile_cache_*`` metrics.
 3. **Persistent compilation cache** (:func:`configure_persistent_cache`):
-   JAX's on-disk cache keyed by HLO hash, directory from
-   ``CKO_COMPILE_CACHE_DIR`` — cold *processes* warm-start from disk
-   (bench children, ftw chunk children, CI runs, sidecar restarts).
+   JAX's on-disk cache keyed by HLO hash — cold *processes* warm-start
+   from disk (bench children, ftw chunk children, CI runs, sidecar
+   restarts). ``JAX_COMPILATION_CACHE_DIR`` wins when set; else
+   ``CKO_COMPILE_CACHE_DIR`` / the flag; else one fixed in-checkout path.
 
 Thread safety: lookups and stats are lock-protected; a miss compiles
 outside the lock (compiles are minutes-long — serializing them behind a
@@ -46,45 +47,74 @@ from ..utils import get_logger
 
 log = get_logger("engine.compile_cache")
 
-# Environment knob shared by the sidecar entrypoint, bench harness, ftw
-# chunk children, and CI: one directory, warm across processes.
+# Where the persistent cache goes, in order of precedence:
+# 1. ``JAX_COMPILATION_CACHE_DIR`` — set from outside (an operator, a
+#    test rig, a machine image that keeps a cache between runs). JAX
+#    reads it itself; nothing here sets another directory over it,
+#    whatever the flag or ``CKO_COMPILE_CACHE_DIR`` say.
+# 2. ``--compile-cache-dir`` / ``CKO_COMPILE_CACHE_DIR`` — the repo's
+#    own knob, shared by the sidecar entrypoint, bench harness, ftw
+#    chunk children and CI. ``"0"`` disables.
+# 3. ``DEFAULT_CACHE_DIR`` — one fixed path inside the checkout (the
+#    path is part of the cache key's neighbourhood: a directory that
+#    moves never hits), used by entrypoints that want a cache whether or
+#    not anyone configured one (``default=True``).
+JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 CACHE_DIR_ENV = "CKO_COMPILE_CACHE_DIR"
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_bench_cache",
+)
 
 _configured_dir: list[str] = []
 
 
-def configure_persistent_cache(cache_dir: str | None = None) -> str | None:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (or
-    ``$CKO_COMPILE_CACHE_DIR``). Idempotent; returns the directory in
-    effect, or None when unset/disabled (``"0"`` disables).
+def resolve_cache_dir(cache_dir: str | None = None, default: bool = False) -> str | None:
+    """The directory the precedence above selects, or None when nothing
+    selects one / the repo's knob says ``"0"``."""
+    d = os.environ.get(JAX_CACHE_DIR_ENV, "")
+    if d:
+        return d  # verbatim: it is JAX's own setting, not ours to rewrite
+    d = cache_dir if cache_dir is not None else os.environ.get(CACHE_DIR_ENV, "")
+    if d == "0":
+        return None
+    if not d and default:
+        d = DEFAULT_CACHE_DIR
+    return os.path.abspath(d) if d else None
+
+
+def configure_persistent_cache(
+    cache_dir: str | None = None, default: bool = False
+) -> str | None:
+    """Point JAX's persistent compilation cache at the directory
+    :func:`resolve_cache_dir` selects. Idempotent; returns the directory
+    in effect, or None when unset/disabled.
 
     Thresholds drop to zero so every executable is eligible — the WAF
     model's per-tier executables are exactly the artifacts a cold
     process needs back, whatever their size or compile time.
     """
-    d = cache_dir if cache_dir is not None else os.environ.get(CACHE_DIR_ENV, "")
-    if not d or d == "0":
+    d = resolve_cache_dir(cache_dir, default)
+    if d is None:
         return _configured_dir[0] if _configured_dir else None
-    d = os.path.abspath(d)
     if _configured_dir and _configured_dir[0] == d:
         return d
     try:
         os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
+        if not os.environ.get(JAX_CACHE_DIR_ENV):
+            # JAX already holds the env's directory; only ours is set here.
+            jax.config.update("jax_compilation_cache_dir", d)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
         # jax initializes its cache object AT MOST ONCE, on the first
-        # compile — and importing this package triggers tiny compiles, so
-        # by the time an entrypoint calls us the None-dir cache is already
-        # latched and the update above would be silently ignored (writes
-        # no-op, warm starts never happen). reset_cache() drops the latch
-        # so the next compile re-initializes against the new directory.
-        try:
-            from jax._src import compilation_cache as _cc
+        # compile, so a process that already compiled something has the
+        # None-dir cache latched and the update above would be silently
+        # ignored (writes no-op, warm starts never happen). reset_cache()
+        # drops the latch so the next compile re-initializes against the
+        # new directory.
+        from jax._src import compilation_cache as _cc
 
-            _cc.reset_cache()
-        except Exception:
-            pass  # private API moved: the dir still applies to fresh processes
+        _cc.reset_cache()
     except Exception as err:  # never let cache wiring break serving
         log.error("persistent compile cache unavailable", err, dir=d)
         return None
@@ -131,6 +161,35 @@ class ExecutableCache:
         # ruleset cheap. This gauge is how an abandoned compile stays
         # visible instead of becoming a silent background CPU burn.
         self.inflight = 0
+        # Collected windows by where their stages ran. Under lazy tier
+        # compilation (CKO_LAZY_TIERS=1) a window with any stage whose
+        # executable was not resident is answered by the host twins —
+        # correct, but not the device: ``host_twin_windows`` is the only
+        # counter that tells the two apart after the fact.
+        self.device_windows = 0
+        self.host_twin_windows = 0
+        # {"platform", "kind", "count"} of the device that produced the
+        # first all-device window's output; None until one was collected.
+        self.device: dict | None = None
+
+    def note_window(self, out, on_device: bool) -> None:
+        """Count one collected window (``WafEngine._collect``). ``out``
+        is the window's not-yet-read-back output array: the first
+        all-device window's array says which device this process runs
+        on, with no question asked of JAX before the engine dispatched."""
+        with self._lock:
+            if not on_device:
+                self.host_twin_windows += 1
+                return
+            self.device_windows += 1
+            if self.device is not None:
+                return
+        dev = next(iter(out.devices()))
+        self.device = {
+            "platform": dev.platform,
+            "kind": dev.device_kind,
+            "count": len(jax.devices()),
+        }
 
     # -- core ---------------------------------------------------------------
 
@@ -235,6 +294,8 @@ class ExecutableCache:
                 "trace_s": round(self.trace_s, 3),
                 "bypasses": self.bypasses,
                 "inflight": self.inflight,
+                "device_windows": self.device_windows,
+                "host_twin_windows": self.host_twin_windows,
                 "persistent_dir": _configured_dir[0] if _configured_dir else None,
             }
 

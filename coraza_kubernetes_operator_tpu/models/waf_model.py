@@ -44,7 +44,7 @@ from ..ops.dfa_gather import GatherBank, plan_gather_bins, stack_gather_bank
 from ..ops.segment import SegmentBlock, build_segment_block, match_segment_block
 from ..ops.transforms import apply_device_pipeline
 
-_BIG = jnp.int32(2**31 - 1)
+_BIG = np.int32(2**31 - 1)
 
 # Conv-tier match-bitmap element budgets (T * (L+2) * N2). A tier whose
 # whole bitmap exceeds the per-chunk budget is row-CHUNKED: the conv

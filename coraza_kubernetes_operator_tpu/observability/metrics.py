@@ -94,6 +94,12 @@ class Gauge(_Metric):
         with self._lock:
             self._values[key] = float(value)
 
+    def clear(self) -> None:
+        """Drop every series (an info gauge whose labels changed)."""
+        with self._lock:
+            self._values.clear()
+            self._fns.clear()
+
     def set_function(self, fn, **labels) -> None:
         """Sample ``fn()`` at render time (for cache sizes etc.)."""
         key = tuple(str(labels.get(k, "")) for k in self.label_names)

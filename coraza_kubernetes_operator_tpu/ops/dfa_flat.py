@@ -57,8 +57,8 @@ from ..compiler.re_dfa import DFA
 _LANE = 128
 # Per-kernel VMEM ceiling. The chip enforces a 16MB scoped-vmem limit at
 # COMPILE time (observed: a 3584-slot bin at L=2048 rejected at
-# 16.09M/16.00M with a clean remote-compile error — not the round-4
-# style runtime fault). The estimator below is calibrated against that
+# 16.09M/16.00M with a clean compile error — not the round-4 style
+# runtime fault). The estimator below is calibrated against that
 # measurement; the default budget keeps ~1MB of margin under the real
 # limit. Env-tunable for validation runs.
 import os as _os
@@ -185,7 +185,7 @@ def _layout_stats(pieces) -> tuple[int, int, int, int]:
 # Widest buffer the Pallas kernel accepts; wider tiers run the XLA
 # formulation (they carry few rows — the body tier is ~128 — so grid
 # parallelism is nil there anyway). The real ceiling is the chip's 16MB
-# scoped-vmem limit, which the REMOTE COMPILER enforces with a clean
+# scoped-vmem limit, which the compiler enforces with a clean
 # compile-time error (observed: a 3584-slot bin at L=2048 rejected at
 # 16.09M/16.00M), so an over-budget combination fails visibly at
 # compile, never as a runtime fault. 2048 with the default 11MB plan

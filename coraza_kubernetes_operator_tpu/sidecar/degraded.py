@@ -370,7 +370,7 @@ class DegradedModeManager:
         self.fallback_enabled = fallback_enabled
         # ONE breaker for the whole sidecar, deliberately: the device is a
         # shared resource, and the fault storms this guards against
-        # (kernel faults, tunnel drops) are device-wide, not per-model. A
+        # (kernel faults, runtime errors) are device-wide, not per-model. A
         # single tenant's model-specific evaluation failure will demote
         # every tenant to the fallback — accepted: correctness-preserving
         # (fallback verdicts are identical), and far simpler than

@@ -95,30 +95,34 @@ def _tier_compile_stats() -> dict:
     return TIER_COMPILER.stats()
 
 
+def _device_identity() -> dict | None:
+    """``{"platform", "kind", "count"}`` as recorded from the arrays of
+    the first all-device window this process collected, or None before
+    one. Never asks JAX itself: reading it forces no backend."""
+    from ..engine.compile_cache import EXEC_CACHE
+
+    return EXEC_CACHE.device
+
+
 def _build_info_labels() -> dict:
-    """Label set for the cko_build_info gauge. The platform label comes
-    from JAX_PLATFORMS (not jax.devices()) so rendering metrics never
-    forces a backend initialization."""
+    """Label set for the cko_build_info gauge. The device labels come
+    from the process's first device window (``_device_identity``), not
+    from the environment and not from ``jax.devices()``: rendering
+    metrics never forces a backend initialization, and what is shown is
+    the device that answered. ``unknown`` until a window ran there."""
     from .. import __version__
 
-    try:
-        import jax
+    import jax
+    import jaxlib
 
-        jax_version = getattr(jax, "__version__", "none")
-    except Exception:
-        jax_version = "none"
-    try:
-        import jaxlib
-
-        jaxlib_version = getattr(jaxlib, "__version__", "none")
-    except Exception:
-        jaxlib_version = "none"
-    platform = os.environ.get("JAX_PLATFORMS", "") or "default"
+    device = _device_identity() or {}
     return {
         "version": __version__,
-        "jax": jax_version,
-        "jaxlib": jaxlib_version,
-        "platform": platform,
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "platform": device.get("platform", "unknown"),
+        "device_kind": device.get("kind", "unknown"),
+        "device_count": device.get("count", 0),
     }
 
 
@@ -1341,11 +1345,12 @@ class TpuEngineSidecar:
             lambda: float(self.audit.rotations if self.audit is not None else 0)
         )
         # -- build / process identity (docs/OBSERVABILITY.md) ---------------
-        self.metrics.gauge(
+        self._m_build_info = self.metrics.gauge(
             "cko_build_info",
             "Build/runtime identity; the value is always 1",
-            ("version", "jax", "jaxlib", "platform"),
-        ).set(1.0, **_build_info_labels())
+            ("version", "jax", "jaxlib", "platform", "device_kind", "device_count"),
+        )
+        self._build_info: dict = {}
         self.metrics.gauge(
             "cko_process_resident_memory_bytes",
             "Resident set size of the sidecar process",
@@ -2571,6 +2576,13 @@ class TpuEngineSidecar:
         cannot be registered up front). Per-tenant fairness gauges and
         per-knob retune counts refresh the same way: their label sets
         grow with traffic."""
+        labels = _build_info_labels()
+        if labels != self._build_info:
+            # One series: the device labels fill in once the first
+            # device window was collected.
+            self._m_build_info.clear()
+            self._m_build_info.set(1.0, **labels)
+            self._build_info = labels
         for label, secs in _tier_compile_stats().items():
             self._m_tier_s.set(secs, tier=label)
         for tenant, row in self.governor.tenant_ledger().items():
@@ -2632,6 +2644,7 @@ class TpuEngineSidecar:
             "degraded": self.degraded.stats(),
             "shed_total": int(self._m_shed.value()),
             "failopen_total": int(self._m_failopen.value()),
+            "device": _device_identity(),
             "compile_cache": {
                 **_exec_cache_stats(),
                 "exec_signatures": self._report_int("exec_signatures"),

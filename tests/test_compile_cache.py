@@ -11,7 +11,12 @@ runtime operand. These tests pin the three serving-facing invariants:
 3. N tenants on M distinct rulesets hold M resident engines.
 """
 
+import json
+import os
+import subprocess
+import sys
 import threading
+from pathlib import Path
 
 from coraza_kubernetes_operator_tpu.engine.compile_cache import (
     EXEC_CACHE,
@@ -261,3 +266,78 @@ def test_degraded_probe_prewarms_before_canary():
     assert calls[0][0] == "prewarm"
     assert ("evaluate", 1) in calls
     mgr.stop()
+
+
+# -- where the persistent cache goes (ISSUE 22) ------------------------------
+#
+# Each case runs configure_persistent_cache in a fresh interpreter: the
+# wiring is process-global (jax.config + a module latch) and conftest has
+# already pointed this process at tests/.jax_cache.
+
+REPO = Path(__file__).resolve().parents[1]
+
+_PROBE = """
+import json, os, sys
+import jax
+from coraza_kubernetes_operator_tpu.engine.compile_cache import (
+    configure_persistent_cache, resolve_cache_dir,
+)
+flag, default = json.loads(sys.argv[1])
+print(json.dumps({
+    "resolved": resolve_cache_dir(flag, default=default),
+    "returned": configure_persistent_cache(flag, default=default),
+    "jax": jax.config.jax_compilation_cache_dir,
+}))
+"""
+
+
+def _probe_cache_dir(cwd, flag=None, default=False, **env_dirs):
+    env = {
+        k: v
+        for k, v in os.environ.items()
+        if k not in ("JAX_COMPILATION_CACHE_DIR", "CKO_COMPILE_CACHE_DIR")
+    }
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=str(REPO), **env_dirs)
+    out = subprocess.run(
+        [sys.executable, "-c", _PROBE, json.dumps([flag, default])],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_jax_cache_env_wins_over_flag_and_repo_knob(tmp_path):
+    jax_dir, cko_dir, flag_dir = (tmp_path / n for n in ("jax", "cko", "flag"))
+    got = _probe_cache_dir(
+        tmp_path,
+        flag=str(flag_dir),
+        default=True,
+        JAX_COMPILATION_CACHE_DIR=str(jax_dir),
+        CKO_COMPILE_CACHE_DIR=str(cko_dir),
+    )
+    assert got == {"resolved": str(jax_dir), "returned": str(jax_dir), "jax": str(jax_dir)}
+    assert jax_dir.is_dir() and not cko_dir.exists() and not flag_dir.exists()
+
+
+def test_repo_knob_and_flag_apply_when_jax_env_unset(tmp_path):
+    cko_dir, flag_dir = tmp_path / "cko", tmp_path / "flag"
+    got = _probe_cache_dir(tmp_path, CKO_COMPILE_CACHE_DIR=str(cko_dir))
+    assert got["jax"] == got["returned"] == str(cko_dir)
+    got = _probe_cache_dir(
+        tmp_path, flag=str(flag_dir), CKO_COMPILE_CACHE_DIR=str(cko_dir)
+    )
+    assert got["jax"] == got["returned"] == str(flag_dir)
+    got = _probe_cache_dir(tmp_path, flag="0", default=True)
+    assert got == {"resolved": None, "returned": None, "jax": None}
+
+
+def test_default_cache_dir_is_one_fixed_path_in_the_checkout(tmp_path):
+    a, b = tmp_path / "a", tmp_path / "b"
+    a.mkdir()
+    b.mkdir()
+    got_a = _probe_cache_dir(a, default=True)
+    got_b = _probe_cache_dir(b, default=True)
+    assert got_a == got_b
+    assert got_a["jax"] == got_a["returned"] == str(REPO / ".jax_bench_cache")
+    # Nothing selects a directory unless an entrypoint asks for the default.
+    assert _probe_cache_dir(a)["jax"] is None

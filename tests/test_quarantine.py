@@ -320,7 +320,7 @@ def test_late_loss_class_error_reaches_fault_hook_without_requests():
     """Regression: a DEVICE_LOST landing AFTER abandonment must still be
     classified (loss check only — requests_fn is None so the breaker is
     not double-fed)."""
-    loss = faults.DeviceLostFault("DEVICE_LOST: tunnel dropped")
+    loss = faults.DeviceLostFault("DEVICE_LOST: device halted")
     eng = _BlockingEngine(collect_error=loss)
     b = MicroBatcher(lambda: eng, max_batch_size=1, max_batch_delay_ms=0)
     b.window_deadline_s = 0.3

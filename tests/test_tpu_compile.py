@@ -1,0 +1,190 @@
+"""Compile the served path's device programs for a DESCRIBED v5e chip.
+
+No chip is attached to the test machine, but the TPU compiler is
+installed and compiles for a topology that is described, not present
+(``jax.experimental.topologies``). That catches what interpret mode and
+the CPU backend cannot: a Pallas kernel Mosaic will not lower, a kernel
+over its scoped-VMEM limit, a program that does not fit HBM. Nothing
+runs, so these say nothing about results or times — ``chip_smoke.py`` on
+a real chip does.
+
+Shapes are the ones full crs-lite produces: the banks of the engine
+built on ``ftw/rules/crs-lite`` at the smoke's sidecar window (32 unique
+rows x 512) and at the bench scale (4096 rows x the 2048 Pallas width
+cap), the promotion canary's whole per-tier matcher (16 x 32), and the
+post stage.
+
+Dispatch in ``ops/`` asks ``jax.default_backend()`` — which is the CPU
+here — so the module fixture answers "tpu" for the duration of this
+file: the banks are built with their on-chip dtypes and the dispatchers
+take their on-chip branch. Everything that touches the TPU library
+happens inside fixtures of THIS file (never at import, never in
+conftest): only the xdist worker that is handed this file loads it.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+ROWS_WINDOW, WIDTH_WINDOW = 32, 512  # the chip smoke's one sidecar window
+ROWS_BENCH, WIDTH_MAX = 4096, 2048  # bench batch x the Pallas width cap
+ROWS_CANARY, WIDTH_CANARY = 16, 32  # engine/waf.py:warmup_request's window
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def on_chip(one_chip):
+    """Steer backend-dependent dispatch to its on-chip branch and keep
+    the persistent compile cache out of it (an executable compiled for a
+    described chip is written but cannot be read back without one)."""
+    from jax._src import compilation_cache as cc
+
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+    mp.undo()
+
+
+@pytest.fixture(scope="module")
+def crs_lite(on_chip):
+    from coraza_kubernetes_operator_tpu.engine.waf import WafEngine
+    from coraza_kubernetes_operator_tpu.ftw.corpus import load_ruleset_text
+
+    return WafEngine(load_ruleset_text())
+
+
+@pytest.fixture(scope="module")
+def operand(one_chip):
+    """``operand(shape, dtype)`` -> an array of that shape on the chip."""
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.fixture(scope="module")
+def described(operand):
+    """``described(tree)`` -> the same pytree as shapes on the chip."""
+    return lambda tree: jax.tree_util.tree_map(
+        lambda x: operand(np.shape(x), np.result_type(x)), tree
+    )
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _widest(banks):
+    return max(banks, key=lambda b: b.n_states * b.n_groups)
+
+
+# (kernel family, rows, width): each bank family of the default crs-lite
+# plan at the sidecar window and at bench scale. The dispatchers must
+# pick the Pallas kernel (one tpu_custom_call), never the XLA fallback.
+KERNEL_CASES = [
+    ("flat", ROWS_WINDOW, WIDTH_WINDOW),
+    ("flat", ROWS_BENCH, WIDTH_MAX),
+    ("prefilter", ROWS_BENCH, WIDTH_MAX),
+    ("gather", ROWS_WINDOW, WIDTH_WINDOW),
+    ("gather", ROWS_BENCH, WIDTH_MAX),
+]
+
+
+@pytest.mark.parametrize("family,rows,width", KERNEL_CASES)
+def test_pallas_kernel_compiles_for_v5e(crs_lite, described, operand, family, rows, width):
+    from coraza_kubernetes_operator_tpu.ops.dfa import scan_dfa_bank
+    from coraza_kubernetes_operator_tpu.ops.dfa_flat import scan_flat_bank
+    from coraza_kubernetes_operator_tpu.ops.dfa_gather import scan_gather_bank
+
+    model = crs_lite.model
+    data = operand((rows, width), jnp.uint8)
+    lengths = operand((rows,), jnp.int32)
+    if family == "flat":
+        # ops/dfa_flat.py:_scan_flat_pallas — every generic DFA bank of
+        # crs-lite rides one fused flat bin.
+        assert model.flat_banks and len(model.flat_covered) == len(model.banks)
+        bank = model.flat_banks[0]
+        pipes = sorted(set(bank.seg_pipes))
+        text = _compile(
+            lambda b, d, n: scan_flat_bank(b, {p: (d, n) for p in pipes}),
+            described(bank), data, lengths,
+        )
+    elif family == "prefilter":
+        # ops/dfa_pallas.py:scan_dfa_bank_pallas — the approximate
+        # prefilter banks are the dense banks left outside the flat bin.
+        text = _compile(scan_dfa_bank, described(_widest(model.pre_banks)), data, lengths)
+    else:
+        # ops/dfa_gather_pallas.py:scan_gather_bank_pallas — dfa-hot tier.
+        text = _compile(scan_gather_bank, described(_widest(model.gather_banks)), data, lengths)
+    assert text.count("tpu_custom_call") == 1, "dispatch fell back off the Pallas kernel"
+
+
+def test_post_stage_compiles_for_v5e(crs_lite, described, operand):
+    from coraza_kubernetes_operator_tpu.models.waf_model import eval_post_tiered
+
+    model = crs_lite.model
+    packed = (int(model.e_lg.shape[0]) + 7) // 8
+    n_vars = crs_lite.compiled.numvars.n_vars
+    pair = operand((512,), jnp.int32)
+    text = (
+        eval_post_tiered.lower(
+            described(model),
+            (operand((ROWS_WINDOW, packed), jnp.uint8),),
+            ((pair,) * 5,),
+            operand((16, n_vars), jnp.int32),
+            max_phase=2,
+            cached=(operand((256, packed), jnp.uint8),),
+        )
+        .compile()
+        .as_text()
+    )
+    assert "HloModule" in text
+
+
+def test_canary_matcher_compiles_for_v5e(crs_lite, described, operand):
+    """The whole per-tier matcher executable — transforms, the XLA conv
+    tier of ops/segment.py, and every Pallas bank in one program — at
+    the promotion canary's shape, which every cold sidecar compiles
+    before it may serve from the device."""
+    from coraza_kubernetes_operator_tpu.models.waf_model import match_tier_packed
+
+    model = crs_lite.model
+    h = max(1, len(crs_lite._host_pipelines))
+    u, width = ROWS_CANARY, WIDTH_CANARY
+    compiled = match_tier_packed.lower(
+        described(model),
+        operand((u, width), jnp.uint8),
+        operand((u,), jnp.int32),
+        operand((h, u, width), jnp.uint8),
+        operand((h, u), jnp.int32),
+        mask=None,
+    ).compile()
+    n_pallas = (
+        len(model.flat_banks) + len(model.pre_banks) + len(model.gather_banks)
+    )
+    assert compiled.as_text().count("tpu_custom_call") == n_pallas
+    # It has to fit beside the model's tables in one v5e's 16 GB.
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
