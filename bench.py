@@ -86,8 +86,7 @@ def _automata_breakdown(eng) -> dict:
     produced, and the prefilter's runtime economics — hit rate (how often
     the approximate automata fired per examined row-column) and confirm
     rate (how many of those the exact DFA upheld; the complement is the
-    over-approximation cost). With CKO_TIER_TIMING=1 the engine also
-    records per-stage p50 wall ms (match:<shape> / post)."""
+    over-approximation cost)."""
     summary = eng.automata_summary()
     tiers = summary.get("tiers", {})
     pf = summary.get("prefilter", {})
@@ -109,10 +108,6 @@ def _automata_breakdown(eng) -> dict:
             round(int(pf.get("confirms", 0)) / hits, 4) if hits else None
         ),
     }
-    if "tier_p50_ms" in summary:
-        out["tier_p50_ms"] = {
-            k: round(v, 3) for k, v in summary["tier_p50_ms"].items()
-        }
     return out
 
 

@@ -15,6 +15,7 @@ import os
 import signal
 import sys
 import threading
+from pathlib import Path
 
 from ..sidecar.batcher import DEFAULT_MAX_BATCH_DELAY_MS, DEFAULT_MAX_BATCH_SIZE
 from ..sidecar.reloader import DEFAULT_POLL_INTERVAL_S
@@ -318,7 +319,17 @@ def build_config(argv: list[str] | None = None) -> SidecarConfig:
         " delays, pipeline depth and queue budgets stay at their static"
         " configured values",
     )
+    p.add_argument(
+        "--metrics-auth-token-file",
+        default="",
+        help="file holding the bearer token that /waf/v1/metrics and"
+        " /waf/v1/profile require (a mounted secret); without it metrics"
+        " are open and the profiler endpoint answers 403",
+    )
     args = p.parse_args(argv)
+    metrics_auth_token = None
+    if args.metrics_auth_token_file:
+        metrics_auth_token = Path(args.metrics_auth_token_file).read_text().strip()
 
     # Wire the persistent compile cache BEFORE any engine compiles: a
     # restart of this sidecar (or any sibling pointed at the same dir)
@@ -374,6 +385,7 @@ def build_config(argv: list[str] | None = None) -> SidecarConfig:
         tenant_weights=args.tenant_weights,
         lane_delay_ms=args.lane_delay_ms,
         adaptive_enabled=not args.disable_adaptive,
+        metrics_auth_token=metrics_auth_token or None,
     )
 
 

@@ -20,6 +20,8 @@ import numpy as np
 from ..compiler.ruleset import CompiledRuleSet, compile_rules
 from ..compiler.transforms_host import apply_pipeline
 from ..models.waf_model import WafModel, build_model, eval_waf
+from ..observability.stages import HOST_STAGES
+from ..observability.stages import current as current_stages
 from ..utils import get_logger
 from .request import Extraction, HttpRequest, TargetExtractor
 
@@ -375,16 +377,17 @@ class InFlightBatch:
     # device verdicts are displaced here by the 413/phase-1 outcome,
     # matching prepare()'s row-exclusion semantics bit for bit).
     overrides: dict[int, Verdict] | None = None
-    # Stage timings (observability + bench): host_s is filled by prepare
-    # (extract + tensorize + tier + dispatch enqueue); device_s/decode_s
-    # by collect (readback block / verdict decode).
+    # The window's stage record (observability/stages.py): prepare
+    # stamps assemble … post_enqueue on it, collect readback_wait and
+    # decode. The four sums below are read off those stamps for this
+    # batch alone: host_s = assemble … post_enqueue (it holds the
+    # prefilter's device wait), device_s = readback_wait, decode_s =
+    # decode, assemble_s = assemble (blob -> dispatch-ready tensors: what
+    # ingest_smoke compares across native paths).
+    stages: object = None
     host_s: float = 0.0
     device_s: float = 0.0
     decode_s: float = 0.0
-    # Host assemble segment alone (blob -> dispatch-ready tensors, before
-    # the dispatch enqueue) — the tiered-pipeline gate in ingest_smoke
-    # compares THIS across native paths; host_s would dilute the ratio
-    # with enqueue/compile-check costs common to both.
     assemble_s: float = 0.0
     # Staging-arena lease backing this window's tier tensors (tiered
     # native path only). collect() releases it after device_get — the
@@ -560,12 +563,6 @@ class WafEngine:
             "false_positives": 0,
         }
         self._prefilter_lock = threading.Lock()
-        # Per-tier stage timing (CKO_TIER_TIMING=1): label -> recent wall
-        # seconds per dispatch (device sync per stage — costs pipelining,
-        # so it is bench/debug-only). bench.py turns these into per-tier
-        # p50s; /waf/v1/stats exposes them under the automata block.
-        self._tier_timing_on = _os.environ.get("CKO_TIER_TIMING", "0") == "1"
-        self.tier_timing: dict[str, list[float]] = {}
         # Stamp the automata composition onto the matcher stage label at
         # tier-selection time: tier stats / bench can then report what
         # the compiled matchers actually contain, not just their shapes.
@@ -731,8 +728,47 @@ class WafEngine:
         executable runs on device, the caller (``sidecar/batcher.py``)
         assembles and dispatches the next window — host CPU work and
         device compute overlap instead of strictly alternating."""
+        rec = current_stages()
+        return self._prepare(rec, len(rec.spans), lambda: requests)
 
-        t0 = time.perf_counter()
+    def _prepare(self, rec, since: int, requests_fn) -> InFlightBatch:
+        """``prepare`` on the window's stage record; ``requests_fn``
+        yields the requests inside ``assemble`` (a blob without the
+        native library materializes there)."""
+        with rec.stage("assemble"):
+            requests = requests_fn()
+            batch = self._assemble(requests)
+        if isinstance(batch, InFlightBatch):  # nothing left for the device
+            return batch
+        rejected, n_live, tensors = batch
+        inflight = self._enqueue(rec, since, n_live, tensors)
+        inflight.n_requests = len(requests)
+        inflight.rejected = rejected
+        return inflight
+
+    def _enqueue(self, rec, since: int, n_live: int, tensors) -> InFlightBatch:
+        """The device half shared by ``prepare`` and ``prepare_blob``:
+        enqueue the assembled batch, hand it its staging lease, and read
+        its host-stage sums off the record (spans from ``since`` on)."""
+        tiers, numvals, masks, cached, mkeys, lease = tensors
+        try:
+            inflight = self._dispatch_tiers(
+                tiers, numvals, n_live, masks=masks, cached=cached,
+                miss_keys=mkeys, rec=rec,
+            )
+        except BaseException:
+            if lease is not None:
+                lease.release()
+            raise
+        inflight.arena_lease = lease
+        inflight.assemble_s = rec.total(("assemble",), since)
+        inflight.host_s = rec.total(HOST_STAGES, since)
+        return inflight
+
+    def _assemble(self, requests: list[HttpRequest]):
+        """``prepare``'s host half: the body-limit pre-pass and the
+        tensorizer. Returns ``(rejected, n_live, batch tensors)``, or the
+        finished InFlightBatch where every request was rejected."""
         prog = self.compiled.program
         rejected: dict[int, Verdict] = {}
         if (
@@ -780,24 +816,8 @@ class WafEngine:
                 rejected=rejected,
                 miss_keys=None,
                 cache_pop=False,
-                host_s=time.perf_counter() - t0,
             )
-        tiers, numvals, masks, cached, mkeys, lease = self._batch_tensors(live)
-        t_assemble = time.perf_counter() - t0
-        try:
-            inflight = self._dispatch_tiers(
-                tiers, numvals, len(live), masks=masks, cached=cached, miss_keys=mkeys
-            )
-        except BaseException:
-            if lease is not None:
-                lease.release()
-            raise
-        inflight.arena_lease = lease
-        inflight.assemble_s = t_assemble
-        inflight.n_requests = len(requests)
-        inflight.rejected = rejected
-        inflight.host_s = time.perf_counter() - t0
-        return inflight
+        return rejected, len(live), self._batch_tensors(live)
 
     def prepare_blob(self, blob: bytes, n_req: int) -> InFlightBatch:
         """``prepare`` for a pre-assembled request blob (the
@@ -813,10 +833,22 @@ class WafEngine:
         only those few requests materialize for the batched phase-1
         pre-pass; the resulting 413/phase-1 verdicts land as collect
         overrides, displacing the over-limit rows' device verdicts."""
+        rec = current_stages()
+        since = len(rec.spans)
         if not self._native.available:
             from ..native import blob_requests
 
-            return self.prepare(blob_requests(blob, n_req))
+            return self._prepare(rec, since, lambda: blob_requests(blob, n_req))
+        with rec.stage("assemble"):
+            overrides, tensors = self._assemble_blob(blob, n_req)
+        inflight = self._enqueue(rec, since, n_req, tensors)
+        inflight.overrides = overrides or None
+        self._record_assemble(inflight.assemble_s)
+        return inflight
+
+    def _assemble_blob(self, blob: bytes, n_req: int):
+        """``prepare_blob``'s host half: the body-limit pre-pass and the
+        native tensorizer. Returns ``(overrides, batch tensors)``."""
         from ..testing.faults import DeviceFault, poison_marker
 
         marker = poison_marker()
@@ -824,7 +856,6 @@ class WafEngine:
             raise DeviceFault(
                 "injected poison request (CKO_FAULT_POISON_MARKER)"
             )
-        t0 = time.perf_counter()
         prog = self.compiled.program
         overrides: dict[int, Verdict] = {}
         if (
@@ -864,21 +895,7 @@ class WafEngine:
         else:
             tensors = self._native.tensorize_blob(blob, n_req)
             tiers, numvals, masks, cached, mkeys = self.tier_cached(tensors)
-        t_assemble = time.perf_counter() - t0
-        self._record_assemble(t_assemble)
-        try:
-            inflight = self._dispatch_tiers(
-                tiers, numvals, n_req, masks=masks, cached=cached, miss_keys=mkeys
-            )
-        except BaseException:
-            if lease is not None:
-                lease.release()
-            raise
-        inflight.arena_lease = lease
-        inflight.assemble_s = t_assemble
-        inflight.overrides = overrides or None
-        inflight.host_s = time.perf_counter() - t0
-        return inflight
+        return overrides, (tiers, numvals, masks, cached, mkeys, lease)
 
     def collect(self, inflight: InFlightBatch) -> list[Verdict]:
         """Stage 2 of the pipelined hot path: block on the device
@@ -907,31 +924,34 @@ class WafEngine:
         hang = injected_device_hang_s()
         if hang > 0:
             time.sleep(hang)
-        t0 = time.perf_counter()
         from .compile_cache import EXEC_CACHE
 
         EXEC_CACHE.note_window(
             inflight.out[0] if inflight.cache_pop else inflight.out,
             inflight.device,
         )
-        if inflight.cache_pop:
-            packed, tier_hits = jax.device_get(inflight.out)
-            if self.value_cache is not None and inflight.miss_keys is not None:
-                for keys, hp in zip(inflight.miss_keys, tier_hits):
-                    if keys:
-                        self.value_cache.insert(keys, hp[: len(keys)])
-        else:
-            packed = jax.device_get(inflight.out)
+        rec = inflight.stages  # the record its prepare stamped on
+        since = len(rec.spans)
+        with rec.stage("readback_wait"):
+            out = jax.device_get(inflight.out)
+        with rec.stage("decode"):
+            if inflight.cache_pop:
+                packed, tier_hits = out
+                if self.value_cache is not None and inflight.miss_keys is not None:
+                    for keys, hp in zip(inflight.miss_keys, tier_hits):
+                        if keys:
+                            self.value_cache.insert(keys, hp[: len(keys)])
+            else:
+                packed = out
+            verdicts = self._decode_packed(packed, inflight.n_live)
+            if inflight.overrides:
+                for i, v in inflight.overrides.items():
+                    if 0 <= i < len(verdicts):
+                        verdicts[i] = v
         if inflight.device:
             self.warmed = True
-        t1 = time.perf_counter()
-        inflight.device_s = t1 - t0
-        verdicts = self._decode_packed(packed, inflight.n_live)
-        inflight.decode_s = time.perf_counter() - t1
-        if inflight.overrides:
-            for i, v in inflight.overrides.items():
-                if 0 <= i < len(verdicts):
-                    verdicts[i] = v
+        inflight.device_s = rec.total(("readback_wait",), since)
+        inflight.decode_s = rec.total(("decode",), since)
         if not inflight.rejected:
             return verdicts
         out: list[Verdict] = []
@@ -989,7 +1009,7 @@ class WafEngine:
         shapes/dtypes enter the executable-cache key and the lowered
         program, so warming with zeros mints exactly the executable the
         real dispatch calls with live matcher output."""
-        from ..models.waf_model import eval_post_tiered, match_tier_packed
+        from ..models.waf_model import stage_executable
 
         if masks is None:
             masks = (None,) * len(tiers)
@@ -1003,7 +1023,7 @@ class WafEngine:
                 (
                     f"match:{u}x{length}",
                     float(u) * float(length),
-                    match_tier_packed,
+                    stage_executable("match", f"{u}x{length}"),
                     (self.model, t[0], t[1], t[6], t[7]),
                     {"mask": mask},
                     {},
@@ -1017,7 +1037,9 @@ class WafEngine:
         post_spec = (
             "post",
             0.0,  # sorts first: every verdict needs the post stage
-            eval_post_tiered,
+            stage_executable(
+                "eval_post", "_".join(f"{t[0].shape[0]}x{t[0].shape[1]}" for t in tiers)
+            ),
             (self.model, ph_hits, pairs, numvals),
             {"max_phase": max_phase},
             {"cached": cached},
@@ -1033,6 +1055,7 @@ class WafEngine:
         masks=None,
         cached=None,
         miss_keys=None,
+        rec=None,
     ) -> InFlightBatch:
         """Enqueue one tiered batch (no host sync on the device path)
         and return the in-flight handle. The single dispatch site shared
@@ -1051,70 +1074,74 @@ class WafEngine:
         paths are bit-identical: packbits over the group-hit columns is
         lossless and the host twins are differential-tested against the
         device stages."""
-        from ..models.waf_model import eval_post_tiered
         from ..testing.faults import on_device_dispatch
         from .compile_cache import EXEC_CACHE
         from .tier_compile import TIER_COMPILER, spec_key
 
-        # Fault-injection hook (no-op when the CKO_FAULT_* knobs are
-        # unset): stalls cold engines like a real first XLA compile and
-        # raises DeviceFault per the configured error rate — the levers
-        # tests/test_degraded_mode.py uses to prove the fallback +
-        # breaker invariants.
-        on_device_dispatch(warmed=self.warmed)
-        if masks is None:
-            masks = (None,) * len(tiers)
-        match_specs, post_spec, pairs = self._tier_specs(
-            tiers, numvals, max_phase=max_phase, masks=masks, cached=cached
-        )
-        specs = match_specs + [post_spec]
-        for s in specs:
-            self._exec_signatures.add(spec_key(s))
-        self.compiled.report.exec_signatures = len(self._exec_signatures)
-        if self._lazy:
-            # Non-blocking: enqueue every missing executable NOW, in
-            # ascending cost order, so the pool mints the smallest tier
-            # (and the post stage) first — first-verdict-from-device
-            # latency is gated on the smallest group's compile.
-            for s in sorted(specs, key=lambda s: s[1]):
-                TIER_COMPILER.ensure(s)
-        else:
-            TIER_COMPILER.compile_all(specs)
-        device = True
-        tier_hits = []
-        from_device = []
-        for spec, tier, mask in zip(match_specs, tiers, masks):
-            if not self._lazy or TIER_COMPILER.resident(spec):
-                label, _cost, fn, fargs, statics, dyn = spec
-                tier_hits.append(self._timed_call(label, fn, fargs, statics, dyn))
-                from_device.append(True)
+        if rec is None:
+            rec = current_stages()
+        with rec.stage("tier_enqueue"):
+            # Fault-injection hook (no-op when the CKO_FAULT_* knobs are
+            # unset): stalls cold engines like a real first XLA compile
+            # and raises DeviceFault per the configured error rate — the
+            # levers tests/test_degraded_mode.py uses to prove the
+            # fallback + breaker invariants.
+            on_device_dispatch(warmed=self.warmed)
+            if masks is None:
+                masks = (None,) * len(tiers)
+            match_specs, post_spec, pairs = self._tier_specs(
+                tiers, numvals, max_phase=max_phase, masks=masks, cached=cached
+            )
+            specs = match_specs + [post_spec]
+            for s in specs:
+                self._exec_signatures.add(spec_key(s))
+            self.compiled.report.exec_signatures = len(self._exec_signatures)
+            if self._lazy:
+                # Non-blocking: enqueue every missing executable NOW, in
+                # ascending cost order, so the pool mints the smallest
+                # tier (and the post stage) first — first-verdict-from-
+                # device latency is gated on the smallest group's compile.
+                for s in sorted(specs, key=lambda s: s[1]):
+                    TIER_COMPILER.ensure(s)
             else:
-                device = False
-                tier_hits.append(self._host_tier_hits(tier, mask))
-                from_device.append(False)
-        tier_hits = tuple(tier_hits)
+                TIER_COMPILER.compile_all(specs)
+            device = True
+            tier_hits = []
+            from_device = []
+            for spec, tier, mask in zip(match_specs, tiers, masks):
+                if not self._lazy or TIER_COMPILER.resident(spec):
+                    _label, _cost, fn, fargs, statics, dyn = spec
+                    tier_hits.append(EXEC_CACHE.call(fn, fargs, statics, dyn))
+                    from_device.append(True)
+                else:
+                    device = False
+                    tier_hits.append(self._host_tier_hits(tier, mask))
+                    from_device.append(False)
+            tier_hits = tuple(tier_hits)
         # Prefilter confirm (two-level automata): device matcher rows for
         # prefiltered groups are OVER-approximate — re-check positives
         # against the exact DFAs and clear the false ones before anything
         # downstream (post stage, value-cache insert, host post) reads
         # the bits. Host-twin rows are already exact and are skipped.
         if self.model.prefilter_cols:
-            tier_hits = self._confirm_prefilter(tier_hits, tiers, from_device)
+            tier_hits = self._confirm_prefilter(tier_hits, tiers, from_device, rec)
         # The post stage takes packed hit rows from EITHER provenance —
         # device matcher output or host-computed numpy — at identical
         # shapes/bit layout, so a mixed window still shares the one post
         # executable.
-        if not self._lazy or TIER_COMPILER.resident(post_spec):
-            packed = self._timed_call(
-                "post",
-                eval_post_tiered,
-                (self.model, tier_hits, pairs, numvals),
-                {"max_phase": max_phase},
-                {"cached": cached},
-            )
-        else:
-            device = False
-            packed = self._host_post(tier_hits, pairs, numvals, max_phase, cached)
+        with rec.stage("post_enqueue"):
+            if not self._lazy or TIER_COMPILER.resident(post_spec):
+                packed = EXEC_CACHE.call(
+                    post_spec[2],
+                    (self.model, tier_hits, pairs, numvals),
+                    {"max_phase": max_phase},
+                    {"cached": cached},
+                )
+            else:
+                device = False
+                packed = self._host_post(
+                    tier_hits, pairs, numvals, max_phase, cached
+                )
         return InFlightBatch(
             out=(packed, tier_hits) if cached is not None else packed,
             n_live=n_requests,
@@ -1123,29 +1150,10 @@ class WafEngine:
             miss_keys=miss_keys,
             cache_pop=cached is not None,
             device=device,
+            stages=rec,
         )
 
-    def _timed_call(self, label: str, fn, fargs, statics, dyn):
-        """EXEC_CACHE.call, optionally wall-timed per stage label when
-        CKO_TIER_TIMING=1. Timing blocks on device completion per stage
-        (costs pipelining), so it is a bench/debug knob, never the
-        serving default."""
-        from .compile_cache import EXEC_CACHE
-
-        if not self._tier_timing_on:
-            return EXEC_CACHE.call(fn, fargs, statics, dyn)
-        t0 = time.perf_counter()
-        out = EXEC_CACHE.call(fn, fargs, statics, dyn)
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-        with self._prefilter_lock:
-            buf = self.tier_timing.setdefault(label, [])
-            buf.append(dt)
-            if len(buf) > 512:
-                del buf[: len(buf) - 512]
-        return out
-
-    def _confirm_prefilter(self, tier_hits, tiers, from_device):
+    def _confirm_prefilter(self, tier_hits, tiers, from_device, rec):
         """Confirm device prefilter positives against the exact DFAs.
 
         The pre-bank columns (``model.prefilter_cols``: (device column,
@@ -1160,7 +1168,11 @@ class WafEngine:
         cached replays skip both the matcher and the confirm.
 
         Host-twin entries (``from_device`` False) computed exact hits
-        already and pass through untouched."""
+        already and pass through untouched.
+
+        Two stages of the window's record, per tier: ``prefilter_wait``
+        is the host blocked on the matcher's output, ``prefilter_confirm``
+        the unpack, the host transforms, the exact walk and the repack."""
         g = int(self.model.e_lg.shape[0])
         cols = self.model.prefilter_cols
         n_rows = n_hits = n_confirms = 0
@@ -1168,42 +1180,44 @@ class WafEngine:
         for ti, (hp, tier, dev) in enumerate(zip(tier_hits, tiers, from_device)):
             if not dev:
                 continue
-            packed = np.asarray(jax.device_get(hp))
-            hits = np.unpackbits(packed, axis=1, count=g).astype(bool)
-            n_rows += hits.shape[0] * len(cols)
-            d = lg = vd = vl = None
-            val_cache: dict[tuple[int, int], bytes] = {}
-            changed = False
-            for col, gid in cols:
-                rows = np.flatnonzero(hits[:, col])
-                if rows.size == 0:
-                    continue
-                n_hits += int(rows.size)
-                if d is None:
-                    d = np.asarray(tier[0])
-                    lg = np.asarray(tier[1])
-                    vd = np.asarray(tier[6])
-                    vl = np.asarray(tier[7])
-                pid = self.compiled.group_pipeline[gid]
-                slot = int(self.model.host_variant_index[pid])
-                dfa = self.compiled.groups[gid].dfa
-                for i in rows:
-                    i = int(i)
-                    val = val_cache.get((pid, i))
-                    if val is None:
-                        if slot >= 0:
-                            val = vd[slot, i, : vl[slot, i]].tobytes()
+            with rec.stage("prefilter_wait"):
+                packed = np.asarray(jax.device_get(hp))
+            with rec.stage("prefilter_confirm"):
+                hits = np.unpackbits(packed, axis=1, count=g).astype(bool)
+                n_rows += hits.shape[0] * len(cols)
+                d = lg = vd = vl = None
+                val_cache: dict[tuple[int, int], bytes] = {}
+                changed = False
+                for col, gid in cols:
+                    rows = np.flatnonzero(hits[:, col])
+                    if rows.size == 0:
+                        continue
+                    n_hits += int(rows.size)
+                    if d is None:
+                        d = np.asarray(tier[0])
+                        lg = np.asarray(tier[1])
+                        vd = np.asarray(tier[6])
+                        vl = np.asarray(tier[7])
+                    pid = self.compiled.group_pipeline[gid]
+                    slot = int(self.model.host_variant_index[pid])
+                    dfa = self.compiled.groups[gid].dfa
+                    for i in rows:
+                        i = int(i)
+                        val = val_cache.get((pid, i))
+                        if val is None:
+                            if slot >= 0:
+                                val = vd[slot, i, : vl[slot, i]].tobytes()
+                            else:
+                                names = list(self.compiled.pipelines[pid])
+                                val = apply_pipeline(d[i, : lg[i]].tobytes(), names)
+                            val_cache[(pid, i)] = val
+                        if dfa.search(val):
+                            n_confirms += 1
                         else:
-                            names = list(self.compiled.pipelines[pid])
-                            val = apply_pipeline(d[i, : lg[i]].tobytes(), names)
-                        val_cache[(pid, i)] = val
-                    if dfa.search(val):
-                        n_confirms += 1
-                    else:
-                        hits[i, col] = False
-                        changed = True
-            if changed:
-                out[ti] = np.packbits(hits, axis=1)
+                            hits[i, col] = False
+                            changed = True
+                if changed:
+                    out[ti] = np.packbits(hits, axis=1)
         if n_rows:
             with self._prefilter_lock:
                 self.prefilter_stats["rows"] += n_rows
@@ -1221,21 +1235,13 @@ class WafEngine:
         counts = plan.counts()
         with self._prefilter_lock:
             pstats = dict(self.prefilter_stats)
-            timing = {
-                label: sorted(buf)[len(buf) // 2] * 1000.0
-                for label, buf in self.tier_timing.items()
-                if buf
-            }
-        summary = {
+        return {
             "enabled": plan.enabled,
             "tiers": counts,
             "gather_banks": len(self.model.gather_banks),
             "pre_banks": len(self.model.pre_banks),
             "prefilter": pstats,
         }
-        if timing:
-            summary["tier_p50_ms"] = timing
-        return summary
 
     # -- host twins for not-yet-compiled stages (lazy tier compilation) ------
 

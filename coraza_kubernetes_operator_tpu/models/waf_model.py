@@ -879,11 +879,11 @@ def match_tier(
     if model.flat_banks:
         from ..ops.dfa_flat import scan_flat_bank
 
-        for fb in model.flat_banks:
+        for fi, fb in enumerate(model.flat_banks):
             if not any(block_on(p[0]) for p in fb.pieces):
                 continue
             sub = {p: transformed_for(p) for p in sorted(set(fb.seg_pipes))}
-            out = scan_flat_bank(fb, sub)
+            out = scan_flat_bank(fb, sub, name=f"cko_flat_bin{fi}")
             col = 0
             for blk, g_lo, g_hi in fb.pieces:
                 w = g_hi - g_lo
@@ -903,7 +903,7 @@ def match_tier(
             )
             continue
         tdata, tlen = transformed_for(pid)
-        per_block.append(scan_dfa_bank(bank, tdata, tlen))
+        per_block.append(scan_dfa_bank(bank, tdata, tlen, name=f"cko_dfa_bank{bi}"))
     # Two-level automata blocks (after the generic banks in the global
     # column order): DFA hot-tier gather banks, then the approximate
     # prefilter banks (whose columns the engine confirms on the host).
@@ -919,13 +919,17 @@ def match_tier(
                     jnp.zeros((data.shape[0], gb.n_groups), dtype=bool)
                 )
                 continue
-            per_block.append(scan_gather_bank(gb, *transformed_for(pid)))
+            per_block.append(
+                scan_gather_bank(gb, *transformed_for(pid), name=f"cko_gather_bank{gi}")
+            )
     n_gather = len(model.gather_banks)
     for pi, (pb, pid) in enumerate(zip(model.pre_banks, model.pre_bank_pipelines)):
         if not block_on(n_segs + n_banks + n_gather + pi):
             per_block.append(jnp.zeros((data.shape[0], pb.n_groups), dtype=bool))
             continue
-        per_block.append(scan_dfa_bank(pb, *transformed_for(pid)))
+        per_block.append(
+            scan_dfa_bank(pb, *transformed_for(pid), name=f"cko_prefilter_bank{pi}")
+        )
     if per_block:
         return jnp.concatenate(per_block, axis=1)  # [T, G]
     return jnp.zeros((data.shape[0], 1), dtype=bool)
@@ -1361,3 +1365,37 @@ def matched_id_lists(
     ids = rule_ids[rule_idx]
     splits = np.searchsorted(req_idx, np.arange(1, n_requests))
     return [a.tolist() for a in np.split(ids, splits)]
+
+
+# The split stages under module names a trace reduction can find after
+# the rule set changes: role and window shape, e.g.
+# ``jit_cko_match_32x512`` / ``jit_cko_eval_post_32x512`` (one shape per
+# tier, joined by ``_``), where jit would name every shape
+# ``jit_match_tier_packed``. ``eval_post`` names the post stage alone
+# (wafbench/layer_metrics/matcher_device_ms_per_window.py finds it by
+# that). The name is in the persistent compile cache's key.
+_STAGE_FNS = {
+    "match": (match_tier_packed, ("mask",)),
+    "eval_post": (eval_post_tiered, ("max_phase",)),
+}
+_stage_executables: dict[tuple[str, str], object] = {}
+
+
+def stage_executable(role: str, shape: str):
+    """``match_tier_packed`` / ``eval_post_tiered`` jitted under the
+    module name ``cko_<role>_<shape>``; one object per name, so the
+    executable cache keys on it."""
+    fn = _stage_executables.get((role, shape))
+    if fn is None:
+        jitted, static = _STAGE_FNS[role]
+        inner = jitted.__wrapped__
+
+        def stage(*args, **kwargs):
+            return inner(*args, **kwargs)
+
+        stage.__name__ = stage.__qualname__ = f"cko_{role}_{shape}"
+        # setdefault: two lanes may race the first window of a shape.
+        fn = _stage_executables.setdefault(
+            (role, shape), jax.jit(stage, static_argnames=static)
+        )
+    return fn

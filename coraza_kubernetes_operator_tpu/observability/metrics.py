@@ -151,17 +151,45 @@ class Histogram(_Metric):
         # wins per bucket — an exemplar is a pointer, not a log.
         self._exemplars: dict[tuple[tuple[str, ...], int], tuple[str, float, float]] = {}
 
-    def observe(self, value: float, exemplar: str | None = None, **labels) -> None:
-        key = tuple(str(labels.get(k, "")) for k in self.label_names)
+    def observe(
+        self, value: float, exemplar: str | None = None, n: int = 1, **labels
+    ) -> None:
+        """Record ``n`` samples of ``value`` (n > 1: a group of equal
+        samples taken on one stamp, e.g. the requests one read delivered)."""
+        self.observe_key(
+            tuple(str(labels.get(k, "")) for k in self.label_names), value, n, exemplar
+        )
+
+    def observe_key(
+        self, key: tuple[str, ...], value: float, n: int = 1, exemplar: str | None = None
+    ) -> None:
+        """``observe`` for a caller that holds the label values already,
+        in ``label_names`` order."""
         idx = bisect_left(self.buckets, value)
         with self._lock:
-            counts = self._counts.setdefault(key, [0] * len(self.buckets))
+            counts = self._counts.get(key)
+            if counts is None:
+                counts = self._counts[key] = [0] * len(self.buckets)
             if idx < len(counts):
-                counts[idx] += 1
-            self._sums[key] = self._sums.get(key, 0.0) + value
-            self._totals[key] = self._totals.get(key, 0) + 1
+                counts[idx] += n
+            self._sums[key] = self._sums.get(key, 0.0) + value * n
+            self._totals[key] = self._totals.get(key, 0) + n
             if exemplar:
                 self._exemplars[(key, idx)] = (exemplar, value, time.time())
+
+    def snapshot(self) -> dict[tuple[str, ...], dict]:
+        """Label values -> ``{"count", "sum_s", "buckets"}``; ``buckets``
+        holds each bucket's own count, the overflow last."""
+        with self._lock:
+            return {
+                key: {
+                    "count": total,
+                    "sum_s": self._sums[key],
+                    "buckets": self._counts[key]
+                    + [total - sum(self._counts[key])],
+                }
+                for key, total in self._totals.items()
+            }
 
     @staticmethod
     def _exemplar_suffix(ex: tuple[str, float, float] | None) -> str:

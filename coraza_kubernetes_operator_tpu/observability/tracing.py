@@ -154,6 +154,7 @@ class SpanContext:
         "t_accept",
         "t_submit",
         "committed",
+        "window",
     )
 
     def __init__(
@@ -175,6 +176,11 @@ class SpanContext:
         self.t_accept = time.monotonic() if t_accept is None else t_accept
         self.t_submit = 0.0
         self.committed = False
+        # (window_id, [(stage, t0, t1), ...]): the stamps of the device
+        # window the request rode in (observability/stages.py), shared by
+        # every traced request of the window and expanded into
+        # ``cko.<stage>`` child events only at export.
+        self.window: tuple | None = None
 
     def event(
         self,
@@ -269,6 +275,7 @@ class TraceRecorder:
             "t_accept": ctx.t_accept,
             "t_end": t_end if t_end is not None else time.monotonic(),
             "events": list(ctx.events),
+            "window": ctx.window,
         }
         with self._lock:
             if len(self._ring) == self.capacity:
@@ -313,6 +320,20 @@ class TraceRecorder:
                 }
             )
         mono0 = self._mono0
+
+        def duration_event(name, t0, t1, track, args):
+            events.append(
+                {
+                    "name": name,
+                    "ph": "X",
+                    "ts": max(0.0, (t0 - mono0) * 1e6),
+                    "dur": max(0.0, (t1 - t0) * 1e6),
+                    "pid": 1,
+                    "tid": TRACKS.get(track, 1),
+                    "args": args,
+                }
+            )
+
         for rec in records:
             base_args = {
                 "trace_id": rec["trace_id"],
@@ -322,20 +343,14 @@ class TraceRecorder:
             if rec["parent_id"]:
                 base_args["parent_id"] = rec["parent_id"]
             for name, t0, t1, track, extra in rec["events"]:
-                args = dict(base_args)
-                if extra:
-                    args.update(extra)
-                events.append(
-                    {
-                        "name": name,
-                        "ph": "X",
-                        "ts": max(0.0, (t0 - mono0) * 1e6),
-                        "dur": max(0.0, (t1 - t0) * 1e6),
-                        "pid": 1,
-                        "tid": TRACKS.get(track, 1),
-                        "args": args,
-                    }
-                )
+                duration_event(name, t0, t1, track, {**base_args, **(extra or {})})
+            if rec.get("window"):
+                # The window's stages, as children inside the chain.
+                window_id, stamped = rec["window"]
+                args = dict(base_args, window_id=window_id, stage=True)
+                for stage, t0, t1 in stamped:
+                    track = "device" if stage in ("readback_wait", "decode") else "pipeline"
+                    duration_event(f"cko.{stage}", t0, t1, track, args)
         return {
             "traceEvents": events,
             "displayTimeUnit": "ms",

@@ -160,7 +160,7 @@ def _pallas_vmem_bytes(s: int, g: int, itemsize: int, length: int) -> int:
 
 
 def scan_dfa_bank(
-    bank: DFABank, data: jnp.ndarray, lengths: jnp.ndarray
+    bank: DFABank, data: jnp.ndarray, lengths: jnp.ndarray, name: str | None = None
 ) -> jnp.ndarray:
     """Scan ``data`` [B, L] uint8 (zero-padded past ``lengths`` [B]) against
     every DFA in the bank. Returns ``matched`` [B, G] bool.
@@ -189,6 +189,7 @@ def scan_dfa_bank(
             s=bank.n_states,
             g=bank.n_groups,
             block_b=_PALLAS_BLOCK_B,
+            name=name,
         )
     return scan_dfa_bank_take(bank, data, lengths)
 

@@ -497,8 +497,10 @@ def _flat_kernel(*refs, seg_pipes, seg_slots, pid_ix, n, gp_n, length, n_pipes):
     out_ref[:] = ((matched + end_hit) > 0).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _scan_flat_pallas(flat: FlatBank, dataT_list, lens_list, gp, interpret=False):
+@functools.partial(jax.jit, static_argnames=("interpret", "name"))
+def _scan_flat_pallas(
+    flat: FlatBank, dataT_list, lens_list, gp, interpret=False, name=None
+):
     from jax.experimental import pallas as pl
 
     n, gp_n = flat.n_slots, flat.n_groups_padded
@@ -538,6 +540,7 @@ def _scan_flat_pallas(flat: FlatBank, dataT_list, lens_list, gp, interpret=False
         out_specs=pl.BlockSpec((_BLOCK_B, gp_n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, gp_n), jnp.int32),
         interpret=interpret,
+        name=name,
     )(
         *dataT_list,
         *lens_list,
@@ -556,8 +559,10 @@ def scan_flat_bank(
     flat: FlatBank,
     data_by_pipe: dict[int, tuple[jnp.ndarray, jnp.ndarray]],
     interpret: bool | None = None,
+    name: str | None = None,
 ) -> jnp.ndarray:
-    """Fused scan of one bin. Returns matched [B, G_bin] bool.
+    """Fused scan of one bin. Returns matched [B, G_bin] bool. ``name``
+    is the Pallas kernel's name in a device trace.
 
     Pallas kernel on TPU; XLA scan elsewhere. ``interpret=True`` forces
     the kernel through the Pallas interpreter (CPU kernel-logic tests).
@@ -582,6 +587,6 @@ def scan_flat_bank(
         lens_list.append(jnp.pad(ln.astype(jnp.int32), (0, bp - b))[:, None])
     gp = jnp.asarray(_group_pipe_onehot(flat, pids))
     out = _scan_flat_pallas(
-        flat, tuple(dataT_list), tuple(lens_list), gp, interpret=interpret
+        flat, tuple(dataT_list), tuple(lens_list), gp, interpret=interpret, name=name
     )
     return (out[:b, : flat.n_groups] != 0) | flat.always[None, :]

@@ -179,7 +179,7 @@ def plan_gather_bins(dfas: list[DFA], length_hint: int = 512) -> list[list[int]]
 
 
 def scan_gather_bank(
-    bank: GatherBank, data: jnp.ndarray, lengths: jnp.ndarray
+    bank: GatherBank, data: jnp.ndarray, lengths: jnp.ndarray, name: str | None = None
 ) -> jnp.ndarray:
     """Scan ``data`` [B, L] uint8 (zero-padded past ``lengths`` [B])
     against every hot-tier DFA in the bank. Returns matched [B, G] bool.
@@ -217,6 +217,7 @@ def scan_gather_bank(
             g=bank.n_groups,
             c=bank.n_classes,
             block_b=_PALLAS_BLOCK_B,
+            name=name,
         )
     return scan_gather_bank_jnp(bank, data, lengths)
 

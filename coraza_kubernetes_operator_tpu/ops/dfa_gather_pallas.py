@@ -91,7 +91,7 @@ def _gather_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("s", "g", "c", "block_b", "interpret")
+    jax.jit, static_argnames=("s", "g", "c", "block_b", "interpret", "name")
 )
 def scan_gather_bank_pallas(
     tc: jnp.ndarray,  # [C, S*G] packed
@@ -106,9 +106,11 @@ def scan_gather_bank_pallas(
     c: int,
     block_b: int = 128,
     interpret: bool | None = None,
+    name: str | None = None,
 ) -> jnp.ndarray:
     """Hot-tier bank scan via the transition-gather kernel. Returns
-    matched [B, G] bool."""
+    matched [B, G] bool. ``name`` is the kernel's name in a device
+    trace (the caller's bank index; XLA's running counter otherwise)."""
     b, length = data.shape
     gp = _round_up(g, _LANE)
     cp = _round_up(c, _LANE)
@@ -143,5 +145,6 @@ def scan_gather_bank_pallas(
         out_specs=pl.BlockSpec((block_b, gp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, gp), jnp.int32),
         interpret=interpret,
+        name=name,
     )(dataT, lens, clsoh, t3, mend)
     return (out[:b, :g] != 0) | always[None, :]

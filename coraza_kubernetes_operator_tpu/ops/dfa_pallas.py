@@ -91,7 +91,9 @@ def _scan_kernel(dataT_ref, len_ref, t256_ref, mend_ref, out_ref, *, s, gp, leng
     out_ref[:] = matched | (end_hit > 0).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("s", "g", "block_b", "interpret"))
+@functools.partial(
+    jax.jit, static_argnames=("s", "g", "block_b", "interpret", "name")
+)
 def scan_dfa_bank_pallas(
     t256: jnp.ndarray,  # [256, S*G]
     match_end_t: jnp.ndarray,  # [S, G] bool
@@ -103,8 +105,11 @@ def scan_dfa_bank_pallas(
     g: int,
     block_b: int = 128,
     interpret: bool | None = None,
+    name: str | None = None,
 ) -> jnp.ndarray:
-    """Bank scan via the Pallas kernel. Returns matched [B, G] bool."""
+    """Bank scan via the Pallas kernel. Returns matched [B, G] bool.
+    ``name`` is the kernel's name in a device trace (the caller's bank
+    index; XLA's running counter otherwise)."""
     b, length = data.shape
     gp = _round_up(g, _LANE)
     bp = _round_up(max(b, block_b), block_b)
@@ -132,5 +137,6 @@ def scan_dfa_bank_pallas(
         out_specs=pl.BlockSpec((block_b, gp), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bp, gp), jnp.int32),
         interpret=interpret,
+        name=name,
     )(dataT, lens, t3, mend)
     return (out[:b, :g] != 0) | always[None, :]

@@ -51,6 +51,7 @@ from dataclasses import dataclass, field
 
 from ..engine.request import HttpRequest
 from ..engine.waf import Verdict, WafEngine
+from ..observability.stages import CHAIN_STAGES, StageStats, WindowStages
 from ..utils import get_logger
 from .quarantine import fingerprint
 
@@ -72,6 +73,8 @@ DEFAULT_PIPELINE_DEPTH = 2
 LANE_INTERACTIVE = "interactive"
 LANE_BULK = "bulk"
 LANES = (LANE_INTERACTIVE, LANE_BULK)
+# Stages stamped once per window whatever its groups.
+_WINDOW_STAGES = frozenset(CHAIN_STAGES["queue"] + ("route",))
 
 
 def classify_lane(request) -> str:
@@ -129,7 +132,7 @@ class _FairQueue:
     ``queue.Empty``).
 
     Items are the batcher's queue entries: ``(request, tenant, fut,
-    span)`` triples (cost 1, bucketed by tenant), pre-assembled
+    span, no_cache, t_submit)`` tuples (cost 1, bucketed by tenant), pre-assembled
     ``_BlobWindow`` windows (cost 1 — one already-packed unit, bucketed
     under the default tenant), and ``None`` shutdown sentinels (a
     control channel with absolute priority so stop() is never stuck
@@ -349,6 +352,10 @@ class _Group:
     fps: dict | None = None
     dups: dict | None = None
     cache_uuid: object = None
+    # The spans of the window's stage record that this group's engine
+    # calls stamped (dispatch, then collect): what the flight recorder
+    # copies onto the group's traced requests.
+    stage_spans: list = field(default_factory=list)
 
 
 @dataclass
@@ -374,16 +381,21 @@ class _BlobWindow:
     # Priority lane the assembling frontend classified this window into
     # (per-lane accounting must survive the queue round-trip).
     lane: str = LANE_BULK
+    # The window's stage record (observability/stages.py). A frontend
+    # that hands one in goes on stamping after the future resolves
+    # (loop_hop, reply_write) and closes it; otherwise the batcher makes
+    # one at submit and closes it when the future is set.
+    stages: WindowStages | None = None
+    frontend_stages: bool = False
 
 
 @dataclass
 class _WindowRecord:
-    window: object  # list of (req, tenant, fut, span) items, or a _BlobWindow
+    window: object  # list of (req, tenant, fut, span, ...) items, or a _BlobWindow
     groups: list
-    # Dispatch-stage entry time (after assembly + the depth-semaphore
-    # backpressure wait): the boundary between a traced request's
-    # "queue" and "assemble" spans.
-    t_win: float = 0.0
+    # The window's stage record (a blob window's own, or the one made
+    # when the window formed).
+    stages: WindowStages | None = None
     # Blob window split by quarantine routing: groups carry idxs into the
     # blob's request index space and the collect stage stitches verdicts
     # back into one list for the window future.
@@ -489,6 +501,9 @@ class MicroBatcher:
         self._collector: threading.Thread | None = None
         self._running = False
         self.stats = BatcherStats()
+        # Where every closed window's stage record lands: the ``stages``
+        # block of /waf/v1/stats and cko_window_stage_seconds.
+        self.stage_stats = StageStats()
         # Per-lane window/request counters (cko_lane_* gauges).
         self.lane_windows: dict[str, int] = {lane: 0 for lane in LANES}
         self.lane_requests: dict[str, int] = {lane: 0 for lane in LANES}
@@ -741,6 +756,7 @@ class MicroBatcher:
             self._drain_triple(item)
 
     def _drain_blob(self, bw: _BlobWindow) -> None:
+        bw.stages.abort(self.stage_stats)
         if bw.fut.cancelled():
             return
         verdicts = None
@@ -761,7 +777,7 @@ class MicroBatcher:
             _resolve(bw.fut.set_exception, EngineUnavailable("batcher stopped"))
 
     def _drain_triple(self, item) -> None:
-        req, tenant, fut, span, _no_cache = item
+        req, tenant, fut, span = item[:4]
         if fut.cancelled():
             return
         if span is not None:
@@ -791,16 +807,17 @@ class MicroBatcher:
         deadline-header requests — their rescue/cancel dance must see
         the unmodified device path)."""
         fut: Future = Future()
+        t_submit = time.monotonic()
         if span is not None:
-            span.t_submit = time.monotonic()
+            span.t_submit = t_submit
         if lane is None:
             lane = classify_lane(request)
-        self._queues[lane].put((request, tenant, fut, span, no_cache))
+        self._queues[lane].put((request, tenant, fut, span, no_cache, t_submit))
         return fut
 
     def submit_window(
         self, blob: bytes | bytearray, n_req: int, spans=None,
-        lane: str = LANE_BULK
+        lane: str = LANE_BULK, stages: WindowStages | None = None
     ) -> Future:
         """Enqueue a pre-assembled ingest window (request blob in the
         ``native.serialize_requests`` format). Dispatched as its own
@@ -810,14 +827,20 @@ class MicroBatcher:
         the window's ``list[Verdict]``. ``spans`` optionally carries one
         flight-recorder context per blob request index (or None); the
         assembling frontend names the ``lane`` it already accumulates
-        per-lane windows for."""
+        per-lane windows for, and may hand in the window's stage record
+        (``stages``): it then stamps ``loop_hop`` and ``reply_write``
+        itself once the future resolves, and closes the record."""
         fut: Future = Future()
         with self._inflight_lock:
             self._blob_pending[lane] += n_req
             self._blob_pending_bytes[lane] += len(blob)
-        self._queues[lane].put(
-            _BlobWindow(blob=blob, n_req=n_req, fut=fut, spans=spans, lane=lane)
+        bw = _BlobWindow(
+            blob=blob, n_req=n_req, fut=fut, spans=spans, lane=lane,
+            stages=stages or WindowStages(lane, n_req),
+            frontend_stages=stages is not None,
         )
+        bw.stages.begin("queue_wait")
+        self._queues[lane].put(bw)
         return fut
 
     def pending(self, lane: str | None = None) -> int:
@@ -889,10 +912,11 @@ class MicroBatcher:
             try:
                 if isinstance(item, _BlobWindow):
                     # Pre-assembled window: dispatch as-is, never coalesce.
+                    item.stages.end("queue_wait")
                     with self._inflight_lock:
                         self._blob_pending[lane] -= item.n_req
                         self._blob_pending_bytes[lane] -= len(item.blob)
-                    self._dispatch_or_fail(item, lane)
+                    self._dispatch_or_fail(item, lane, item.stages)
                     continue
                 window: list[tuple[HttpRequest, str | None, Future]] = [item]
                 # The lane delay is read at window open so a live retune
@@ -914,12 +938,17 @@ class MicroBatcher:
                         carry = nxt
                         break
                     window.append(nxt)
-                self._dispatch_or_fail(window, lane)
+                # A window formed here waited in the queue from its
+                # first request's submit until it closed just now.
+                rec = WindowStages(lane, len(window))
+                rec.begin("queue_wait", window[0][5])
+                rec.end("queue_wait")
+                self._dispatch_or_fail(window, lane, rec)
             finally:
                 with self._inflight_lock:
                     self._windows_open -= 1
 
-    def _dispatch_or_fail(self, window, lane: str = LANE_BULK) -> None:
+    def _dispatch_or_fail(self, window, lane: str, rec: WindowStages) -> None:
         """Acquire the lane's in-flight slot (bounded depth — THE
         backpressure point: while the device is ``pipeline_depth``
         windows behind, assembly blocks here, the submit queue grows,
@@ -927,17 +956,20 @@ class MicroBatcher:
         bulk flood holding its slots never blocks interactive
         dispatch."""
         gate = self._depth_gates[lane]
+        rec.begin("depth_wait")
         while not gate.acquire(timeout=0.1):
             if not self._running:
                 # Shutdown with the pipeline full: drain the assembled
                 # window off-device instead of failing it. (Blob-backlog
                 # accounting already ran when the item left the queue.)
+                rec.abort(self.stage_stats)
                 if isinstance(window, _BlobWindow):
                     self._drain_blob(window)
                 else:
                     for triple in window:
                         self._drain_triple(triple)
                 return
+        rec.next("depth_wait", "route")
         with self._inflight_lock:
             self._inflight_count += 1
             self.lane_windows[lane] += 1
@@ -948,21 +980,39 @@ class MicroBatcher:
             if isinstance(window, _BlobWindow):
                 record = self._dispatch_blob(window)
             else:
-                record = self._dispatch_window(window)
+                record = self._dispatch_window(window, rec)
             record.lane = lane
+            record.stages = rec
         except BaseException:
             # _dispatch_window is defensive per group; anything escaping
             # it must still release the slot or the pipeline deadlocks.
+            rec.abort(self.stage_stats)
             with self._inflight_lock:
                 self._inflight_count -= 1
             gate.release()
             raise
+        rec.end("route")  # a window that made no engine call (all cached)
+        rec.begin("inflight_wait")
         self._inflight.put(record)
 
-    def _dispatch_window(
-        self, window: list[tuple[HttpRequest, str | None, Future, object]]
-    ) -> _WindowRecord:
-        t_win = time.monotonic()
+    def _engine_stage(self, rec: WindowStages, g: _Group, stage: str, call, *args):
+        """One call into the engine for group ``g`` (``prepare*`` at
+        dispatch, ``collect`` at collect), with the window's record bound
+        so that the engine stamps its stages on it. ``route`` ends where
+        the first such call begins. An engine that stamps nothing (a
+        stub) has the whole call recorded as ``stage``."""
+        t0 = rec.end("route")
+        since = len(rec.spans)
+        try:
+            with rec.bound():
+                return call(*args)
+        finally:
+            if len(rec.spans) == since:
+                rec.begin(stage, t0)
+                rec.end(stage)
+            g.stage_spans += rec.spans[since:]
+
+    def _dispatch_window(self, window: list[tuple], rec: WindowStages) -> _WindowRecord:
         # Group the window by the tenant's COMPILED MODEL, not by tenant
         # name: tenants typically fork a few base policies, so windows
         # touching many tenants still coalesce into one device step per
@@ -995,7 +1045,7 @@ class MicroBatcher:
         # tenant-manager lock); memoizing also pins one engine per tenant
         # for the whole window even if a hot reload lands mid-grouping.
         tenant_cache: dict[str | None, WafEngine | None] = {}
-        for idx, (_req, tenant, _fut, _span, _no_cache) in enumerate(window):
+        for idx, (_req, tenant, _fut, _span, _no_cache, _t) in enumerate(window):
             if _fut.cancelled():
                 # Deadline-missed request already answered by the host
                 # fallback — don't spend a device slot on it.
@@ -1079,23 +1129,23 @@ class MicroBatcher:
                     # Synchronous group (phase-split or a stub engine
                     # without the two-stage API): evaluated here, riding
                     # the in-flight queue for FIFO resolution only.
-                    if self.phase_split:
-                        g.verdicts = engine.evaluate_phased(reqs)
-                    else:
-                        g.verdicts = engine.evaluate(reqs)
+                    sync = engine.evaluate_phased if self.phase_split else engine.evaluate
+                    g.verdicts = self._engine_stage(rec, g, "assemble", sync, reqs)
                 else:
-                    g.inflight = engine.prepare(reqs)
+                    g.inflight = self._engine_stage(
+                        rec, g, "assemble", engine.prepare, reqs
+                    )
             except Exception as err:  # dispatch failure → per-request error
                 g.error = err
             out_groups.append(g)
-        return _WindowRecord(window=window, groups=out_groups, t_win=t_win)
+        return _WindowRecord(window=window, groups=out_groups)
 
     def _dispatch_blob(self, bw: _BlobWindow) -> _WindowRecord:
         """Dispatch a pre-assembled ingest window: one engine (default
         tenant, pinned here — a reload lands on the NEXT window), one
         ``prepare_blob`` call. Engines without the blob API (test stubs)
         materialize the requests and evaluate synchronously."""
-        t_win = time.monotonic()
+        rec = bw.stages
         engine = self._engine_fn(None)
         registry = self.quarantine
         if registry is not None and not len(registry):
@@ -1122,18 +1172,18 @@ class MicroBatcher:
         else:
             try:
                 if not self.phase_split and hasattr(engine, "prepare_blob"):
-                    g.inflight = engine.prepare_blob(bw.blob, bw.n_req)
+                    g.inflight = self._engine_stage(
+                        rec, g, "assemble", engine.prepare_blob, bw.blob, bw.n_req
+                    )
                 else:
                     from ..native import blob_requests
 
                     reqs = blob_requests(bw.blob, bw.n_req)
-                    if self.phase_split:
-                        g.verdicts = engine.evaluate_phased(reqs)
-                    else:
-                        g.verdicts = engine.evaluate(reqs)
+                    sync = engine.evaluate_phased if self.phase_split else engine.evaluate
+                    g.verdicts = self._engine_stage(rec, g, "assemble", sync, reqs)
             except Exception as err:
                 g.error = err
-        return _WindowRecord(window=bw, groups=[g], t_win=t_win)
+        return _WindowRecord(window=bw, groups=[g])
 
     def _dispatch_blob_split(
         self, bw: _BlobWindow, engine, registry, vcache=None
@@ -1150,6 +1200,7 @@ class MicroBatcher:
         along for insertion at collect."""
         from ..native import blob_requests
 
+        rec = bw.stages
         reqs = blob_requests(bw.blob, bw.n_req)
         spans = bw.spans
         qidx = []
@@ -1209,14 +1260,15 @@ class MicroBatcher:
             )
             try:
                 if not self.phase_split and hasattr(engine, "prepare_blob"):
-                    g.inflight = engine.prepare_blob(bw.blob, bw.n_req)
-                elif self.phase_split:
-                    g.verdicts = engine.evaluate_phased(reqs)
+                    g.inflight = self._engine_stage(
+                        rec, g, "assemble", engine.prepare_blob, bw.blob, bw.n_req
+                    )
                 else:
-                    g.verdicts = engine.evaluate(reqs)
+                    sync = engine.evaluate_phased if self.phase_split else engine.evaluate
+                    g.verdicts = self._engine_stage(rec, g, "assemble", sync, reqs)
             except Exception as err:
                 g.error = err
-            return _WindowRecord(window=bw, groups=[g], t_win=time.monotonic())
+            return _WindowRecord(window=bw, groups=[g])
         groups: list[_Group] = []
         if device_idx:
             g = _Group(
@@ -1229,12 +1281,13 @@ class MicroBatcher:
                 cache_uuid=uuid,
             )
             try:
-                if self.phase_split:
-                    g.verdicts = engine.evaluate_phased(g.reqs)
-                elif hasattr(engine, "prepare"):
-                    g.inflight = engine.prepare(g.reqs)
+                if not self.phase_split and hasattr(engine, "prepare"):
+                    g.inflight = self._engine_stage(
+                        rec, g, "assemble", engine.prepare, g.reqs
+                    )
                 else:
-                    g.verdicts = engine.evaluate(g.reqs)
+                    sync = engine.evaluate_phased if self.phase_split else engine.evaluate
+                    g.verdicts = self._engine_stage(rec, g, "assemble", sync, g.reqs)
             except Exception as err:
                 g.error = err
             groups.append(g)
@@ -1258,9 +1311,7 @@ class MicroBatcher:
                     reqs=[reqs[i] for i in qidx],
                 )
             )
-        return _WindowRecord(
-            window=bw, groups=groups, split=True, t_win=time.monotonic()
-        )
+        return _WindowRecord(window=bw, groups=groups, split=True)
 
     # -- collect stage -------------------------------------------------------
 
@@ -1271,6 +1322,7 @@ class MicroBatcher:
                 # stop() enqueues the sentinel AFTER the dispatch thread
                 # exits, so every dispatched window was already drained.
                 return
+            record.stages.end("inflight_wait")
             try:
                 self._collect_record(record)
             except Exception as err:
@@ -1280,6 +1332,7 @@ class MicroBatcher:
                 # sidecar still looks alive. Fail this record's
                 # unresolved futures and keep collecting.
                 log.error("window collect failed", err)
+                record.stages.abort(self.stage_stats)
                 if isinstance(record.window, _BlobWindow):
                     if not record.window.fut.done():
                         _resolve(record.window.fut.set_exception, err)
@@ -1359,8 +1412,15 @@ class MicroBatcher:
                     self._notify(self.on_window_fault, job.engine, error, None)
                 return
 
-    def _collect_group(self, g: _Group) -> list[Verdict]:
-        """Collect one device group's readback, supervised by the window
+    def _collect_group(self, g: _Group, rec: WindowStages) -> list[Verdict]:
+        """Collect one device group: the engine stamps ``readback_wait``
+        and ``decode`` on the window's record (it rides the in-flight
+        batch); for an engine that stamps nothing (a stub) the whole
+        call is recorded as ``readback_wait``."""
+        return self._engine_stage(rec, g, "readback_wait", self._readback, g)
+
+    def _readback(self, g: _Group) -> list[Verdict]:
+        """One device group's readback, supervised by the window
         deadline when armed. Raises ``WindowAbandoned`` on a blown
         deadline; the group's futures then fail with it and the server's
         rescue paths re-answer them from host fallback."""
@@ -1419,35 +1479,36 @@ class MicroBatcher:
         return tuple(out)
 
     def _trace_group(self, record: _WindowRecord, g: _Group, spans: tuple) -> None:
-        """Stamp the pipeline span chain (queue -> assemble -> dispatch
-        -> readback -> decode) onto a collected group's traced requests.
-        Must run BEFORE the group's futures resolve — the frontend
-        commits the flight record when its future lands. Sync groups
-        (stub engines, phase-split) have no stage timings; their device
-        spans degenerate to zero length but the chain stays complete."""
+        """Copy the window record's stamps onto a collected group's
+        traced requests: the chain queue -> assemble -> dispatch ->
+        readback -> decode and, inside it, every stage, each with the
+        record's ``window_id``. Must run BEFORE the group's futures
+        resolve — the frontend commits the flight record when its future
+        lands. Groups whose engine stamps nothing (stubs) keep a
+        complete chain with zero-length device spans."""
         try:
-            t_end = time.monotonic()
-            inflight = g.inflight
-            host_s = getattr(inflight, "host_s", 0.0) if inflight is not None else 0.0
-            device_s = getattr(inflight, "device_s", 0.0) if inflight is not None else 0.0
-            decode_s = getattr(inflight, "decode_s", 0.0) if inflight is not None else 0.0
-            t_win = record.t_win or g.t_dispatch
-            t_disp = g.t_dispatch
-            t_host1 = min(t_end, t_disp + host_s)
-            t_rb0 = max(t_host1, t_end - device_s - decode_s)
-            t_rb1 = max(t_rb0, t_end - decode_s)
-            n = len(g.idxs) if g.idxs else getattr(record.window, "n_req", 0)
-            for span in spans:
-                t_sub = span.t_submit or span.t_accept
-                span.event("queue", min(t_sub, t_win), t_win, track="pipeline")
-                span.event(
-                    "assemble", t_win, t_disp, track="pipeline", args={"window": n}
-                )
-                span.event("dispatch", t_disp, t_host1, track="pipeline")
-                span.event("readback", t_rb0, t_rb1, track="device")
-                span.event("decode", t_rb1, t_end, track="device")
+            rec = record.stages
+            # The window's own stages (queue and route), then this
+            # group's engine stages.
+            stamped = [
+                sp for sp in rec.spans if sp[0] in _WINDOW_STAGES
+            ] + g.stage_spans
+            rec.trace_onto(spans, stamped)
         except Exception as err:  # tracing must never decide a verdict
             log.error("flight recorder stamp failed", err)
+
+    def _finish_stages(self, record: _WindowRecord) -> None:
+        """The collector's last stamp on a window, taken BEFORE its
+        future is set (the frontend's callback may start at once): a
+        frontend that handed the record in gets it back with
+        ``loop_hop`` running; otherwise the window ends here and is
+        counted before its caller can read /stats."""
+        rec = record.stages
+        if getattr(record.window, "frontend_stages", False):
+            rec.next("resolve", "loop_hop")
+        else:
+            rec.end("resolve")
+            rec.close(self.stage_stats)
 
     def _trace_degraded(
         self, record: _WindowRecord, g: _Group, path: str, name: str
@@ -1490,6 +1551,7 @@ class MicroBatcher:
     def _collect_quarantined(self, record: _WindowRecord, g: _Group) -> None:
         """Resolve a quarantined group's futures from host fallback —
         no breaker traffic, no device stats, no shadow mirror."""
+        record.stages.abort(self.stage_stats)
         self._trace_degraded(record, g, "quarantine", "quarantine")
         try:
             verdicts = self._quarantine_eval(g)
@@ -1551,16 +1613,18 @@ class MicroBatcher:
         if isinstance(record.window, _BlobWindow):
             self._collect_blob(record)
             return
+        rec = record.stages
         for g in record.groups:
             if g.quarantined:
                 self._collect_quarantined(record, g)
                 continue
             if g.error is None and g.verdicts is None:
                 try:
-                    g.verdicts = self._collect_group(g)
+                    g.verdicts = self._collect_group(g, rec)
                 except Exception as err:
                     g.error = err
             if g.error is not None:
+                rec.abort(self.stage_stats)
                 if g.engine is None:
                     # Missing-engine group: a routing condition, not a
                     # device failure — never feeds the breaker.
@@ -1585,6 +1649,7 @@ class MicroBatcher:
                         # server's rescue paths re-answer each future.
                         _resolve(record.window[j][2].set_exception, g.error)
                 continue
+            rec.begin("resolve")
             self._notify(self.on_engine_success, g.engine)
             spans = self._group_spans(record, g)
             # One stats sample per model group, recorded BEFORE the
@@ -1612,6 +1677,10 @@ class MicroBatcher:
                 log.error("batch stats hook failed", err)
             if spans:
                 self._trace_group(record, g, spans)
+            if g is record.groups[-1]:
+                self._finish_stages(record)
+            else:
+                rec.end("resolve")
             for i, verdict in zip(g.idxs, g.verdicts):
                 _resolve(record.window[i][2].set_result, verdict)
                 for j in g.dups.get(i, ()) if g.dups else ():
@@ -1635,6 +1704,9 @@ class MicroBatcher:
                     list(g.verdicts),
                     serving_s,
                 )
+        # A window none of whose groups ended it (every request cached or
+        # cancelled at assembly) ends here; a no-op otherwise.
+        rec.close(self.stage_stats)
 
     def _collect_blob(self, record: _WindowRecord) -> None:
         """Collect one blob window: resolve its single future with the
@@ -1645,13 +1717,15 @@ class MicroBatcher:
         if record.split:
             self._collect_blob_split(record)
             return
+        rec = record.stages
         g = record.groups[0]
         if g.error is None and g.verdicts is None:
             try:
-                g.verdicts = self._collect_group(g)
+                g.verdicts = self._collect_group(g, rec)
             except Exception as err:
                 g.error = err
         if g.error is not None:
+            rec.abort(self.stage_stats)
             self.stats.errors += bw.n_req
             if g.engine is not None:
                 log.error("blob window evaluation failed", g.error, batch=bw.n_req)
@@ -1664,6 +1738,7 @@ class MicroBatcher:
                 self._trace_degraded(record, g, "error", "window_error")
             _resolve(bw.fut.set_exception, g.error)
             return
+        rec.begin("resolve")
         self._notify(self.on_engine_success, g.engine)
         spans = self._group_spans(record, g)
         trace_id = spans[0].trace_id if spans else None
@@ -1690,6 +1765,7 @@ class MicroBatcher:
             log.error("batch stats hook failed", err)
         if spans:
             self._trace_group(record, g, spans)
+        self._finish_stages(record)
         _resolve(bw.fut.set_result, list(g.verdicts))
         self._cache_insert(g)
         if self.on_window is not None and (
@@ -1716,10 +1792,12 @@ class MicroBatcher:
         The shadow mirror is skipped in split mode (sampling loss while
         a quarantine is active is acceptable)."""
         bw: _BlobWindow = record.window
+        rec = record.stages
         out: list[Verdict | None] = [None] * bw.n_req
         for g in record.groups:
             try:
                 if g.quarantined:
+                    rec.abort(self.stage_stats)
                     self._trace_degraded(record, g, "quarantine", "quarantine")
                     verdicts = self._quarantine_eval(g)
                 elif g.cached:
@@ -1731,9 +1809,10 @@ class MicroBatcher:
                     if g.error is not None:
                         raise g.error
                     if g.verdicts is None:
-                        g.verdicts = self._collect_group(g)
+                        g.verdicts = self._collect_group(g, rec)
                     verdicts = g.verdicts
             except Exception as err:
+                rec.abort(self.stage_stats)
                 self.stats.errors += bw.n_req
                 log.error(
                     "split blob window evaluation failed", err, batch=bw.n_req
@@ -1744,6 +1823,7 @@ class MicroBatcher:
                 _resolve(bw.fut.set_exception, err)
                 return
             if not g.quarantined and not g.cached:
+                rec.begin("resolve")
                 self._notify(self.on_engine_success, g.engine)
                 spans = self._group_spans(record, g)
                 try:
@@ -1756,6 +1836,7 @@ class MicroBatcher:
                     log.error("batch stats hook failed", err)
                 if spans:
                     self._trace_group(record, g, spans)
+                rec.end("resolve")
             for i, verdict in zip(g.idxs, verdicts):
                 out[i] = verdict
                 for j in g.dups.get(i, ()) if g.dups else ():
@@ -1763,6 +1844,8 @@ class MicroBatcher:
                     # every row that shared the fingerprint.
                     out[j] = verdict
             self._cache_insert(g)
+        rec.begin("resolve")
+        self._finish_stages(record)
         _resolve(bw.fut.set_result, out)
 
     def _wants_window(self, engine) -> bool:

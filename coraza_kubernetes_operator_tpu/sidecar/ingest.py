@@ -57,6 +57,7 @@ from concurrent.futures import ThreadPoolExecutor
 from http.client import responses as _REASONS
 
 from ..engine.request import HttpRequest
+from ..observability.stages import WindowStages
 from ..utils import get_logger
 from .batcher import LANE_BULK, LANE_INTERACTIVE, LANES, EngineUnavailable
 from .degraded import BreakerOpen, Overloaded
@@ -133,7 +134,7 @@ class _ConnReader:
     arrived.
     """
 
-    __slots__ = ("_r", "_loop", "buf", "eof")
+    __slots__ = ("_r", "_loop", "buf", "eof", "t_read")
     CHUNK = 65536
 
     def __init__(self, reader: asyncio.StreamReader, loop) -> None:
@@ -141,6 +142,9 @@ class _ConnReader:
         self._loop = loop
         self.buf = bytearray()
         self.eof = False
+        # Monotonic stamp of the socket read that delivered the newest
+        # bytes: where the lane_wait of every request in them starts.
+        self.t_read = 0.0
 
     async def _fill(self, timeout: float | None) -> bool:
         """Pull one chunk into the buffer; False on EOF; raises
@@ -156,6 +160,7 @@ class _ConnReader:
         if not data:
             self.eof = True
             return False
+        self.t_read = _time.monotonic()
         self.buf += data
         return True
 
@@ -344,6 +349,12 @@ class AsyncIngestFrontend:
         # materialized: None until some request in the window is traced,
         # so the sampling-off hot path never touches it.
         self._win_traces: dict[str, list | None] = {lane: None for lane in LANES}
+        # The stage record of each lane's window under assembly
+        # (observability/stages.py): made when the window opens.
+        self._win_stages: dict[str, WindowStages | None] = {
+            lane: None for lane in LANES
+        }
+        self._stage_stats = sidecar.batcher.stage_stats
         self._tracer = sidecar.tracer
         self._win_timer: dict[str, asyncio.TimerHandle | None] = {
             lane: None for lane in LANES
@@ -567,9 +578,9 @@ class AsyncIngestFrontend:
                     self._put_static(queue, 408, b"request header timeout\n")
                 # "idle" (quiet keep-alive) and "closed" end silently.
                 return
-            t0 = _time.perf_counter()
+            t_parse = _time.monotonic()
             parsed = _parse_head(head)
-            self.parse_s += _time.perf_counter() - t0
+            self.parse_s += _time.monotonic() - t_parse
             if parsed is None:
                 self._put_static(queue, 400, b"bad request\n")
                 return
@@ -676,8 +687,11 @@ class AsyncIngestFrontend:
                 keep_alive = b"keep-alive" in conn_tok
             if close_after:
                 keep_alive = False
-            fut = self._route(method, target, version, pairs, special, body, remote_b)
-            queue.put_nowait((fut, keep_alive, nbytes, tenant))
+            fut, rec = self._route(
+                method, target, version, pairs, special, body, remote_b,
+                cr.t_read, t_parse,
+            )
+            queue.put_nowait((fut, keep_alive, nbytes, tenant, rec))
             if not keep_alive:
                 return
 
@@ -726,7 +740,7 @@ class AsyncIngestFrontend:
                 item = await queue.get()
                 if item is None:
                     return
-                fut, keep_alive, charge, tenant = item
+                fut, keep_alive, charge, tenant, rec = item
                 try:
                     try:
                         status, payload, headers = await fut
@@ -740,6 +754,12 @@ class AsyncIngestFrontend:
                             {"Content-Type": "text/plain"},
                         )
                     writer.write(self._render(status, payload, headers, keep_alive))
+                    if rec is not None:
+                        # Handed to its transport; the window's last such
+                        # reply ends reply_write and the window's wall.
+                        rec.replies_left -= 1
+                        if not rec.replies_left:
+                            rec.close(self._stage_stats)
                     transport = writer.transport
                     if queue.empty() or (
                         transport is not None
@@ -792,7 +812,7 @@ class AsyncIngestFrontend:
     def _put_static(self, queue, status: int, payload: bytes) -> None:
         fut = self._loop.create_future()
         fut.set_result((status, payload, {"Content-Type": "text/plain"}))
-        queue.put_nowait((fut, False, 0, None))
+        queue.put_nowait((fut, False, 0, None, None))
 
     def _put_shed(self, queue, tenant: str | None = None) -> None:
         """Memory-budget shed: same 429 + Retry-After + x-waf-action
@@ -808,16 +828,22 @@ class AsyncIngestFrontend:
         err = Overloaded(msg, retry_after_s=sc.shed_retry_after())
         fut = self._loop.create_future()
         fut.set_result(sc.overloaded_reply(err, as_json=False))
-        queue.put_nowait((fut, False, 0, None))
+        queue.put_nowait((fut, False, 0, None, None))
 
     # -- routing -------------------------------------------------------------
 
-    def _route(self, method, target, version, pairs, special, body, remote_b):
+    def _route(
+        self, method, target, version, pairs, special, body, remote_b, t_read,
+        t_parse,
+    ):
+        """Returns the reply's future and, for a request that joined a
+        lane's window, that window's stage record (its reply's writer
+        stamps on it); None beside it on every other path."""
         sc = self.sidecar
         target_s = target.decode("latin-1", "replace")
         path, _, query = target_s.partition("?")
         if path.startswith(API_PREFIX):
-            return self._route_api(method, path, special, body, query)
+            return self._route_api(method, path, special, body, query), None
         # -- filter mode ------------------------------------------------------
         # Flight recorder: one dict probe + one attribute read when off
         # and no header — the zero-hot-path-cost contract. The span (when
@@ -826,14 +852,13 @@ class AsyncIngestFrontend:
         ctx = None
         tp = special.get(b"traceparent")
         if tp is not None or self._tracer.sample_rate > 0.0:
-            t_accept = _time.monotonic()
-            ctx = self._tracer.start(tp, t_accept=t_accept)
+            ctx = self._tracer.start(tp, t_accept=t_read)
             if ctx is not None:
-                # The head was parsed just before routing; accept and
-                # parse collapse onto the route entry point (same
-                # convention as the threaded frontend).
-                ctx.event("accept", t_accept, t_accept, track="frontend")
-                ctx.event("parse", t_accept, t_accept, track="frontend")
+                # accept: the socket read that delivered the request ->
+                # its turn to be parsed (the requests ahead of it in the
+                # same read). parse: its head, its body, and (below) its
+                # bytes packed into the lane's window.
+                ctx.event("accept", t_read, t_parse, track="frontend")
         # Threaded parity: GET bodies are consumed for framing but not
         # evaluated (do_GET calls _handle_filter(b"")).
         eval_body = body if method != b"GET" else b""
@@ -848,8 +873,11 @@ class AsyncIngestFrontend:
                 t = special.get(b"x-waf-tenant")
                 tenant = t.decode("latin-1", "replace") if t else None
             req = _materialize(method, target_s, version, pairs, eval_body, remote_b)
-            return self._spawn(
-                self._eval_pool, self._python_filter, req, tenant, deadline_s, ctx
+            return (
+                self._spawn(
+                    self._eval_pool, self._python_filter, req, tenant, deadline_s, ctx
+                ),
+                None,
             )
         # -- hot path: slice the wire bytes straight into the native
         # batch-blob record (native.serialize_requests wire format; zero
@@ -878,10 +906,22 @@ class AsyncIngestFrontend:
         fut = self._loop.create_future()
         futs = self._win_futs[lane]
         futs.append(fut)
+        rec = self._win_stages[lane]
+        if rec is None:
+            # The lane's window opens: its record starts at the read
+            # that delivered its first request.
+            rec = self._win_stages[lane] = WindowStages(lane)
+            rec.begin("lane_wait", t_read)
+        reads = rec.reads
+        if not reads or reads[-1][0] != t_read:
+            # One stamp per socket read: this request is the first that
+            # a new read delivered into the window.
+            reads.append([t_read, len(futs) - 1])
         if ctx is not None:
             if self._win_traces[lane] is None:
                 self._win_traces[lane] = [None] * (len(futs) - 1)
             self._win_traces[lane].append(ctx)
+            ctx.event("parse", t_parse, _time.monotonic(), track="frontend")
         elif self._win_traces[lane] is not None:
             self._win_traces[lane].append(None)
         self.parse_s += _time.perf_counter() - t0
@@ -894,7 +934,7 @@ class AsyncIngestFrontend:
             self._win_timer[lane] = self._loop.call_later(
                 delay, self._flush_window, lane
             )
-        return fut
+        return fut, rec
 
     def _route_api(self, method, path, special, body, query=""):
         sc = self.sidecar
@@ -959,15 +999,24 @@ class AsyncIngestFrontend:
         )
         return self._finish_trace(reply, ctx)
 
-    def _finish_trace(self, reply, ctx):
+    def _finish_trace(self, reply, ctx, rec=None):
         """Echo the response traceparent, stamp the reply span, and
-        commit the flight record. Identity for untraced requests."""
+        commit the flight record. Identity for untraced requests. A
+        window's reply span starts where its ``reply_write`` did (the
+        completion callback) and ends with this reply built."""
         if ctx is None:
             return reply
         status, payload, headers = reply
         headers = {**(headers or {}), "traceparent": ctx.response_traceparent()}
         t_reply = _time.monotonic()
-        ctx.event("reply", t_reply, t_reply, track="frontend")
+        t0 = rec.opened("reply_write") if rec is not None else None
+        ctx.event(
+            "reply",
+            t_reply if t0 is None else t0,
+            t_reply,
+            track="frontend",
+            args=None if rec is None else {"window_id": rec.window_id},
+        )
         self.sidecar.tracer.commit(ctx)
         return status, payload, headers
 
@@ -1057,33 +1106,39 @@ class AsyncIngestFrontend:
         # bytes() here re-paid every window's bytes once per flush.
         blob = self._win_buf[lane]
         spans = self._win_traces[lane]
+        rec = self._win_stages[lane]
         self._win_futs[lane] = []
         self._win_buf[lane] = bytearray()
         self._win_traces[lane] = None
+        self._win_stages[lane] = None
+        rec.close_lane(len(futs))
         self.windows_total += 1
         self.window_requests_total += len(futs)
         self.lane_windows_total[lane] += 1
         try:
-            self._dispatch_window(blob, futs, spans, lane)
+            self._dispatch_window(blob, futs, spans, lane, rec)
         except Exception as err:
             # Dispatch containment: a routing bug answers this window
             # 500 instead of leaving futures (and connections) hanging.
             log.error("ingest window dispatch failed", err)
+            rec.abort(self._stage_stats)
             reply = (500, b"internal error\n", {"Content-Type": "text/plain"})
             for f in futs:
                 if not f.done():
                     f.set_result(reply)
 
     def _dispatch_window(
-        self, blob: bytes | bytearray, futs: list, spans=None,
-        lane: str = LANE_BULK
+        self, blob: bytes | bytearray, futs: list, spans, lane: str,
+        rec: WindowStages,
     ) -> None:
         """Route one assembled window. Runs on the loop thread — every
         step here is a cheap probe; blocking work goes to the batcher or
-        the evaluation pool."""
+        the evaluation pool. A window that is not submitted to the
+        batcher leaves the promoted path here (``rec.abort``)."""
         sc = self.sidecar
         engine = sc.tenants.engine_for(None)
         if engine is None:
+            rec.abort(self._stage_stats)
             self._answer_all_traced(
                 futs, spans, sc.unavailable_reply, "unavailable", "unavailable"
             )
@@ -1091,22 +1146,27 @@ class AsyncIngestFrontend:
         try:
             route = sc.degraded.route(engine)
         except BreakerOpen:
+            rec.abort(self._stage_stats)
             self._answer_all_traced(
                 futs, spans, sc.breaker_filter_reply, "breaker", "breaker_open"
             )
             return
         if route == "fallback":
+            rec.abort(self._stage_stats)
             self._inflight_windows += 1
             self._submit_eval(self._fallback_window, engine, blob, futs, spans)
             return
         try:
             sc._admit_device(len(futs), lane=lane)
         except Overloaded as err:
+            rec.abort(self._stage_stats)
             reply = sc.overloaded_reply(err, as_json=False)
             self._answer_all_traced(futs, spans, lambda: reply, "shed", "shed")
             return
         self._inflight_windows += 1
-        wfut = sc.batcher.submit_window(blob, len(futs), spans=spans, lane=lane)
+        wfut = sc.batcher.submit_window(
+            blob, len(futs), spans=spans, lane=lane, stages=rec
+        )
         # Same budget ladder as the threaded bulk path: cold engines get
         # the compile budget; warmed ones the strict timeout plus a
         # bounded recompile grace (fresh-shape tier buckets mid-stream).
@@ -1114,42 +1174,49 @@ class AsyncIngestFrontend:
         if timeout <= sc.config.request_timeout_s:
             timeout += max(0.0, sc.config.recompile_grace_s)
         handle = self._loop.call_later(
-            timeout, self._window_timeout, wfut, futs, spans
+            timeout, self._window_timeout, wfut, futs, spans, rec
         )
         wfut.add_done_callback(
             lambda f: self._call_soon(
-                self._window_done, f, futs, blob, engine, handle, spans
+                self._window_done, f, futs, blob, engine, handle, spans, rec
             )
         )
 
-    def _window_timeout(self, wfut, futs, spans=None) -> None:
+    def _window_timeout(self, wfut, futs, spans, rec) -> None:
         # Threaded-path legacy-timeout contract: the failurePolicy
         # answers. Cancel so the batcher skips the window if still queued.
+        rec.abort(self._stage_stats)
         wfut.cancel()
         self._answer_all_traced(
             futs, spans, self.sidecar.unavailable_reply, "error", "window_timeout"
         )
 
-    def _window_done(self, wfut, futs, blob, engine, handle, spans=None) -> None:
+    def _window_done(self, wfut, futs, blob, engine, handle, spans, rec) -> None:
+        # The collector left loop_hop running when it set the future; a
+        # window it failed is closed already and this stamps nothing.
+        rec.next("loop_hop", "reply_write")
         self._inflight_windows -= 1
         handle.cancel()
         sc = self.sidecar
         try:
-            self._window_done_inner(wfut, futs, blob, engine, spans)
+            self._window_done_inner(wfut, futs, blob, engine, spans, rec)
         except Exception as err:
             log.error("ingest window completion failed", err)
+            rec.abort(self._stage_stats)
             reply = (500, b"internal error\n", {"Content-Type": "text/plain"})
             for f in futs:
                 if not f.done():
                     f.set_result(reply)
             sc.governor.count("conn_errors_total")
 
-    def _window_done_inner(self, wfut, futs, blob, engine, spans=None) -> None:
+    def _window_done_inner(self, wfut, futs, blob, engine, spans, rec) -> None:
         sc = self.sidecar
         if wfut.cancelled():
             self._answer_all(futs, sc.unavailable_reply)
             return
         err = wfut.exception()
+        if err is not None:
+            rec.abort(self._stage_stats)  # the batcher did; idempotent
         if err is None:
             verdicts = wfut.result()
             # Verdict counters BEFORE the replies resolve: a client that
@@ -1162,7 +1229,7 @@ class AsyncIngestFrontend:
                     if not f.done():
                         ctx = spans[i] if i < len(spans) else None
                         f.set_result(
-                            self._finish_trace(sc.verdict_filter_reply(v), ctx)
+                            self._finish_trace(sc.verdict_filter_reply(v), ctx, rec)
                         )
             else:
                 for f, v in zip(futs, verdicts):

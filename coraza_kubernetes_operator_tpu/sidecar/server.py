@@ -42,6 +42,7 @@ import sys
 from ..engine.request import HttpRequest
 from ..engine.waf import Verdict, WafEngine
 from ..observability import AuditLogger, MetricsRegistry, TraceRecorder
+from ..observability.stages import STAGE_BUCKETS, StageStats
 from ..observability.audit import AuditRecord
 from ..utils import get_logger
 from .batcher import (
@@ -102,6 +103,26 @@ def _device_identity() -> dict | None:
     from ..engine.compile_cache import EXEC_CACHE
 
     return EXEC_CACHE.device
+
+
+def _device_stats() -> dict | None:
+    """The ``device`` block of /waf/v1/stats: the identity plus
+    ``memory_peak_bytes``, the largest ``peak_bytes_in_use`` over the
+    local devices (None where the backend reports none, as the CPU's).
+    JAX is asked only once a device window has run, so this too forces
+    no backend."""
+    device = _device_identity()
+    if device is None:
+        return None
+    import jax
+
+    peaks = [
+        (d.memory_stats() or {}).get("peak_bytes_in_use") for d in jax.local_devices()
+    ]
+    return {
+        **device,
+        "memory_peak_bytes": max((p for p in peaks if p is not None), default=None),
+    }
 
 
 def _build_info_labels() -> dict:
@@ -820,13 +841,26 @@ class TpuEngineSidecar:
         ).set_function(lambda: float(self.batcher.inflight_windows()))
         self._m_host_stage = self.metrics.histogram(
             "cko_host_stage_s",
-            "Host assemble stage per window group (tensorize+tier+dispatch)",
+            "Window stages assemble..post_enqueue per window group"
+            " (holds the prefilter's wait for the matcher)",
         )
         self._m_device_stage = self.metrics.histogram(
             "cko_device_stage_s",
-            "Device stage per window group (readback block + decode)",
+            "Window stages readback_wait + decode per window group"
+            " (a host clock: blocked on the device, then decoding)",
         )
         self.batcher.stats.on_stage = self._on_stage
+        # Window stage record (observability/stages.py): the registry's
+        # histogram is the series /waf/v1/stats `stages` reads too.
+        self.batcher.stage_stats = StageStats(
+            self.metrics.histogram(
+                "cko_window_stage_seconds",
+                "Seconds a device window spent in each stage of the served"
+                " path (lane_wait: per request; window_wall: the total)",
+                ("stage", "lane"),
+                buckets=STAGE_BUCKETS,
+            )
+        )
         # -- native window pipeline + staging arena (docs/NATIVE.md) --------
         self.metrics.gauge(
             "cko_native_window_s",
@@ -1858,7 +1892,13 @@ class TpuEngineSidecar:
         """POST /waf/v1/profile — on-demand device profiling wrapping
         ``jax.profiler``. Body: ``{"action": "start"|"stop"}``;
         ``{"dir": ...}`` optionally overrides the start dump directory
-        (default CKO_PROFILE_DIR or /tmp/cko-profile).
+        (default CKO_PROFILE_DIR or /tmp/cko-profile) and
+        ``{"python_tracer": true}`` turns the Python tracer on for this
+        capture (default off: it slows the host it measures). The HLO
+        protos stay out of the dump (with them one traced second of a
+        CRS-sized matcher is tens of megabytes and ``stop`` takes most
+        of a minute); the ``cko.<stage>`` spans of
+        observability/stages.py are on the host plane either way.
 
         Auth: the profiler serializes device execution and writes dumps
         to disk, so the endpoint is bearer-guarded with the SAME token
@@ -1897,7 +1937,12 @@ class TpuEngineSidecar:
                 try:
                     import jax
 
-                    jax.profiler.start_trace(profile_dir)
+                    options = jax.profiler.ProfileOptions()
+                    options.python_tracer_level = int(
+                        bool((payload or {}).get("python_tracer"))
+                    )
+                    options.enable_hlo_proto = False
+                    jax.profiler.start_trace(profile_dir, profiler_options=options)
                 except Exception as err:
                     return _json_reply(
                         500,
@@ -2644,7 +2689,10 @@ class TpuEngineSidecar:
             "degraded": self.degraded.stats(),
             "shed_total": int(self._m_shed.value()),
             "failopen_total": int(self._m_failopen.value()),
-            "device": _device_identity(),
+            "device": _device_stats(),
+            # Cumulative per-stage, per-lane seconds of every device
+            # window (observability/stages.py): take after − before.
+            "stages": self.batcher.stage_stats.snapshot(),
             "compile_cache": {
                 **_exec_cache_stats(),
                 "exec_signatures": self._report_int("exec_signatures"),

@@ -43,7 +43,7 @@ def test_allow_cpu_runs_every_phase_and_reports_the_cpu():
     assert counted["device_windows"] == sum(counted["windows"].values()) > 0
     assert set(last) == {"ok", "device"}
     assert last["ok"] is False and last["device"]["platform"] == "cpu"
-    assert set(last["device"]) == {"platform", "kind", "count"}
+    assert set(last["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
 
 
 def test_refuses_a_jax_held_to_the_cpu():

@@ -155,5 +155,7 @@ def test_compile_order_is_smallest_first(monkeypatch):
     assert tc.submitted[0][0] == "post"
     # With one worker, execution order == submission order: the post
     # executable is minted before any matcher.
-    assert stub.warm_order[0] == "eval_post_tiered"
-    assert set(stub.warm_order[1:]) == {"match_tier_packed"}
+    # (the executables are named by role and window shape)
+    assert stub.warm_order[0].startswith("cko_eval_post_")
+    assert all(name.startswith("cko_match_") for name in stub.warm_order[1:])
+    assert not any("eval_post" in name for name in stub.warm_order[1:])

@@ -143,8 +143,11 @@ def test_pallas_kernel_compiles_for_v5e(crs_lite, described, operand, family, ro
 
 
 def test_post_stage_compiles_for_v5e(crs_lite, described, operand):
-    from coraza_kubernetes_operator_tpu.models.waf_model import eval_post_tiered
+    from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
 
+    # As the engine dispatches it: under the module name a trace
+    # reduction finds the post stage by (wafbench: "eval_post").
+    eval_post_tiered = stage_executable("eval_post", f"{ROWS_WINDOW}x{WIDTH_WINDOW}")
     model = crs_lite.model
     packed = (int(model.e_lg.shape[0]) + 7) // 8
     n_vars = crs_lite.compiled.numvars.n_vars
@@ -161,7 +164,7 @@ def test_post_stage_compiles_for_v5e(crs_lite, described, operand):
         .compile()
         .as_text()
     )
-    assert "HloModule" in text
+    assert f"HloModule jit_cko_eval_post_{ROWS_WINDOW}x{WIDTH_WINDOW}" in text
 
 
 def test_canary_matcher_compiles_for_v5e(crs_lite, described, operand):
@@ -169,11 +172,12 @@ def test_canary_matcher_compiles_for_v5e(crs_lite, described, operand):
     tier of ops/segment.py, and every Pallas bank in one program — at
     the promotion canary's shape, which every cold sidecar compiles
     before it may serve from the device."""
-    from coraza_kubernetes_operator_tpu.models.waf_model import match_tier_packed
+    from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
 
     model = crs_lite.model
     h = max(1, len(crs_lite._host_pipelines))
     u, width = ROWS_CANARY, WIDTH_CANARY
+    match_tier_packed = stage_executable("match", f"{u}x{width}")
     compiled = match_tier_packed.lower(
         described(model),
         operand((u, width), jnp.uint8),
@@ -185,6 +189,16 @@ def test_canary_matcher_compiles_for_v5e(crs_lite, described, operand):
     n_pallas = (
         len(model.flat_banks) + len(model.pre_banks) + len(model.gather_banks)
     )
-    assert compiled.as_text().count("tpu_custom_call") == n_pallas
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == n_pallas
+    # The names a device trace prints: the module by role and window
+    # shape (only the post stage's holds "eval_post"), every Pallas
+    # kernel by family and bank, not by XLA's running counter.
+    assert f"HloModule jit_cko_match_{u}x{width}" in text and "eval_post" not in text
+    for family, banks in (("gather_bank", model.gather_banks),
+                          ("prefilter_bank", model.pre_banks),
+                          ("flat_bin", model.flat_banks)):
+        for i in range(len(banks)):
+            assert f"%cko_{family}{i}" in text, f"cko_{family}{i}"
     # It has to fit beside the model's tables in one v5e's 16 GB.
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
