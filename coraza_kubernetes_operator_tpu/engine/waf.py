@@ -561,8 +561,23 @@ class WafEngine:
             "hits": 0,
             "confirms": 0,
             "false_positives": 0,
+            "native_hits": 0,  # hits the native library confirmed
+            "native_errors": 0,  # native calls that failed (window re-walked)
         }
         self._prefilter_lock = threading.Lock()
+        # The prefiltered groups' exact DFAs and pipelines, handed to the
+        # native library once (native/__init__.py:NativeConfirm); which
+        # columns it handles is decided here, from what the library
+        # exports and the opcodes it has — no knob.
+        from ..native import NativeConfirm
+
+        self._prefilter_dev_cols = np.asarray(
+            [c for c, _g in self.model.prefilter_cols], dtype=np.int64
+        )
+        self._native_confirm = NativeConfirm(
+            self.compiled, self.model.prefilter_cols, self.model.host_variant_index
+        )
+        self._native_confirm_failed = False  # the failure is logged once
         # Stamp the automata composition onto the matcher stage label at
         # tier-selection time: tier stats / bench can then report what
         # the compiled matchers actually contain, not just their shapes.
@@ -1159,13 +1174,19 @@ class WafEngine:
         The pre-bank columns (``model.prefilter_cols``: (device column,
         gid) pairs) carry verdicts of the APPROXIMATE automata — sound
         over-approximations, so a 0 is final but a 1 may be spurious.
-        For every positive row, run the exact ``DFA.search`` on the same
+        For every positive row, run the exact DFA on the same
         transformed bytes the device saw (variant-buffer row when the
-        pipeline has a host slot, ``apply_pipeline`` otherwise — the
-        ``_host_tier_hits`` convention) and clear the bit unless it
-        confirms. Patched rows re-pack to numpy; the post stage accepts
-        either provenance, and the value cache then stores EXACT bits, so
-        cached replays skip both the matcher and the confirm.
+        pipeline has a host slot, the pipeline applied to the raw row
+        otherwise — the ``_host_tier_hits`` convention) and clear the
+        bit unless it confirms. Patched rows re-pack to numpy; the post
+        stage accepts either provenance, and the value cache then stores
+        EXACT bits, so cached replays skip both the matcher and the
+        confirm.
+
+        All of a tier's positives whose group the native library handles
+        (``NativeConfirm.handled``) go to it in ONE GIL-released call;
+        the rest, and every positive of a tier whose native call failed,
+        take ``_confirm_python`` — the reference walk, bit-identical.
 
         Host-twin entries (``from_device`` False) computed exact hits
         already and pass through untouched.
@@ -1174,8 +1195,10 @@ class WafEngine:
         is the host blocked on the matcher's output, ``prefilter_confirm``
         the unpack, the host transforms, the exact walk and the repack."""
         g = int(self.model.e_lg.shape[0])
-        cols = self.model.prefilter_cols
-        n_rows = n_hits = n_confirms = 0
+        cols = self._prefilter_dev_cols
+        nc = self._native_confirm
+        n_rows = n_hits = n_confirms = n_native = n_errors = 0
+        failure = None
         out = list(tier_hits)
         for ti, (hp, tier, dev) in enumerate(zip(tier_hits, tiers, from_device)):
             if not dev:
@@ -1183,40 +1206,30 @@ class WafEngine:
             with rec.stage("prefilter_wait"):
                 packed = np.asarray(jax.device_get(hp))
             with rec.stage("prefilter_confirm"):
-                hits = np.unpackbits(packed, axis=1, count=g).astype(bool)
+                hits = np.unpackbits(packed, axis=1, count=g)
                 n_rows += hits.shape[0] * len(cols)
-                d = lg = vd = vl = None
-                val_cache: dict[tuple[int, int], bytes] = {}
-                changed = False
-                for col, gid in cols:
-                    rows = np.flatnonzero(hits[:, col])
-                    if rows.size == 0:
-                        continue
-                    n_hits += int(rows.size)
-                    if d is None:
-                        d = np.asarray(tier[0])
-                        lg = np.asarray(tier[1])
-                        vd = np.asarray(tier[6])
-                        vl = np.asarray(tier[7])
-                    pid = self.compiled.group_pipeline[gid]
-                    slot = int(self.model.host_variant_index[pid])
-                    dfa = self.compiled.groups[gid].dfa
-                    for i in rows:
-                        i = int(i)
-                        val = val_cache.get((pid, i))
-                        if val is None:
-                            if slot >= 0:
-                                val = vd[slot, i, : vl[slot, i]].tobytes()
-                            else:
-                                names = list(self.compiled.pipelines[pid])
-                                val = apply_pipeline(d[i, : lg[i]].tobytes(), names)
-                            val_cache[(pid, i)] = val
-                        if dfa.search(val):
-                            n_confirms += 1
-                        else:
-                            hits[i, col] = False
-                            changed = True
-                if changed:
+                rows, ks = np.nonzero(hits[:, cols])
+                if rows.size == 0:
+                    continue
+                n_hits += int(rows.size)
+                ok = np.zeros(rows.size, dtype=bool)
+                native = nc.handled[ks]
+                if native.any():
+                    try:
+                        ok[native] = nc.run(
+                            tier, rows[native], nc.group_of[ks[native]]
+                        )
+                        n_native += int(native.sum())
+                    except RuntimeError as err:
+                        n_errors += 1
+                        failure = err
+                        native[:] = False  # the whole tier is re-walked
+                rest = ~native
+                if rest.any():
+                    ok[rest] = self._confirm_python(tier, rows[rest], ks[rest])
+                n_confirms += int(ok.sum())
+                if not ok.all():
+                    hits[rows[~ok], cols[ks[~ok]]] = 0
                     out[ti] = np.packbits(hits, axis=1)
         if n_rows:
             with self._prefilter_lock:
@@ -1224,7 +1237,44 @@ class WafEngine:
                 self.prefilter_stats["hits"] += n_hits
                 self.prefilter_stats["confirms"] += n_confirms
                 self.prefilter_stats["false_positives"] += n_hits - n_confirms
+                self.prefilter_stats["native_hits"] += n_native
+                self.prefilter_stats["native_errors"] += n_errors
+                first_failure = n_errors and not self._native_confirm_failed
+                self._native_confirm_failed |= bool(n_errors)
+            if first_failure:
+                log.error(
+                    "native prefilter confirm failed; the Python walk confirms"
+                    " such windows",
+                    failure,
+                )
         return tuple(out)
+
+    def _confirm_python(self, tier, rows, ks) -> np.ndarray:
+        """The reference confirm: ``DFA.search`` over ``apply_pipeline``
+        of each positive ``(rows[j], prefilter column ks[j])``, one
+        transform per (pipeline, row). What the native call is held to
+        (tests/test_prefilter_confirm_native.py), and what serves where
+        the library is absent, older, or lacks an opcode."""
+        d = np.asarray(tier[0])
+        lg = np.asarray(tier[1])
+        vd = np.asarray(tier[6])
+        vl = np.asarray(tier[7])
+        ok = np.zeros(rows.size, dtype=bool)
+        val_cache: dict[tuple[int, int], bytes] = {}
+        for j, (i, k) in enumerate(zip(rows.tolist(), ks.tolist())):
+            gid = self.model.prefilter_cols[k][1]
+            pid = self.compiled.group_pipeline[gid]
+            val = val_cache.get((pid, i))
+            if val is None:
+                slot = int(self.model.host_variant_index[pid])
+                if slot >= 0:
+                    val = vd[slot, i, : vl[slot, i]].tobytes()
+                else:
+                    names = list(self.compiled.pipelines[pid])
+                    val = apply_pipeline(d[i, : lg[i]].tobytes(), names)
+                val_cache[(pid, i)] = val
+            ok[j] = self.compiled.groups[gid].dfa.search(val)
+        return ok
 
     def automata_summary(self) -> dict:
         """Automata-tier composition + prefilter counters for stats,

@@ -101,7 +101,8 @@ _NUMERIC_ORDER = [
 #               must stay signed c_int or the sentinel inverts (CKO-N007).
 #   "optional": symbol tolerated missing in an older .so.
 #   "group":    all-or-nothing feature set; "plan" gates the tiered
-#               window pipeline (lib._cko_has_plan).
+#               window pipeline (lib._cko_has_plan), "confirm" the
+#               prefilter confirm call (lib._cko_has_confirm).
 _ABI: dict = {
     "cko_ctx_new": {"args": ["buf", "size"], "ret": "ptr"},
     "cko_ctx_free": {"args": ["ptr"], "ret": None},
@@ -167,6 +168,26 @@ _ABI: dict = {
         "group": "plan",
     },
     "cko_plan_free": {"args": ["ptr"], "ret": None, "group": "plan"},
+    # Prefilter confirm ABI (NativeConfirm): the prefiltered groups' exact
+    # DFAs and pipelines go in once per engine, then one GIL-released
+    # call per tier per window confirms every device prefilter positive.
+    # Older .so -> NativeConfirm handles no group and the Python walk
+    # (DFA.search) serves.
+    "cko_confirm_new": {
+        "args": ["buf", "size"], "ret": "ptr", "group": "confirm",
+    },
+    "cko_confirm_free": {"args": ["ptr"], "ret": None, "group": "confirm"},
+    "cko_confirm_run": {
+        # handle, data, lengths, U, L, vdata, vlengths, H, pos_row
+        # (int32[]), pos_group (int32[]), n_pos, out (uint8[n_pos]).
+        "args": [
+            "ptr", "arr", "arr", "int", "int", "arr", "arr", "int", "arr",
+            "arr", "int", "arr",
+        ],
+        "ret": "int",
+        "rc": True,
+        "group": "confirm",
+    },
 }
 
 # Token -> ctypes type. nativelint cross-checks the TOKEN against the C
@@ -204,6 +225,7 @@ def _bind(lib) -> None:
         ret = spec.get("ret")
         fn.restype = _CTYPES[ret] if ret is not None else None
     lib._cko_has_plan = "plan" not in missing_groups
+    lib._cko_has_confirm = "confirm" not in missing_groups
 
 
 def _lib_path() -> Path | None:
@@ -731,6 +753,125 @@ class NativeTensorizer:
         if self._ctx is not None and self._lib is not None:
             self._lib.cko_ctx_free(self._ctx)
             self._ctx = None
+
+
+def serialize_confirm(crs, prefilter_cols, host_variant_index):
+    """Build the blob for cko_confirm_new: the prefiltered groups' exact
+    DFAs laid out for a raw-byte walk, and their pipelines as opcodes.
+
+    Returns ``(blob, handled, group_of)``: ``handled[k]`` says whether
+    prefilter column ``k`` (an index into ``prefilter_cols``) is in the
+    blob — every op of its group's pipeline has a native opcode (md5/sha1
+    have none) — and ``group_of[k]`` its group index there. ``blob`` is
+    None when no column is."""
+    handled = np.zeros(len(prefilter_cols), dtype=bool)
+    group_of = np.zeros(len(prefilter_cols), dtype=np.int32)
+    pipes: dict[int, int] = {}
+    pipe_blobs: list[bytes] = []
+    group_blobs: list[bytes] = []
+    for k, (_col, gid) in enumerate(prefilter_cols):
+        pid = crs.group_pipeline[gid]
+        ops = [_OPCODES.get(n) for n in crs.pipelines[pid]]
+        dfa = crs.groups[gid].dfa
+        if None in ops or not (dfa.n_states or dfa.always_match):
+            continue
+        if pid not in pipes:
+            pipes[pid] = len(pipe_blobs)
+            pipe_blobs.append(struct.pack("<I", len(ops)) + bytes(ops))
+        # HostFlatDFA's layout for one group: the classmap resolved into
+        # a raw-byte column per state, the emit bit folded in (bit 31).
+        table = dfa.trans[:, dfa.classmap].astype(np.uint32)
+        table |= dfa.emit[:, dfa.classmap].astype(np.uint32) << 31
+        group_of[k] = len(group_blobs)
+        handled[k] = True
+        group_blobs.append(
+            struct.pack(
+                "<IiIB", pipes[pid], int(host_variant_index[pid]),
+                dfa.n_states, int(dfa.always_match),
+            )
+            + table.tobytes()
+            + np.ascontiguousarray(dfa.match_end, dtype=np.uint8).tobytes()
+        )
+    if not group_blobs:
+        return None, handled, group_of
+    blob = b"".join(
+        [struct.pack("<I", len(pipe_blobs)), *pipe_blobs,
+         struct.pack("<I", len(group_blobs)), *group_blobs]
+    )
+    return blob, handled, group_of
+
+
+class NativeConfirm:
+    """The prefiltered groups' exact DFAs inside the native library: what
+    ``WafEngine._confirm_prefilter`` hands a window's device prefilter
+    positives to. Built once per engine; ``handled[k]`` says whether
+    prefilter column ``k`` is confirmed natively — the library is
+    loaded, exports the confirm ABI, and ``serialize_confirm`` could lay
+    the group out. Columns not handled keep the Python walk."""
+
+    def __init__(self, crs, prefilter_cols, host_variant_index):
+        self._lib = lib = load_library()
+        self._h = None
+        self.handled = np.zeros(len(prefilter_cols), dtype=bool)
+        self.group_of = np.zeros(len(prefilter_cols), dtype=np.int32)
+        if lib is None or not getattr(lib, "_cko_has_confirm", False):
+            return
+        blob, handled, group_of = serialize_confirm(
+            crs, prefilter_cols, host_variant_index
+        )
+        if blob is None:
+            return
+        h = lib.cko_confirm_new(blob, len(blob))
+        if h:
+            self._h, self.handled, self.group_of = h, handled, group_of
+
+    def run(self, tier, rows: np.ndarray, groups: np.ndarray) -> np.ndarray:
+        """Confirm positives ``(rows[k], groups[k])`` of one tier in one
+        GIL-released call; returns uint8 [n], 1 where the exact DFA
+        matches. Raises ``RuntimeError`` on a negative rc or a ctypes
+        argument rejection — the caller counts it and confirms the
+        window on the Python path, never silently (the CKO-N004 class)."""
+        data = np.ascontiguousarray(tier[0], dtype=np.uint8)
+        lengths = np.ascontiguousarray(tier[1], dtype=np.int32)
+        vdata = np.ascontiguousarray(tier[6], dtype=np.uint8)
+        vlengths = np.ascontiguousarray(tier[7], dtype=np.int32)
+        rows = np.ascontiguousarray(rows, dtype=np.int32)
+        groups = np.ascontiguousarray(groups, dtype=np.int32)
+        out = np.empty(rows.size, dtype=np.uint8)
+        if (
+            data.ndim != 2
+            or lengths.shape != data.shape[:1]
+            or vdata.ndim != 3
+            or vdata.shape[1:] != data.shape
+            or vlengths.shape != vdata.shape[:2]
+            or groups.shape != rows.shape
+        ):
+            raise RuntimeError("native prefilter confirm rejected: tier shapes disagree")
+        try:
+            rc = self._lib.cko_confirm_run(
+                self._h,
+                data.ctypes.data_as(ctypes.c_void_p),
+                lengths.ctypes.data_as(ctypes.c_void_p),
+                data.shape[0],
+                data.shape[1],
+                vdata.ctypes.data_as(ctypes.c_void_p),
+                vlengths.ctypes.data_as(ctypes.c_void_p),
+                vdata.shape[0],
+                rows.ctypes.data_as(ctypes.c_void_p),
+                groups.ctypes.data_as(ctypes.c_void_p),
+                rows.size,
+                out.ctypes.data_as(ctypes.c_void_p),
+            )
+        except ctypes.ArgumentError as err:
+            raise RuntimeError(f"native prefilter confirm rejected: {err}") from err
+        if rc != 0:
+            raise RuntimeError(f"native prefilter confirm failed rc={rc}")
+        return out
+
+    def __del__(self):
+        if self._h is not None and self._lib is not None:
+            self._lib.cko_confirm_free(self._h)
+            self._h = None
 
 
 def blob_over_limit(blob: bytes, limit: int) -> list[int]:

@@ -1219,6 +1219,16 @@ class TpuEngineSidecar:
             "Prefilter positives the exact DFA cleared (over-approximation"
             " cost)",
         ).set_function(lambda: float(self._prefilter_stat("false_positives")))
+        self.metrics.gauge(
+            "cko_prefilter_native_hits_total",
+            "Prefilter positives confirmed by the native library's one call"
+            " per tier (the rest took the Python walk)",
+        ).set_function(lambda: float(self._prefilter_stat("native_hits")))
+        self.metrics.gauge(
+            "cko_prefilter_native_errors_total",
+            "Native prefilter confirm calls that failed; such a tier is"
+            " re-confirmed by the Python walk",
+        ).set_function(lambda: float(self._prefilter_stat("native_errors")))
         self.batcher.on_engine_error = (
             lambda _engine, err: self.degraded.record_device_failure(err)
         )

@@ -23,7 +23,12 @@ Pass requires all of:
   - forced ``cko_result_export`` / ``cko_plan_export`` overflows return
     a negative rc, and a clean window exported into the SAME buffers
     afterwards digests identically to a fresh-buffer export — a failed
-    export never leaves residue the next window can observe.
+    export never leaves residue the next window can observe;
+  - the prefilter confirm ABI (``cko_confirm_new`` / ``cko_confirm_run``)
+    refuses malformed table blobs with NULL, walks rows exactly ``L``
+    long, zero-length rows and positives on the last row inside their
+    buffers, and answers a row, group, plane or length out of range with
+    a negative rc.
 
 Skips LOUDLY (exit 0) when the sanitized library or libasan is missing.
 Env knobs: CKO_SANITIZE_SEED / CKO_SANITIZE_ITERS / CKO_SANITIZE_WINDOWS.
@@ -310,6 +315,129 @@ def _export_overflow(out: dict) -> None:
             lib.cko_plan_free(plan)
 
 
+# Prefiltered groups for the confirm fuzz: the 384-state pattern of
+# tests/test_automata_routing.py under a device pipeline (raw row +
+# native transforms) and a host one (variant plane).
+CONFIRM_RULES = """
+SecRuleEngine On
+SecRule ARGS "@rx (a|bc)*a(a|bc){7}d" "id:1,phase:2,deny,status:403,t:none,t:urlDecodeUni,t:htmlEntityDecode"
+SecRule ARGS "@rx (a|bc)*a(a|bc){7}d" "id:2,phase:2,deny,status:403,t:none,t:lowercase"
+SecRule ARGS "@rx (a|bc)*a(a|bc){7}d" "id:3,phase:2,deny,status:403,t:none,t:cmdLine"
+"""
+
+
+def _confirm_bounds(out: dict) -> None:
+    """Seeded bounds fuzz of the prefilter confirm ABI: malformed table
+    blobs must come back NULL; calls with rows exactly ``L`` long,
+    zero-length rows and positives on the last row must stay inside
+    their buffers (ASan watches) and agree between builds; a row, group,
+    plane or length out of range must return a negative rc."""
+    import random
+
+    import numpy as np
+
+    from coraza_kubernetes_operator_tpu.engine import WafEngine
+    from coraza_kubernetes_operator_tpu.native import (
+        load_library,
+        serialize_confirm,
+    )
+
+    lib = load_library()
+    if not getattr(lib, "_cko_has_confirm", False):
+        out["confirm"] = "absent"
+        return
+    os.environ["CKO_AUTOMATA"] = "1"
+    engine = WafEngine(CONFIRM_RULES)
+    nc = engine._native_confirm
+    n_groups = len(engine.model.prefilter_cols)
+    assert n_groups == 3 and nc.handled.all(), (n_groups, nc.handled)
+    slots = [
+        int(engine.model.host_variant_index[engine.compiled.group_pipeline[g]])
+        for _c, g in engine.model.prefilter_cols
+    ]
+    assert min(slots) == -1 and max(slots) >= 0, slots
+    blob, _handled, _group_of = serialize_confirm(
+        engine.compiled, engine.model.prefilter_cols, engine.model.host_variant_index
+    )
+
+    rng = random.Random(SEED)
+    marks = []
+    # Malformed table blobs: truncations, lying counts, a next-state past S.
+    for i in range(48):
+        cut = rng.randrange(0, len(blob))
+        mut = bytearray(blob[:cut] if i % 3 == 0 else blob)
+        if i % 3 == 1:
+            off = rng.randrange(0, 64)
+            mut[off : off + 4] = rng.choice([0xFFFFFFFF, 0x7FFFFFFF, 1 << 20]).to_bytes(4, "little")
+        if i % 3 == 2:
+            off = len(blob) - 4 * rng.randrange(600, 60_000)
+            mut[off : off + 4] = (0x7FFFFFFF).to_bytes(4, "little")
+        h = lib.cko_confirm_new(bytes(mut), len(mut))
+        marks.append("null" if not h else "ok")
+        if h:
+            lib.cko_confirm_free(h)
+    assert marks.count("null") >= 40, marks
+
+    u, width = 9, 64
+    h_planes = max(1, max(slots) + 1)
+    alphabet = b"abcd%&#;x3Cu0 "
+
+    def tier():
+        data = np.zeros((u, width), dtype=np.uint8)
+        lengths = np.zeros(u, dtype=np.int32)
+        vdata = np.zeros((h_planes, u, width), dtype=np.uint8)
+        vlengths = np.zeros((h_planes, u), dtype=np.int32)
+        for i in range(u):
+            # Row 0 stays empty, the last row is exactly `width` long.
+            n = 0 if i == 0 else width if i == u - 1 else rng.randrange(0, width + 1)
+            data[i, :n] = rng.choices(alphabet, k=n)
+            lengths[i] = n
+            for hp in range(h_planes):
+                m = width if i == u - 1 else rng.randrange(0, width + 1)
+                vdata[hp, i, :m] = rng.choices(alphabet, k=m)
+                vlengths[hp, i] = m
+        return (data, lengths, None, None, None, None, vdata, vlengths)
+
+    def ptr(a):
+        return a.ctypes.data_as(ctypes.c_void_p)
+
+    def raw(t, rows, groups, n_planes=h_planes):
+        rows = np.asarray(rows, dtype=np.int32)
+        groups = np.asarray(groups, dtype=np.int32)
+        res = np.zeros(max(1, rows.size), dtype=np.uint8)
+        rc = lib.cko_confirm_run(
+            nc._h, ptr(t[0]), ptr(t[1]), u, width, ptr(t[6]), ptr(t[7]),
+            n_planes, ptr(rows), ptr(groups), rows.size, ptr(res),
+        )
+        return rc, res[: rows.size]
+
+    digest = hashlib.sha256()
+    for _ in range(ITERS):
+        t = tier()
+        # Every (row, group), the last row and the empty one included.
+        rows = np.repeat(np.arange(u), n_groups)
+        groups = np.tile(np.arange(n_groups), u)
+        res = nc.run(t, rows, groups)
+        digest.update(res.tobytes())
+        rc0, _ = raw(t, [], [])
+        bad = [
+            raw(t, [u], [0])[0],  # one past the last row
+            raw(t, [-1], [0])[0],
+            raw(t, [u - 1], [n_groups])[0],
+            raw(t, [0], [-1])[0],
+            raw(t, [0], [slots.index(max(slots))], n_planes=0)[0],  # no such plane
+        ]
+        t[1][u - 1] = width + 1  # a length one past the row
+        t[7][:, u - 1] = width + 1
+        bad += [raw(t, [u - 1], [k])[0] for k in range(n_groups)]
+        t[1][0] = -1
+        bad.append(raw(t, [0], [slots.index(-1)])[0])
+        assert rc0 == 0 and all(rc < 0 for rc in bad), (rc0, bad)
+        digest.update(repr(bad).encode())
+    out["confirm_blobs"] = marks
+    out["confirm"] = _digest(digest)
+
+
 def ptr_arr(a):
     import numpy as np
 
@@ -321,6 +449,7 @@ def child() -> int:
     _corpus_digests(out)
     _fuzz_bounds(out)
     _export_overflow(out)
+    _confirm_bounds(out)
     print("SANITIZE-DIGEST " + json.dumps(out, sort_keys=True))
     return 0
 
@@ -438,6 +567,7 @@ def main() -> int:
                 "windows": d_reg.get("windows"),
                 "fuzz_cases": d_reg.get("fuzz_cases"),
                 "export_rcs": d_reg.get("export_rcs"),
+                "confirm": (d_reg.get("confirm") or "")[:12],
                 "verdicts": (d_reg.get("verdicts") or "")[:12],
                 "tensors": (d_reg.get("tensors") or "")[:12],
             }

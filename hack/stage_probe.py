@@ -16,7 +16,8 @@ Then one closed-loop window per entry of ``--windows``:
 
 Each prints one JSON line: the window's end-to-end numbers from the
 client's side, and after − before of ``/waf/v1/stats`` ``stages`` as
-milliseconds per window (``lane_wait``: per request). What
+milliseconds per window (``lane_wait``: per request), and after − before
+of ``automata.prefilter`` (``native_hits`` against ``hits``). What
 ``wafbench.run --trace 1`` reports for the same stages also holds its
 traced intervals; this is the untraced reading to hold it against.
 The result is no benchmark line: nothing is checked for correctness
@@ -146,7 +147,9 @@ def main() -> int:
                         failed=numbers["failed"],
                         traces_written=after["tracing"]["writes"] - before["tracing"]["writes"],
                         memory_peak_bytes=after["device"]["memory_peak_bytes"],
-                        stages_ms=stage_ms(before["stages"], after["stages"]))
+                        stages_ms=stage_ms(before["stages"], after["stages"]),
+                        prefilter={k: v - before["automata"]["prefilter"].get(k, 0) for k, v
+                                   in after["automata"]["prefilter"].items()})
             harness.emit(line)
         proc.send_signal(signal.SIGTERM)
         return proc.wait(timeout=harness.T_EXIT_S)

@@ -2792,3 +2792,150 @@ int cko_plan_export(void* h, const unsigned long long* ptrs,
 void cko_plan_free(void* h) { delete (Plan*)h; }
 
 }  // extern "C"
+
+// ---------------------------------------------------------------------------
+// Prefilter confirm (engine/waf.py:_confirm_prefilter): the exact DFAs of
+// the prefiltered groups, laid out for a raw-byte walk, plus each group's
+// transform pipeline. One cko_confirm_run call confirms every device
+// prefilter positive of one tier; semantics are DFA.search
+// (compiler/re_dfa.py) over apply_pipeline(row) — emit on transition,
+// match_end at end of input, always_match — and
+// tests/test_prefilter_confirm_native.py holds the two bit-for-bit equal.
+// ---------------------------------------------------------------------------
+
+namespace {
+
+constexpr uint32_t kConfirmEmit = 0x80000000u;
+
+struct ConfirmGroup {
+  uint32_t pipe = 0;   // index into Confirm::pipes (memo key with the row)
+  int32_t slot = -1;   // host variant plane, or -1: transform the raw row
+  uint32_t S = 0;
+  bool always = false;
+  std::vector<uint32_t> table;     // [S*256]: next state | kConfirmEmit
+  std::vector<uint8_t> match_end;  // [S]
+
+  bool search(const uint8_t* p, size_t n) const {
+    if (always) return true;
+    const uint32_t* t = table.data();
+    uint32_t s = 0;
+    for (size_t i = 0; i < n; i++) {
+      const uint32_t v = t[(size_t)s * 256 + p[i]];
+      if (v & kConfirmEmit) return true;
+      s = v;
+    }
+    return match_end[s] != 0;
+  }
+};
+
+struct Confirm {
+  std::vector<std::vector<uint8_t>> pipes;  // transform opcodes, in order
+  std::vector<ConfirmGroup> groups;
+};
+
+}  // namespace
+
+extern "C" {
+
+// Blob (little-endian): u32 n_pipes, per pipe {u32 n_ops, u8 ops[]};
+// u32 n_groups, per group {u32 pipe, i32 slot, u32 S, u8 always,
+// u32 table[S*256], u8 match_end[S]}. NULL on a malformed blob.
+void* cko_confirm_new(const uint8_t* blob, size_t len) {
+  Reader r{blob, blob + len};
+  auto cf = std::make_unique<Confirm>();
+  const uint32_t n_pipes = r.u32();
+  for (uint32_t i = 0; i < n_pipes && r.ok; i++) {
+    const uint32_t n_ops = r.u32();
+    if (!r.ok || (size_t)(r.end - r.p) < n_ops) return nullptr;
+    std::vector<uint8_t> ops;
+    for (uint32_t j = 0; j < n_ops; j++) {
+      const uint8_t op = r.p[j];
+      if (op >= OP_COUNT_) return nullptr;
+      if (op != OP_NONE) ops.push_back(op);
+    }
+    r.p += n_ops;
+    cf->pipes.push_back(std::move(ops));
+  }
+  const uint32_t n_groups = r.u32();
+  for (uint32_t i = 0; i < n_groups && r.ok; i++) {
+    ConfirmGroup g;
+    g.pipe = r.u32();
+    g.slot = (int32_t)r.u32();
+    g.S = r.u32();
+    g.always = r.u8() != 0;
+    if (!r.ok || g.pipe >= cf->pipes.size() || g.slot < -1 ||
+        g.S >= kConfirmEmit || (g.S == 0 && !g.always))
+      return nullptr;
+    const size_t cells = (size_t)g.S * 256;
+    if ((size_t)(r.end - r.p) / 4 < cells) return nullptr;
+    g.table.resize(cells);
+    if (cells) memcpy(g.table.data(), r.p, cells * 4);
+    r.p += cells * 4;
+    for (uint32_t v : g.table)
+      if ((v & ~kConfirmEmit) >= g.S) return nullptr;
+    if ((size_t)(r.end - r.p) < g.S) return nullptr;
+    g.match_end.assign(r.p, r.p + g.S);
+    r.p += g.S;
+    cf->groups.push_back(std::move(g));
+  }
+  if (!r.ok || r.p != r.end) return nullptr;
+  return cf.release();
+}
+
+void cko_confirm_free(void* h) { delete (Confirm*)h; }
+
+// Confirm n_pos device prefilter positives of one tier. data [U, L] uint8
+// and lengths [U] int32 are the tier's raw rows, vdata [H, U, L] /
+// vlengths [H, U] its host-variant planes; pos_row[k] / pos_group[k]
+// name positive k (group = index into the handle's groups). A group's
+// pipeline runs once per (pipeline, row); out[k] = 1 iff the exact DFA
+// matches. Returns 0, or a negative code when an argument is out of
+// range — out is then unspecified and the caller confirms the window on
+// the Python path.
+int cko_confirm_run(void* h, const uint8_t* data, const int32_t* lengths,
+                    int U, int L, const uint8_t* vdata,
+                    const int32_t* vlengths, int H, const int32_t* pos_row,
+                    const int32_t* pos_group, int n_pos, uint8_t* out) {
+  const Confirm* cf = (const Confirm*)h;
+  if (!cf || U < 0 || L < 0 || H < 0 || n_pos < 0) return -1;
+  if (n_pos == 0) return 0;
+  if (!data || !lengths || !pos_row || !pos_group || !out) return -1;
+  const size_t n_pipes = cf->pipes.size();
+  std::vector<int32_t> memo(n_pipes * (size_t)U, -1);
+  std::vector<bytes> vals;
+  for (int k = 0; k < n_pos; k++) {
+    const int32_t row = pos_row[k], gi = pos_group[k];
+    if (row < 0 || row >= U) return -2;
+    if (gi < 0 || (size_t)gi >= cf->groups.size()) return -3;
+    const ConfirmGroup& g = cf->groups[(size_t)gi];
+    if (g.slot >= 0) {
+      if (g.slot >= H || !vdata || !vlengths) return -4;
+      const int32_t vl = vlengths[(size_t)g.slot * (size_t)U + (size_t)row];
+      if (vl < 0 || vl > L) return -5;
+      out[k] = g.search(
+          vdata + ((size_t)g.slot * (size_t)U + (size_t)row) * (size_t)L,
+          (size_t)vl);
+      continue;
+    }
+    const int32_t lg = lengths[row];
+    if (lg < 0 || lg > L) return -5;
+    const uint8_t* raw = data + (size_t)row * (size_t)L;
+    const std::vector<uint8_t>& ops = cf->pipes[g.pipe];
+    if (ops.empty()) {
+      out[k] = g.search(raw, (size_t)lg);
+      continue;
+    }
+    int32_t& mi = memo[(size_t)g.pipe * (size_t)U + (size_t)row];
+    if (mi < 0) {
+      bytes v((const char*)raw, (size_t)lg);
+      for (uint8_t op : ops) v = apply_op(op, v);
+      mi = (int32_t)vals.size();
+      vals.push_back(std::move(v));
+    }
+    const bytes& v = vals[(size_t)mi];
+    out[k] = g.search((const uint8_t*)v.data(), v.size());
+  }
+  return 0;
+}
+
+}  // extern "C"
