@@ -75,6 +75,12 @@ class ExtractedTarget:
 class Extraction:
     targets: list[ExtractedTarget]
     numerics: dict[tuple, int]
+    # The body as the processor met it: which one read it ("JSON",
+    # "URLENCODED", "MULTIPART" or "" for none), the bytes received and
+    # whether it failed to parse (engine/waf.py:BODY_COUNTERS).
+    processor: str = ""
+    body_bytes: int = 0
+    body_error: int = 0
 
 
 def _parse_pairs(raw: str, sep: str = "&") -> list[tuple[bytes, bytes]]:
@@ -365,7 +371,13 @@ class TargetExtractor:
                     if sel is None or (t.name or "").lower() == sel:
                         count += 1
                 numerics[key] = count
-        return Extraction(targets=targets, numerics=numerics)
+        return Extraction(
+            targets=targets,
+            numerics=numerics,
+            processor=processor,
+            body_bytes=0 if phase1_only else len(req.body),
+            body_error=reqbody_error,
+        )
 
     def _eval_hostop(self, key: tuple, targets: list[ExtractedTarget]) -> int:
         from ..compiler.sqli import is_sqli

@@ -69,6 +69,15 @@ def _bucket_rows(n: int) -> int:
 _TIER_BOUNDS = (64, 256, 1024, 4096, 16384)
 _MIN_TIER_ROWS = 256
 
+# ``WafEngine.body_summary``: bodied requests by the processor that read
+# them, their bytes, and the bodies a processor could not parse. The
+# native tensorizer counts in this order (cko_result_bodies).
+BODY_COUNTERS = (
+    "json_total", "urlencoded_total", "multipart_total", "other_total",
+    "bytes_total", "parse_errors",
+)
+_BODY_SLOT = {"JSON": 0, "URLENCODED": 1, "MULTIPART": 2}
+
 # Kind-partitioned matching: rows within a length tier are further split
 # into at most CKO_TIER_PARTS partitions by which matcher blocks their
 # kinds can reach (models/waf_model.py block_kinds), so header-only rows
@@ -394,6 +403,8 @@ class InFlightBatch:
     # device has consumed the host buffers by then, so the arena may
     # recycle them into the next window.
     arena_lease: object = None
+    # An injected readback hang (testing/faults.py) is in progress.
+    hung: bool = False
 
 
 class WafEngine:
@@ -537,6 +548,12 @@ class WafEngine:
         # Distinct executable shape signatures this engine has dispatched
         # (cko_exec_signatures / CompileReport.exec_signatures).
         self._exec_signatures: set = set()
+        # Cumulative over the windows this engine dispatched: matcher
+        # tiers launched, their cells (rows x width) and the bytes in
+        # them that are not padding (``tiering_summary``); bodied
+        # requests the Python extractor read (``body_summary``).
+        self._tiering = {"windows": 0, "tiers": 0, "cells": 0, "real_bytes": 0}
+        self._bodies = np.zeros(len(BODY_COUNTERS), dtype=np.int64)
         # Host-tier-path helpers: _dev_col_of[orig_gid] = device hit
         # column (inverse of model.group_order), and per matcher block
         # (segs then banks — match_tier's column order) its group count,
@@ -652,6 +669,12 @@ class WafEngine:
                     chunk = kinds[off : off + 3]
                     chunk += [0] * (3 - len(chunk))
                     rows.append((i, t.value[:body_cap], tuple(chunk)))
+
+        for ex in extractions:
+            if ex.body_bytes:
+                self._bodies[_BODY_SLOT.get(ex.processor, 3)] += 1
+                self._bodies[4] += ex.body_bytes
+                self._bodies[5] += ex.body_error
 
         n_req = _bucket(max(1, len(extractions)))
         n_targets = _bucket_rows(max(1, len(rows)))
@@ -929,6 +952,20 @@ class WafEngine:
             if inflight.arena_lease is not None:
                 inflight.arena_lease.release()
 
+    def device_done(self, inflight: InFlightBatch) -> bool:
+        """True once the window's device work has finished: every output
+        array is computed (a host twin's NumPy output always is), so all
+        that is left of ``collect`` is the host's. Asked by the batcher's
+        watchdog from another thread while ``collect`` runs; an injected
+        hang stands for a hung device and reads as not done."""
+        if inflight.hung:
+            return False
+        return all(
+            leaf.is_ready()
+            for leaf in jax.tree_util.tree_leaves(inflight.out)
+            if hasattr(leaf, "is_ready")
+        )
+
     def _collect(self, inflight: InFlightBatch) -> list[Verdict]:
         if inflight.out is None:
             return [
@@ -938,7 +975,9 @@ class WafEngine:
 
         hang = injected_device_hang_s()
         if hang > 0:
+            inflight.hung = True
             time.sleep(hang)
+            inflight.hung = False
         from .compile_cache import EXEC_CACHE
 
         EXEC_CACHE.note_window(
@@ -1108,6 +1147,12 @@ class WafEngine:
                 tiers, numvals, max_phase=max_phase, masks=masks, cached=cached
             )
             specs = match_specs + [post_spec]
+            counts = self._tiering
+            counts["windows"] += 1
+            for tier in tiers:
+                counts["tiers"] += 1
+                counts["cells"] += tier[0].shape[0] * tier[0].shape[1]
+                counts["real_bytes"] += int(np.sum(tier[1]))
             for s in specs:
                 self._exec_signatures.add(spec_key(s))
             self.compiled.report.exec_signatures = len(self._exec_signatures)
@@ -1292,6 +1337,27 @@ class WafEngine:
             "pre_banks": len(self.model.pre_banks),
             "prefilter": pstats,
         }
+
+    def tiering_summary(self) -> dict:
+        """What the windows' tiering came to, cumulative: windows
+        dispatched, matcher tiers launched (one executable call each),
+        their cells (unique rows x width, as bucketed) and the real
+        bytes in them. 1 - real_bytes / cells is the share of the
+        matchers' bytes that was padding."""
+        return dict(self._tiering)
+
+    def body_summary(self) -> dict:
+        """Requests that came with a body, by the processor that read it
+        (the native tensorizer's count and the Python extractor's), the
+        body bytes received and the bodies that did not parse."""
+        total = self._bodies + getattr(self._native, "bodies", 0)
+        out: dict = dict(zip(BODY_COUNTERS, (int(v) for v in total)))
+        # A native library from before these counters tensorizes bodies
+        # and counts none: say so, or the zeros read as "no bodies".
+        out["native_uncounted"] = bool(
+            getattr(self._native, "available", False)
+            and not getattr(self._native, "counts_bodies", True))
+        return out
 
     # -- host twins for not-yet-compiled stages (lazy tier compilation) ------
 

@@ -123,6 +123,14 @@ _ABI: dict = {
         "rc": True,
     },
     "cko_result_free": {"args": ["ptr"], "ret": None},
+    # Bodied requests of a result / a plan by body processor, their bytes
+    # and parse errors (int64[6]); an older .so counts nothing.
+    "cko_result_bodies": {
+        "args": ["ptr", "arr"], "ret": "int", "rc": True, "optional": True,
+    },
+    "cko_plan_bodies": {
+        "args": ["ptr", "arr"], "ret": "int", "rc": True, "optional": True,
+    },
     "cko_json_to_blob": {"args": ["buf", "size"], "ret": "ptr"},
     "cko_blob_data": {"args": ["ptr"], "ret": "ptr"},
     "cko_blob_len": {"args": ["ptr"], "ret": "size"},
@@ -466,6 +474,7 @@ class NativeTensorizer:
         self.windows_total = 0
         self.window_s_total = 0.0
         self._window_recent: deque[float] = deque(maxlen=512)
+        self.bodies = np.zeros(6, dtype=np.int64)  # _count_bodies
         if self._lib is None:
             return
         blob = serialize_config(crs)
@@ -481,6 +490,12 @@ class NativeTensorizer:
     @property
     def available(self) -> bool:
         return self._ctx is not None
+
+    @property
+    def counts_bodies(self) -> bool:
+        """False for a library built before ``cko_plan_bodies`` /
+        ``cko_result_bodies``: it parses bodies and counts none."""
+        return all(hasattr(self._lib, s) for s in ("cko_plan_bodies", "cko_result_bodies"))
 
     @property
     def tiered(self) -> bool:
@@ -586,6 +601,7 @@ class NativeTensorizer:
             meta = np.zeros(nt * 6, dtype=np.int64)
             lib.cko_plan_tiers(plan, meta.ctypes.data_as(ctypes.c_void_p))
             meta = meta.reshape(nt, 6)
+            self._count_bodies("cko_plan_bodies", plan)
             masks = tuple(
                 int(m[5]) if m[4] else None for m in meta.tolist()
             )
@@ -711,8 +727,20 @@ class NativeTensorizer:
         )
         return out
 
+    def _count_bodies(self, symbol: str, handle) -> None:
+        """Add a result's (or plan's) bodied-request counts to ``bodies``
+        (``engine/waf.py:BODY_COUNTERS`` order)."""
+        fn = getattr(self._lib, symbol, None)
+        if fn is None:
+            return
+        out = np.zeros(6, dtype=np.int64)
+        if fn(handle, out.ctypes.data_as(ctypes.c_void_p)) == 0 and out.any():
+            with self._stats_lock:
+                self.bodies += out
+
     def _export(self, res, n_requests: int):
         try:
+            self._count_bodies("cko_result_bodies", res)
             n_rows = self._lib.cko_result_rows(res)
             max_len = self._lib.cko_result_maxlen(res)
             n_req = _bucket(max(1, n_requests))

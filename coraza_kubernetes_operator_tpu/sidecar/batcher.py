@@ -267,6 +267,9 @@ class BatcherStats:
     """Counters exposed on the sidecar /stats endpoint."""
 
     batches: int = 0
+    # Every request the batcher answered: those that rode a device batch
+    # (one ``record`` sample a batch) and the repeats that did not
+    # (``count_repeats``: verdict-cache hits, in-window duplicates).
     requests: int = 0
     errors: int = 0
     batch_sizes: list[int] = field(default_factory=list)
@@ -289,6 +292,12 @@ class BatcherStats:
         self.step_latencies_s.append(latency_s)
         if self.on_batch is not None:
             self.on_batch(size, latency_s, trace_id)  # type: ignore[operator]
+
+    def count_repeats(self, n: int) -> None:
+        """Requests answered without a device row of their own: they are
+        requests, but no batch and no sample of its size or latency.
+        Called from the collector thread only, as ``record`` is."""
+        self.requests += n
 
     def record_stage(
         self, host_s: float, device_s: float, trace_id: str | None = None
@@ -403,6 +412,10 @@ class _WindowRecord:
     # Lane that dispatched this window: the collector releases the SAME
     # lane's depth slot.
     lane: str = LANE_BULK
+    # Rows the verdict cache answered while the window was assembled
+    # (per-request windows resolve them there and keep no group for
+    # them); the collector adds them to ``stats.requests``.
+    cache_hits: int = 0
 
 
 @dataclass
@@ -556,6 +569,10 @@ class MicroBatcher:
         self.window_deadline_s: float | None = None
         self.windows_abandoned = 0
         self.parked_readbacks = 0
+        # Windows that passed their deadline with the device's outputs
+        # already computed (``engine.device_done``): the host was late,
+        # not the device, so they were waited for and not abandoned.
+        self.windows_host_late = 0
         # Auto-deadline gate: below this many latency samples the p99 is
         # too noisy to trust as a deadline baseline.
         self._deadline_min_samples = 20
@@ -1041,6 +1058,7 @@ class MicroBatcher:
         group_seen: dict[int, dict[str, int]] = {}
         uuid_cache: dict[int, object] = {}
         dedup_rows = 0
+        cache_hits = 0
         # engine_fn resolved once per DISTINCT tenant (it may take the
         # tenant-manager lock); memoizing also pins one engine per tenant
         # for the whole window even if a hot reload lands mid-grouping.
@@ -1075,6 +1093,7 @@ class MicroBatcher:
                     # never rides the device or waits on the FIFO.
                     self._trace_cached_span(_span)
                     _resolve(_fut.set_result, verdict)
+                    cache_hits += 1
                     continue
                 seen = group_seen.setdefault(key, {})
                 first = seen.get(fp)
@@ -1138,7 +1157,7 @@ class MicroBatcher:
             except Exception as err:  # dispatch failure → per-request error
                 g.error = err
             out_groups.append(g)
-        return _WindowRecord(window=window, groups=out_groups)
+        return _WindowRecord(window=window, groups=out_groups, cache_hits=cache_hits)
 
     def _dispatch_blob(self, bw: _BlobWindow) -> _WindowRecord:
         """Dispatch a pre-assembled ingest window: one engine (default
@@ -1436,7 +1455,23 @@ class MicroBatcher:
             self._spawn_readback_worker()
         job = _ReadbackJob(engine=g.engine, inflight=g.inflight)
         self._readback_q.put(job)
-        if not job.done.wait(timeout=budget):
+        if not job.done.wait(timeout=budget) and self._device_done(g):
+            # The deadline is the DEVICE's. Outputs already computed
+            # mean the host is what is late (the interpreter lock under
+            # a compile, a starved or stolen core): abandoning would put
+            # the window's evaluation on that same host, and feed the
+            # breaker and the bisector for a device that answered. Such
+            # a window may wait for half its requests' budget; the other
+            # half stays for the fallback, should the host never finish.
+            with self._inflight_lock:
+                self.windows_host_late += 1
+            log.info(
+                "window past its deadline with the device done; waiting on the host",
+                deadline_s=round(deadline, 3),
+            )
+            spent = time.monotonic() - g.t_dispatch
+            job.done.wait(timeout=max(self.request_timeout_s / 2 - spent, 0.0))
+        if not job.done.is_set():
             with job.lock:
                 if not job.done.is_set():
                     # Lost the race for good: park the readback and move
@@ -1455,6 +1490,21 @@ class MicroBatcher:
         if job.error is not None:
             raise job.error
         return job.verdicts
+
+    @staticmethod
+    def _device_done(g: _Group) -> bool:
+        """True when the engine can tell that the group's device work has
+        finished (``engine.device_done(inflight)``); an engine that
+        cannot tell, or fails telling, reads as not done: the watchdog
+        then abandons as before."""
+        probe = getattr(g.engine, "device_done", None)
+        if probe is None:
+            return False
+        try:
+            return bool(probe(g.inflight))
+        except Exception as err:
+            log.error("device_done probe failed", err)
+            return False
 
     # -- flight recorder (observability/tracing.py) --------------------------
 
@@ -1610,9 +1660,34 @@ class MicroBatcher:
             log.error("flight recorder stamp failed", err)
 
     def _collect_record(self, record: _WindowRecord) -> None:
+        """Collect one dispatched window, whatever its kind, then count
+        the requests of it that no device batch counted."""
         if isinstance(record.window, _BlobWindow):
             self._collect_blob(record)
-            return
+        else:
+            self._collect_requests(record)
+        self.stats.count_repeats(self._repeats_answered(record))
+
+    @staticmethod
+    def _repeats_answered(record: _WindowRecord) -> int:
+        """Requests of a collected window answered without a device row
+        of their own: verdict-cache hits (a cached group of a split blob
+        window, or ``cache_hits`` of a per-request window) and in-window
+        duplicates of a group that came back whole. The one place they
+        are counted, after the window's collect, so no collect path can
+        leave them out; a failed or quarantined group counts nothing, as
+        it records no batch."""
+        n = record.cache_hits
+        for g in record.groups:
+            if g.error is not None or g.quarantined or g.verdicts is None:
+                continue
+            if g.cached:
+                n += len(g.idxs)
+            elif g.dups:
+                n += sum(len(v) for v in g.dups.values())
+        return n
+
+    def _collect_requests(self, record: _WindowRecord) -> None:
         rec = record.stages
         for g in record.groups:
             if g.quarantined:
@@ -1802,8 +1877,8 @@ class MicroBatcher:
                     verdicts = self._quarantine_eval(g)
                 elif g.cached:
                     # Answered from the verdict cache at assembly time:
-                    # no device step, no breaker traffic, no stats
-                    # sample — the hit accounting lives in the cache.
+                    # no device step, no breaker traffic, no batch
+                    # sample (``_repeats_answered`` counts them).
                     verdicts = g.verdicts
                 else:
                     if g.error is not None:

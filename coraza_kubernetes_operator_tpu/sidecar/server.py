@@ -1434,6 +1434,47 @@ class TpuEngineSidecar:
             )
             config.extproc_port = self._extproc.port
             config.extproc_impl = self._extproc.impl
+        # -- tiering of windows, bodied requests (docs/OBSERVABILITY.md) ------
+        self.metrics.gauge(
+            "cko_tiering_windows_total",
+            "Windows the engine tiered and dispatched",
+        ).set_function(lambda: self._engine_stat("tiering_summary", "windows"))
+        self.metrics.gauge(
+            "cko_tiering_tiers_total",
+            "Matcher tiers launched (one executable call each)",
+        ).set_function(lambda: self._engine_stat("tiering_summary", "tiers"))
+        self.metrics.gauge(
+            "cko_tiering_cells_total",
+            "Bytes of matcher input launched: unique rows x width, as bucketed",
+        ).set_function(lambda: self._engine_stat("tiering_summary", "cells"))
+        self.metrics.gauge(
+            "cko_tiering_real_bytes_total",
+            "Bytes of matcher input that were not padding",
+        ).set_function(lambda: self._engine_stat("tiering_summary", "real_bytes"))
+        self.metrics.gauge(
+            "cko_bodies_json_total",
+            "Bodied requests read by the JSON body processor",
+        ).set_function(lambda: self._engine_stat("body_summary", "json_total"))
+        self.metrics.gauge(
+            "cko_bodies_urlencoded_total",
+            "Bodied requests read by the URLENCODED body processor",
+        ).set_function(lambda: self._engine_stat("body_summary", "urlencoded_total"))
+        self.metrics.gauge(
+            "cko_bodies_multipart_total",
+            "Bodied requests read by the MULTIPART body processor",
+        ).set_function(lambda: self._engine_stat("body_summary", "multipart_total"))
+        self.metrics.gauge(
+            "cko_bodies_other_total",
+            "Bodied requests no body processor read",
+        ).set_function(lambda: self._engine_stat("body_summary", "other_total"))
+        self.metrics.gauge(
+            "cko_bodies_bytes_total",
+            "Request body bytes received by the tensorizer",
+        ).set_function(lambda: self._engine_stat("body_summary", "bytes_total"))
+        self.metrics.gauge(
+            "cko_bodies_parse_errors",
+            "Bodies their processor could not parse (REQBODY_ERROR)",
+        ).set_function(lambda: self._engine_stat("body_summary", "parse_errors"))
         self.metrics.gauge(
             "cko_ingest_connections",
             "Open connections on the async ingest frontend",
@@ -2619,6 +2660,17 @@ class TpuEngineSidecar:
             return {"enabled": False, "tiers": {}, "prefilter": {}}
         return engine.automata_summary()
 
+    def _engine_summary(self, method: str) -> dict:
+        """``tiering_summary`` / ``body_summary`` of the default tenant's
+        engine (cumulative since that engine was installed), or {} while
+        none is resident or the engine is a test stub."""
+        engine = self.tenants.engine_for(None)
+        fn = getattr(engine, method, None)
+        return fn() if fn is not None else {}
+
+    def _engine_stat(self, method: str, key: str) -> float:
+        return float(self._engine_summary(method).get(key, 0))
+
     def _automata_count(self, kind: str) -> int:
         return int(self._automata_summary()["tiers"].get(kind, 0))
 
@@ -2676,6 +2728,7 @@ class TpuEngineSidecar:
                 "window_deadline_s": self.config.window_deadline_s,
                 "effective_deadline_s": self._effective_deadline(),
                 "windows_abandoned": self.batcher.windows_abandoned,
+                "windows_host_late": self.batcher.windows_host_late,
                 "parked_readbacks": self.batcher.parked_readbacks,
                 "collector_wedged": self.batcher.collector_wedged,
             },
@@ -2713,6 +2766,10 @@ class TpuEngineSidecar:
             "resident_engines": self.tenants.resident_engines(),
             "engine_dedup_hits": self.tenants.engine_dedup_hits,
             "automata": self._automata_summary(),
+            # Per window the matcher tiers launched, their cells (rows x
+            # width) and real bytes; bodied requests by body processor.
+            "tiering": self._engine_summary("tiering_summary"),
+            "bodies": self._engine_summary("body_summary"),
             "native": self._native_summary(),
             "analysis": {
                 "cko_analysis_findings_total": self.tenants.analysis_counts(),

@@ -1545,6 +1545,10 @@ struct Result {
   std::vector<Row> rows;
   std::vector<std::vector<int32_t>> numvals;  // [n_req][NV]
   size_t max_len = 1;
+  // Requests with a body, by the processor that read it (JSON,
+  // URLENCODED, MULTIPART, none), their body bytes as received, and how
+  // many of them the processor could not parse (REQBODY_ERROR).
+  long long bodies[6] = {0, 0, 0, 0, 0, 0};
 };
 
 // target scratch (before kind packing)
@@ -2146,6 +2150,12 @@ void* cko_tensorize(void* h, const uint8_t* blob, size_t len, int n_req) {
         parse_pairs(body, args_post);
       }
     }
+    if (!body_full.empty()) {
+      res->bodies[processor == "JSON" ? 0 : processor == "URLENCODED" ? 1
+                  : processor == "MULTIPART" ? 2 : 3]++;
+      res->bodies[4] += (long long)body_full.size();
+      res->bodies[5] += reqbody_error;
+    }
 
     // targets, in the exact order of engine/request.py extract()
     std::vector<Target> targets;
@@ -2404,6 +2414,13 @@ int cko_result_export(void* h, uint8_t* data, int32_t* lengths, int32_t* k1,
     for (size_t vi = 0; vi < nv.size() && (int)vi < NV; vi++)
       numvals[req * NV + vi] = nv[vi];
   }
+  return 0;
+}
+
+// json, urlencoded, multipart, other, body bytes, parse errors (6 slots).
+int cko_result_bodies(void* h, long long* out) {
+  if (!h || !out) return -1;
+  memcpy(out, ((Result*)h)->bodies, sizeof(((Result*)h)->bodies));
   return 0;
 }
 
@@ -2787,6 +2804,11 @@ int cko_plan_export(void* h, const unsigned long long* ptrs,
       numvals[req * NV + vi] = nv[vi];
   }
   return 0;
+}
+
+int cko_plan_bodies(void* h, long long* out) {
+  if (!h) return -1;
+  return cko_result_bodies(((Plan*)h)->res, out);
 }
 
 void cko_plan_free(void* h) { delete (Plan*)h; }

@@ -299,6 +299,79 @@ def test_watchdog_abandons_blown_window_collector_keeps_serving():
         b.stop()
 
 
+@pytest.mark.parametrize(
+    "device_done, abandoned",
+    [(True, 0), (False, 1), (RuntimeError("cannot tell"), 1)],
+    ids=["device-done-host-late", "device-not-done", "probe-raises"],
+)
+def test_watchdog_deadline_is_the_devices(device_done, abandoned):
+    """A window past its deadline whose device work is done
+    (``engine.device_done``) is the HOST's lateness: it is waited for,
+    answered from its own collect and counted ``windows_host_late``, not
+    abandoned. A device that is not done, or an engine that cannot
+    tell, is abandoned as ever."""
+    eng = _BlockingEngine()
+
+    def probe(inflight):
+        if isinstance(device_done, Exception):
+            raise device_done
+        return device_done
+
+    eng.device_done = probe
+    b = MicroBatcher(lambda: eng, max_batch_size=1, max_batch_delay_ms=0)
+    b.window_deadline_s = 0.2
+    b.request_timeout_s = 10.0
+    b.start()
+    try:
+        eng.block_next.set()
+        threading.Timer(0.8, eng.release.set).start()  # four deadlines late
+        if abandoned:
+            with pytest.raises(WindowAbandoned):
+                b.evaluate(HttpRequest(uri="/late"), timeout_s=10)
+        else:
+            assert b.evaluate(HttpRequest(uri="/late"), timeout_s=10) == ("ok", "/late")
+        assert b.windows_abandoned == abandoned
+        assert b.windows_host_late == 1 - abandoned
+        assert b.evaluate(HttpRequest(uri="/ok"), timeout_s=10) == ("ok", "/ok")
+    finally:
+        eng.release.set()
+        b.stop()
+
+
+def test_watchdog_host_late_wait_is_bounded_by_the_requests_budget():
+    """With the device done and the host never finishing, the window is
+    abandoned at half its requests' budget: the fallback keeps the rest."""
+    eng = _BlockingEngine()
+    eng.device_done = lambda inflight: True
+    b = MicroBatcher(lambda: eng, max_batch_size=1, max_batch_delay_ms=0)
+    b.window_deadline_s = 0.1
+    b.request_timeout_s = 1.0
+    b.start()
+    try:
+        eng.block_next.set()
+        t0 = time.monotonic()
+        with pytest.raises(WindowAbandoned):
+            b.evaluate(HttpRequest(uri="/stuck"), timeout_s=10)
+        assert 0.4 < time.monotonic() - t0 < 3.0
+        assert (b.windows_host_late, b.windows_abandoned) == (1, 1)
+    finally:
+        eng.release.set()
+        b.stop()
+
+
+def test_engine_device_done_reads_the_outputs():
+    """``WafEngine.device_done``: true once the window's outputs are
+    computed, false while an injected hang stands for a hung device."""
+    engine = WafEngine(BASE + EVIL_MONKEY)
+    inflight = engine.prepare([HttpRequest(uri="/?q=1")])
+    assert _wait(lambda: engine.device_done(inflight), 60)
+    inflight.hung = True
+    assert not engine.device_done(inflight)
+    inflight.hung = False
+    assert engine.collect(inflight)[0].status == 200
+    assert engine.device_done(inflight)
+
+
 def test_watchdog_disarmed_until_warmed():
     eng = _BlockingEngine(warmed=False)
     b = MicroBatcher(lambda: eng, max_batch_size=1, max_batch_delay_ms=0)
@@ -541,6 +614,7 @@ def test_sidecar_watchdog_abandon_recovers(monkeypatch):
         assert sc.degraded.breaker.state == BREAKER_CLOSED
         st = sc.stats()["watchdog"]
         assert st["windows_abandoned"] >= 1 and st["collector_wedged"] is False
+        assert st["windows_host_late"] == 0  # an injected hang is the device's
     finally:
         sc.stop()
 

@@ -11,8 +11,10 @@ a real chip does.
 Shapes are the ones full crs-lite produces: the banks of the engine
 built on ``ftw/rules/crs-lite`` at the smoke's sidecar window (32 unique
 rows x 512) and at the bench scale (4096 rows x the 2048 Pallas width
-cap), the promotion canary's whole per-tier matcher (16 x 32), and the
-post stage.
+cap), the promotion canary's whole per-tier matcher (16 x 32), the
+whole matcher at the widest rows the Pallas kernels take (32 x 2048: a
+window of bodied API requests, wafbench's ``crs-lite-pl2-bodies``), and
+the post stage.
 
 Dispatch in ``ops/`` asks ``jax.default_backend()`` — which is the CPU
 here — so the module fixture answers "tpu" for the duration of this
@@ -33,6 +35,7 @@ from jax.sharding import SingleDeviceSharding
 ROWS_WINDOW, WIDTH_WINDOW = 32, 512  # the chip smoke's one sidecar window
 ROWS_BENCH, WIDTH_MAX = 4096, 2048  # bench batch x the Pallas width cap
 ROWS_CANARY, WIDTH_CANARY = 16, 32  # engine/waf.py:warmup_request's window
+ROWS_BODIES, WIDTH_BODIES = 32, 2048  # wafbench crs-bodies.api-2k-c1's one window shape
 
 
 @pytest.fixture(scope="module")
@@ -201,4 +204,29 @@ def test_canary_matcher_compiles_for_v5e(crs_lite, described, operand):
         for i in range(len(banks)):
             assert f"%cko_{family}{i}" in text, f"cko_{family}{i}"
     # It has to fit beside the model's tables in one v5e's 16 GB.
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
+
+
+@pytest.mark.parametrize("rows,width", [(ROWS_BODIES, WIDTH_BODIES)])
+def test_long_matcher_compiles_for_v5e(crs_lite, described, operand, rows, width):
+    """The whole matcher at width 2048, where a window holds a body of
+    1 to 2 KiB: every bank still rides its Pallas kernel (the flat bins
+    sit at the edge of their VMEM plan there; ``_PALLAS_MAX_LEN``), none
+    falls to the XLA scan, and the program fits beside the tables."""
+    from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
+
+    model = crs_lite.model
+    h = max(1, len(crs_lite._host_pipelines))
+    compiled = stage_executable("match", f"{rows}x{width}").lower(
+        described(model),
+        operand((rows, width), jnp.uint8),
+        operand((rows,), jnp.int32),
+        operand((h, rows, width), jnp.uint8),
+        operand((h, rows), jnp.int32),
+        mask=None,
+    ).compile()
+    text = compiled.as_text()
+    n_pallas = len(model.flat_banks) + len(model.pre_banks) + len(model.gather_banks)
+    assert text.count("tpu_custom_call") == n_pallas
+    assert f"HloModule jit_cko_match_{rows}x{width}" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30

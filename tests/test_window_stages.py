@@ -9,6 +9,7 @@ path without a frontend), and real engines behind the async frontend
 routes a prefiltered group so that every stage is stamped on the CPU.
 """
 
+import itertools
 import json
 import socket
 import threading
@@ -207,14 +208,18 @@ def served():
         sc.stop()
 
 
+_DRIVES = itertools.count()
+
+
 def _drive(how, served, n_windows):
     """Run ``n_windows`` windows; returns (records, stages snapshot
     before, after, requests a window)."""
     if how in served:
         sc, seen = served[how]
         before, first = sc.stats()["stages"], len(seen)
+        drive = next(_DRIVES)  # a drive made again sends nothing the verdict cache holds
         for k in range(n_windows):
-            _burst(sc.port, 48, b"%s%d" % (how.encode(), k))
+            _burst(sc.port, 48, b"%s%d_%d" % (how.encode(), drive, k))
         deadline = time.monotonic() + 10  # the last reply's writer closes the record
         while len(seen) < first + n_windows and time.monotonic() < deadline:
             time.sleep(0.01)
@@ -246,9 +251,10 @@ def _grew(before, after, stage, key):
     return total(after) - total(before)
 
 
-@pytest.mark.parametrize("how", ["stub-submit", "stub-window", "operator-sample", "all-tiers"])
-def test_every_window_is_stamped_whole(how, served):
-    n = 6
+def _stamped_whole(how, served, n):
+    """One drive of ``n`` windows with everything the record promises
+    asserted; returns the windows that left more of their wall
+    uncovered than a window may."""
     records, before, after, per_window = _drive(how, served, n)
     assert len(records) == n
     want = set(STAGES) - PREFILTER_STAGES - FRONTEND_STAGES
@@ -259,6 +265,7 @@ def test_every_window_is_stamped_whole(how, served):
     if how.startswith("stub"):
         # a stub stamps nothing: its prepare is assemble, its collect readback_wait
         want -= {"tier_enqueue", "post_enqueue", "decode"}
+    uncovered, over = [], []
     for rec in records:
         assert {s for s, _a, _b in rec.spans} == want, rec.spans
         # stamps are monotone: every span ends after it starts, and
@@ -270,8 +277,13 @@ def test_every_window_is_stamped_whole(how, served):
         assert rec.spans[0][1] == rec.t_first and ends[-1] == rec.t_last
         # and the stages cover the window's wall
         wall = rec.t_last - rec.t_first
-        assert sum(rec.durations().values()) == pytest.approx(wall, rel=0.05), rec.spans
+        hole = wall - sum(rec.durations().values())
+        uncovered.append(hole / wall)
+        if hole > max(0.05 * wall, _HANDOFF_S):
+            over.append((hole, wall, rec.spans))
         assert rec.n_req == per_window and not rec.aborted_at
+    # the windows' median holds the 5% in every drive, loaded or not
+    assert sorted(uncovered)[len(uncovered) // 2] <= 0.05, uncovered
     # the cumulative block: count == windows, requests for lane_wait
     for stage in want | {WINDOW_WALL}:
         expect = n * per_window if stage == "lane_wait" else n
@@ -285,6 +297,29 @@ def test_every_window_is_stamped_whole(how, served):
         assert _grew(before, after, stage, "sum_s") == pytest.approx(seconds, rel=1e-6)
     for stage in set(STAGES) - want:
         assert _grew(before, after, stage, "count") == 0
+    return over
+
+
+# What one hand-off between two stages' stamps may take when nothing
+# preempts it. The walls are 4-9 ms here, so 5% of one is 0.2-0.45 ms:
+# under six xdist workers one window in eighty loses 0.3-0.8 ms between
+# `lane_wait` and `queue_wait` or at the collector, which is what failed
+# `[operator-sample]` in the driver's run of PR 27's tree.
+_HANDOFF_S = 1e-3
+
+
+@pytest.mark.parametrize("how", ["stub-submit", "stub-window", "operator-sample", "all-tiers"])
+def test_every_window_is_stamped_whole(how, served):
+    # EVERY window's stages sum to its wall within 5% (or one hand-off).
+    # A stage that stamps too little leaves its hole in every drive; a
+    # hand-off the scheduler preempted for longer than `_HANDOFF_S` does
+    # not come again, so a drive with such a window is made once more,
+    # and the third in a row fails.
+    for _attempt in range(3):
+        over = _stamped_whole(how, served, 6)
+        if not over:
+            return
+    pytest.fail(f"stages leave a hole in the window's wall, three drives running: {over}")
 
 
 def test_batcher_host_and_device_stage_samples_come_from_the_record(served):
