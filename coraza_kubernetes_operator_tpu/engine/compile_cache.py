@@ -123,8 +123,17 @@ def configure_persistent_cache(
     return d
 
 
+# numpy builds a dtype's ``name`` anew on every read (a few Python calls
+# each); a window's signature reads a dozen, a model's hundreds.
+_DTYPE_NAMES: dict = {}
+
+
 def _aval(leaf) -> tuple:
-    return (tuple(np.shape(leaf)), np.result_type(leaf).name)
+    dtype = np.result_type(leaf)
+    name = _DTYPE_NAMES.get(dtype)
+    if name is None:
+        name = _DTYPE_NAMES.setdefault(dtype, dtype.name)
+    return (tuple(np.shape(leaf)), name)
 
 
 def batch_signature(args: tuple, static_kwargs: tuple) -> tuple:
@@ -134,6 +143,27 @@ def batch_signature(args: tuple, static_kwargs: tuple) -> tuple:
     kwargs. Two calls share an executable iff their signatures match."""
     leaves, treedef = jax.tree_util.tree_flatten(args)
     return (treedef, tuple(_aval(l) for l in leaves), static_kwargs)
+
+
+def model_signature(model) -> tuple:
+    """The model's half of every stage key: ``batch_signature`` of the
+    first argument alone. It walks every table of the model (hundreds of
+    leaves at CRS scale, a millisecond of Python), so an engine computes
+    it where it assigns its model and never under traffic."""
+    return batch_signature((model,), ())
+
+
+def stage_key(jitted, model_sig: tuple, operands: tuple, static_kwargs: dict) -> tuple:
+    """The executable-cache key of ``jitted(model, *operands,
+    **static_kwargs)``, composed from the model's signature and a walk
+    over the window's own operands only. Equal to
+    ``ExecutableCache.key_for(jitted, (model,) + operands, static_kwargs)``."""
+    name = getattr(jitted, "__name__", None) or str(jitted)
+    return (
+        name,
+        model_sig,
+        batch_signature(operands, tuple(sorted(static_kwargs.items()))),
+    )
 
 
 class ExecutableCache:
@@ -171,6 +201,15 @@ class ExecutableCache:
         # {"platform", "kind", "count"} of the device that produced the
         # first all-device window's output; None until one was collected.
         self.device: dict | None = None
+        # Bumped by ``clear()``: an engine's launch table (resolved
+        # executables per window shape, ``WafEngine._dispatch_tiers``)
+        # is good for one generation only.
+        self.generation = 0
+        # Windows an engine launched from its table / windows that took
+        # the spec-by-spec path (first sight of a shape on that engine,
+        # a stage not resident, a table dropped with its model).
+        self.launch_plan_hits = 0
+        self.launch_plan_misses = 0
 
     def note_window(self, out, on_device: bool) -> None:
         """Count one collected window (``WafEngine._collect``). ``out``
@@ -190,6 +229,14 @@ class ExecutableCache:
             "kind": dev.device_kind,
             "count": len(jax.devices()),
         }
+
+    def note_launch_plan(self, hit: bool) -> None:
+        """Count one window's lookup in its engine's launch table."""
+        with self._lock:
+            if hit:
+                self.launch_plan_hits += 1
+            else:
+                self.launch_plan_misses += 1
 
     # -- core ---------------------------------------------------------------
 
@@ -232,9 +279,13 @@ class ExecutableCache:
         return compiled
 
     def key_for(self, jitted, args: tuple, static_kwargs: dict) -> tuple:
-        name = getattr(jitted, "__name__", None) or str(jitted)
-        return (name,) + batch_signature(
-            args, tuple(sorted(static_kwargs.items()))
+        """The key of ``jitted(*args, **static_kwargs)``: the first
+        argument (the model: constant between engine swaps) and the rest
+        (the window's operands) are signed apart, so that a caller that
+        keeps the first half composes the same key from the second
+        alone (:func:`stage_key`)."""
+        return stage_key(
+            jitted, batch_signature(args[:1], ()), args[1:], static_kwargs
         )
 
     def call(self, jitted, args: tuple, static_kwargs: dict, dyn_kwargs: dict):
@@ -242,8 +293,7 @@ class ExecutableCache:
         through the executable cache: AOT-compile on first sight of the
         signature, then call the compiled object directly (tables and
         batch tensors are runtime operands — new values at the same
-        shapes never retrace). Falls back to the plain jit dispatch on
-        any AOT argument rejection (counted, logged once per key)."""
+        shapes never retrace)."""
         key = self.key_for(jitted, args + (dyn_kwargs.get("cached"),), static_kwargs)
         compiled = self._lookup(key, count_hit=False)
         was_resident = compiled is not None
@@ -251,6 +301,24 @@ class ExecutableCache:
             compiled = self._compile(
                 key, jitted, args, {**static_kwargs, **dyn_kwargs}
             )
+        return self.run(
+            key, compiled, jitted, args, static_kwargs, dyn_kwargs, was_resident
+        )
+
+    def run(
+        self,
+        key: tuple,
+        compiled,
+        jitted,
+        args: tuple,
+        static_kwargs: dict,
+        dyn_kwargs: dict,
+        was_resident: bool = True,
+    ):
+        """Call a resolved executable (``WafEngine`` keeps them per
+        window shape and calls here directly; nothing walks ``args``).
+        Falls back to the plain jit dispatch on any AOT argument
+        rejection (counted, logged once per key)."""
         try:
             out = compiled(*args, **dyn_kwargs)
         except (TypeError, ValueError) as err:
@@ -269,10 +337,18 @@ class ExecutableCache:
                 self.hits += 1
         return out
 
-    def warm(self, jitted, args: tuple, static_kwargs: dict, dyn_kwargs: dict) -> bool:
+    def warm(
+        self,
+        jitted,
+        args: tuple,
+        static_kwargs: dict,
+        dyn_kwargs: dict,
+        key: tuple | None = None,
+    ) -> bool:
         """AOT-lower and compile WITHOUT executing (the promotion-probe
         pre-warm). Returns True when this call minted a new executable."""
-        key = self.key_for(jitted, args + (dyn_kwargs.get("cached"),), static_kwargs)
+        if key is None:
+            key = self.key_for(jitted, args + (dyn_kwargs.get("cached"),), static_kwargs)
         if self._lookup(key, count_hit=False) is not None:
             return False
         self._compile(key, jitted, args, {**static_kwargs, **dyn_kwargs})
@@ -296,6 +372,8 @@ class ExecutableCache:
                 "inflight": self.inflight,
                 "device_windows": self.device_windows,
                 "host_twin_windows": self.host_twin_windows,
+                "launch_plan_hits": self.launch_plan_hits,
+                "launch_plan_misses": self.launch_plan_misses,
                 "persistent_dir": _configured_dir[0] if _configured_dir else None,
             }
 
@@ -307,6 +385,7 @@ class ExecutableCache:
     def clear(self) -> None:
         with self._lock:
             self._entries.clear()
+            self.generation += 1
 
 
 # Process-wide singleton: tenants, reloads, the promotion probe, and the

@@ -37,7 +37,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 
 from ..utils import get_logger
-from .compile_cache import EXEC_CACHE
+from .compile_cache import EXEC_CACHE, model_signature, stage_key
 
 log = get_logger("engine.tier_compile")
 
@@ -46,12 +46,17 @@ log = get_logger("engine.tier_compile")
 # smallest-first sort key (~rows x width; 0 for the post stage).
 
 
-def spec_key(spec) -> tuple:
+def spec_key(spec, model_sig: tuple | None = None) -> tuple:
     """The EXEC_CACHE key a spec's dispatch will use (same composition
     as ``ExecutableCache.call``/``warm``: the ``cached`` dyn kwarg rides
-    the key because its shapes change the trace)."""
+    the key because its shapes change the trace). This walks the spec's
+    first argument, the whole model; a caller that keeps that half
+    (``compile_cache.model_signature``; ``WafEngine`` does, beside its
+    model) passes it and pays for the window's operands alone."""
     _label, _cost, jitted, args, statics, dyn = spec
-    return EXEC_CACHE.key_for(jitted, args + (dyn.get("cached"),), statics)
+    if model_sig is None:
+        model_sig = model_signature(args[0])
+    return stage_key(jitted, model_sig, args[1:] + (dyn.get("cached"),), statics)
 
 
 class TierCompiler:
@@ -97,15 +102,19 @@ class TierCompiler:
             )
         return self._pool
 
-    def resident(self, spec) -> bool:
-        """Probe without counting a cache hit (the pre-warm peek)."""
-        return EXEC_CACHE._lookup(spec_key(spec), count_hit=False) is not None
+    def resident(self, spec, key: tuple | None = None) -> bool:
+        """Probe without counting a cache hit (the pre-warm peek).
+        ``key``, here and below, is ``spec_key(spec)`` where the caller
+        already computed it."""
+        if key is None:
+            key = spec_key(spec)
+        return EXEC_CACHE._lookup(key, count_hit=False) is not None
 
     def _compile_one(self, key: tuple, spec) -> bool:
         label, _cost, jitted, args, statics, dyn = spec
         t0 = time.perf_counter()
         try:
-            minted = EXEC_CACHE.warm(jitted, args, statics, dyn)
+            minted = EXEC_CACHE.warm(jitted, args, statics, dyn, key=key)
         finally:
             with self._lock:
                 self._inflight.pop(key, None)
@@ -115,10 +124,9 @@ class TierCompiler:
                 self.tier_s[label] = self.tier_s.get(label, 0.0) + dt
         return minted
 
-    def _submit(self, spec) -> object | None:
+    def _submit(self, spec, key: tuple) -> object | None:
         """Enqueue one spec (deduped on key). Returns the Future, or
         None when the executable is already resident."""
-        key = spec_key(spec)
         if EXEC_CACHE._lookup(key, count_hit=False) is not None:
             return None
         with self._lock:
@@ -129,22 +137,26 @@ class TierCompiler:
                 self._inflight[key] = fut
         return fut
 
-    def ensure(self, spec) -> bool:
+    def ensure(self, spec, key: tuple | None = None) -> bool:
         """Non-blocking: True when the spec's executable is resident and
         can dispatch now; otherwise enqueue its compile (idempotent) and
         return False so the caller routes through the host fallback."""
-        if self.resident(spec):
+        if key is None:
+            key = spec_key(spec)
+        if self.resident(spec, key):
             return True
-        self._submit(spec)
+        self._submit(spec, key)
         return False
 
-    def compile_all(self, specs) -> int:
+    def compile_all(self, specs, keys=None) -> int:
         """Blocking parallel compile of every non-resident spec,
         submitted smallest-first. Returns how many executables this call
         minted (0 = everything was already resident)."""
-        pending = [s for s in specs if not self.resident(s)]
-        pending.sort(key=lambda s: s[1])
-        futures = [f for f in (self._submit(s) for s in pending) if f is not None]
+        if keys is None:
+            keys = [spec_key(s) for s in specs]
+        pending = [(s, k) for s, k in zip(specs, keys) if not self.resident(s, k)]
+        pending.sort(key=lambda sk: sk[0][1])
+        futures = [f for f in (self._submit(s, k) for s, k in pending) if f is not None]
         minted = 0
         for f in futures:
             if f.result():
@@ -153,7 +165,7 @@ class TierCompiler:
             log.info(
                 "tier executables compiled",
                 minted=minted,
-                labels=[s[0] for s in pending],
+                labels=[s[0] for s, _k in pending],
             )
         return minted
 

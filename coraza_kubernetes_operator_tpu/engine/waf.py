@@ -23,6 +23,8 @@ from ..models.waf_model import WafModel, build_model, eval_waf
 from ..observability.stages import HOST_STAGES
 from ..observability.stages import current as current_stages
 from ..utils import get_logger
+from .compile_cache import EXEC_CACHE, model_signature
+from .compile_cache import batch_signature as shape_signature
 from .request import Extraction, HttpRequest, TargetExtractor
 
 log = get_logger("engine.waf")
@@ -424,7 +426,7 @@ class WafEngine:
         from ..compiler.automata_plan import plan_automata
 
         self.automata_plan = plan_automata(self.compiled)
-        self.model: WafModel = build_model(self.compiled, automata=self.automata_plan)
+        self._set_model(build_model(self.compiled, automata=self.automata_plan))
         self.extractor = TargetExtractor(self.compiled)
         self._n_real_rules = len(self.compiled.rules)  # model pads to ≥1 row
         self._rule_ids = np.asarray(
@@ -635,8 +637,20 @@ class WafEngine:
         device batch re-proves the path before promotion — executables
         are re-fetched from the process/persistent compile caches, so the
         re-put costs array transfers, not XLA compiles."""
-        self.model = build_model(self.compiled, automata=self.automata_plan)
+        self._set_model(build_model(self.compiled, automata=self.automata_plan))
         self.warmed = False
+
+    def _set_model(self, model: WafModel) -> None:
+        """Assign the model with what is resolved once per model: the
+        model's half of every stage key (one walk over all its tables,
+        here and never under traffic) and an empty launch table (window
+        signature -> the resolved executables of a window of that shape,
+        filled by ``_dispatch_tiers``). The table is kept with the
+        executable cache's generation it was resolved in:
+        ``EXEC_CACHE.clear()`` outdates it."""
+        self.model: WafModel = model
+        self._model_sig = model_signature(model)
+        self._launch_table: tuple[int, dict] = (EXEC_CACHE.generation, {})
 
     @property
     def host_fallback(self):
@@ -978,8 +992,6 @@ class WafEngine:
             inflight.hung = True
             time.sleep(hang)
             inflight.hung = False
-        from .compile_cache import EXEC_CACHE
-
         EXEC_CACHE.note_window(
             inflight.out[0] if inflight.cache_pop else inflight.out,
             inflight.device,
@@ -1050,6 +1062,12 @@ class WafEngine:
             tensors, self._kind_block_lut, cache=self.value_cache
         )
 
+    @staticmethod
+    def _tier_pairs(tiers) -> tuple:
+        """Per tier the ``(kind1, kind2, kind3, req_id, uid)`` pair rows
+        the post stage consumes."""
+        return tuple((t[2], t[3], t[4], t[5], t[8]) for t in tiers)
+
     def _tier_specs(
         self, tiers, numvals, max_phase: int = 2, masks=None, cached=None
     ):
@@ -1070,7 +1088,6 @@ class WafEngine:
         g = int(self.model.e_lg.shape[0])
         pb = (g + 7) // 8
         match_specs = []
-        pairs = []
         for t, mask in zip(tiers, masks):
             u, length = t[0].shape
             match_specs.append(
@@ -1083,8 +1100,7 @@ class WafEngine:
                     {},
                 )
             )
-            pairs.append((t[2], t[3], t[4], t[5], t[8]))
-        pairs = tuple(pairs)
+        pairs = self._tier_pairs(tiers)
         ph_hits = tuple(
             np.zeros((t[0].shape[0], pb), dtype=np.uint8) for t in tiers
         )
@@ -1129,8 +1145,6 @@ class WafEngine:
         lossless and the host twins are differential-tested against the
         device stages."""
         from ..testing.faults import on_device_dispatch
-        from .compile_cache import EXEC_CACHE
-        from .tier_compile import TIER_COMPILER, spec_key
 
         if rec is None:
             rec = current_stages()
@@ -1143,61 +1157,49 @@ class WafEngine:
             on_device_dispatch(warmed=self.warmed)
             if masks is None:
                 masks = (None,) * len(tiers)
-            match_specs, post_spec, pairs = self._tier_specs(
-                tiers, numvals, max_phase=max_phase, masks=masks, cached=cached
-            )
-            specs = match_specs + [post_spec]
             counts = self._tiering
             counts["windows"] += 1
             for tier in tiers:
                 counts["tiers"] += 1
                 counts["cells"] += tier[0].shape[0] * tier[0].shape[1]
                 counts["real_bytes"] += int(np.sum(tier[1]))
-            for s in specs:
-                self._exec_signatures.add(spec_key(s))
-            self.compiled.report.exec_signatures = len(self._exec_signatures)
-            if self._lazy:
-                # Non-blocking: enqueue every missing executable NOW, in
-                # ascending cost order, so the pool mints the smallest
-                # tier (and the post stage) first — first-verdict-from-
-                # device latency is gated on the smallest group's compile.
-                for s in sorted(specs, key=lambda s: s[1]):
-                    TIER_COMPILER.ensure(s)
-            else:
-                TIER_COMPILER.compile_all(specs)
+            match_stages, post_stage = self._resolve_launch(
+                tiers, numvals, max_phase, masks, cached
+            )
+            model = self.model
             device = True
             tier_hits = []
             from_device = []
-            for spec, tier, mask in zip(match_specs, tiers, masks):
-                if not self._lazy or TIER_COMPILER.resident(spec):
-                    _label, _cost, fn, fargs, statics, dyn = spec
-                    tier_hits.append(EXEC_CACHE.call(fn, fargs, statics, dyn))
-                    from_device.append(True)
-                else:
+            for stage, tier, mask in zip(match_stages, tiers, masks):
+                hits = self._launch(
+                    stage, (model, tier[0], tier[1], tier[6], tier[7]), {"mask": mask}, {}
+                )
+                from_device.append(hits is not None)
+                if hits is None:
                     device = False
-                    tier_hits.append(self._host_tier_hits(tier, mask))
-                    from_device.append(False)
+                    hits = self._host_tier_hits(tier, mask)
+                tier_hits.append(hits)
             tier_hits = tuple(tier_hits)
         # Prefilter confirm (two-level automata): device matcher rows for
         # prefiltered groups are OVER-approximate — re-check positives
         # against the exact DFAs and clear the false ones before anything
         # downstream (post stage, value-cache insert, host post) reads
         # the bits. Host-twin rows are already exact and are skipped.
-        if self.model.prefilter_cols:
+        if model.prefilter_cols:
             tier_hits = self._confirm_prefilter(tier_hits, tiers, from_device, rec)
         # The post stage takes packed hit rows from EITHER provenance —
         # device matcher output or host-computed numpy — at identical
         # shapes/bit layout, so a mixed window still shares the one post
         # executable.
         with rec.stage("post_enqueue"):
-            if not self._lazy or TIER_COMPILER.resident(post_spec):
-                packed = EXEC_CACHE.call(
-                    post_spec[2],
-                    (self.model, tier_hits, pairs, numvals),
-                    {"max_phase": max_phase},
-                    {"cached": cached},
-                )
-            else:
+            pairs = self._tier_pairs(tiers)
+            packed = self._launch(
+                post_stage,
+                (model, tier_hits, pairs, numvals),
+                {"max_phase": max_phase},
+                {"cached": cached},
+            )
+            if packed is None:
                 device = False
                 packed = self._host_post(
                     tier_hits, pairs, numvals, max_phase, cached
@@ -1212,6 +1214,68 @@ class WafEngine:
             device=device,
             stages=rec,
         )
+
+    def _resolve_launch(self, tiers, numvals, max_phase, masks, cached):
+        """What a window of this shape launches: ``(match_stages,
+        post_stage)``, each stage ``(key, jitted, compiled)`` with
+        ``compiled`` None where the executable is not resident (lazy
+        mode: the host twin answers that stage).
+
+        Resolved once per (model, window shape): the window's signature
+        — shapes and dtypes of its own operands, the masks,
+        ``max_phase``, the ``cached`` buckets; a dozen leaves — looks up
+        the engine's launch table, and a hit is the whole resolution.
+        A miss builds the specs, composes one key per executable from
+        the model's kept signature and hands it to every step that used
+        to recompute it (each recomputation walked the whole model), and
+        enters the table only when every stage is resident: a window
+        that a host twin answers is never a hit."""
+        from .tier_compile import TIER_COMPILER, spec_key
+
+        sig = shape_signature((tiers, numvals, cached), (masks, max_phase))
+        generation, table = self._launch_table
+        if generation != EXEC_CACHE.generation:
+            generation, table = self._launch_table = (EXEC_CACHE.generation, {})
+        plan = table.get(sig)
+        EXEC_CACHE.note_launch_plan(hit=plan is not None)
+        if plan is not None:
+            return plan
+        match_specs, post_spec, _pairs = self._tier_specs(
+            tiers, numvals, max_phase=max_phase, masks=masks, cached=cached
+        )
+        specs = match_specs + [post_spec]
+        keys = [spec_key(s, self._model_sig) for s in specs]
+        self._exec_signatures.update(keys)
+        self.compiled.report.exec_signatures = len(self._exec_signatures)
+        if self._lazy:
+            # Non-blocking: enqueue every missing executable NOW, in
+            # ascending cost order, so the pool mints the smallest
+            # tier (and the post stage) first — first-verdict-from-
+            # device latency is gated on the smallest group's compile.
+            ready = {
+                k: TIER_COMPILER.ensure(s, k)
+                for s, k in sorted(zip(specs, keys), key=lambda sk: sk[0][1])
+            }
+        else:
+            TIER_COMPILER.compile_all(specs, keys)
+            ready = dict.fromkeys(keys, True)
+        stages = [
+            (k, s[2], EXEC_CACHE._lookup(k, count_hit=False) if ready[k] else None)
+            for s, k in zip(specs, keys)
+        ]
+        plan = (stages[:-1], stages[-1])
+        if all(compiled is not None for _k, _fn, compiled in stages):
+            table[sig] = plan
+        return plan
+
+    @staticmethod
+    def _launch(stage, args: tuple, statics: dict, dyn: dict):
+        """Enqueue one resolved stage on the device; None where its
+        executable is not resident and the host twin has to answer."""
+        key, jitted, compiled = stage
+        if compiled is None:
+            return None
+        return EXEC_CACHE.run(key, compiled, jitted, args, statics, dyn)
 
     def _confirm_prefilter(self, tier_hits, tiers, from_device, rec):
         """Confirm device prefilter positives against the exact DFAs.

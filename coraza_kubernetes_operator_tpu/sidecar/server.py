@@ -1091,6 +1091,14 @@ class TpuEngineSidecar:
             "Dispatches that fell back to plain jit (AOT call rejected)",
         ).set_function(lambda: float(EXEC_CACHE.bypasses))
         self.metrics.gauge(
+            "cko_compile_cache_launch_plan_hits_total",
+            "Windows launched from their engine's table of resolved executables",
+        ).set_function(lambda: float(EXEC_CACHE.launch_plan_hits))
+        self.metrics.gauge(
+            "cko_compile_cache_launch_plan_misses_total",
+            "Windows resolved spec by spec (first of a shape on an engine, a stage not resident)",
+        ).set_function(lambda: float(EXEC_CACHE.launch_plan_misses))
+        self.metrics.gauge(
             "cko_engine_dedup_total",
             "Tenant engines deduped onto a resident same-ruleset engine",
         ).set_function(lambda: float(self.tenants.engine_dedup_hits))

@@ -17,7 +17,10 @@ Then one closed-loop window per entry of ``--windows``:
 Each prints one JSON line: the window's end-to-end numbers from the
 client's side, and after − before of ``/waf/v1/stats`` ``stages`` as
 milliseconds per window (``lane_wait``: per request), and after − before
-of ``automata.prefilter`` (``native_hits`` against ``hits``). What
+of ``automata.prefilter`` (``native_hits`` against ``hits``) and of
+``compile_cache`` (``launch_plan_hits`` against ``device_windows``:
+every warm window launched from its engine's table; ``launch_plan_misses``
+and ``misses`` flat). What
 ``wafbench.run --trace 1`` reports for the same stages also holds its
 traced intervals; this is the untraced reading to hold it against.
 The result is no benchmark line: nothing is checked for correctness
@@ -43,6 +46,8 @@ from wafbench import harness  # noqa: E402
 from wafbench.layer_metrics._window_stages import PER_WINDOW, grew  # noqa: E402
 
 TOKEN = "stage-probe"
+LAUNCH_COUNTERS = ("launch_plan_hits", "launch_plan_misses", "device_windows",
+                   "host_twin_windows", "hits", "misses", "bypasses")
 
 
 def stage_ms(before: dict, after: dict) -> dict:
@@ -149,7 +154,9 @@ def main() -> int:
                         memory_peak_bytes=after["device"]["memory_peak_bytes"],
                         stages_ms=stage_ms(before["stages"], after["stages"]),
                         prefilter={k: v - before["automata"]["prefilter"].get(k, 0) for k, v
-                                   in after["automata"]["prefilter"].items()})
+                                   in after["automata"]["prefilter"].items()},
+                        compile_cache={k: after["compile_cache"][k] - before["compile_cache"][k]
+                                       for k in LAUNCH_COUNTERS})
             harness.emit(line)
         proc.send_signal(signal.SIGTERM)
         return proc.wait(timeout=harness.T_EXIT_S)

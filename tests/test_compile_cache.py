@@ -18,6 +18,8 @@ import sys
 import threading
 from pathlib import Path
 
+import pytest
+
 from coraza_kubernetes_operator_tpu.engine.compile_cache import (
     EXEC_CACHE,
     batch_signature,
@@ -341,3 +343,185 @@ def test_default_cache_dir_is_one_fixed_path_in_the_checkout(tmp_path):
     assert got_a["jax"] == got_a["returned"] == str(REPO / ".jax_bench_cache")
     # Nothing selects a directory unless an entrypoint asks for the default.
     assert _probe_cache_dir(a)["jax"] is None
+
+
+# -- composed keys and the launch table (ISSUE 29) ---------------------------
+
+
+def _old_key(jitted, args, statics):
+    """The key as it was before keys were composed: one signature over
+    the whole argument list, model tables included."""
+    name = getattr(jitted, "__name__", None) or str(jitted)
+    return (name,) + batch_signature(args, tuple(sorted(statics.items())))
+
+
+def _window(
+    rows=16,
+    width=32,
+    dtype="uint8",
+    mask=None,
+    max_phase=2,
+    cached_rows=None,
+    pipelines=1,
+    n_tiers=1,
+    fill=0,
+):
+    """A window's operands as ``tier_tensors`` lays them out, at the
+    given shapes: ``(tiers, numvals, masks, cached, max_phase)``."""
+    import numpy as np
+
+    def tier(u):
+        i32 = lambda *shape: np.full(shape, fill, dtype=np.int32)  # noqa: E731
+        return (
+            np.full((u, width), fill, dtype=dtype),
+            i32(u),
+            i32(u),
+            i32(u),
+            i32(u),
+            i32(u),
+            np.zeros((pipelines, u, width), dtype=np.uint8),
+            i32(pipelines, u),
+            i32(u),
+        )
+
+    tiers = tuple(tier(rows * (i + 1)) for i in range(n_tiers))
+    cached = None
+    if cached_rows is not None:
+        cached = tuple(np.zeros((cached_rows, 4), dtype=np.uint8) for _ in tiers)
+    return tiers, np.zeros((8, 4), dtype=np.int32), (mask,) * n_tiers, cached, max_phase
+
+
+def _keys(eng, window):
+    """Per executable of the window: (composed from the engine's kept
+    model signature, composed from the whole spec, the old key); and the
+    launch table's signature of the window."""
+    from coraza_kubernetes_operator_tpu.engine.tier_compile import spec_key
+    from coraza_kubernetes_operator_tpu.engine.waf import shape_signature
+
+    tiers, numvals, masks, cached, max_phase = window
+    match_specs, post_spec, _pairs = eng._tier_specs(
+        tiers, numvals, max_phase=max_phase, masks=masks, cached=cached
+    )
+    specs = match_specs + [post_spec]
+    composed = tuple(spec_key(s, eng._model_sig) for s in specs)
+    whole = tuple(spec_key(s) for s in specs)
+    old = tuple(
+        _old_key(s[2], s[3] + (s[5].get("cached"),), s[4]) for s in specs
+    )
+    table = shape_signature((tiers, numvals, cached), (masks, max_phase))
+    return composed, whole, old, table
+
+
+_BASE = dict(cached_rows=16)
+_VARIANTS = {
+    "tier_rows": dict(rows=32),
+    "tier_width": dict(width=64),
+    "window_tensor_dtype": dict(dtype="int8"),
+    "mask": dict(mask=5),
+    "max_phase": dict(max_phase=1),
+    "cached_bucket": dict(cached_rows=32),
+    "no_cached_rows": dict(cached_rows=None),
+    "host_pipelines": dict(pipelines=2),
+    "tier_count": dict(n_tiers=2),
+    "values_only": dict(fill=7),  # the one pair whose keys must be EQUAL
+}
+
+
+
+@pytest.mark.parametrize("what", sorted(_VARIANTS))
+def test_composed_key_partitions_calls_like_the_old_key(what):
+    """Keys composed from the kept model signature equal the keys
+    composed from the whole spec, and separate two windows exactly where
+    the old whole-pytree key separated them; so does the launch table's
+    signature of the window."""
+    eng = WafEngine(RULES_A)
+    a = _keys(eng, _window(**_BASE))
+    b = _keys(eng, _window(**{**_BASE, **_VARIANTS[what]}))
+    for composed, whole, _old, _table in (a, b):
+        assert composed == whole
+        assert hash(composed) == hash(whole)
+    old_equal = a[2] == b[2]
+    assert old_equal == (what == "values_only")
+    assert (a[0] == b[0]) == old_equal
+    assert (a[3] == b[3]) == old_equal
+    # Executable by executable, where the two windows have as many.
+    if len(a[0]) == len(b[0]):
+        for ca, cb, oa, ob in zip(a[0], b[0], a[2], b[2]):
+            assert (ca == cb) == (oa == ob)
+
+
+def test_same_layout_rulesets_compose_equal_keys():
+    eng_a = WafEngine(RULES_A)
+    eng_b = WafEngine(RULES_B)
+    assert eng_a._model_sig is not eng_b._model_sig
+    assert eng_a._model_sig == eng_b._model_sig
+    assert _keys(eng_a, _window())[0] == _keys(eng_b, _window())[0]
+    # A ruleset of another layout composes other keys.
+    eng_c = WafEngine(RULES_A + 'SecRule ARGS "@rx x+y" "id:101,phase:2,pass"\n')
+    assert eng_c._model_sig != eng_a._model_sig
+    assert _keys(eng_c, _window())[0] != _keys(eng_a, _window())[0]
+
+
+def test_warm_windows_never_walk_the_model(monkeypatch):
+    """Ten warm windows: no signature is taken of anything that holds
+    the model, each launches from the table, nothing compiles."""
+    from coraza_kubernetes_operator_tpu.engine import compile_cache, waf
+    from coraza_kubernetes_operator_tpu.models.waf_model import WafModel
+
+    eng = WafEngine(RULES_A)
+    reqs = _requests()
+    want = [v.interrupted for v in eng.evaluate(reqs)]
+    eng.evaluate(reqs)  # the value cache's rows: another window shape
+
+    walks = []
+    real = compile_cache.batch_signature
+
+    def counting(args, static_kwargs):
+        if any(isinstance(a, WafModel) for a in args):
+            walks.append(args)
+        return real(args, static_kwargs)
+
+    monkeypatch.setattr(compile_cache, "batch_signature", counting)
+    monkeypatch.setattr(waf, "shape_signature", counting)
+    before = EXEC_CACHE.stats()
+    for _ in range(10):
+        assert [v.interrupted for v in eng.evaluate(reqs)] == want
+    after = EXEC_CACHE.stats()
+
+    assert walks == []
+    assert after["launch_plan_hits"] - before["launch_plan_hits"] == 10
+    assert after["launch_plan_misses"] == before["launch_plan_misses"]
+    assert after["device_windows"] - before["device_windows"] == 10
+    for flat in ("misses", "bypasses", "host_twin_windows"):
+        assert after[flat] == before[flat]
+    # One hit per executable called, as before the table.
+    assert after["hits"] - before["hits"] == 10 * 2
+    # The counting wrapper does see a walk where one is made.
+    compile_cache.model_signature(eng.model)
+    assert len(walks) == 1
+
+
+def test_table_hit_keeps_the_aot_rejection_fallback(monkeypatch):
+    """An executable that rejects its arguments when called from the
+    launch table is bypassed to the plain jit dispatch and counted, as
+    on the spec-by-spec path: the window's verdicts do not move and the
+    rejected call is no hit."""
+    monkeypatch.setenv("CKO_VALUE_CACHE_MB", "0")  # one window shape
+    eng = WafEngine(RULES_A)
+    reqs = _requests()
+    want = [(v.interrupted, v.rule_id) for v in eng.evaluate(reqs)]
+
+    def rejecting(*_args, **_kwargs):
+        raise TypeError("compiled for another signature")
+
+    _generation, table = eng._launch_table
+    (sig, (match_stages, post_stage)), = table.items()
+    table[sig] = ([(k, fn, rejecting) for k, fn, _c in match_stages], post_stage)
+
+    before = EXEC_CACHE.stats()
+    assert [(v.interrupted, v.rule_id) for v in eng.evaluate(reqs)] == want
+    after = EXEC_CACHE.stats()
+    assert after["bypasses"] - before["bypasses"] == len(match_stages)
+    assert after["hits"] - before["hits"] == 1  # the post stage alone
+    assert after["launch_plan_hits"] - before["launch_plan_hits"] == 1
+    assert after["device_windows"] - before["device_windows"] == 1

@@ -534,3 +534,65 @@ def test_bench_budget_schedule_fits_driver_wall(monkeypatch):
     budgets = bench._schedule_budgets(keys, 1450.0)
     assert budgets["3"] == 700.0
     assert sum(budgets.values()) <= 1450.0
+
+
+# -- the launch table's lifetime (ISSUE 29) ----------------------------------
+
+
+@pytest.mark.parametrize("event", ["reinit_device", "engine_swap", "cache_clear"])
+def test_launch_table_is_dropped_with_its_model_and_its_cache(event, monkeypatch):
+    """A device re-init, an engine swap and ``EXEC_CACHE.clear()`` each
+    leave the next window to resolve its launch again (a lookup in the
+    executable cache, or a compile after a clear); the one after it
+    launches from the table; verdicts never move."""
+    from coraza_kubernetes_operator_tpu.engine.compile_cache import EXEC_CACHE
+
+    monkeypatch.setenv("CKO_VALUE_CACHE_MB", "0")  # one window shape
+    rules = BASE + EVIL_MONKEY
+    reqs = [HttpRequest(uri="/?q=evilmonkey"), HttpRequest(uri="/?q=fine")]
+
+    def verdicts(engine):
+        return [(v.interrupted, v.status, v.rule_id) for v in engine.evaluate(reqs)]
+
+    def grew(before):
+        after = EXEC_CACHE.stats()
+        return {k: after[k] - before[k] for k in
+                ("launch_plan_hits", "launch_plan_misses", "misses", "host_twin_windows")}
+
+    eng = WafEngine(rules)
+    want = verdicts(eng)
+    assert want == [(True, 403, 3001), (False, 200, None)]
+    s0 = EXEC_CACHE.stats()
+    assert verdicts(eng) == want
+    assert grew(s0) == {"launch_plan_hits": 1, "launch_plan_misses": 0,
+                        "misses": 0, "host_twin_windows": 0}
+    old_sig, old_table = eng._model_sig, eng._launch_table[1]
+    assert len(old_table) == 1
+
+    resident = dict(EXEC_CACHE._entries)
+    try:
+        if event == "reinit_device":
+            eng.reinit_device()
+            assert eng._model_sig == old_sig and eng._model_sig is not old_sig
+        elif event == "engine_swap":
+            eng = WafEngine(rules)  # what every swap path serves next
+        else:
+            EXEC_CACHE.clear()
+            assert len(EXEC_CACHE) == 0
+        s1 = EXEC_CACHE.stats()
+        assert verdicts(eng) == want
+        first = grew(s1)
+        assert first["launch_plan_hits"] == 0 and first["launch_plan_misses"] == 1
+        assert first["host_twin_windows"] == 0
+        # Only the cleared cache has anything to compile.
+        assert (first["misses"] > 0) == (event == "cache_clear")
+        assert eng._launch_table[1] is not old_table
+        assert len(eng._launch_table[1]) == 1
+        s2 = EXEC_CACHE.stats()
+        assert verdicts(eng) == want
+        assert grew(s2) == {"launch_plan_hits": 1, "launch_plan_misses": 0,
+                            "misses": 0, "host_twin_windows": 0}
+    finally:
+        # Other tests of this process keep the executables they minted.
+        for key, compiled in resident.items():
+            EXEC_CACHE._entries.setdefault(key, compiled)

@@ -63,8 +63,8 @@ def test_lazy_host_routing_matches_eager_on_ftw_corpus(monkeypatch):
     # Cold start, nothing resident yet: force every residency probe to
     # miss so EVERY stage routes through the host twin.
     with monkeypatch.context() as m:
-        m.setattr(TierCompiler, "resident", lambda self, spec: False)
-        m.setattr(TierCompiler, "ensure", lambda self, spec: False)
+        m.setattr(TierCompiler, "resident", lambda self, spec, key=None: False)
+        m.setattr(TierCompiler, "ensure", lambda self, spec, key=None: False)
         lazy_cold = [_vt(v) for v in lazy.evaluate(reqs)]
     assert lazy_cold == eager_first
     assert not lazy.warmed, "host-served window must not claim warmed"
@@ -89,7 +89,7 @@ def test_lazy_cold_dispatch_enqueues_compiles(monkeypatch):
     monkeypatch.setattr(
         TierCompiler,
         "ensure",
-        lambda self, spec: (submitted.append(spec[0]), False)[1],
+        lambda self, spec, key=None: (submitted.append(spec[0]), False)[1],
     )
     eng = WafEngine(
         "SecRuleEngine On\n"
@@ -115,12 +115,11 @@ class _RecordingCache:
 
     def __init__(self):
         self.warm_order: list[str] = []
-        self.key_for = EXEC_CACHE.key_for  # real key composition
 
     def _lookup(self, key, count_hit=False):
         return None
 
-    def warm(self, jitted, args, statics, dyn):
+    def warm(self, jitted, args, statics, dyn, key=None):
         self.warm_order.append(getattr(jitted, "__name__", "?"))
         return True
 
@@ -159,3 +158,54 @@ def test_compile_order_is_smallest_first(monkeypatch):
     assert stub.warm_order[0].startswith("cko_eval_post_")
     assert all(name.startswith("cko_match_") for name in stub.warm_order[1:])
     assert not any("eval_post" in name for name in stub.warm_order[1:])
+
+
+def test_host_twin_window_never_enters_the_launch_table(monkeypatch):
+    """A window with a stage that is not resident takes the host twins
+    and leaves the engine's launch table empty; once every stage is
+    resident the next window fills it and the one after launches from
+    it, with the same verdicts."""
+    reqs = [
+        HttpRequest(uri="/?q=lazy-table-probe-7"),
+        HttpRequest(uri="/?q=benign"),
+    ]
+    rules = (
+        "SecRuleEngine On\n"
+        'SecRule ARGS "@rx lazy-table-probe-[0-9]+" '
+        '"id:901,phase:2,deny,status:403"\n'
+    )
+    monkeypatch.setenv("CKO_VALUE_CACHE_MB", "0")  # one window shape
+    eager = WafEngine(rules)
+    want = [_vt(v) for v in eager.evaluate(reqs)]
+
+    monkeypatch.setenv("CKO_LAZY_TIERS", "1")
+    lazy = WafEngine(rules)
+    _generation, table = lazy._launch_table
+    s0 = EXEC_CACHE.stats()
+    with monkeypatch.context() as m:
+        # The post stage alone is "still compiling": a mixed window.
+        real = TierCompiler.ensure
+        m.setattr(
+            TierCompiler,
+            "ensure",
+            lambda self, spec, key=None: spec[0] != "post"
+            and real(self, spec, key),
+        )
+        for _ in range(2):
+            assert [_vt(v) for v in lazy.evaluate(reqs)] == want
+            assert table == {}
+    s1 = EXEC_CACHE.stats()
+    assert s1["host_twin_windows"] - s0["host_twin_windows"] == 2
+    assert s1["launch_plan_misses"] - s0["launch_plan_misses"] == 2
+    assert s1["launch_plan_hits"] == s0["launch_plan_hits"]
+    assert not lazy.warmed
+
+    assert [_vt(v) for v in lazy.evaluate(reqs)] == want  # resolves, fills
+    assert len(table) == 1
+    assert [_vt(v) for v in lazy.evaluate(reqs)] == want  # launches from it
+    s2 = EXEC_CACHE.stats()
+    assert s2["launch_plan_misses"] - s1["launch_plan_misses"] == 1
+    assert s2["launch_plan_hits"] - s1["launch_plan_hits"] == 1
+    assert s2["device_windows"] - s1["device_windows"] == 2
+    assert s2["host_twin_windows"] == s1["host_twin_windows"]
+    assert lazy.warmed
