@@ -47,10 +47,6 @@ ftw.crs-lite:  ## Conformance: crs-lite corpus (CRS v4-structured) in-process.
 	r = run_corpus('ftw/tests-crs-lite', load_ruleset_text()); \
 	print(json.dumps(r.summary())); sys.exit(0 if r.ok else 1)"
 
-.PHONY: bench
-bench:  ## Streaming JSON benchmark: one line per config + final summary.
-	$(PYTHON) bench.py
-
 .PHONY: pipeline.smoke
 pipeline.smoke:  ## Host/device overlap gate: pipelined >= 1.2x sync, verdicts identical.
 	$(PYTHON) hack/pipeline_smoke.py
@@ -104,24 +100,10 @@ automata.smoke:  ## Two-level automata gate: ftw+crs-lite replay on vs off, byte
 metrics.lint:  ## Metric catalog drift: every registered cko_*/waf_* metric documented, no dead doc entries.
 	$(PYTHON) hack/metrics_lint.py
 
-# bench.warm populates .jax_bench_cache with the FINAL compiler's HLO so
-# the driver's timed run hits a warm XLA cache (VERDICT r3 item 1d). Runs
-# every config once with minimal iters; throughput output is discarded.
-.PHONY: bench.warm
-bench.warm:
-	BENCH_ITERS=1 BENCH_LAT_ITERS=2 BENCH_CONFIG_BUDGET_S=1800 \
-	BENCH_TOTAL_BUDGET_S=7200 $(PYTHON) bench.py
-
-.PHONY: bench.smoke
-bench.smoke:  ## Fast single-config bench (presubmit gate; strict exit).
-	BENCH_CONFIGS=1 BENCH_ITERS=2 BENCH_STRICT=1 $(PYTHON) bench.py
-
 .PHONY: presubmit
-presubmit:  ## Gate before any end-of-round snapshot: warm-cache freshness FIRST (pytest/bench write entries and would mask staleness), then fast tier + smoke bench.
+presubmit:  ## Gate before any end-of-round snapshot: warm-cache freshness FIRST (pytest writes entries and would mask staleness), then the fast tier.
 	$(PYTHON) hack/check_cache_fresh.py tests/.jax_cache --hint 'run make test over the FINAL code and commit tests/.jax_cache'
-	$(PYTHON) hack/check_cache_fresh.py .jax_bench_cache --hint 'run make bench.warm LAST, after every engine change'
 	$(PYTHON) -m pytest tests/ -x -q
-	$(MAKE) bench.smoke
 
 .PHONY: lint
 lint:

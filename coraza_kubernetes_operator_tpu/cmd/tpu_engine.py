@@ -392,8 +392,8 @@ def build_config(argv: list[str] | None = None) -> SidecarConfig:
 def main(argv: list[str] | None = None) -> int:
     # Production default: lazy per-tier compilation — serve from the
     # host fallback while the thread pool mints tier executables
-    # smallest-first (engine/tier_compile.py). Tests and bench leave
-    # the env unset and get deterministic eager-parallel compiles.
+    # smallest-first (engine/tier_compile.py). Tests leave the env
+    # unset and get deterministic eager-parallel compiles.
     os.environ.setdefault("CKO_LAZY_TIERS", "1")
     config = build_config(argv)
     sidecar = TpuEngineSidecar(config)

@@ -1,6 +1,6 @@
 """Per-group automata tier planning for the two-level device engine.
 
-One planner, consumed from four places so they can never disagree:
+One planner, consumed from three places so they can never disagree:
 
 - ``models/waf_model.build_model`` routes groups into segment blocks,
   DFA hot-tier gather banks, prefiltered banks, or exact NFA banks
@@ -11,8 +11,7 @@ One planner, consumed from four places so they can never disagree:
 - ``analysis/rulelint`` reports the tier assignment in the CKO-R010
   coverage summary and raises CKO-R011 advisories for
   prefilter-ineligible groups (this module is numpy-only so the
-  analyzer needs no jax);
-- ``bench.py`` attaches the tier breakdown to BENCH records.
+  analyzer needs no jax).
 
 Tier kinds per rule group:
 

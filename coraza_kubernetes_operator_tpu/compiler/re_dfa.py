@@ -310,9 +310,9 @@ def compile_nfa_dfa(nfa: PositionNFA, max_states: int = 8192, ast: object | None
 
 
 # DFA construction cache: in-process memo + persistent on-disk pickle.
-# The bench compiles overlapping rulesets (crs-lite base shared by
-# configs 2/3/4, config 3's padding is a prefix of config 4's) and the
-# control plane recompiles identical CRS text on every hot-reload poll;
+# Tenants compile overlapping rulesets (one CRS base under many
+# policies) and the control plane recompiles identical CRS text on
+# every hot-reload poll;
 # determinization is the dominant host-compile cost (~0.1 s per
 # CRS-grade pattern on one core), so both layers pay for themselves
 # immediately. Keyed by (algo version, pattern, ci, max_states); the

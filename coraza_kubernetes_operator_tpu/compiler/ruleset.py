@@ -1137,8 +1137,8 @@ def compile_rules_cached(text: str, cache_dir: str | None = None) -> CompiledRul
     """``compile_rules`` with a persistent pickle cache keyed by
     (ruleset hash, compiler-source hash).
 
-    compile_rules on the crs-lite corpus is ~30s of host work on the
-    1-core bench machine, and the conformance gate re-needs the identical
+    compile_rules on the crs-lite corpus is ~30s of host work on one
+    core, and the conformance gate re-needs the identical
     artifact on every run (ISSUE 1: the gate must finish <3 min). The
     cache dir defaults to ``$CKO_CRS_CACHE`` or ``~/.cache/cko-crs``;
     ``CKO_CRS_CACHE=0`` disables. Corrupt/stale entries recompile and

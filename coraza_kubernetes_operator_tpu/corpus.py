@@ -1,12 +1,13 @@
-"""Shared rule/request corpus for benchmarks, the graft entry and tests.
+"""Shared rule/request corpus for the engine's prewarm, the graft entry,
+the ``hack/`` smokes and tests.
 
 ``sample_rules()`` mirrors the reference's sample RuleSet
 (``config/samples/ruleset.yaml``: base config + SQLi + XSS + evil-monkey).
 ``synthetic_crs(n)`` generates a CRS-shaped ruleset (anomaly-scoring
-paranoia-style rules across attack categories) scaling to the
-``BASELINE.json`` configs (full CRS ≈ 800 rules; +5k synthetic @rx).
+paranoia-style rules across attack categories) of any size.
 ``synthetic_requests(n)`` generates a benign/attack request mix shaped like
-the go-ftw corpus traffic.
+the go-ftw corpus traffic (``WafEngine.prewarm`` warms its window shapes
+on it); ``zipfian_requests(n)`` draws repeats from a pool of those.
 """
 
 from __future__ import annotations
@@ -188,92 +189,8 @@ def zipfian_requests(
     Where ``synthetic_requests`` deliberately suppresses repeats (honest
     uncached numbers), this generator produces them ON PURPOSE: it is
     the workload the verdict cache and in-window dedup are built for
-    (BENCH_E2E_ZIPF / hack/verdict_cache_smoke.py)."""
+    (hack/verdict_cache_smoke.py)."""
     rng = random.Random(seed)
     pool = synthetic_requests(pool_size, attack_ratio=attack_ratio, seed=seed)
     weights = [1.0 / (k + 1) ** s for k in range(len(pool))]
     return rng.choices(pool, weights=weights, k=n)
-
-
-# ---------------------------------------------------------------------------
-# CRS-grade synthetic padding (VERDICT r2 item 3): real CRS regexes are
-# long alternations of realistic tokens, bounded repeats, wide char
-# classes — stressing DFA state counts and the conv-segment decomposer
-# in ways short templates do not. These generators emit that complexity
-# grade; patterns the decomposer rejects land on the DFA tier, exactly
-# like real CRS traffic.
-# ---------------------------------------------------------------------------
-
-_SQL_FUNCS = [
-    "concat", "group_concat", "load_file", "benchmark", "sleep", "updatexml",
-    "extractvalue", "substring", "substr", "mid", "chr", "ascii", "hex",
-    "unhex", "version", "database", "schema", "current_user", "system_user",
-    "session_user", "coalesce", "ifnull", "greatest", "least", "strcmp",
-]
-_XSS_EVENTS = [
-    "onerror", "onload", "onclick", "onmouseover", "onfocus", "onblur",
-    "onkeydown", "onsubmit", "ontoggle", "onanimationstart", "onpointerover",
-    "onwheel", "ondrag", "oncut", "onpaste",
-]
-_XSS_TAGS = [
-    "script", "img", "svg", "iframe", "object", "embed", "video", "audio",
-    "details", "marquee", "body", "input", "form", "math", "style",
-]
-_RCE_CMDS = [
-    "cat", "ls", "id", "whoami", "uname", "curl", "wget", "nc", "bash",
-    "sh", "python", "perl", "ruby", "php", "nmap", "ping", "chmod", "touch",
-]
-_PHP_FUNCS = [
-    "base64_decode", "eval", "assert", "system", "exec", "shell_exec",
-    "passthru", "popen", "proc_open", "file_get_contents", "include",
-    "require", "preg_replace", "create_function", "call_user_func",
-    "gzinflate", "str_rot13",
-]
-_LFI_PATHS = [
-    "etc/passwd", "etc/shadow", "proc/self/environ", "boot\\.ini",
-    "win\\.ini", "windows/system32", "\\.git/config", "\\.env",
-    "wp-config\\.php", "id_rsa",
-]
-
-
-def _crs_grade_pattern(i: int, rng: random.Random) -> str:
-    kind = i % 6
-    if kind == 0:
-        funcs = rng.sample(_SQL_FUNCS, k=rng.randrange(8, 16))
-        return rf"(?i:\b(?:{'|'.join(funcs)})\s*\()"
-    if kind == 1:
-        gap = rng.randrange(20, 60)
-        return (
-            rf"(?i:\b(?:select|update|delete|insert)\b"
-            rf".{{0,{gap}}}\b(?:from|into|where|set)\b)"
-        )
-    if kind == 2:
-        tags = rng.sample(_XSS_TAGS, k=rng.randrange(5, 10))
-        evs = rng.sample(_XSS_EVENTS, k=rng.randrange(5, 10))
-        return rf"(?i:<(?:{'|'.join(tags)})[^>]{{0,60}}(?:{'|'.join(evs)})\s*=)"
-    if kind == 3:
-        cmds = rng.sample(_RCE_CMDS, k=rng.randrange(6, 12))
-        return rf"(?i:[;|`]\s*(?:{'|'.join(cmds)})\b)"
-    if kind == 4:
-        funcs = rng.sample(_PHP_FUNCS, k=rng.randrange(6, 12))
-        return rf"(?i:\b(?:{'|'.join(funcs)})\s*\()"
-    paths = rng.sample(_LFI_PATHS, k=rng.randrange(4, 8))
-    return rf"(?i:(?:\.\./|%2e%2e%2f){{1,4}}(?:{'|'.join(paths)}))"
-
-
-def crs_grade_rules(n_rules: int, seed: int = 0, id_base: int = 9500000) -> str:
-    """``n_rules`` anomaly-scoring @rx rules at CRS pattern complexity.
-    Use to pad a real (crs-lite) ruleset to full-CRS scale — the
-    BASELINE configs 3/4 workloads."""
-    rng = random.Random(seed)
-    out = []
-    for i in range(n_rules):
-        rule_id = id_base + i
-        pattern = _crs_grade_pattern(i, rng).replace('"', '\\"')
-        out.append(
-            f'SecRule ARGS|REQUEST_URI "@rx {pattern}" '
-            f"\"id:{rule_id},phase:2,pass,t:none,t:urlDecodeUni,"
-            f"msg:'crs-grade synthetic {rule_id}',"
-            f"setvar:tx.inbound_anomaly_score=+%{{tx.critical_anomaly_score}}\""
-        )
-    return "\n".join(out)

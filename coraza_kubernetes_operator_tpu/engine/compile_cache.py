@@ -1,9 +1,7 @@
 """Shape-canonical executable reuse: the AOT compile cache.
 
-Round-5 bench attribution: compilation, not evaluation, dominates wall
-time (config 2 spent 144.6s of 168.9s in ``compile_s``; configs 3/e2e
-died as ``{"error": "budget"}`` because every distinct tier-shape
-signature minted a fresh full-model compile). The fix is the fixed-table
+Compilation, not evaluation, dominates a cold start: every distinct
+tier-shape signature used to mint a fresh full-model compile. The fix is the fixed-table
 idiom from SIMD DFA engines (Hyperflex, arXiv:2512.07123; in-memory
 regex matching, arXiv:2209.05686): the *executable* is a function of the
 **shape signature only** — tier buffer shapes, mask tuple, model table
@@ -17,14 +15,13 @@ swapped into it. Three layers implement that here:
    rulesets with the same bucketed layout hash to the same signature.
 2. **In-process executable cache** (:class:`ExecutableCache` /
    ``EXEC_CACHE``): signature → AOT-compiled executable
-   (``jit.lower(...).compile()``). Tenants, hot reloads, and bench
-   configs sharing a signature reuse ONE executable; a reload on an
+   (``jit.lower(...).compile()``). Tenants and hot reloads
+   sharing a signature reuse ONE executable; a reload on an
    unchanged signature performs zero XLA compiles. Hit/miss/compile-time
    counters back the ``cko_compile_cache_*`` metrics.
 3. **Persistent compilation cache** (:func:`configure_persistent_cache`):
    JAX's on-disk cache keyed by HLO hash — cold *processes* warm-start
-   from disk (bench children, ftw chunk children, CI runs, sidecar
-   restarts). ``JAX_COMPILATION_CACHE_DIR`` wins when set; else
+   from disk (ftw chunk children, CI runs, sidecar restarts). ``JAX_COMPILATION_CACHE_DIR`` wins when set; else
    ``CKO_COMPILE_CACHE_DIR`` / the flag; else one fixed in-checkout path.
 
 Thread safety: lookups and stats are lock-protected; a miss compiles
@@ -53,8 +50,8 @@ log = get_logger("engine.compile_cache")
 #    reads it itself; nothing here sets another directory over it,
 #    whatever the flag or ``CKO_COMPILE_CACHE_DIR`` say.
 # 2. ``--compile-cache-dir`` / ``CKO_COMPILE_CACHE_DIR`` — the repo's
-#    own knob, shared by the sidecar entrypoint, bench harness, ftw
-#    chunk children and CI. ``"0"`` disables.
+#    own knob, shared by the sidecar entrypoint, ftw chunk children
+#    and CI. ``"0"`` disables.
 # 3. ``DEFAULT_CACHE_DIR`` — one fixed path inside the checkout (the
 #    path is part of the cache key's neighbourhood: a directory that
 #    moves never hits), used by entrypoints that want a cache whether or
@@ -378,7 +375,7 @@ class ExecutableCache:
             }
 
     def snapshot(self) -> tuple[int, int, float]:
-        """(hits, misses, compile_s) — for delta reporting (bench)."""
+        """(hits, misses, compile_s) — for delta reporting."""
         with self._lock:
             return (self.hits, self.misses, self.compile_s)
 
@@ -388,6 +385,6 @@ class ExecutableCache:
             self.generation += 1
 
 
-# Process-wide singleton: tenants, reloads, the promotion probe, and the
-# bench harness all share it — that sharing IS the executable reuse.
+# Process-wide singleton: tenants, reloads and the promotion probe all
+# share it — that sharing IS the executable reuse.
 EXEC_CACHE = ExecutableCache()

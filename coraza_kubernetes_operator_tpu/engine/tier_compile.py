@@ -24,9 +24,8 @@ manager owns HOW those executables get compiled:
   pattern, applied per tier instead of per engine).
 
 All compiles flow through ``EXEC_CACHE.warm`` so residency, hit/miss
-accounting, and the persistent disk cache behave exactly as on the
-monolithic path. Per-label compile seconds feed ``cko_compile_tier_s``
-and the bench per-config breakdown.
+accounting, and the persistent disk cache behave the same for every
+stage. Per-label compile seconds feed ``cko_compile_tier_s``.
 """
 
 from __future__ import annotations
@@ -68,14 +67,14 @@ class TierCompiler:
         self._workers = workers
         self._inflight: dict[tuple, object] = {}  # key -> Future
         # label -> cumulative XLA wall seconds spent minting executables
-        # with that label (cko_compile_tier_s; bench per-config records).
+        # with that label (cko_compile_tier_s).
         self.tier_s: dict[str, float] = {}
         # (label, cost) in submission order — smallest-first is the
         # contract (tests/test_lazy_tiers.py pins it).
         self.submitted: list[tuple[str, float]] = []
         # label -> free-form metadata annotated at tier-selection time
         # (engine startup stamps the automata composition here so stats
-        # and bench can say WHAT each compiled stage contains — e.g. how
+        # can say WHAT each compiled stage contains — e.g. how
         # many dfa-hot gather banks rode into the matcher trace).
         self.meta: dict[str, dict] = {}
 

@@ -19,7 +19,7 @@ import numpy as np
 
 from ..compiler.ruleset import CompiledRuleSet, compile_rules
 from ..compiler.transforms_host import apply_pipeline
-from ..models.waf_model import WafModel, build_model, eval_waf
+from ..models.waf_model import WafModel, build_model
 from ..observability.stages import HOST_STAGES
 from ..observability.stages import current as current_stages
 from ..utils import get_logger
@@ -46,7 +46,8 @@ def _bucket_rows(n: int) -> int:
     QUANTIZED lattice (shape quantization): the old 1024-granularity
     above 2048 capped padding waste at ~12% but minted a fresh shape
     signature — and a fresh cold executable — every 1024 rows, which is
-    exactly the signature explosion that blew bench config 3's budget.
+    exactly the signature explosion that made a full-CRS cold start
+    outlast any budget.
     Two sizes per octave bounds padding waste at ~33% while the distinct
     shape count grows logarithmically, so similar-size batches collapse
     onto the same executables (EXEC_CACHE hits instead of compiles)."""
@@ -153,13 +154,17 @@ def tier_tensors(tensors, kind_lut=None, cache=None):
     never pay the body's width. With ``kind_lut`` (``WafEngine``'s
     kind -> block bitmask table) rows are additionally partitioned by
     which matcher blocks their kinds can reach; each partition's static
-    mask lets ``eval_waf_tiered`` skip unreachable matchers entirely.
+    mask lets ``match_tier_packed`` skip unreachable matchers entirely.
 
     Input is the 9-tuple from ``WafEngine._tensorize`` (or the native
     tensorizer — both produce identical row layouts); output is
     ``(tiers, numvals, masks)`` where tiers is a tuple of per-tier
-    9-tuples for ``eval_waf_tiered`` and masks the aligned static
-    block-bitmask tuple (entries None when kind_lut is absent).
+    9-tuples ``(data, lengths, kind1, kind2, kind3, req_id, vdata,
+    vlengths, uid)``: the first, second and last three go to the tier's
+    ``match_tier_packed`` and the kinds, req_id and uid to the window's
+    ``eval_post_tiered`` (``WafEngine._tier_specs``). masks is the
+    aligned static block-bitmask tuple (entries None when kind_lut is
+    absent).
 
     With ``cache`` (a ``ValueHitCache``), unique rows whose key was
     matched in an earlier batch skip the matcher: the return grows to
@@ -545,7 +550,7 @@ class WafEngine:
         # a thread pool compiles them smallest-first (the sidecar entry
         # defaults it on); the default eager mode blocks the first
         # dispatch until every executable landed — still parallel and
-        # smallest-first, but deterministic for tests and bench.
+        # smallest-first, but deterministic for tests.
         self._lazy = _os.environ.get("CKO_LAZY_TIERS", "0") == "1"
         # Distinct executable shape signatures this engine has dispatched
         # (cko_exec_signatures / CompileReport.exec_signatures).
@@ -598,7 +603,7 @@ class WafEngine:
         )
         self._native_confirm_failed = False  # the failure is logged once
         # Stamp the automata composition onto the matcher stage label at
-        # tier-selection time: tier stats / bench can then report what
+        # tier-selection time: tier stats can then report what
         # the compiled matchers actually contain, not just their shapes.
         from .tier_compile import TIER_COMPILER
 
@@ -1085,6 +1090,12 @@ class WafEngine:
 
         if masks is None:
             masks = (None,) * len(tiers)
+        elif len(masks) != len(tiers):
+            # The zips below and in _dispatch_tiers would drop the
+            # trailing tiers in silence: missed matches.
+            raise ValueError(
+                f"masks length {len(masks)} != tiers length {len(tiers)}"
+            )
         g = int(self.model.e_lg.shape[0])
         pb = (g + 7) // 8
         match_specs = []
@@ -1386,8 +1397,8 @@ class WafEngine:
         return ok
 
     def automata_summary(self) -> dict:
-        """Automata-tier composition + prefilter counters for stats,
-        metrics, and bench: which groups run where (the plan's verdict),
+        """Automata-tier composition + prefilter counters for stats
+        and metrics: which groups run where (the plan's verdict),
         how many device banks each tier produced, and how the prefilter's
         over-approximation is paying off at runtime."""
         plan = self.automata_plan

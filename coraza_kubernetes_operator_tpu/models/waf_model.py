@@ -830,7 +830,10 @@ def match_tier(
     group hits [T, G]. Segment blocks first, DFA banks after — the same
     global order build_model's remap assigned. Tiers are independent
     until post_match (rows only meet at the req_id reduction), which is
-    what makes row-level length tiering (``eval_waf_tiered``) sound.
+    what makes row-level length tiering (``engine.waf.tier_tensors``)
+    sound: each tier's matcher runs at its own buffer width (conv work
+    is linear in Q = L + 2), so a long request's short rows never pay
+    the body's width.
 
     ``mask`` (static int) is the kind-partition block bitmask: bit i set
     = scan block i (segs first, then banks — build_model order). Bits
@@ -941,73 +944,6 @@ def _unpack_hit_rows(packed: jnp.ndarray, g: int) -> jnp.ndarray:
     shifts = 7 - jnp.arange(8, dtype=jnp.uint8)
     bits = (packed[:, :, None] >> shifts[None, None, :]) & 1
     return bits.reshape(u, pb * 8)[:, :g].astype(bool)
-
-
-@partial(jax.jit, static_argnames=("max_phase", "masks"))
-def eval_waf_tiered(
-    model: WafModel, tiers, numvals, max_phase: int = 2, masks=None, cached=None
-):
-    """Row-level length-tiered, value-deduped evaluation. ``tiers`` is a
-    tuple of ``(data, lengths, kind1, kind2, kind3, req_id, vdata,
-    vlengths, uid)`` per length class (``engine.waf.tier_tensors``):
-    the matcher arrays hold UNIQUE target values only (real traffic
-    repeats header values/names and hot paths constantly — a serving
-    batch collapses ~5-15x), each tier's matcher runs at its own buffer
-    width (conv work is linear in Q = L + 2, so a long request's short
-    rows never pay the body's width), the unique group-hit rows expand
-    back to per-(target, kinds) pair rows by index, and one global
-    post_match reduces all pair rows by req_id. Request atomicity holds
-    because req_id is global across tiers and post_match is the only
-    cross-row stage.
-
-    ``masks`` (static tuple, len(tiers), entries int or None) carries
-    each tier's kind-partition block bitmask (``match_tier``): tiers are
-    further partitioned by which matcher blocks their rows' kinds can
-    reach, so e.g. header-only rows never scan arg-only banks.
-
-    ``cached`` (aligned tuple, entries [Uc, PB] uint8 or None) carries
-    each tier's cross-batch cached hit rows (``engine.value_cache``):
-    tier uid then indexes [matcher rows | cached rows], so cached rows
-    never touch a matcher. Returns the verdict dict; the per-tier
-    matcher-row hits ride along under "_tier_hits" when ``cached`` is
-    given (the engine bit-packs and stores them after the batch)."""
-    hits, k1s, k2s, k3s, rids = [], [], [], [], []
-    if masks is None:
-        masks = (None,) * len(tiers)
-    elif len(masks) != len(tiers):
-        # Static check at trace time: a short masks tuple would silently
-        # zip-drop trailing tiers from evaluation (missed matches).
-        raise ValueError(
-            f"masks length {len(masks)} != tiers length {len(tiers)}"
-        )
-    tier_hits = []
-    for ti, ((data, lengths, k1, k2, k3, rid, vd, vl, uid), mask) in enumerate(
-        zip(tiers, masks)
-    ):
-        hits_u = match_tier(model, data, lengths, vd, vl, mask=mask)
-        if cached is not None:
-            tier_hits.append(hits_u)
-            if cached[ti] is not None:
-                ch = _unpack_hit_rows(cached[ti], hits_u.shape[1])
-                hits_u = jnp.concatenate([hits_u, ch], axis=0)
-        hits.append(jnp.take(hits_u, uid, axis=0))  # [P, G] pair rows
-        k1s.append(k1)
-        k2s.append(k2)
-        k3s.append(k3)
-        rids.append(rid)
-    out = post_match(
-        model,
-        jnp.concatenate(hits, axis=0),
-        jnp.concatenate(k1s),
-        jnp.concatenate(k2s),
-        jnp.concatenate(k3s),
-        jnp.concatenate(rids),
-        numvals,
-        max_phase,
-    )
-    if cached is not None:
-        out["_tier_hits"] = tuple(tier_hits)
-    return out
 
 
 def post_match(
@@ -1236,45 +1172,18 @@ def _pack_verdicts(out) -> jnp.ndarray:
     return jnp.concatenate([head, words, out["scores"]], axis=1)
 
 
-@partial(jax.jit, static_argnames=("max_phase",))
-def eval_waf_compact(model: WafModel, *tensors, max_phase: int = 2):
-    """eval_waf + ``_pack_verdicts`` in one dispatch."""
-    return _pack_verdicts(eval_waf.__wrapped__(model, *tensors, max_phase=max_phase))
-
-
-@partial(jax.jit, static_argnames=("max_phase", "masks"))
-def eval_waf_compact_tiered(
-    model: WafModel, tiers, numvals, max_phase: int = 2, masks=None, cached=None
-):
-    """eval_waf_tiered + ``_pack_verdicts`` in one dispatch. With
-    ``cached``, also returns the per-tier matcher-row hits bit-packed
-    ([U, PB] uint8 each) for cache population — one extra small
-    transfer instead of a second dispatch."""
-    out = eval_waf_tiered.__wrapped__(
-        model, tiers, numvals, max_phase=max_phase, masks=masks, cached=cached
-    )
-    packed = _pack_verdicts(out)
-    if cached is None:
-        return packed
-    hits_packed = tuple(
-        jnp.packbits(h.astype(jnp.uint8), axis=1) for h in out["_tier_hits"]
-    )
-    return packed, hits_packed
-
-
-# -- split per-tier dispatch (cold-compile collapse) --------------------------
+# -- the served entry point: one matcher executable per tier, one post stage ---
 #
-# The monolithic eval_waf_compact_tiered trace compiles every tier's
-# matcher plus the post stage as ONE executable: any tier-shape change
-# recompiles everything, and a cold start pays the whole program before
-# the first verdict. The split entries below compile independently —
-# same-shape tiers across batches/tenants share one matcher executable,
-# a thread pool compiles them in parallel (XLA releases the GIL), and a
-# not-yet-compiled tier can route through the host fallback while its
-# executable lands (engine/tier_compile.py + WafEngine._dispatch_tiers).
-# Verdict parity with the monolith is exact: packbits/unpackbits over G
-# group-hit bits is lossless, and post_match is byte-for-byte the same
-# stage the monolith runs.
+# A window is evaluated by ``match_tier_packed`` once per length tier and
+# one ``eval_post_tiered`` over all of them (WafEngine._dispatch_tiers).
+# The stages compile independently: same-shape tiers across windows and
+# tenants share one matcher executable, a thread pool compiles them in
+# parallel (XLA releases the GIL), a tier-shape change recompiles that
+# tier alone, and a tier whose executable has not landed yet can route
+# through the host fallback meanwhile (engine/tier_compile.py). Between
+# the stages the group hits travel bit-packed: packbits/unpackbits over
+# G bits is lossless, so the pair computes exactly ``eval_waf``'s math
+# (``match_tier`` then ``post_match``), tier by tier.
 
 
 @partial(jax.jit, static_argnames=("mask",))
@@ -1286,10 +1195,13 @@ def match_tier_packed(
     variant_lengths: jnp.ndarray,  # [H, U]
     mask: int | None = None,
 ) -> jnp.ndarray:
-    """One tier's matcher stage as its own executable: transforms +
-    matchers over the tier's unique rows, bit-packed to [U, PB] uint8
-    (np.packbits layout — the same format the value cache stores and
-    ``eval_post_tiered`` / the host post path unpack)."""
+    """One tier's matcher stage, the executable a window launches per
+    tier: transforms + matchers (``match_tier``) over the tier's UNIQUE
+    target values (a serving window repeats header values, names and
+    hot paths, so the rows collapse before they reach a matcher),
+    bit-packed to [U, PB] uint8 (np.packbits layout — the same format
+    the value cache stores and ``eval_post_tiered`` / the host post path
+    unpack)."""
     hits_u = match_tier(model, data, lengths, variant_data, variant_lengths, mask=mask)
     return jnp.packbits(hits_u.astype(jnp.uint8), axis=1)
 
@@ -1303,12 +1215,15 @@ def eval_post_tiered(
     max_phase: int = 2,
     cached=None,  # aligned tuple of [Uc, PB] uint8 or None per tier
 ) -> jnp.ndarray:
-    """The post stage as its own executable: unpack each tier's packed
-    hit rows (matcher output or host-computed — same shapes, same bit
-    layout, so provenance never changes the trace), append the tier's
-    cached rows, expand to pair rows via uid, and run ONE global
-    post_match + verdict pack. Identical math to the tail of
-    ``eval_waf_compact_tiered``."""
+    """The post stage, one executable a window: unpack each tier's
+    packed hit rows (matcher output or host-computed — same shapes, same
+    bit layout, so provenance never changes the trace), append the
+    tier's cross-batch cached rows (``engine.value_cache``: ``uid`` then
+    indexes [matcher rows | cached rows], so a cached value never
+    touches a matcher), expand to per-(target, kinds) pair rows via
+    ``uid``, and run ONE global ``post_match`` + ``_pack_verdicts``.
+    Request atomicity holds because req_id is global across tiers and
+    post_match is the only cross-row stage."""
     g = model.e_lg.shape[0]
     hits, k1s, k2s, k3s, rids = [], [], [], [], []
     for ti, (hp, (k1, k2, k3, rid, uid)) in enumerate(zip(tier_hits, pairs)):
@@ -1334,7 +1249,7 @@ def eval_post_tiered(
 
 
 def unpack_compact(packed: np.ndarray, n_rules: int, n_counters: int):
-    """Host-side split of eval_waf_compact's packed array (numpy)."""
+    """Host-side split of ``_pack_verdicts``' packed array (numpy)."""
     nb = (n_rules + 7) // 8
     nw = (nb + 3) // 4
     head = packed[:, :3]

@@ -1,7 +1,7 @@
 """Flat-slot fused multi-bank DFA scan — bank fusion for the matcher tier.
 
-Round-4 profiling (BASELINE.md) attributed ~96% of the CRS-scale device
-step to 19 matcher stages whose cost is per-stage fixed work, not FLOPs:
+Round-4 profiling attributed ~96% of the CRS-scale device step to 19
+matcher stages whose cost is per-stage fixed work, not FLOPs:
 every DFA bank was its own scan, small banks padded their group axis to
 128 lanes, banks with S > 128 states fell to XLA's serializing gather,
 and the hot S=104 x G=84 bank exceeded the per-bank Pallas VMEM budget

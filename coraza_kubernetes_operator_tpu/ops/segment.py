@@ -52,38 +52,12 @@ import numpy as np
 from ..compiler.re_parser import ALL_BYTES
 from ..compiler.segments import Branch, Gap, Seg, SegmentPlan
 
-import os as _os
-
-# Fused Pallas finals tier (ops/segment_pallas.py v2), measured and
-# DISABLED by default. v2 fixed v1's blocker (no XLA-side im2col — the
-# residue-block decomposition turns window extraction into 128-aligned
-# block indexing) and is exact (interpret-mode differential test), but
-# on v5e it still loses to the XLA conv at serving shapes: the
-# per-position [Tt, 128] x [128, Nt] dot form ran 11.6 ms/step and the
-# batched M = Tt*lr8 form 11.1 ms/step vs 6.9 ms/step for the XLA conv
-# path (batch 4096, 800 rules) — Mosaic's scheduling of many small
-# dependent dots plus the f32 [Tt, qr8, Nt] temporaries outweigh the
-# saved [T, Q, N] bitmap traffic. Kept for rulesets/hardware where the
-# economics flip; CKO_PALLAS_FINALS=1 opts in.
-_PALLAS_FINALS = _os.environ.get("CKO_PALLAS_FINALS", "0") == "1"
-_FINALS_BLOCK_T = 128  # row tile; t must be a multiple (or a small power of two)
-
 # Above this Q the NCE prefix sum uses jnp.cumsum instead of a [Q, Q]
 # triangular matmul — the table is O(Q²) HBM and on long-body buckets
 # (up to SecRequestBodyLimit) would be a request-triggerable multi-GB
 # allocation.
 _NCE_MATMUL_MAX_Q = 512
 
-
-def _use_pallas_finals(t: int, n_cols: int, n_channels: int, n_groups_f: int) -> bool:
-    return (
-        _PALLAS_FINALS
-        and (t % _FINALS_BLOCK_T == 0 or (t < _FINALS_BLOCK_T and t % 8 == 0))
-        and n_cols >= 128
-        and n_channels <= 128
-        and n_groups_f <= 512
-        and jax.default_backend() == "tpu"
-    )
 
 # ---------------------------------------------------------------------------
 # Host-side build: plans → channel/kernel spec
@@ -552,22 +526,14 @@ def match_segment_block(
     if not col_order:
         col_order = [0]
 
-    # Finals columns go to the fused Pallas tier when eligible (TPU,
-    # tile-divisible batch): they are then EXCLUDED from the XLA conv —
-    # the Pallas kernel computes them itself with a K = W*C im2col
-    # matmul, so m_all below covers only columns [off, N2).
-    n_finals_cols = sum(len(gs) for gs in final_gidsets.values())
-    pallas_finals = n_finals_cols > 0 and _use_pallas_finals(
-        t, n_finals_cols, len(spec.channels), len(finals)
-    )
-    off = n_finals_cols if pallas_finals else 0
-
     # 2. conv: all segments, all start positions. out[t, p, n] == 2W ⇔
     # segment n matches the window starting at padded position p. (An
     # im2col-matmul formulation was measured 1.6x SLOWER here at XLA
     # level — the [T·Q, W·C] window materialization's HBM traffic
-    # exceeds the conv's MXU inefficiency; the Pallas finals tier gets
-    # the same K without the HBM cost by building windows in VMEM.)
+    # exceeds the conv's MXU inefficiency. A fused Pallas finals tier
+    # that built the windows in VMEM read 11.1–11.6 ms/step against
+    # this conv's 6.9 on a v5e and was removed in PR 30; git history
+    # has ops/segment_pallas.py.)
     kernel_p = kernel[:, :, np.asarray(col_order)]  # [W, C, N2] tiny gather
     # bf16 accumulation is exact here (integer partial sums ≤ 2W = 34
     # ≪ 256) and halves the conv-output HBM traffic — the threshold is
@@ -575,17 +541,17 @@ def match_segment_block(
     # materialized bool.
     out = jax.lax.conv_general_dilated(
         embed,
-        kernel_p[:, :, off:] if off else kernel_p,
+        kernel_p,
         window_strides=(1,),
         padding="VALID",
         dimension_numbers=("NWC", "WIO", "NWC"),
         preferred_element_type=jnp.bfloat16,
-    )  # [T, Q, N2 - off]
+    )  # [T, Q, N2]
     m_all = out >= jnp.bfloat16(2.0 * w)  # equality; >= is safe (2W is the max)
 
     def mslice(a0: int, a1: int) -> jnp.ndarray:
-        """Columns [a0, a1) of the global allocation, off-adjusted."""
-        return m_all[:, :, a0 - off : a1 - off]
+        """Columns [a0, a1) of the global allocation."""
+        return m_all[:, :, a0:a1]
 
     iota = jnp.arange(q, dtype=jnp.int32)[None, :]  # [1, Q]
     len1 = 1 + lengths[:, None]  # [T, 1] position just past the last byte
@@ -799,52 +765,33 @@ def match_segment_block(
                 g = g & (iota2 == 1)
             gj_per_group.append(_lshift_fill(g, n_lead, False))  # window-start idx
 
-        # NOTE: reuses the pallas_finals decision computed before the conv
-        # — the conv's column exclusion (`off`) and this dispatch MUST
-        # agree or mslice() would read shifted columns.
-        if pallas_finals:
-            # Fused Pallas tier: im2col matmul (K = W*C, near MXU peak) +
-            # threshold + reachability-AND + Q-reduce per VMEM tile — the
-            # [T, Q, N] finals bitmap never touches HBM (ops/segment_pallas.py).
-            from .segment_pallas import finals_match
+        for gj, key in zip(gj_per_group, finals):
+            a0, a1 = final_alloc[key]
+            m = mslice(a0, a1)  # [T, Q, NB]
 
-            sel = np.zeros((len(finals), n_finals_cols), dtype=np.float32)
-            for slot, key in enumerate(finals):
-                a0, a1 = final_alloc[key]
-                sel[slot, a0:a1] = 1.0
-            gj_stack = jnp.stack(gj_per_group, axis=-1).astype(jnp.bfloat16)
-            weights_f = kernel_p[:, :, :n_finals_cols].reshape(-1, n_finals_cols)
-            cols.append(
-                finals_match(embed, weights_f, gj_stack, sel, w=w, q=q)
-            )  # [T, F] in allocation order
-        else:
-            for gj, key in zip(gj_per_group, finals):
-                a0, a1 = final_alloc[key]
-                m = mslice(a0, a1)  # [T, Q, NB]
+            # Prefilter gate (as in the bucketed tier): if none of this
+            # group's first segments matched anywhere in the block, skip
+            # the AND-any reduction entirely — benign-heavy traffic pays
+            # only the cheap any() read. ONLY for small column groups:
+            # the any() itself is a full read of the slice, and a
+            # many-hundred-column group in a serving-sized batch almost
+            # always has some hit somewhere, so the gate would pay a
+            # whole extra [T, Q, NB] pass (profiled at ~1.1 ms/step as
+            # fusion.406) to skip nothing.
+            def run_final(_, m=m, gj=gj):
+                return jnp.any(m & gj[:, :, None], axis=1)  # [T, NB]
 
-                # Prefilter gate (as in the bucketed tier): if none of this
-                # group's first segments matched anywhere in the block, skip
-                # the AND-any reduction entirely — benign-heavy traffic pays
-                # only the cheap any() read. ONLY for small column groups:
-                # the any() itself is a full read of the slice, and a
-                # many-hundred-column group in a serving-sized batch almost
-                # always has some hit somewhere, so the gate would pay a
-                # whole extra [T, Q, NB] pass (profiled at ~1.1 ms/step as
-                # fusion.406) to skip nothing.
-                def run_final(_, m=m, gj=gj):
-                    return jnp.any(m & gj[:, :, None], axis=1)  # [T, NB]
-
-                if a1 - a0 > 64:
-                    cols.append(run_final(None))
-                else:
-                    no_match = jnp.broadcast_to(
-                        m_all[:, 0, :1] & False, (t, a1 - a0)
+            if a1 - a0 > 64:
+                cols.append(run_final(None))
+            else:
+                no_match = jnp.broadcast_to(
+                    m_all[:, 0, :1] & False, (t, a1 - a0)
+                )
+                cols.append(
+                    jax.lax.cond(
+                        jnp.any(m), run_final, lambda _, z=no_match: z, None
                     )
-                    cols.append(
-                        jax.lax.cond(
-                            jnp.any(m), run_final, lambda _, z=no_match: z, None
-                        )
-                    )
+                )
         for gk in finals:
             col_groups.extend(final_gidsets[gk])  # deduped: one col → gid set
         bh_all = jnp.concatenate(cols, axis=1)

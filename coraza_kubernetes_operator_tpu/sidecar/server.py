@@ -10,8 +10,7 @@ Two serving surfaces, mirroring how the reference data plane is consumed
   filter in front of an upstream.
 - **Bulk mode** (``POST /waf/v1/evaluate``): a JSON object
   ``{"requests": [...]}`` of serialized requests evaluated in one call —
-  the high-throughput path for replayers and load generators, and the
-  shape the benchmarks use.
+  the high-throughput path for replayers and load generators.
 
 Control endpoints: ``/waf/v1/healthz`` (liveness: the process answers),
 ``/waf/v1/readyz`` (readiness: 503 while no ruleset is loaded or the

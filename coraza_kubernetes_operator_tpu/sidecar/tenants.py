@@ -33,7 +33,7 @@ TENANT_HEADER = "x-waf-tenant"
 class SharedEngineFactory:
     """Dedupe resident engines by compiled-ruleset content hash.
 
-    Tenants fork few base policies (bench config 5's shape: 32 tenants
+    Tenants fork few base policies (``BASELINE.json`` config 5's shape: 32 tenants
     over 4 distinct rulesets), and an engine's device tables + executable
     signatures are a pure function of its ruleset text — so N tenants on
     M distinct rulesets must hold M engines, not N. Keying by tenant id

@@ -1,10 +1,9 @@
 """Shared pipelined-vs-sync overlap measurement (docs/PIPELINE.md).
 
-Bench config 3's ``pipeline`` block and the CI gate
-(``hack/pipeline_smoke.py``) must measure the exact same discipline —
-warm policy, stage accounting, depth-bounded double buffering — or a
-change to one silently skews the other's numbers. This is the one copy
-both call.
+The CI gate (``hack/pipeline_smoke.py``) measures with this: warm
+policy, stage accounting, depth-bounded double buffering.
+``verdict_tuple`` is the comparison the gate, the tests and the rollout
+shadow check share.
 """
 
 from __future__ import annotations

@@ -49,8 +49,8 @@ def _verdict_key(v):
 
 
 def _ftw_replay(n: int):
-    """ftw attack stages interleaved with synthetic benign traffic —
-    the bench config-2/3 replay shape, sized for a smoke."""
+    """ftw attack stages interleaved with synthetic benign traffic,
+    sized for a smoke."""
     from coraza_kubernetes_operator_tpu.corpus import synthetic_requests
     from coraza_kubernetes_operator_tpu.ftw.loader import load_tests
     from coraza_kubernetes_operator_tpu.ftw.runner import _stage_request

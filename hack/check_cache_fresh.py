@@ -2,15 +2,12 @@
 """Fail when an XLA persistent cache is STALE relative to the code that
 shapes the compiled HLO.
 
-VERDICT r4 weak #1/#2: engine changes landed after the last `make
-bench.warm` / conformance run, so the driver's timed bench and the
-judge's conformance reruns faced cold XLA keys
-(config 3/4 burned 2x480s; the committed tests/.jax_cache was missing
-267 entries). The warm-cache discipline is only real if presubmit
-ENFORCES the ordering: any HLO-shaping source newer than the newest
-cache entry means the warm pass must be re-run LAST.
+A warm cache is only warm for the code it was filled from: any
+HLO-shaping source newer than the newest cache entry means the warm
+pass (for ``tests/.jax_cache``: ``make test``) must be re-run LAST.
+``make presubmit`` enforces that ordering.
 
-Usage: check_cache_fresh.py CACHE_DIR [--hint 'make bench.warm']
+Usage: check_cache_fresh.py CACHE_DIR [--hint 'make test']
 Exit 0 = fresh; 1 = stale (a missing or empty cache dir is
 stale by definition — the warm pass never ran).
 """

@@ -139,7 +139,7 @@ def main() -> int:
         ratio = 0.9
 
     os.environ.setdefault("CKO_VALUE_CACHE_MB", "0")
-    # Verdict cache OFF (honesty, same reason as the bench e2e config):
+    # Verdict cache OFF (honesty):
     # the timed passes replay the warm pass's stream, so with the
     # fingerprint cache hooked nearly every window is served at
     # assembly — the blob windows would route through the split

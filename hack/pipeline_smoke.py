@@ -18,7 +18,7 @@ The workload is sized so host assemble and device step are comparable
 (that is where double buffering pays; a degenerate stage ratio measures
 nothing). The measurement discipline (untimed warm, value-cache bypass
 for shape stability, deque double buffer) is the shared
-``testing/overlap.py`` helper — the exact loop bench config 3 times.
+``testing/overlap.py`` helper.
 
 Usage: pipeline_smoke.py [--ratio 1.2] [--batches 12] [--batch 512]
 (env overrides: PIPELINE_SMOKE_RATIO / _BATCHES / _BATCH). Exit 0 on

@@ -42,7 +42,7 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", False)
 # Shared persistent compile cache (ISSUE 2): CKO_FTW_CACHE keeps its
 # legacy priority, then the process-wide CKO_COMPILE_CACHE_DIR (the same
-# dir the sidecar/bench/CI use — chunk children then warm-start their
+# dir the sidecar and CI use — chunk children then warm-start their
 # XLA compiles from whatever any sibling already paid for), then the
 # tests-local default.
 _cache_dir = (

@@ -16,6 +16,8 @@ for a described chip.
 """
 
 import os
+import shutil
+import subprocess
 import sys
 
 _flags = os.environ.get("XLA_FLAGS", "")
@@ -35,7 +37,7 @@ jax.config.update("jax_enable_x64", False)
 # HLO and skip compilation entirely. First run warms it (~10 min);
 # subsequent runs finish in ~1-2 min. Kept under tests/ so `git clean`
 # or a compiler change naturally invalidates it.
-# CKO_COMPILE_CACHE_DIR (the process-wide knob the sidecar, bench, and
+# CKO_COMPILE_CACHE_DIR (the process-wide knob the sidecar and the
 # ftw chunk children share — CI caches it between runs) overrides the
 # tests-local default. configure_persistent_cache is the ONE place the
 # cache is wired (abspath, thresholds, jax cache-latch reset).
@@ -91,3 +93,71 @@ def _forked_put(*args, **kwargs):
 
 
 _cc.put_executable_and_time = _forked_put
+
+
+# -- the native library, for the test files that ask for it -------------------
+#
+# ``native/libcko_native.so`` is git-ignored and a fresh checkout has
+# none, so the suite runs the Python tensorizer unless a test asks for
+# the library through the fixtures below. They build it from the
+# committed source and load it for one module (or one engine) at a time;
+# the load is undone afterwards, so no other test file of the worker
+# sees a native library it did not ask for.
+
+import pytest  # noqa: E402
+
+import coraza_kubernetes_operator_tpu.native as _native  # noqa: E402
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="session")
+def native_lib(tmp_path_factory):
+    """libcko_native.so built from the committed source (as
+    wafbench/harness.py builds its own); skips only without a compiler.
+
+    Session scope is per xdist worker: each worker that is handed a file
+    using this builds its own copy (about 9 s) into its own temporary
+    directory, so there is no lock and nothing shared to race on."""
+    if shutil.which("make") is None or shutil.which("g++") is None:
+        pytest.skip("no C++ compiler to build the native library with")
+    lib = tmp_path_factory.mktemp("native") / "libcko_native.so"
+    proc = subprocess.run(
+        ["make", "-C", os.path.join(_REPO, "native"), f"TARGET={lib}"],
+        capture_output=True, text=True,
+    )
+    assert proc.returncode == 0 and lib.exists(), proc.stdout + proc.stderr
+    return lib
+
+
+def load_native(mp, lib_path):
+    """Point load_library() at ``lib_path`` (None: no library) until
+    ``mp`` is undone."""
+    mp.setattr(_native, "_lib", None)
+    mp.setenv("CKO_NATIVE", "1")
+    if lib_path is None:
+        mp.setenv("CKO_NATIVE_LIB", "/nonexistent/libcko_native.so")
+    else:
+        mp.setenv("CKO_NATIVE_LIB", str(lib_path))
+
+
+def native_engine(rules, lib_path):
+    """A ``WafEngine`` built with ``lib_path`` loaded (None: with no
+    library, so on the Python tensorizer); it keeps its tensorizer after
+    the load is undone."""
+    from coraza_kubernetes_operator_tpu.engine import WafEngine
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("CKO_AUTOMATA", "1")
+        load_native(mp, lib_path)
+        return WafEngine(rules)
+
+
+@pytest.fixture(scope="module")
+def native_loaded(native_lib):
+    """The built library loaded for every test of the module, for files
+    whose tests build engines or call ``load_library()`` themselves
+    (``pytestmark = pytest.mark.usefixtures("native_loaded")``)."""
+    with pytest.MonkeyPatch.context() as mp:
+        load_native(mp, native_lib)
+        yield native_lib

@@ -3,9 +3,9 @@ size on the CPU: the native body processors against their Python twins,
 the device against the host evaluator on windows that hold short and
 long rows together, and the tiering of such windows.
 
-The native library is built here from the committed source, as
-``tests/test_prefilter_confirm_native.py`` does, so these cases run in a
-checkout where nobody ran ``make native``. Full crs-lite does not
+The native library is ``conftest.py``'s ``native_lib``, built from the
+committed source, so these cases run in a checkout where nobody ran
+``make native``. Full crs-lite does not
 compile on XLA:CPU, so the verdict cases ride ``ftw/rules/crs-mini.conf``
 on ``base.conf`` (body access on, as the deployment has it).
 """
@@ -24,8 +24,8 @@ from coraza_kubernetes_operator_tpu.engine import waf as waf_mod
 from coraza_kubernetes_operator_tpu.engine.waf import _bucket_rows, tier_tensors
 from coraza_kubernetes_operator_tpu.native import serialize_requests
 
+from conftest import native_engine
 from test_native_tiered import _assert_window_parity as _tiered_parity
-from test_prefilter_confirm_native import _engine, native_lib  # noqa: F401  (fixture)
 
 REPO = Path(__file__).resolve().parents[1]
 BODIES = REPO / "wafbench" / "configs" / "crs-lite-pl2-bodies"
@@ -35,15 +35,15 @@ SALT_TOKEN = b"__WAFBENCH_SALT__"
 
 
 @pytest.fixture(scope="module")
-def engine(native_lib):  # noqa: F811
-    eng = _engine(RULES, native_lib)
+def engine(native_lib):
+    eng = native_engine(RULES, native_lib)
     assert eng._native.tiered
     return eng
 
 
 @pytest.fixture(scope="module")
 def python_engine():
-    return _engine(RULES, None)
+    return native_engine(RULES, None)
 
 
 def post(ctype: str, body: bytes, uri: str = "/api/v1/orders") -> HttpRequest:
@@ -402,13 +402,13 @@ def _send(port: int, requests: list[bytes]) -> None:
 
 
 @pytest.fixture(scope="module")
-def sidecar(native_lib):  # noqa: F811
+def sidecar(native_lib):
 
     from coraza_kubernetes_operator_tpu.sidecar import SidecarConfig, TpuEngineSidecar
 
     sc = TpuEngineSidecar(
         SidecarConfig(host="127.0.0.1", port=0, frontend="async", adaptive_enabled=False),
-        engine=_engine(RULES, native_lib))
+        engine=native_engine(RULES, native_lib))
     sc.start()
     deadline = time.monotonic() + 120
     while sc.serving_mode() != "promoted" and time.monotonic() < deadline:

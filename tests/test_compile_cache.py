@@ -135,8 +135,8 @@ def test_batch_signature_canonical_under_host_metadata():
 
 
 def test_tenant_engines_dedupe_by_ruleset_hash():
-    """32 tenants over 4 distinct rulesets hold 4 engines (bench config
-    5's shape) — resident engines key on content hash, not tenant id."""
+    """32 tenants over 4 distinct rulesets hold 4 engines (``BASELINE.json``
+    config 5's shape) — resident engines key on content hash, not tenant id."""
     from coraza_kubernetes_operator_tpu.sidecar.tenants import (
         SharedEngineFactory,
     )

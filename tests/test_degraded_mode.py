@@ -455,7 +455,7 @@ def test_fallback_parity_synthetic_corpus(monkeypatch):
 def test_fallback_parity_crs_lite_ftw_corpus(monkeypatch):
     """ISSUE 1 acceptance: fallback verdicts match device verdicts
     byte-for-byte on ftw crs-lite corpus traffic (the SQLi family +
-    blocking evaluation, replayed like bench config 2)."""
+    blocking evaluation)."""
     monkeypatch.setenv("CKO_FAULT_COMPILE_STALL_S", "0")
     from pathlib import Path
 
@@ -496,7 +496,7 @@ def test_fallback_parity_crs_lite_ftw_corpus(monkeypatch):
     assert sum(v.interrupted for v in fb) > 0
 
 
-# -- satellite: compiled-ruleset cache + bench budget scheduling -------------
+# -- satellite: compiled-ruleset cache ---------------------------------------
 
 
 def test_compile_rules_cached_roundtrip(tmp_path, monkeypatch):
@@ -514,26 +514,6 @@ def test_compile_rules_cached_roundtrip(tmp_path, monkeypatch):
     crs2 = rs.compile_rules_cached(text, cache_dir=str(tmp_path))
     assert crs2.n_rules == crs1.n_rules
     assert [r.rule_id for r in crs2.rules] == [r.rule_id for r in crs1.rules]
-
-
-def test_bench_budget_schedule_fits_driver_wall(monkeypatch):
-    import bench
-
-    for var in list(os.environ):
-        if var.startswith("BENCH_BUDGET_"):
-            monkeypatch.delenv(var)
-    monkeypatch.delenv("BENCH_CONFIG_BUDGET_S", raising=False)
-    keys = ["3", "1", "2", "e2e", "5", "4"]
-    budgets = bench._schedule_budgets(keys, 1450.0)
-    assert set(budgets) == set(keys)
-    assert sum(budgets.values()) <= 1450.0
-    # The graded config keeps the largest share.
-    assert budgets["3"] == max(budgets.values())
-    # Explicit overrides are verbatim; the rest still fit.
-    monkeypatch.setenv("BENCH_BUDGET_3", "700")
-    budgets = bench._schedule_budgets(keys, 1450.0)
-    assert budgets["3"] == 700.0
-    assert sum(budgets.values()) <= 1450.0
 
 
 # -- the launch table's lifetime (ISSUE 29) ----------------------------------

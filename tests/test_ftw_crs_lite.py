@@ -21,7 +21,7 @@ from coraza_kubernetes_operator_tpu.compiler.ruleset import compile_rules_cached
 from coraza_kubernetes_operator_tpu.ftw.corpus import CRS_LITE_DIR, load_ruleset_text
 
 # Compiled-ruleset artifact cache (ISSUE 1 satellite: the gate must fit
-# <3 min on the 1-core bench machine). Keyed by (ruleset hash, compiler
+# <3 min on a 1-core machine). Keyed by (ruleset hash, compiler
 # source hash); lives next to the XLA cache so `git clean` invalidates.
 CRS_CACHE_DIR = str(Path(__file__).resolve().parent / ".crs_cache")
 
@@ -36,7 +36,7 @@ CORPUS = Path(__file__).resolve().parents[1] / "ftw" / "tests-crs-lite"
 # response chunk exhausts the arena where 12 request tests fit), so it
 # weighs RESPONSE_COST request-equivalents when cutting chunks.
 #
-# MEASURED ECONOMICS (1-core bench host, warm disk caches): each child
+# MEASURED ECONOMICS (1-core host, warm disk caches): each child
 # pays ~3 min of FIXED cost — almost entirely jit TRACING of the
 # CRS-scale model's shape signatures, which the persistent XLA cache
 # cannot skip — then ~2.3 s/test marginal. Small chunks therefore pay
@@ -57,8 +57,8 @@ RESPONSE_COST = 4
 # (905/911/912/913/920) incl. the ledger-exercising 920160-1.
 SMOKE_COUNT = int(os.environ.get("CKO_FTW_SMOKE_COUNT", "48"))
 # Children are independent (own process, own arena, shared disk cache) —
-# overlap them up to the core count (the bench machine has ONE core:
-# parallelism there only adds memory pressure). Wall-clock bar: <3 min.
+# overlap them up to the core count (on a ONE-core machine
+# parallelism only adds memory pressure). Wall-clock bar: <3 min.
 CHUNK_PARALLEL = int(
     os.environ.get("CKO_FTW_PARALLEL", str(min(4, os.cpu_count() or 1)))
 )
@@ -75,7 +75,7 @@ def _run_corpus_chunked(
     runner = repo / "hack" / "run_ftw_chunk.py"
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     # Chunk children share ONE persistent compile cache with this parent
-    # (and the sidecar/bench/CI): CKO_COMPILE_CACHE_DIR when set, else
+    # (and the sidecar and CI): CKO_COMPILE_CACHE_DIR when set, else
     # the tests-local dir conftest.py configured. The ~3-min per-child
     # jit TRACING is paid per process, but the XLA-compile half is paid
     # once per HLO across all children and gate invocations.
@@ -257,7 +257,7 @@ EXPECTED_IGNORED = 1
 
 def test_crs_lite_corpus_smoke_green(crs):
     """Default-tier gate: the first SMOKE_COUNT title-sorted corpus tests
-    replayed in ONE resident child (~4.5 min on the 1-core bench host,
+    replayed in ONE resident child (~4.5 min on a 1-core host,
     where the full 326 could not finish in 25 — VERDICT r5 item 3). The
     full corpus stays green in the slow tier below."""
     from coraza_kubernetes_operator_tpu.ftw.loader import load_tests_report
@@ -281,7 +281,7 @@ def test_crs_lite_corpus_smoke_green(crs):
 @pytest.mark.slow
 def test_crs_lite_corpus_green(crs):
     """Full-corpus green over exactly the committed breakdown — slow tier
-    (`make test.slow` / pre-snapshot): ~15 min on the 1-core bench host
+    (`make test.slow` / pre-snapshot): ~15 min on a 1-core host
     even with resident chunk children, since each child pays ~3 min of
     untraceable-by-cache jit tracing plus ~2.3 s/test."""
     summary = _run_corpus_chunked(crs)

@@ -159,15 +159,20 @@ def test_partition_masks_skip_blocks(monkeypatch):
     assert partial, f"no mask ever excluded a block: {masks}"
 
 
-def test_short_masks_tuple_rejected():
-    """eval_waf_tiered must reject a masks tuple shorter than tiers
-    instead of silently dropping trailing tiers (ADVICE r4 low)."""
+def test_short_masks_tuple_rejected(monkeypatch):
+    """A masks tuple shorter than tiers is refused where the window's
+    launch is resolved (``WafEngine._tier_specs``) instead of zipping
+    the trailing tiers away, so the served dispatch cannot miss their
+    matches (ADVICE r4 low)."""
+    monkeypatch.setattr(waf_mod, "_MIN_PART_ROWS", 1)
+    monkeypatch.setattr(waf_mod, "_MIN_TIER_ROWS", 8)
     engine = WafEngine(RULES)
-    tensors = _tensorize(engine, _traffic(16))
-    tiers, numvals, masks = waf_mod.tier_tensors(tensors, engine._kind_block_lut)
-    if len(tiers) < 2:
-        pytest.skip("need >= 2 tiers to truncate")
-    from coraza_kubernetes_operator_tpu.models.waf_model import eval_waf_tiered
-
+    reqs = _traffic()
+    tiers, numvals, masks = waf_mod.tier_tensors(
+        _tensorize(engine, reqs), engine._kind_block_lut
+    )
+    assert len(tiers) >= 2, f"the window never tiered: {len(tiers)}"
     with pytest.raises(ValueError, match="masks length"):
-        eval_waf_tiered(engine.model, tiers, numvals, masks=masks[:-1])
+        engine._tier_specs(tiers, numvals, masks=masks[:-1])
+    with pytest.raises(ValueError, match="masks length"):
+        engine._verdicts_from_tiers(tiers, numvals, len(reqs), masks=masks[:-1])

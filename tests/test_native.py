@@ -3,8 +3,8 @@
 The native library must produce bit-for-bit identical tensors to
 ``engine/request.py`` + ``engine/waf.py:_tensorize`` on the same requests —
 randomized corpora over every transform family, arg shapes, JSON bodies,
-cookies, and selector-regex kinds. Skipped when the library is not built
-(`make native`).
+cookies, and selector-regex kinds. The library is ``conftest.py``'s
+``native_lib``, loaded for this module alone (``native_loaded``).
 """
 
 import random
@@ -19,14 +19,7 @@ from coraza_kubernetes_operator_tpu.compiler.transforms_host import (
     apply_pipeline,
 )
 from coraza_kubernetes_operator_tpu.engine import HttpRequest, WafEngine
-from coraza_kubernetes_operator_tpu.native import (
-    NativeTensorizer,
-    load_library,
-)
-
-pytestmark = pytest.mark.skipif(
-    load_library() is None, reason="native library not built"
-)
+pytestmark = pytest.mark.usefixtures("native_loaded")
 
 RULES = r"""
 SecRuleEngine On
@@ -96,7 +89,7 @@ def _random_requests(n: int, seed: int) -> list[HttpRequest]:
 
 
 @pytest.fixture(scope="module")
-def engine():
+def engine(native_loaded):
     return WafEngine(RULES)
 
 

@@ -9,7 +9,8 @@ invariants: zero-copy blob handoff, same-shape reuse allocates nothing,
 pad regions are re-zeroed on dirty reuse, concurrent leases never
 share buffers, hot-swapped engines never share an arena.
 
-Skipped when the native library (or its plan ABI) is not built.
+The library is ``conftest.py``'s ``native_lib``, loaded for this module
+alone (``native_loaded``).
 """
 
 import ctypes
@@ -21,22 +22,17 @@ from coraza_kubernetes_operator_tpu.engine import WafEngine
 from coraza_kubernetes_operator_tpu.engine.waf import tier_tensors
 from coraza_kubernetes_operator_tpu.native import (
     blob_requests,
-    load_library,
     serialize_requests,
 )
 from coraza_kubernetes_operator_tpu.native.arena import StagingArena
 
 from test_native import RULES, _random_requests
 
-pytestmark = pytest.mark.skipif(
-    load_library() is None
-    or not getattr(load_library(), "_cko_has_plan", False),
-    reason="native library (plan ABI) not built",
-)
+pytestmark = pytest.mark.usefixtures("native_loaded")
 
 
 @pytest.fixture(scope="module")
-def engine():
+def engine(native_loaded):
     eng = WafEngine(RULES)
     assert eng._native.tiered
     return eng

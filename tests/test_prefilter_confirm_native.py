@@ -5,11 +5,11 @@ positives to ``cko_confirm_run`` in one call where the native library
 handles the group, and to ``_confirm_python`` (``DFA.search`` over
 ``apply_pipeline``) otherwise. Both must clear exactly the same bits.
 
-The library is built here, from the committed source, into a temp dir
-and loaded through ``CKO_NATIVE_LIB`` — these cases run wherever a C++
-compiler exists, not only where somebody ran ``make native`` first. The
-load is undone after each fixture or test, so no other test file of the
-worker sees a native library it did not ask for.
+The library comes from ``conftest.py``'s ``native_lib`` (built from the
+committed source into a temp dir, loaded through ``CKO_NATIVE_LIB``), so
+these cases run wherever a C++ compiler exists, not only where somebody
+ran ``make native`` first. The load is undone after each fixture or
+test.
 
 No device executable is involved: the tiers and the packed hit rows are
 made here, which is also what lets full crs-lite take part on the CPU.
@@ -17,8 +17,6 @@ made here, which is also what lets full crs-lite take part on the CPU.
 
 import base64
 import random
-import shutil
-import subprocess
 from collections import deque
 from pathlib import Path
 
@@ -35,7 +33,8 @@ from coraza_kubernetes_operator_tpu.engine import WafEngine
 from coraza_kubernetes_operator_tpu.ftw.corpus import load_ruleset_text
 from coraza_kubernetes_operator_tpu.observability.stages import current as current_stages
 
-REPO = Path(__file__).resolve().parents[1]
+from conftest import load_native, native_engine
+
 CRS_CACHE_DIR = str(Path(__file__).resolve().parent / ".crs_cache")
 
 # crs-lite's prefiltered groups (docs/AUTOMATA.md; the wafbench cell's 12).
@@ -68,42 +67,9 @@ SYNTHETIC_RULES = "SecRuleEngine On\n" + "".join(
 N_SYNTHETIC_GROUPS = len(NATIVE_TRANSFORMS) + 3
 
 
-@pytest.fixture(scope="session")
-def native_lib(tmp_path_factory):
-    """libcko_native.so built from the committed source (as
-    wafbench/harness.py builds its own); skips only without a compiler."""
-    if shutil.which("make") is None or shutil.which("g++") is None:
-        pytest.skip("no C++ compiler to build the native library with")
-    lib = tmp_path_factory.mktemp("native") / "libcko_native.so"
-    proc = subprocess.run(
-        ["make", "-C", str(REPO / "native"), f"TARGET={lib}"],
-        capture_output=True, text=True,
-    )
-    assert proc.returncode == 0 and lib.exists(), proc.stdout + proc.stderr
-    return lib
-
-
-def _load(mp, lib_path):
-    """Point load_library() at ``lib_path`` (None: no library) until
-    ``mp`` is undone."""
-    mp.setattr(native, "_lib", None)
-    mp.setenv("CKO_NATIVE", "1")
-    if lib_path is None:
-        mp.setenv("CKO_NATIVE_LIB", "/nonexistent/libcko_native.so")
-    else:
-        mp.setenv("CKO_NATIVE_LIB", str(lib_path))
-
-
-def _engine(rules, lib_path) -> WafEngine:
-    with pytest.MonkeyPatch.context() as mp:
-        mp.setenv("CKO_AUTOMATA", "1")
-        _load(mp, lib_path)
-        return WafEngine(rules)
-
-
 @pytest.fixture(scope="module")
 def crs_lite(native_lib):
-    return _engine(compile_rules_cached(load_ruleset_text(), CRS_CACHE_DIR), native_lib)
+    return native_engine(compile_rules_cached(load_ruleset_text(), CRS_CACHE_DIR), native_lib)
 
 
 @pytest.fixture(scope="module")
@@ -113,7 +79,7 @@ def synthetic_crs():
 
 @pytest.fixture(scope="module")
 def synthetic(native_lib, synthetic_crs):
-    return _engine(synthetic_crs, native_lib)
+    return native_engine(synthetic_crs, native_lib)
 
 
 def _witness(dfa) -> bytes:
@@ -306,9 +272,9 @@ def test_without_the_export_the_python_walk_answers(
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("CKO_AUTOMATA", "1")
         if library == "absent":
-            _load(mp, None)
+            load_native(mp, None)
         else:
-            _load(mp, native_lib)
+            load_native(mp, native_lib)
             older = _OlderLib(native.load_library())
             native._bind(older)
             assert older._cko_has_plan and not older._cko_has_confirm

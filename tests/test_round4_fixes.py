@@ -14,6 +14,8 @@ import pytest
 
 from coraza_kubernetes_operator_tpu.engine import HttpRequest, WafEngine
 
+from conftest import native_engine
+
 BASE = """
 SecRuleEngine On
 SecRequestBodyAccess On
@@ -33,14 +35,26 @@ def _post(body: bytes, ctype: str = "application/octet-stream", uri: str = "/up"
 # -- SecRequestBodyLimitAction ------------------------------------------------
 
 
+LIMIT_RULES = (
+    BASE
+    + "SecRequestBodyLimit 4096\n"
+    + "SecRequestBodyLimitAction Reject\n"
+    + 'SecRule REQUEST_BODY "@contains evilword" "id:10,phase:2,deny,status:403,t:none"\n'
+)
+
+
 @pytest.fixture(scope="module")
 def limit_engine():
-    return WafEngine(
-        BASE
-        + "SecRequestBodyLimit 4096\n"
-        + "SecRequestBodyLimitAction Reject\n"
-        + 'SecRule REQUEST_BODY "@contains evilword" "id:10,phase:2,deny,status:403,t:none"\n'
-    )
+    return WafEngine(LIMIT_RULES)
+
+
+@pytest.fixture(scope="module")
+def native_limit_engine(native_lib):
+    """The same rules on the native tensorizer (conftest.py builds the
+    library); the other tests of this file keep the Python one."""
+    eng = native_engine(LIMIT_RULES, native_lib)
+    assert eng.native_enabled
+    return eng
 
 
 def test_body_over_limit_rejected_413(limit_engine):
@@ -83,9 +97,7 @@ def test_process_partial_truncates_instead():
     assert v.interrupted and v.status == 403
 
 
-def test_bulk_fast_path_rejects_over_limit(limit_engine):
-    if not limit_engine.native_enabled:
-        pytest.skip("native tier unavailable")
+def test_bulk_fast_path_rejects_over_limit(native_limit_engine):
     payload = json.dumps(
         {
             "requests": [
@@ -95,7 +107,7 @@ def test_bulk_fast_path_rejects_over_limit(limit_engine):
             ]
         }
     ).encode()
-    out = limit_engine.evaluate_bulk_json(payload)
+    out = native_limit_engine.evaluate_bulk_json(payload)
     assert out is not None
     verdicts, _blob = out
     assert [(v.interrupted, v.status) for v in verdicts] == [
@@ -172,6 +184,13 @@ def mp_engine():
     return WafEngine(MP_RULES)
 
 
+@pytest.fixture(scope="module")
+def native_mp_engine(native_lib):
+    eng = native_engine(MP_RULES, native_lib)
+    assert eng.native_enabled
+    return eng
+
+
 def _part(content: bytes) -> bytes:
     return (
         b'--XB\r\nContent-Disposition: form-data; name="a"\r\n\r\n'
@@ -202,9 +221,7 @@ def test_smuggled_boundary_still_flagged(mp_engine):
     assert v.interrupted and v.status == 403
 
 
-def test_boundary_heuristic_native_parity(mp_engine):
-    if not mp_engine.native_enabled:
-        pytest.skip("native tier unavailable")
+def test_boundary_heuristic_native_parity(native_mp_engine):
     bodies = [
         _part(b"-----BEGIN CERTIFICATE-----"),
         _part(b"-----"),
@@ -213,18 +230,18 @@ def test_boundary_heuristic_native_parity(mp_engine):
         _part(b"-- spaced out"),
     ]
     reqs = [_mp(b) for b in bodies]
-    native = [v.interrupted for v in mp_engine.evaluate(reqs)]
+    native = [v.interrupted for v in native_mp_engine.evaluate(reqs)]
 
-    saved = mp_engine._native
+    saved = native_mp_engine._native
 
     class _Off:
         available = False
 
-    mp_engine._native = _Off()
+    native_mp_engine._native = _Off()
     try:
-        python = [v.interrupted for v in mp_engine.evaluate(reqs)]
+        python = [v.interrupted for v in native_mp_engine.evaluate(reqs)]
     finally:
-        mp_engine._native = saved
+        native_mp_engine._native = saved
     assert native == python, (native, python)
 
 
@@ -245,16 +262,12 @@ STRICT_CASES = [
 ]
 
 
-def test_native_json_strict_rejects(limit_engine):
-    if not limit_engine.native_enabled:
-        pytest.skip("native tier unavailable")
+def test_native_json_strict_rejects(native_limit_engine):
     for payload in STRICT_CASES:
-        assert limit_engine.evaluate_bulk_json(payload) is None, payload
+        assert native_limit_engine.evaluate_bulk_json(payload) is None, payload
 
 
-def test_native_json_still_accepts_valid(limit_engine):
-    if not limit_engine.native_enabled:
-        pytest.skip("native tier unavailable")
+def test_native_json_still_accepts_valid(native_limit_engine):
     payload = json.dumps(
         {
             "requests": [
@@ -270,7 +283,7 @@ def test_native_json_still_accepts_valid(limit_engine):
             ]
         }
     ).encode()
-    out = limit_engine.evaluate_bulk_json(payload)
+    out = native_limit_engine.evaluate_bulk_json(payload)
     assert out is not None
     verdicts, _ = out
     assert len(verdicts) == 1 and not verdicts[0].interrupted

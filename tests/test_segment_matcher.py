@@ -148,12 +148,13 @@ def test_group_routing_in_model():
     assert sum(b.n_groups for b in model.banks) >= 1
 
 
-def test_pallas_finals_matches_xla_path(monkeypatch):
-    """The fused Pallas finals tier (interpret mode on CPU) must agree
-    with the XLA conv + AND-any path on the same block."""
-    import jax
-    import numpy as np
+def test_finals_tier_matches_python_re():
+    """The conv + AND-any finals tier agrees with Python ``re`` on one
+    block of five patterns whose last segment decides the match."""
+    import re
+
     import jax.numpy as jnp
+    import numpy as np
 
     from coraza_kubernetes_operator_tpu.compiler.re_parser import parse_regex
     from coraza_kubernetes_operator_tpu.compiler.segments import plan_segments
@@ -180,7 +181,7 @@ def test_pallas_finals_matches_xla_path(monkeypatch):
         b"union of selections",
         b"attack7=3",
     ]
-    T = 64  # pallas block size
+    T = 64
     L = 32
     data = np.zeros((T, L), dtype=np.uint8)
     lengths = np.zeros(T, dtype=np.int32)
@@ -188,24 +189,14 @@ def test_pallas_finals_matches_xla_path(monkeypatch):
         data[i, : len(txt)] = list(txt)
         lengths[i] = len(txt)
 
-    ref = S.match_segment_block(blk.kernel, blk.spec, jnp.asarray(data), jnp.asarray(lengths))
-
-    monkeypatch.setattr(S, "_use_pallas_finals", lambda *a: True)
-    jax.clear_caches()
-    try:
-        got = S.match_segment_block(
-            blk.kernel, blk.spec, jnp.asarray(data), jnp.asarray(lengths)
-        )
-    finally:
-        jax.clear_caches()
-    assert np.array_equal(np.asarray(got), np.asarray(ref))
-    # sanity: the reference itself matches python re on the real rows
-    import re
-
+    got = np.asarray(
+        S.match_segment_block(blk.kernel, blk.spec, jnp.asarray(data), jnp.asarray(lengths))
+    )
     for i, txt in enumerate(texts):
         for gi, p in enumerate(pats):
             want = re.search(p.encode(), txt) is not None
-            assert bool(ref[i, gi]) == want, (p, txt)
+            assert bool(got[i, gi]) == want, (p, txt)
+    assert not got[len(texts) :].any()  # padding rows match nothing
 
 
 def test_gapcls_cumsum_path_at_large_q():

@@ -10,7 +10,7 @@ a real chip does.
 
 Shapes are the ones full crs-lite produces: the banks of the engine
 built on ``ftw/rules/crs-lite`` at the smoke's sidecar window (32 unique
-rows x 512) and at the bench scale (4096 rows x the 2048 Pallas width
+rows x 512) and at a large batch (4096 rows x the 2048 Pallas width
 cap), the promotion canary's whole per-tier matcher (16 x 32), the
 whole matcher at the widest rows the Pallas kernels take (32 x 2048: a
 window of bodied API requests, wafbench's ``crs-lite-pl2-bodies``), and
@@ -33,7 +33,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 ROWS_WINDOW, WIDTH_WINDOW = 32, 512  # the chip smoke's one sidecar window
-ROWS_BENCH, WIDTH_MAX = 4096, 2048  # bench batch x the Pallas width cap
+ROWS_BATCH, WIDTH_MAX = 4096, 2048  # a large batch x the Pallas width cap
 ROWS_CANARY, WIDTH_CANARY = 16, 32  # engine/waf.py:warmup_request's window
 ROWS_BODIES, WIDTH_BODIES = 32, 2048  # wafbench crs-bodies.api-2k-c1's one window shape
 
@@ -105,14 +105,14 @@ def _widest(banks):
 
 
 # (kernel family, rows, width): each bank family of the default crs-lite
-# plan at the sidecar window and at bench scale. The dispatchers must
+# plan at the sidecar window and at the large batch. The dispatchers must
 # pick the Pallas kernel (one tpu_custom_call), never the XLA fallback.
 KERNEL_CASES = [
     ("flat", ROWS_WINDOW, WIDTH_WINDOW),
-    ("flat", ROWS_BENCH, WIDTH_MAX),
-    ("prefilter", ROWS_BENCH, WIDTH_MAX),
+    ("flat", ROWS_BATCH, WIDTH_MAX),
+    ("prefilter", ROWS_BATCH, WIDTH_MAX),
     ("gather", ROWS_WINDOW, WIDTH_WINDOW),
-    ("gather", ROWS_BENCH, WIDTH_MAX),
+    ("gather", ROWS_BATCH, WIDTH_MAX),
 ]
 
 
