@@ -676,7 +676,9 @@ def main(argv: list[str] | None = None) -> int:
             "cold_wall_to_promotion_s": round(time.monotonic() - t_child, 2),
             "rules_skipped": s["cko_rules_skipped_total"],
             "rules_approximated": s["cko_rules_approximated_total"],
-            "automata": {k: s["automata"].get(k) for k in ("enabled", "tiers", "gather_banks", "pre_banks")},
+            "automata": {k: s["automata"].get(k) for k in (
+                "enabled", "tiers", "gather_banks", "pre_banks",
+                "flat_bins", "flat_slots", "flat_groups", "per_bank_kernels")},
             "dfa_states": [s["compile_cache"]["dfa_states_pre_min"],
                            s["compile_cache"]["dfa_states_post_min"]],
             "tier_compile_s": s["compile_cache"]["tier_compile_s"],

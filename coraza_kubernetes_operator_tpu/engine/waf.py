@@ -421,9 +421,10 @@ class WafEngine:
         self.compiled = rules if isinstance(rules, CompiledRuleSet) else compile_rules(rules)
         # Two-level automata plan (compiler/automata_plan.py): classifies
         # every group into segment / dfa-hot / prefiltered / nfa under
-        # the CKO_AUTOMATA* knobs. build_model routes the hot groups to
-        # joint-byte-class gather banks and replaces prefiltered groups'
-        # device tables with their over-approximating automata; this
+        # the CKO_AUTOMATA* knobs. build_model gives the hot groups
+        # blocks of their own and replaces prefiltered groups' device
+        # tables with their over-approximating automata (both scanned in
+        # the flat-slot bins with the nfa banks, ops/dfa_flat.py); this
         # engine's dispatch then confirms prefilter positives against the
         # exact DFAs (_confirm_prefilter) so verdicts never change.
         # Direct build_model(crs) callers (tests, the sharded mesh) get
@@ -1399,17 +1400,27 @@ class WafEngine:
     def automata_summary(self) -> dict:
         """Automata-tier composition + prefilter counters for stats
         and metrics: which groups run where (the plan's verdict),
-        how many device banks each tier produced, and how the prefilter's
+        how many device banks each tier produced, where the dense-DFA
+        blocks are scanned (fused flat bins, or one kernel per bank for
+        the blocks no bin covers), and how the prefilter's
         over-approximation is paying off at runtime."""
         plan = self.automata_plan
         counts = plan.counts()
+        model = self.model
         with self._prefilter_lock:
             pstats = dict(self.prefilter_stats)
         return {
             "enabled": plan.enabled,
             "tiers": counts,
-            "gather_banks": len(self.model.gather_banks),
-            "pre_banks": len(self.model.pre_banks),
+            "gather_banks": len(model.gather_banks),
+            "pre_banks": len(model.pre_banks),
+            "flat_bins": len(model.flat_banks),
+            "flat_slots": sum(fb.n_slots for fb in model.flat_banks),
+            "flat_groups": sum(fb.n_groups for fb in model.flat_banks),
+            "per_bank_kernels": len(model.banks)
+            + len(model.gather_banks)
+            + len(model.pre_banks)
+            - len(model.flat_covered),
             "prefilter": pstats,
         }
 

@@ -40,7 +40,12 @@ from ..compiler.ruleset import (
 )
 from ..compiler.segments import plan_segments
 from ..ops.dfa import DFABank, stack_dfas
-from ..ops.dfa_gather import GatherBank, plan_gather_bins, stack_gather_bank
+from ..ops.dfa_gather import (
+    GatherBank,
+    plan_gather_bins,
+    scan_gather_bank,
+    stack_gather_bank,
+)
 from ..ops.segment import SegmentBlock, build_segment_block, match_segment_block
 from ..ops.transforms import apply_device_pipeline
 
@@ -135,13 +140,16 @@ class WafModel:
     # so long buckets stream through the constant-memory scan carry.
     long_banks: list = field(default_factory=list)
     seg_perm: jnp.ndarray | None = None  # [Gs, Gs] one-hot: long order → seg order
-    # Flat-slot fused bank bins (ops/dfa_flat.py): cover most DFA banks
-    # with a few fused VMEM-resident scans; covered banks' legacy scans
-    # are skipped in match_tier. Empty when fusion is disabled.
+    # Flat-slot fused bins (ops/dfa_flat.py): cover the dense-DFA blocks
+    # (banks, gather_banks, pre_banks) with a few fused VMEM-resident
+    # scans; a covered block's own scan is skipped in match_tier. Empty
+    # when fusion is disabled.
     flat_banks: list = field(default_factory=list)
     # Two-level automata (ops/dfa_gather.py, compiler/automata_plan.py).
     # DFA hot tier: joint-byte-class packed gather banks for the plan's
-    # "dfa-hot" groups. Empty unless build_model was handed a plan.
+    # "dfa-hot" groups — one block each, scanned by its own kernel only
+    # where no flat bin covers it. Empty unless build_model was handed a
+    # plan.
     gather_banks: list = field(default_factory=list)
     # Approximate prefilter: stacked OVER-APPROXIMATING automata fronting
     # the plan's "prefiltered" groups. Their hit columns may over-match
@@ -177,7 +185,8 @@ class WafModel:
     # ctl:ruleRemoveTargetById variants) — post_match then runs a second
     # counter pass so counter-gated rules' own setvars still accumulate.
     two_pass_counters: bool = False
-    # Static: block indexes whose hit columns come from flat_banks.
+    # Static: block indexes (segs, banks, gather_banks, pre_banks — the
+    # column order) whose hit columns come from flat_banks.
     flat_covered: tuple = ()
     # Host-side only: ORIGINAL group id held by each device hit column
     # (the inverse of build_model's remap). The lazy per-tier dispatch
@@ -387,21 +396,36 @@ def build_model(crs: CompiledRuleSet, automata=None) -> WafModel:
             remap[g] = next_new
             next_new += 1
 
-    # Flat-slot fused bank bins (ops/dfa_flat.py): most banks' scans
-    # collapse into a few VMEM-resident fused kernels (CKO_FLAT=0
-    # disables — the legacy per-bank dispatch in ops/dfa.py remains the
-    # fallback for rejected banks and the sharded path).
+    # Flat-slot fused bins (ops/dfa_flat.py): every dense-DFA block —
+    # the generic banks, the dfa-hot gather banks and the prefilter's
+    # approximations — is offered to the flat planner, and the blocks it
+    # accepts collapse into a few VMEM-resident fused kernels that cost
+    # their real states (a per-bank kernel pads 1-7 groups to 128
+    # lanes). The per-bank scans remain for a block the planner rejects,
+    # for CKO_FLAT=0 and for the sharded path. Column order is
+    # untouched: a bin's pieces carry (block, g_lo, g_hi) and match_tier
+    # stitches by block.
     n_segs_blocks = len(segs)
     flat_banks_built: list = []
     flat_covered: set[int] = set()
-    if _os.environ.get("CKO_FLAT", "1") != "0" and banks:
+    dense_blocks = [
+        (pid, [crs.groups[g].dfa for g in gids])
+        for pid, gids in zip(
+            bank_pipelines + gather_bank_pipelines, bank_gids + gather_bank_gids
+        )
+    ] + [
+        (pid, [approx_of[g] for g in gids])
+        for pid, gids in zip(pre_bank_pipelines, pre_bank_gids)
+    ]
+    if _os.environ.get("CKO_FLAT", "1") != "0" and dense_blocks:
         from ..ops.dfa_flat import build_flat_bank, plan_flat_bins
 
-        bank_dfas = [
-            (n_segs_blocks + bi, bank_pipelines[bi], [crs.groups[g].dfa for g in bank_gids[bi]])
-            for bi in range(len(banks))
-        ]
-        bins, _rejected = plan_flat_bins(bank_dfas)
+        bins, _rejected = plan_flat_bins(
+            [
+                (n_segs_blocks + i, pid, dfas)
+                for i, (pid, dfas) in enumerate(dense_blocks)
+            ]
+        )
         for bn in bins:
             flat_banks_built.append(build_flat_bank(bn))
             for block_idx, _pid, _glo, _ghi, _ds in bn:
@@ -582,19 +606,28 @@ def build_model(crs: CompiledRuleSet, automata=None) -> WafModel:
         for gid in members:
             ks |= gkind_sets[gid]
         block_kinds.append(tuple(sorted(ks)))
-    for gb in gather_banks:
-        # Joint-class packing shrinks the resident table and the dominant
-        # per-step contraction by 256/C vs the byte-indexed dense scan.
-        factor = max(0.1, gb.n_classes / 256.0)
-        block_cost.append(0.5 * factor * gb.n_states * max(gb.n_groups, 128))
+    n_banks_blocks = n_segs_blocks + len(banks)
+    for gi, gb in enumerate(gather_banks):
+        if n_banks_blocks + gi in flat_covered:
+            block_cost.append(0.5 * gb.n_states * gb.n_groups)
+        else:
+            # Joint-class packing shrinks the resident table and the
+            # dominant per-step contraction by 256/C vs the byte-indexed
+            # dense scan.
+            factor = max(0.1, gb.n_classes / 256.0)
+            block_cost.append(0.5 * factor * gb.n_states * max(gb.n_groups, 128))
     for members in pre_bank_gids:
         ks = set()
         for gid in members:
             ks |= gkind_sets[gid]
         block_kinds.append(tuple(sorted(ks)))
-    for pb in pre_banks:
+    n_gather_blocks = n_banks_blocks + len(gather_banks)
+    for pi, pb in enumerate(pre_banks):
         s, g = pb.n_states, pb.n_groups
-        block_cost.append(0.5 * s * max(g, 128))  # small dense approx bank
+        if n_gather_blocks + pi in flat_covered:
+            block_cost.append(0.5 * s * g)
+        else:
+            block_cost.append(0.5 * s * max(g, 128))  # small dense approx bank
     # Inverse of remap: original group id per device hit column (host
     # metadata for the lazy host-tier path — see WafModel.group_order).
     n_g = len(crs.groups)
@@ -892,47 +925,41 @@ def match_tier(
                 w = g_hi - g_lo
                 flat_cols.setdefault(blk, {})[g_lo] = out[:, col : col + w]
                 col += w
-    for bi, (bank, pid) in enumerate(zip(model.banks, model.bank_pipelines)):
-        blk = n_segs + bi
+    # Dense-DFA blocks in the global column order: the generic banks,
+    # then the two-level automata's dfa-hot gather banks, then its
+    # approximate prefilter banks (whose columns the engine confirms on
+    # the host). A flat-covered block takes its columns from its bins;
+    # the per-bank kernel is for a block the flat planner left out.
+    dense_blocks = (
+        [
+            (bank, pid, scan_dfa_bank, f"cko_dfa_bank{i}")
+            for i, (bank, pid) in enumerate(zip(model.banks, model.bank_pipelines))
+        ]
+        + [
+            (bank, pid, scan_gather_bank, f"cko_gather_bank{i}")
+            for i, (bank, pid) in enumerate(
+                zip(model.gather_banks, model.gather_bank_pipelines)
+            )
+        ]
+        + [
+            (bank, pid, scan_dfa_bank, f"cko_prefilter_bank{i}")
+            for i, (bank, pid) in enumerate(
+                zip(model.pre_banks, model.pre_bank_pipelines)
+            )
+        ]
+    )
+    for blk, (bank, pid, scan, name) in enumerate(dense_blocks, start=n_segs):
         if not block_on(blk):
             per_block.append(
                 jnp.zeros((data.shape[0], bank.n_groups), dtype=bool)
             )
-            continue
-        if blk in model.flat_covered:
+        elif blk in model.flat_covered:
             pieces = flat_cols[blk]
             per_block.append(
                 jnp.concatenate([pieces[k] for k in sorted(pieces)], axis=1)
             )
-            continue
-        tdata, tlen = transformed_for(pid)
-        per_block.append(scan_dfa_bank(bank, tdata, tlen, name=f"cko_dfa_bank{bi}"))
-    # Two-level automata blocks (after the generic banks in the global
-    # column order): DFA hot-tier gather banks, then the approximate
-    # prefilter banks (whose columns the engine confirms on the host).
-    n_banks = len(model.banks)
-    if model.gather_banks:
-        from ..ops.dfa_gather import scan_gather_bank
-
-        for gi, (gb, pid) in enumerate(
-            zip(model.gather_banks, model.gather_bank_pipelines)
-        ):
-            if not block_on(n_segs + n_banks + gi):
-                per_block.append(
-                    jnp.zeros((data.shape[0], gb.n_groups), dtype=bool)
-                )
-                continue
-            per_block.append(
-                scan_gather_bank(gb, *transformed_for(pid), name=f"cko_gather_bank{gi}")
-            )
-    n_gather = len(model.gather_banks)
-    for pi, (pb, pid) in enumerate(zip(model.pre_banks, model.pre_bank_pipelines)):
-        if not block_on(n_segs + n_banks + n_gather + pi):
-            per_block.append(jnp.zeros((data.shape[0], pb.n_groups), dtype=bool))
-            continue
-        per_block.append(
-            scan_dfa_bank(pb, *transformed_for(pid), name=f"cko_prefilter_bank{pi}")
-        )
+        else:
+            per_block.append(scan(bank, *transformed_for(pid), name=name))
     if per_block:
         return jnp.concatenate(per_block, axis=1)  # [T, G]
     return jnp.zeros((data.shape[0], 1), dtype=bool)

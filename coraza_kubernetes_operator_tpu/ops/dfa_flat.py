@@ -12,12 +12,14 @@ flattening every (group, local state) pair into one slot axis:
 
 - slot n holds group ``g(n)``'s local state ``n - base_g``;
 - the machine state is a one-hot over slots (``sigma`` [B, N]);
-- one byte step is three MXU matmuls + VPU elementwise:
+- one byte step is four MXU matmuls + VPU elementwise:
     r      = onehot(byte) @ table       # [B, N] packed next + S*emit
     val    = (sigma * r) @ sel          # [N, G] 0/1 -> per-group value
     hit    = val >= S_g ; nxt = val - S_g*hit
-    tb     = target @ bcast             # [G, N] 0/1 -> spread over slots
-    sigma' = (tb == slot_iota)          # re-one-hot
+    lo, hi = digits(base_g + nxt)       # the target slot, base 256
+    sigma' = (lo @ bcast == slot_lo) & (hi @ bcast == slot_hi)
+                                        # [G, N] 0/1 spread, re-one-hot
+  (a row past its length keeps its sigma);
 - no per-bank lane padding: a 7-group bank costs its ~400 slots, not
   7 x 128 padded columns.
 
@@ -26,15 +28,26 @@ split by group ranges — groups are independent, so any split is sound);
 each bin runs as ONE Pallas kernel on TPU (``_flat_kernel``) or one XLA
 ``lax.scan`` with identical math elsewhere (``scan_flat_xla``).
 
-Numerics: table values are ``next + S*emit`` < 2*S — segments with
-2*S <= 256 store bf16 (integers <= 256 are bf16-exact), larger S stores
-f32 (exact < 2^24). Slot-index arithmetic (targets up to N) is f32.
-One-hot/select operands are 0/1, exact in every dtype used.
+Numerics: every number a matmul carries is a whole number below 256
+in a bf16 operand, so ONE bf16 MXU pass with f32 accumulation is exact
+and no dot leans on a backend's f32 matmul precision. (At its default a
+TPU runs an f32 dot as one bf16 pass, in XLA and in Mosaic alike: the
+f32 formulation this replaces lost the low bits of every slot index
+above 256 on the chip, so a group laid past slot 256 fell back to a
+neighbouring or the start state — PR 31 found it when the dfa-hot and
+prefilter groups moved into bins of 640 and 1,664 slots; the CPU and
+the interpreter compute f32 dots exactly and never showed it.) Table
+values ``next + S*emit`` < 2*S are stored as base-256 digit planes (one
+plane when 2*S <= 256, two above); slot targets (up to N) are split
+into the same two digits before they are spread over the slots. The
+one-hot/select operands are 0/1. Everything between the dots is f32
+elementwise arithmetic on whole numbers below 2^24.
 
 Padding: each table segment's slot count and the group axis are padded
 to lane multiples (128). Dead slots carry all-zero table columns, zero
 ``sel``/``bcast``/``init_sigma`` — their sigma can never become 1
-(``tb`` is 0 there while ``slot_iota`` >= 1; slot 0 is always real).
+(both spread digits read 0 there while the slot's own do not, but for
+slot 0, which is always real).
 Dead groups carry ``S_g`` = 2^30 (hit impossible) and zero map columns.
 
 Reference parity: same matcher contract as ``ops/dfa.py:scan_dfa_bank``
@@ -66,6 +79,7 @@ import os as _os
 _FLAT_VMEM_BUDGET = int(_os.environ.get("CKO_FLAT_VMEM_MB", "15")) * 2**20
 _BLOCK_B = 128
 _DEAD_S = float(2**30)  # pad-group state count: hit threshold never reached
+_DIGIT = 256  # whole numbers below it are exact in bf16: the matmuls' digit base
 
 
 def _round_up(n: int, m: int) -> int:
@@ -76,7 +90,7 @@ def _round_up(n: int, m: int) -> int:
 @dataclass
 class FlatBank:
     """One fused scan bin: N slots over G groups, table segmented by
-    (pipeline, dtype-class) runs along the slot axis.
+    (pipeline, one- or two-digit) runs along the slot axis.
 
     OPERAND DISCIPLINE (shape-canonical executable reuse,
     ``engine/compile_cache.py``): tables/maps are pytree LEAVES (runtime
@@ -85,7 +99,10 @@ class FlatBank:
     rulesets then share one compiled executable with their own tables
     swapped in at call time."""
 
-    tables: tuple  # per segment: [256, N_seg] bf16 or f32 (N_seg % 128 == 0)
+    # per segment: the base-256 digit planes of its packed values, low
+    # digit first — ([256, N_seg] bf16,) or two of them where a DFA of
+    # the segment has 2*S > 256 (N_seg % 128 == 0)
+    tables: tuple
     sel: jnp.ndarray  # [N, Gp] bf16 0/1: slot -> its group column
     bcast: jnp.ndarray  # [Gp, N] bf16 0/1: group -> its slots
     init_sigma: jnp.ndarray  # [1, N] f32: one-hot of each group's state 0
@@ -335,10 +352,9 @@ def build_flat_bank(bin_pieces: list[tuple[int, int, int, int, list[DFA]]]) -> F
             group_pipe.append(pid)
             gi += 1
             seg_off += s
-        tj = jnp.asarray(tab)
-        if kc:
-            tj = tj.astype(jnp.bfloat16)
-        tables.append(tj)
+        planes = (tab % _DIGIT,) if kc else (tab % _DIGIT, tab // _DIGIT)
+        assert float(planes[-1].max()) < _DIGIT, "a DFA of 32768 states or more"
+        tables.append(tuple(jnp.asarray(pl).astype(jnp.bfloat16) for pl in planes))
         seg_pipes.append(pid)
         seg_slots.append(seg_n)
         off += seg_n
@@ -359,25 +375,52 @@ def build_flat_bank(bin_pieces: list[tuple[int, int, int, int, list[DFA]]]) -> F
     )
 
 
-def _flat_step_math(sigma, matched, r, active_g, sel_f32, bcast_f32, base_g, s_g, slot_iota):
+def _digits(x):
+    """Whole numbers below 65536 as their two base-256 digits, each a
+    bf16 operand the MXU carries exactly: (low, high)."""
+    hi = jnp.floor(x * (1.0 / _DIGIT))
+    return (x - _DIGIT * hi).astype(jnp.bfloat16), hi.astype(jnp.bfloat16)
+
+
+def _dot(a, b):
+    """Every matmul of the scan. Its operands hold whole numbers below
+    256 (bf16, or f32 0/1 for the activity masks), so the one bf16 pass
+    a TPU gives a dot by default is exact (tests/test_dfa_flat.py runs
+    the bins with the operands rounded to bf16 here)."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32)
+
+
+def _flat_step_math(
+    sigma, matched, r_planes, active_g, active_n, sel, bcast, base_g, s_g, iota_lo, iota_hi
+):
     """Shared per-byte math (Pallas kernel body and XLA fallback).
 
-    sigma [B, N] f32 one-hot; matched [B, Gp] f32; r [B, N] f32 packed
-    values for this byte; active_g [B, Gp] f32 0/1. All matmuls f32 with
-    f32 accumulation — every product term is exact (< 2^24) and at most
-    one term per output is nonzero for the select/spread contractions."""
-    masked = sigma * r  # [B, N]
-    val = jnp.dot(masked, sel_f32, preferred_element_type=jnp.float32)  # [B, Gp]
+    sigma [B, N] f32 one-hot; matched [B, Gp] f32; r_planes: this byte's
+    packed values as base-256 digit planes, [B, N] f32 each, low first;
+    active_g [B, Gp] and active_n [B, N] f32 0/1: the row is inside its
+    length, per group and per slot; sel [N, Gp] / bcast [Gp, N] bf16
+    0/1; iota_lo / iota_hi [1, N] f32: the digits of each slot's index.
+    Every dot takes bf16 operands that hold whole numbers below 256, at
+    most one nonzero term per output: exact in one MXU pass."""
+    val = 0.0
+    for k, r in enumerate(r_planes):  # [B, Gp]: the group's value at its state
+        val = val + float(_DIGIT**k) * _dot((sigma * r).astype(jnp.bfloat16), sel)
     hit = (val >= s_g).astype(jnp.float32)
     nxt = val - s_g * hit
     matched = jnp.maximum(matched, hit * active_g)
-    cur_abs = jnp.dot(
-        sigma * slot_iota, sel_f32, preferred_element_type=jnp.float32
-    )  # [B, Gp] absolute slot of the current state
-    target = active_g * (base_g + nxt) + (1.0 - active_g) * cur_abs
-    tb = jnp.dot(target, bcast_f32, preferred_element_type=jnp.float32)  # [B, N]
-    sigma = (tb == slot_iota).astype(jnp.float32)
+    t_lo, t_hi = _digits(base_g + nxt)  # absolute slot of the next state
+    moved = (_dot(t_lo, bcast) == iota_lo) & (_dot(t_hi, bcast) == iota_hi)
+    # A row past its length keeps its state (and so its end-anchor).
+    sigma = jnp.where(active_n > 0, moved.astype(jnp.float32), sigma)
     return sigma, matched
+
+
+def _slot_digits(slot):
+    """[1, N] int32 slot indexes -> their base-256 digits as f32."""
+    return (
+        (slot % _DIGIT).astype(jnp.float32),
+        (slot // _DIGIT).astype(jnp.float32),
+    )
 
 
 def _group_pipe_onehot(flat: FlatBank, pids: list[int]) -> np.ndarray:
@@ -389,6 +432,32 @@ def _group_pipe_onehot(flat: FlatBank, pids: list[int]) -> np.ndarray:
     return gp
 
 
+def _by_segment(seg_slots, per_segment):
+    """[B, N] from one [B, 1] column per table segment (a segment's
+    slots all belong to its pipeline)."""
+    b = per_segment[0].shape[0]
+    return jnp.concatenate(
+        [jnp.broadcast_to(col, (b, sn)) for col, sn in zip(per_segment, seg_slots)],
+        axis=1,
+    )
+
+
+def _r_planes(seg_planes):
+    """Per-segment digit planes ([B, N_seg] f32 each, low first) -> the
+    bin's planes [B, N]; a segment without a high digit reads zero."""
+    depth = max(len(planes) for planes in seg_planes)
+    return [
+        jnp.concatenate(
+            [
+                planes[k] if k < len(planes) else jnp.zeros_like(planes[0])
+                for planes in seg_planes
+            ],
+            axis=1,
+        )
+        for k in range(depth)
+    ]
+
+
 def scan_flat_xla(
     flat: FlatBank, data_by_pipe: dict[int, tuple[jnp.ndarray, jnp.ndarray]]
 ) -> jnp.ndarray:
@@ -398,7 +467,7 @@ def scan_flat_xla(
     d0 = data_by_pipe[pids[0]][0]
     b = d0.shape[0]
     n, gp_n = flat.n_slots, flat.n_groups_padded
-    slot_iota = jnp.arange(n, dtype=jnp.float32)[None, :]
+    iota_lo, iota_hi = _slot_digits(jnp.arange(n, dtype=jnp.int32)[None, :])
 
     dataT = jnp.stack(
         [data_by_pipe[p][0].T for p in pids], axis=1
@@ -406,8 +475,6 @@ def scan_flat_xla(
     lens = jnp.stack([data_by_pipe[p][1] for p in pids], axis=0)  # [P, B]
     pid_ix = {p: i for i, p in enumerate(pids)}
     gp_j = jnp.asarray(_group_pipe_onehot(flat, pids))
-    sel_f32 = flat.sel.astype(jnp.float32)
-    bcast_f32 = flat.bcast.astype(jnp.float32)
 
     row0 = dataT[0, 0, :, None].astype(jnp.float32) * 0  # [B, 1] varying zero
     sigma0 = jnp.broadcast_to(flat.init_sigma, (b, n)).astype(jnp.float32) + row0
@@ -416,36 +483,44 @@ def scan_flat_xla(
     def step(carry, xs):
         sigma, matched = carry
         t, byte_cols = xs  # byte_cols [P, B]
-        rs = [
-            jnp.take(tab, byte_cols[pid_ix[p]], axis=0).astype(jnp.float32)
-            for tab, p in zip(flat.tables, flat.seg_pipes)
-        ]
-        r = jnp.concatenate(rs, axis=1)  # [B, N]
+        r_planes = _r_planes(
+            [
+                [
+                    jnp.take(plane, byte_cols[pid_ix[p]], axis=0).astype(jnp.float32)
+                    for plane in planes
+                ]
+                for planes, p in zip(flat.tables, flat.seg_pipes)
+            ]
+        )
         active_p = (t < lens).astype(jnp.float32)  # [P, B]
-        active_g = jnp.dot(active_p.T, gp_j)  # [B, Gp]
+        active_g = _dot(active_p.T, gp_j)  # [B, Gp]
+        active_n = _by_segment(
+            flat.seg_slots, [active_p[pid_ix[p]][:, None] for p in flat.seg_pipes]
+        )
         sigma, matched = _flat_step_math(
-            sigma, matched, r, active_g, sel_f32, bcast_f32,
-            flat.base_g, flat.s_g, slot_iota,
+            sigma, matched, r_planes, active_g, active_n, flat.sel, flat.bcast,
+            flat.base_g, flat.s_g, iota_lo, iota_hi,
         )
         return (sigma, matched), None
 
     ts = jnp.arange(dataT.shape[0], dtype=jnp.int32)
     (sigma, matched), _ = jax.lax.scan(step, (sigma0, matched0), (ts, dataT))
-    end_hit = jnp.dot(sigma * flat.mend, sel_f32, preferred_element_type=jnp.float32)
+    end_hit = _dot((sigma * flat.mend).astype(jnp.bfloat16), flat.sel)
     out = (matched + end_hit) > 0
     return out[:, : flat.n_groups] | flat.always[None, :]
 
 
-def _flat_kernel(*refs, seg_pipes, seg_slots, pid_ix, n, gp_n, length, n_pipes):
+def _flat_kernel(*refs, seg_pipes, seg_slots, seg_depth, pid_ix, n, gp_n, length, n_pipes):
     """Pallas kernel: one [Bt] row-block over all bytes, all banks fused.
 
-    refs: dataT_p x P ([L, Bt]), len_p x P ([Bt, 1]), tables per segment,
-    sel [N, Gp], bcast [Gp, N], init_sigma [1, N], mend [1, N],
-    base_g [1, Gp], s_g [1, Gp], gp [P, Gp], out [Bt, Gp]."""
+    refs: dataT_p x P ([L, Bt]), len_p x P ([Bt, 1]), the table planes of
+    every segment in turn (``seg_depth`` of them each), sel [N, Gp],
+    bcast [Gp, N], init_sigma [1, N], mend [1, N], base_g [1, Gp],
+    s_g [1, Gp], gp [P, Gp], out [Bt, Gp]."""
     it = iter(refs)
     dataT = [next(it) for _ in range(n_pipes)]
     lens = [next(it) for _ in range(n_pipes)]
-    tables = [next(it) for _ in range(len(seg_slots))]
+    tables = [[next(it) for _ in range(depth)] for depth in seg_depth]
     sel_ref = next(it)
     bcast_ref = next(it)
     init_ref = next(it)
@@ -457,43 +532,40 @@ def _flat_kernel(*refs, seg_pipes, seg_slots, pid_ix, n, gp_n, length, n_pipes):
 
     bt = out_ref.shape[0]
     # Mosaic's tpu.iota is integer-only; cast after.
-    slot_iota = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1).astype(jnp.float32)
+    iota_lo, iota_hi = _slot_digits(jax.lax.broadcasted_iota(jnp.int32, (1, n), 1))
     bytes_iota = jax.lax.broadcasted_iota(jnp.int32, (bt, 256), 1)
-    sel_f32 = sel_ref[:].astype(jnp.float32)
-    bcast_f32 = bcast_ref[:].astype(jnp.float32)
+    sel = sel_ref[:]
+    bcast = bcast_ref[:]
     base_g = base_ref[:]
     s_g = sg_ref[:]
     gp = gp_ref[:]  # [P, Gp]
 
     def step(t, carry):
         sigma, matched = carry
-        onehots = {}
-        rs = []
-        for si, seg_pid in enumerate(seg_pipes):
-            p = pid_ix[seg_pid]
-            if p not in onehots:
-                byte = dataT[p][t, :][:, None]  # [Bt, 1]
-                onehots[p] = byte == bytes_iota
-            tab = tables[si][:]
-            oh = onehots[p].astype(tab.dtype)
-            rs.append(jnp.dot(oh, tab, preferred_element_type=jnp.float32))
-        r = jnp.concatenate(rs, axis=1)  # [Bt, N]
-        active_p = jnp.concatenate(
+        onehots = [
+            (dataT[p][t, :][:, None] == bytes_iota).astype(jnp.bfloat16)
+            for p in range(n_pipes)
+        ]  # [Bt, 256] each
+        r_planes = _r_planes(
             [
-                (t < lens[i][:, 0][:, None]).astype(jnp.float32)
-                for i in range(n_pipes)
-            ],
-            axis=1,
-        )  # [Bt, P]
-        active_g = jnp.dot(active_p, gp, preferred_element_type=jnp.float32)
+                [_dot(onehots[pid_ix[seg_pid]], plane[:]) for plane in planes]
+                for planes, seg_pid in zip(tables, seg_pipes)
+            ]
+        )
+        active = [
+            (t < lens[i][:, 0][:, None]).astype(jnp.float32) for i in range(n_pipes)
+        ]  # [Bt, 1] each
+        active_g = _dot(jnp.concatenate(active, axis=1), gp)  # [Bt, P] @ [P, Gp], 0/1
+        active_n = _by_segment(seg_slots, [active[pid_ix[p]] for p in seg_pipes])
         return _flat_step_math(
-            sigma, matched, r, active_g, sel_f32, bcast_f32, base_g, s_g, slot_iota
+            sigma, matched, r_planes, active_g, active_n, sel, bcast,
+            base_g, s_g, iota_lo, iota_hi,
         )
 
     sigma0 = jnp.broadcast_to(init_ref[:], (bt, n))
     matched0 = jnp.zeros((bt, gp_n), dtype=jnp.float32)
     sigma, matched = jax.lax.fori_loop(0, length, step, (sigma0, matched0))
-    end_hit = jnp.dot(sigma * mend_ref[:], sel_f32, preferred_element_type=jnp.float32)
+    end_hit = _dot((sigma * mend_ref[:]).astype(jnp.bfloat16), sel)
     out_ref[:] = ((matched + end_hit) > 0).astype(jnp.int32)
 
 
@@ -509,10 +581,12 @@ def _scan_flat_pallas(
     pids = sorted(set(flat.seg_pipes))
     pid_ix = {p: i for i, p in enumerate(pids)}
 
+    seg_depth = tuple(len(planes) for planes in flat.tables)
     kernel = functools.partial(
         _flat_kernel,
         seg_pipes=flat.seg_pipes,
         seg_slots=flat.seg_slots,
+        seg_depth=seg_depth,
         pid_ix=pid_ix,
         n=n,
         gp_n=gp_n,
@@ -522,7 +596,11 @@ def _scan_flat_pallas(
     in_specs = (
         [pl.BlockSpec((length, _BLOCK_B), lambda i: (0, i)) for _ in range(n_pipes)]
         + [pl.BlockSpec((_BLOCK_B, 1), lambda i: (i, 0)) for _ in range(n_pipes)]
-        + [pl.BlockSpec((256, sn), lambda i: (0, 0)) for sn in flat.seg_slots]
+        + [
+            pl.BlockSpec((256, sn), lambda i: (0, 0))
+            for sn, depth in zip(flat.seg_slots, seg_depth)
+            for _ in range(depth)
+        ]
         + [
             pl.BlockSpec((n, gp_n), lambda i: (0, 0)),
             pl.BlockSpec((gp_n, n), lambda i: (0, 0)),
@@ -544,7 +622,7 @@ def _scan_flat_pallas(
     )(
         *dataT_list,
         *lens_list,
-        *flat.tables,
+        *(plane for planes in flat.tables for plane in planes),
         flat.sel,
         flat.bcast,
         flat.init_sigma,

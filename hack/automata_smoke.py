@@ -7,10 +7,11 @@ ruleset:
 
 1. two-level automata OFF (``CKO_AUTOMATA=0``) — the exact pre-feature
    layout: every group on segment/NFA banks; then
-2. two-level automata ON with the Pallas transition-gather kernel forced
-   into ``interpret=True`` mode (``CKO_PALLAS_INTERPRET=1``) — DFA-hot
-   groups ride the gather banks through the exact TPU kernel program,
-   big groups ride their approximate prefilters with host confirm.
+2. two-level automata ON — DFA-hot groups and the big groups'
+   approximate prefilters (with host confirm) are scanned in the fused
+   flat-slot bins, with every other dense-DFA block (since PR 31: no
+   block of crs-lite is left on a per-bank kernel; one that were would
+   run its kernel in ``interpret=True`` mode, ``CKO_PALLAS_INTERPRET=1``).
 
 Gates (exit 1 with the JSON diagnostic on any failure):
 
@@ -18,9 +19,13 @@ Gates (exit 1 with the JSON diagnostic on any failure):
   (status + interrupted + rule id + matched rule ids);
 - the plan exercised the new tiers: >= 1 DFA-hot group and >= 1
   prefiltered group on crs-lite, gather banks + pre banks resident,
-  and prefilter rows actually examined by the confirm step;
-- Pallas interpret-mode parity on CPU: every gather bank's interpret
-  kernel output equals the jnp lowering on a live batch.
+  every one of them covered by a flat bin (``per_bank_kernels`` 0 in
+  ``automata_summary()``), and prefilter rows actually examined by the
+  confirm step;
+- Pallas interpret-mode parity on CPU (``interpret=True``, the exact
+  TPU kernel program): every flat bin's kernel output equals its XLA
+  twin, and every gather bank's kernel (the path of a block no bin
+  covers) equals the jnp lowering, on a live batch.
 
 Usage: automata_smoke.py [--requests 384] [--batch 128]
 (env overrides: AUTOMATA_SMOKE_REQUESTS / AUTOMATA_SMOKE_BATCH).
@@ -70,7 +75,8 @@ def _ftw_replay(n: int):
 
 def _interpret_parity(engine, diag: dict) -> None:
     """Every resident gather bank: interpret-mode Pallas kernel output
-    == jnp gather lowering on a live random batch."""
+    == jnp gather lowering on a live random batch; every flat bin:
+    interpret-mode kernel output == its XLA twin."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -107,6 +113,21 @@ def _interpret_parity(engine, diag: dict) -> None:
             _fail(diag, f"interpret-mode kernel diverged on bank {checked}")
         checked += 1
     diag["interpret_parity_banks"] = checked
+
+    from coraza_kubernetes_operator_tpu.ops.dfa_flat import (
+        scan_flat_bank,
+        scan_flat_xla,
+    )
+
+    data = rng.integers(0, 256, size=(64, 96), dtype=np.uint8)
+    lengths = rng.integers(0, 97, size=(64,)).astype(np.int32)
+    for fi, flat in enumerate(engine.model.flat_banks):
+        sub = {p: (data, lengths) for p in set(flat.seg_pipes)}
+        ref = np.asarray(scan_flat_xla(flat, sub))
+        got = np.asarray(scan_flat_bank(flat, sub, interpret=True))
+        if not (got == ref).all():
+            _fail(diag, f"interpret-mode kernel diverged on flat bin {fi}")
+    diag["interpret_parity_flat_bins"] = len(engine.model.flat_banks)
 
 
 def main() -> None:
@@ -158,12 +179,17 @@ def main() -> None:
     diag["tiers"] = counts
     diag["gather_banks"] = len(eng_on.model.gather_banks)
     diag["pre_banks"] = len(eng_on.model.pre_banks)
+    summary = eng_on.automata_summary()
+    for k in ("flat_bins", "flat_slots", "flat_groups", "per_bank_kernels"):
+        diag[k] = summary[k]
     if counts["dfa-hot"] < 1:
         _fail(diag, "no DFA-hot group on crs-lite")
     if counts["prefiltered"] < 1:
         _fail(diag, "no prefiltered group on crs-lite")
     if not eng_on.model.gather_banks or not eng_on.model.pre_banks:
         _fail(diag, "automata tiers planned but no device banks built")
+    if summary["per_bank_kernels"]:
+        _fail(diag, "a dense-DFA block of crs-lite is not covered by a flat bin")
 
     _interpret_parity(eng_on, diag)
 

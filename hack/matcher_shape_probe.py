@@ -9,7 +9,9 @@ releases the interpreter lock), then calls each 20 times with the
 model's tables resident and waits for the result, twice: every row as
 long as the tier is wide, and every row 32 bytes long in the same tier.
 Wall time per call is device time here: nothing else runs, and a call
-is tens of milliseconds against tens of microseconds of dispatch. One
+is tens of milliseconds against tens of microseconds of dispatch. The
+second line says what a call launches (``automata_summary()``: flat
+bins, their slots and groups, blocks left on one kernel a bank). One
 JSON line per shape, all of them again in
 ``chiprun_out/matcher_shape_probe.json``. ROADMAP Speed 2's question
 ("fixed, or grows with rows?") is answered by the lines it prints.
@@ -49,6 +51,11 @@ def main(argv=None) -> int:
                       "JAX_COMPILATION_CACHE_DIR": os.environ.get("JAX_COMPILATION_CACHE_DIR")}),
           flush=True)
     engine = WafEngine(read_rules(Path(args.rules)))
+    # What each call launches: one Pallas kernel a flat bin and one a
+    # dense-DFA block no bin covers (none for crs-lite since PR 31).
+    layout = engine.automata_summary()
+    print(json.dumps({k: layout[k] for k in ("flat_bins", "flat_slots", "flat_groups",
+                                             "per_bank_kernels")}), flush=True)
     model = jax.device_put(engine.model)
     h = max(1, len(engine._host_pipelines))
     rng = np.random.default_rng(28)

@@ -20,7 +20,9 @@ milliseconds per window (``lane_wait``: per request), and after − before
 of ``automata.prefilter`` (``native_hits`` against ``hits``) and of
 ``compile_cache`` (``launch_plan_hits`` against ``device_windows``:
 every warm window launched from its engine's table; ``launch_plan_misses``
-and ``misses`` flat). What
+and ``misses`` flat). The ``warm`` line carries the engine's matcher
+layout from ``automata`` (``flat_bins``, ``flat_slots``, ``flat_groups``,
+``per_bank_kernels``). What
 ``wafbench.run --trace 1`` reports for the same stages also holds its
 traced intervals; this is the untraced reading to hold it against.
 The result is no benchmark line: nothing is checked for correctness
@@ -48,6 +50,9 @@ from wafbench.layer_metrics._window_stages import PER_WINDOW, grew  # noqa: E402
 TOKEN = "stage-probe"
 LAUNCH_COUNTERS = ("launch_plan_hits", "launch_plan_misses", "device_windows",
                    "host_twin_windows", "hits", "misses", "bypasses")
+# Where the engine scans its dense-DFA blocks (``automata_summary``):
+# fused flat bins, and the blocks left on one kernel a bank.
+MATCHER_LAYOUT = ("flat_bins", "flat_slots", "flat_groups", "per_bank_kernels")
 
 
 def stage_ms(before: dict, after: dict) -> dict:
@@ -131,7 +136,8 @@ def main() -> int:
             if not any(harness.dig(after, k) - harness.dig(before, k) for k in harness.MINTED):
                 break
         harness.emit({"phase": "warm", "device": after["device"],
-                      "sample_rate": after["tracing"]["sample_rate"]})
+                      "sample_rate": after["tracing"]["sample_rate"],
+                      "automata": {k: after["automata"].get(k) for k in MATCHER_LAYOUT}})
         for k, mode in enumerate(args.windows.split(",")):
             trace_dir = work / f"trace{k}"
             before = sc.stats()

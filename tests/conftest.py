@@ -15,6 +15,7 @@ chip), never by this suite; ``tests/test_tpu_compile.py`` only compiles
 for a described chip.
 """
 
+import collections
 import os
 import shutil
 import subprocess
@@ -161,3 +162,30 @@ def native_loaded(native_lib):
     with pytest.MonkeyPatch.context() as mp:
         load_native(mp, native_lib)
         yield native_lib
+
+
+def dfa_witness(dfa) -> bytes:
+    """A shortest input the exact DFA matches (BFS over states), spelled
+    with plain lowercase bytes where a class has one, so that crs-lite's
+    pipelines (lowercase, urlDecodeUni, …) leave it alone."""
+    pref = list(b"abcdefghijklmnopqrstuvwxyz0123456789 =<>()/.;:-_'\"") + list(range(256))
+    rep = {}
+    for b in pref:
+        rep.setdefault(int(dfa.classmap[b]), b)
+    if dfa.always_match or dfa.match_end[0]:
+        return b""
+    seen = {0: b""}
+    todo = collections.deque([0])
+    while todo:
+        s = todo.popleft()
+        for c, b in rep.items():
+            path = seen[s] + bytes([b])
+            if dfa.emit[s, c]:
+                return path
+            nxt = int(dfa.trans[s, c])
+            if nxt not in seen:
+                seen[nxt] = path
+                if dfa.match_end[nxt]:
+                    return path
+                todo.append(nxt)
+    raise AssertionError("the DFA matches nothing")

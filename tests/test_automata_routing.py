@@ -113,6 +113,9 @@ def test_automata_summary_shape(engines):
     assert set(summary["tiers"]) == {"segment", "dfa-hot", "prefiltered", "nfa"}
     assert summary["gather_banks"] >= 1
     assert summary["pre_banks"] >= 1
+    # Where the dense-DFA blocks are scanned: all of them in flat bins.
+    assert summary["flat_bins"] >= 1 and summary["per_bank_kernels"] == 0
+    assert summary["flat_groups"] == 2 and summary["flat_slots"] % 128 == 0
     assert {"rows", "hits", "confirms", "false_positives"} <= set(
         summary["prefilter"]
     )

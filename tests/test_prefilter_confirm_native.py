@@ -17,7 +17,6 @@ made here, which is also what lets full crs-lite take part on the CPU.
 
 import base64
 import random
-from collections import deque
 from pathlib import Path
 
 import numpy as np
@@ -33,7 +32,7 @@ from coraza_kubernetes_operator_tpu.engine import WafEngine
 from coraza_kubernetes_operator_tpu.ftw.corpus import load_ruleset_text
 from coraza_kubernetes_operator_tpu.observability.stages import current as current_stages
 
-from conftest import load_native, native_engine
+from conftest import dfa_witness as _witness, load_native, native_engine
 
 CRS_CACHE_DIR = str(Path(__file__).resolve().parent / ".crs_cache")
 
@@ -80,33 +79,6 @@ def synthetic_crs():
 @pytest.fixture(scope="module")
 def synthetic(native_lib, synthetic_crs):
     return native_engine(synthetic_crs, native_lib)
-
-
-def _witness(dfa) -> bytes:
-    """A shortest input the exact DFA matches (BFS over states), spelled
-    with plain lowercase bytes where a class has one, so that crs-lite's
-    pipelines (lowercase, urlDecodeUni, …) leave it alone."""
-    pref = list(b"abcdefghijklmnopqrstuvwxyz0123456789 =<>()/.;:-_'\"") + list(range(256))
-    rep = {}
-    for b in pref:
-        rep.setdefault(int(dfa.classmap[b]), b)
-    if dfa.always_match or dfa.match_end[0]:
-        return b""
-    seen = {0: b""}
-    todo = deque([0])
-    while todo:
-        s = todo.popleft()
-        for c, b in rep.items():
-            path = seen[s] + bytes([b])
-            if dfa.emit[s, c]:
-                return path
-            nxt = int(dfa.trans[s, c])
-            if nxt not in seen:
-                seen[nxt] = path
-                if dfa.match_end[nxt]:
-                    return path
-                todo.append(nxt)
-    raise AssertionError("the DFA matches nothing")
 
 
 def _rows(seed: int, width: int, witnesses: list[bytes]) -> list[bytes]:

@@ -107,13 +107,33 @@ def _widest(banks):
 # (kernel family, rows, width): each bank family of the default crs-lite
 # plan at the sidecar window and at the large batch. The dispatchers must
 # pick the Pallas kernel (one tpu_custom_call), never the XLA fallback.
+# "flat<i>" is the plan's i-th fused bin: since PR 31 every dense-DFA
+# block of crs-lite (nfa banks, dfa-hot gather banks, prefilter
+# approximations) rides one of FLAT_BINS bins, each compiled at both
+# served window shapes; the per-bank kernels stay compiled here as the
+# path of a block the flat planner leaves out.
+FLAT_BINS = 2
 KERNEL_CASES = [
-    ("flat", ROWS_WINDOW, WIDTH_WINDOW),
-    ("flat", ROWS_BATCH, WIDTH_MAX),
+    ("flat0", ROWS_WINDOW, WIDTH_WINDOW),
+    ("flat0", ROWS_BATCH, WIDTH_MAX),
     ("prefilter", ROWS_BATCH, WIDTH_MAX),
     ("gather", ROWS_WINDOW, WIDTH_WINDOW),
     ("gather", ROWS_BATCH, WIDTH_MAX),
+    ("flat0", ROWS_BODIES, WIDTH_BODIES),
+    ("flat1", ROWS_WINDOW, WIDTH_WINDOW),
+    ("flat1", ROWS_BODIES, WIDTH_BODIES),
+    ("flat1", ROWS_BATCH, WIDTH_MAX),
 ]
+
+
+def _dense_blocks(model) -> int:
+    return len(model.banks) + len(model.gather_banks) + len(model.pre_banks)
+
+
+def _pallas_calls(model) -> int:
+    """Custom calls of the whole matcher: one a flat bin and one a
+    dense-DFA block no bin covers."""
+    return len(model.flat_banks) + _dense_blocks(model) - len(model.flat_covered)
 
 
 @pytest.mark.parametrize("family,rows,width", KERNEL_CASES)
@@ -125,22 +145,24 @@ def test_pallas_kernel_compiles_for_v5e(crs_lite, described, operand, family, ro
     model = crs_lite.model
     data = operand((rows, width), jnp.uint8)
     lengths = operand((rows,), jnp.int32)
-    if family == "flat":
-        # ops/dfa_flat.py:_scan_flat_pallas — every generic DFA bank of
-        # crs-lite rides one fused flat bin.
-        assert model.flat_banks and len(model.flat_covered) == len(model.banks)
-        bank = model.flat_banks[0]
+    if family.startswith("flat"):
+        # ops/dfa_flat.py:_scan_flat_pallas — every dense-DFA block of
+        # crs-lite rides one of the fused flat bins.
+        assert len(model.flat_banks) == FLAT_BINS
+        assert len(model.flat_covered) == _dense_blocks(model)
+        bank = model.flat_banks[int(family[len("flat"):])]
         pipes = sorted(set(bank.seg_pipes))
         text = _compile(
             lambda b, d, n: scan_flat_bank(b, {p: (d, n) for p in pipes}),
             described(bank), data, lengths,
         )
     elif family == "prefilter":
-        # ops/dfa_pallas.py:scan_dfa_bank_pallas — the approximate
-        # prefilter banks are the dense banks left outside the flat bin.
+        # ops/dfa_pallas.py:scan_dfa_bank_pallas — what a prefilter
+        # bank runs when no flat bin covers it.
         text = _compile(scan_dfa_bank, described(_widest(model.pre_banks)), data, lengths)
     else:
-        # ops/dfa_gather_pallas.py:scan_gather_bank_pallas — dfa-hot tier.
+        # ops/dfa_gather_pallas.py:scan_gather_bank_pallas — what a
+        # dfa-hot bank runs when no flat bin covers it.
         text = _compile(scan_gather_bank, described(_widest(model.gather_banks)), data, lengths)
     assert text.count("tpu_custom_call") == 1, "dispatch fell back off the Pallas kernel"
 
@@ -172,7 +194,7 @@ def test_post_stage_compiles_for_v5e(crs_lite, described, operand):
 
 def test_canary_matcher_compiles_for_v5e(crs_lite, described, operand):
     """The whole per-tier matcher executable — transforms, the XLA conv
-    tier of ops/segment.py, and every Pallas bank in one program — at
+    tier of ops/segment.py, and every flat bin in one program — at
     the promotion canary's shape, which every cold sidecar compiles
     before it may serve from the device."""
     from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
@@ -189,20 +211,15 @@ def test_canary_matcher_compiles_for_v5e(crs_lite, described, operand):
         operand((h, u), jnp.int32),
         mask=None,
     ).compile()
-    n_pallas = (
-        len(model.flat_banks) + len(model.pre_banks) + len(model.gather_banks)
-    )
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == n_pallas
+    assert text.count("tpu_custom_call") == _pallas_calls(model) == FLAT_BINS
     # The names a device trace prints: the module by role and window
     # shape (only the post stage's holds "eval_post"), every Pallas
     # kernel by family and bank, not by XLA's running counter.
     assert f"HloModule jit_cko_match_{u}x{width}" in text and "eval_post" not in text
-    for family, banks in (("gather_bank", model.gather_banks),
-                          ("prefilter_bank", model.pre_banks),
-                          ("flat_bin", model.flat_banks)):
-        for i in range(len(banks)):
-            assert f"%cko_{family}{i}" in text, f"cko_{family}{i}"
+    for i in range(len(model.flat_banks)):
+        assert f"%cko_flat_bin{i}" in text, f"cko_flat_bin{i}"
+    assert "%cko_gather_bank" not in text and "%cko_prefilter_bank" not in text
     # It has to fit beside the model's tables in one v5e's 16 GB.
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
 
@@ -210,9 +227,10 @@ def test_canary_matcher_compiles_for_v5e(crs_lite, described, operand):
 @pytest.mark.parametrize("rows,width", [(ROWS_BODIES, WIDTH_BODIES)])
 def test_long_matcher_compiles_for_v5e(crs_lite, described, operand, rows, width):
     """The whole matcher at width 2048, where a window holds a body of
-    1 to 2 KiB: every bank still rides its Pallas kernel (the flat bins
-    sit at the edge of their VMEM plan there; ``_PALLAS_MAX_LEN``), none
-    falls to the XLA scan, and the program fits beside the tables."""
+    1 to 2 KiB: every dense-DFA block still rides a flat bin's Pallas
+    kernel (the bins sit at the edge of their VMEM plan there, one
+    ``int32`` data tile a pipeline; ``_PALLAS_MAX_LEN``), none falls to
+    the XLA scan, and the program fits beside the tables."""
     from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
 
     model = crs_lite.model
@@ -226,7 +244,6 @@ def test_long_matcher_compiles_for_v5e(crs_lite, described, operand, rows, width
         mask=None,
     ).compile()
     text = compiled.as_text()
-    n_pallas = len(model.flat_banks) + len(model.pre_banks) + len(model.gather_banks)
-    assert text.count("tpu_custom_call") == n_pallas
+    assert text.count("tpu_custom_call") == _pallas_calls(model) == FLAT_BINS
     assert f"HloModule jit_cko_match_{rows}x{width}" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
