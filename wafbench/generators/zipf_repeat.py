@@ -11,7 +11,14 @@ is one steady group with ``repeat_per_burst`` draws from the lane's
 repeat pool around it, rank ``k`` drawn with weight ``k ** -zipf_s``.
 From ``--seed`` come the order of the groups, the draws, where in the
 burst the salted requests sit, and the salts. The prime pass sends each
-lane's repeat pool once, whole, so that every later draw is a repeat.
+lane's repeat pool once, whole, so that every later draw is a repeat;
+where the plan has a ``prime`` list it sends those groups instead, one
+burst each (a whole pool in one burst is one window of whatever shape
+its rows make, and a large rule set pays a compile for every shape).
+Every request of a prime group goes out on its fixed salt, so a plan's
+groups must hold the repeat pools, and may hold more: requests that
+only the steady groups send, seen once so that their unsalted values
+are cached before a steady group first rides a window.
 """
 
 from __future__ import annotations
@@ -61,7 +68,11 @@ class Traffic:
         self.cum = {lane: list(itertools.accumulate(
             (k + 1) ** -float(mix["zipf_s"]) for k in range(len(reqs))))
             for lane, reqs in self.repeat.items()}
-        self.prime = [Burst(lane, reqs) for lane, reqs in self.repeat.items()]
+        if "prime" in plan:
+            self.prime = [Burst(g["lane"], [fixed(i) for i in g["requests"]])
+                          for g in plan["prime"]]
+        else:
+            self.prime = [Burst(lane, reqs) for lane, reqs in self.repeat.items()]
         self.connections = []
         for c in mix["connections"]:
             mine = [(g["lane"], [fresh(i) for i in g["requests"]])

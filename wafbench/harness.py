@@ -32,6 +32,10 @@ from . import arith
 REPO = Path(__file__).resolve().parents[1]
 WORK = REPO / "build" / "wafbench"  # build/ is git-ignored
 INSTANCE = "wafbench/ruleset"
+# What the harness states itself on the sidecar's command line: a
+# configuration's ``sidecar_args`` may name none of these.
+HARNESS_FLAGS = ("--cache-server-instance", "--cache-server-cluster", "--bind-address",
+                 "--port", "--compile-cache-dir")
 
 T_READY_S = 300.0
 T_PROMOTE_S = 900.0
@@ -118,15 +122,67 @@ class Cell:
         name = self.workload["name"]
         return [m for m in self.bench[group] if name in m.get("workloads", [name])]
 
-    def rules_text(self, control: bool = False) -> str:
-        text = read_rules(self.config_dir / self.config["rules"])
+    def instances(self) -> list[dict]:
+        """The deployment's RuleSets, the default tenant first: the
+        configuration's ``instances``, or its one ``rules`` under
+        ``INSTANCE``."""
+        return self.config.get("instances") or [
+            {"instance": INSTANCE, "rules": self.config["rules"]}]
+
+    def rules_texts(self, control: bool = False) -> dict[str, str]:
+        """Instance name -> rule text, in the order deployed. The
+        control edits one instance's text (``control.instance``, else
+        the first) and leaves the others as they are."""
+        texts = {i["instance"]: read_rules(self.config_dir / i["rules"])
+                 for i in self.instances()}
         if control:
-            for old, new in self.config["control"]["replace"]:
+            edit = self.config["control"]
+            name = edit.get("instance", next(iter(texts)))
+            if name not in texts:
+                raise SystemExit(f"control: no instance {name!r} in the configuration")
+            text = texts[name]
+            for old, new in edit["replace"]:
                 if old not in text:
                     raise SystemExit(f"control: {old!r} not in the rule text")
                 text = text.replace(old, new)
-            text += "\n" + self.config["control"].get("append", "") + "\n"
-        return text
+            texts[name] = text + "\n" + edit.get("append", "") + "\n"
+        return texts
+
+    def rules_text(self, control: bool = False) -> str:
+        """The default tenant's rule text."""
+        return next(iter(self.rules_texts(control).values()))
+
+    def sidecar_args(self) -> list[str]:
+        """The configuration's own arguments to the sidecar, refused
+        where one (spelt out, abbreviated or with ``=``) is a flag the
+        harness sets itself."""
+        args = self.config.get("sidecar_args", [])
+        if not isinstance(args, list) or not all(isinstance(a, str) for a in args):
+            raise RunFailure("sidecar_args", "sidecar_args is not a list of strings")
+        for a in args:
+            flag = a.split("=", 1)[0]
+            owned = [f for f in HARNESS_FLAGS if len(flag) > 2 and f.startswith(flag)]
+            if owned:
+                raise RunFailure("sidecar_args", f"{a!r} sets {owned[0]}, which the harness sets",
+                                 harness_flags=list(HARNESS_FLAGS))
+        return list(args)
+
+    def sidecar_argv(self, cache_port: int, port: int, compile_cache_dir) -> list[str]:
+        """The shipped command's arguments for this deployment: the
+        harness's own, then the configuration's."""
+        argv = ["--cache-server-instance", ",".join(i["instance"] for i in self.instances()),
+                "--cache-server-cluster", f"127.0.0.1:{cache_port}",
+                "--bind-address", "127.0.0.1"]
+        if compile_cache_dir is not None:
+            argv += ["--compile-cache-dir", str(compile_cache_dir)]
+        return argv + ["--port", str(port)] + self.sidecar_args()
+
+    def not_loaded(self, stats: dict) -> list[str]:
+        """The deployment's instances that ``/waf/v1/stats`` does not
+        show serving a rule set."""
+        tenants = stats.get("tenants", {})
+        return [i["instance"] for i in self.instances()
+                if not tenants.get(i["instance"].strip("/"), {}).get("loaded")]
 
     def traffic(self, seed: int):
         gen = load_by_path(self.bench_dir / "generators" / f"{self.mix['generator']}.py")
@@ -393,6 +449,7 @@ def comparisons(cell: Cell, before: dict, after: dict, numbers: dict, on_tpu: bo
     sent_through = after["batcher"]["requests"] - before["batcher"]["requests"]
     out.append(("batcher_requests_minus_attempted", abs(sent_through - numbers["attempted"]), 0))
     out.append(("not_promoted", int(after["serving_mode"] != "promoted"), 0))
+    out.append(("instances_not_loaded", len(cell.not_loaded(after)), 0))
     out.append(("breaker_not_closed", int(after["degraded"]["breaker"]["state"] != "closed"), 0))
     if device_check:
         out.append(("not_on_tpu", int(not on_tpu), 0))
@@ -418,6 +475,11 @@ def run_cell(
     TPU" out of ``correct``, so that a test on the CPU can see what else
     makes it false."""
     cell = Cell(workload)
+    try:
+        extra_args = cell.sidecar_args()  # refused before anything is started
+    except RunFailure as f:
+        emit({"phase": f.phase, "ok": False, "error": f.why, **f.detail})
+        return 1, None
     # The cache server is JAX-free; importing it is also what fails in a
     # directory that holds the benchmark without the program.
     from coraza_kubernetes_operator_tpu.cache import RuleSetCache, RuleSetCacheServer
@@ -448,28 +510,25 @@ def run_cell(
                              log=build_log.read_text(errors="replace").splitlines()[-20:])
         emit({"phase": "native_build", "ok": True, "seconds": round(time.monotonic() - t0, 3)})
 
-        # -- rule set into a cache server, traffic from the seed -------------------
-        text = cell.rules_text(control=control)
+        # -- rule sets into a cache server, traffic from the seed ------------------
         cache = RuleSetCache()
         cache_server = RuleSetCacheServer(cache, host="127.0.0.1", port=0)
         cache_server.start()
-        cache.put(INSTANCE, text)
+        for instance, text in cell.rules_texts(control=control).items():
+            cache.put(instance, text)
 
-        # -- the one chip-holding child: the sidecar, shipped defaults --------------
+        # -- the one chip-holding child: the sidecar as the configuration deploys it -
         env = dict(os.environ, CKO_NATIVE_LIB=str(lib))
-        argv = ["--cache-server-instance", INSTANCE,
-                "--cache-server-cluster", f"127.0.0.1:{cache_server.port}",
-                "--bind-address", "127.0.0.1"]
-        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-            # A fixed path inside the checkout: the path is part of the key.
-            argv += ["--compile-cache-dir", str(WORK / "jax_cache")]
         port = free_port()
+        # A fixed path inside the checkout: the path is part of the key.
+        argv = cell.sidecar_argv(
+            cache_server.port, port,
+            None if os.environ.get("JAX_COMPILATION_CACHE_DIR") else WORK / "jax_cache")
         log_path = work / "sidecar.log"
         t_child = time.monotonic()
         with open(log_path, "wb") as log_fh:
             proc = subprocess.Popen(
-                [sys.executable, "-m", launcher, str(control_dir), "--", *argv,
-                 "--port", str(port)],
+                [sys.executable, "-m", launcher, str(control_dir), "--", *argv],
                 cwd=REPO, env=env, stdin=subprocess.PIPE, stdout=log_fh,
                 stderr=subprocess.STDOUT,
             )
@@ -478,7 +537,9 @@ def run_cell(
 
         sidecar.wait_for("ready", T_READY_S, "/waf/v1/readyz to answer 200",
                          lambda: sidecar.get("/waf/v1/readyz")[0] == 200)
-        emit({"phase": "ready", "ok": True, "seconds": round(time.monotonic() - t_child, 3)})
+        emit({"phase": "ready", "ok": True, "seconds": round(time.monotonic() - t_child, 3),
+              "instances": [i["instance"] for i in cell.instances()],
+              "sidecar_args": extra_args})
 
         def promoted():
             s = sidecar.stats()
@@ -487,10 +548,24 @@ def run_cell(
 
         s = sidecar.wait_for("promotion", T_PROMOTE_S,
                              "serving_mode promoted with compile_cache.inflight 0", promoted)
+
+        def instances_settled():
+            # Loaded, or refused by the sidecar: ``instances_not_loaded``
+            # then fails the run, and waiting longer would not load it.
+            s = sidecar.stats()
+            refused = lambda t: t.get("failed_reloads") or t.get("analyze_rejected")
+            waiting = [k for k in cell.not_loaded(s)
+                       if not refused(s["tenants"].get(k.strip("/"), {}))]
+            return (not waiting and s["compile_cache"]["inflight"] == 0) and s
+
+        s = sidecar.wait_for("instances", T_PROMOTE_S,
+                             "every instance loaded with compile_cache.inflight 0",
+                             instances_settled)
         device = s["device"]
         emit({"phase": "promotion", "ok": True,
               "seconds": round(time.monotonic() - t_child, 3), "device": device,
-              "persistent_dir": s["compile_cache"]["persistent_dir"]})
+              "persistent_dir": s["compile_cache"]["persistent_dir"],
+              "instances_not_loaded": cell.not_loaded(s)})
         on_tpu = bool(device) and device["platform"] == "tpu" \
             and device["count"] >= cell.workload["chips"]
         if not on_tpu and not rehearse_cpu:
@@ -498,6 +573,7 @@ def run_cell(
                              f" {cell.workload['chips']} TPU chip(s)", device=device)
 
         # -- warm: prime pass, then rounds of the timed loop until none mints ------
+        t_prime = time.monotonic()
         n, bad = send_sequential(sidecar, traffic, traffic.prime, "prime")
         sidecar.settle("prime")
         emit({"phase": "prime", "ok": True, "requests": n, "differ": bad,
@@ -545,6 +621,7 @@ def run_cell(
             w["t_end"][0] = max(w["t0"] + seconds, time.perf_counter())
         join(w, "window", sidecar)
         gc.enable()
+        since_prime_s = time.monotonic() - t_prime
         after = sidecar.stats()
         memory = sidecar.command("memory")
         device = after["device"]
@@ -576,11 +653,14 @@ def run_cell(
         return 1, None
     sched = after["scheduler"]
     emit({"phase": "window", **numbers["line"],
+          # against the verdict cache's lifetime from insert, for a mix that repeats
+          "since_prime_s": round(since_prime_s, 3),
           # where the program's adaptive scheduler stood, before and after
           "scheduler": {k: [before["scheduler"].get(k), sched.get(k)] for k in
                         ("lane_delay_ms", "pipeline_depth", "queue_budgets", "retunes_total")}})
     failed_checks = []
-    for name, value, limit in comparisons(cell, before, after, numbers, on_tpu, device_check):
+    compared = comparisons(cell, before, after, numbers, on_tpu, device_check)
+    for name, value, limit in compared:
         ok = value <= limit
         if not ok:
             failed_checks.append(name)
@@ -623,4 +703,11 @@ def run_cell(
                                "idle_gaps": reduced["idle_gaps"]}
     result["metrics"] = metrics
     result["device"] = dev
+    # Every number compared beside its limit: last in the line, and the
+    # last lines of standard error.
+    result["compared"] = {name: {"value": value, "limit": limit}
+                          for name, value, limit in compared}
+    for name, value, limit in compared:
+        print(f"compared {name} value={value} limit={limit}", file=sys.stderr)
+    sys.stderr.flush()
     return 0, result

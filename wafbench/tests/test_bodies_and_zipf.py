@@ -4,19 +4,16 @@ on hand-made ``/waf/v1/stats`` snapshots. The one whole run here (the
 repeat cell and its control, on the CPU at the sample's size) starts
 sidecars: slow, like ``test_served_path.py``."""
 
-import gzip
 import json
 import time
 from collections import Counter
-from pathlib import Path
 
 import pytest
 
-from wafbench import harness, trace_reduce
+from wafbench import harness
 from wafbench.generators.planned_bursts import SALT_TOKEN
 
 BODIES, ZIPF = "crs-bodies.api-2k-c1", "sample.zipf-c2"
-RECORDED = Path(__file__).parent / "recorded_trace.json.gz"
 
 
 def test_discovery_finds_the_bodies_cell():
@@ -35,8 +32,8 @@ def test_discovery_finds_the_bodies_cell():
     wire = t.salted(t.connections[0][0], "c0")
     assert SALT_TOKEN not in wire and wire.count(b"HTTP/1.1\r\n") == 6 and len(wire) <= 16384
     names = {m["name"] for m in cell.metrics("per_layer")}
-    assert {"long_tier_device_ms_per_window", "tier_padding_share",
-            "matcher_device_ms_per_window", "device_idle_share"} <= names
+    assert {"tier_padding_share", "matcher_device_ms_per_window", "device_idle_share",
+            "prefilter_wait_ms_per_window", "assemble_ms_per_window"} <= names
     assert "verdict_cache_hit_share" not in names
     assert "verdict_cache.hits_total" in cell.mix["zero_growth"]
 
@@ -147,30 +144,3 @@ def test_verdict_cache_hit_share():
     assert reader.read(ctx) == pytest.approx(100 * 1120 / 1280)
     assert reader.read({"before": {}, "after": {}, "attempted": 10}) is None
     assert reader.read({**ctx, "attempted": 0}) is None
-
-
-def test_long_tier_device_ms_per_window():
-    reader = harness.Cell(BODIES).reader("long_tier_device_ms_per_window")
-    assert reader.SOURCE == "device_trace"
-    trace = {"module_busy_s": {"jit_cko_eval_post_32x2048(1)": 0.012,
-                               "jit_cko_match_32x2048(2)": 1.02, "jit_cko_match_32x512(3)": 0.3,
-                               "jit_cko_match_16x1024(4)": 0.06},
-             "module_runs": {"jit_cko_eval_post_32x2048(1)": 12, "jit_cko_match_32x2048(2)": 12,
-                             "jit_cko_match_32x512(3)": 12, "jit_cko_match_16x1024(4)": 3}}
-    assert reader.read({"trace": trace}) == pytest.approx(1e3 * 1.08 / 12)
-    assert reader.read({"trace": {}}) is None
-    short = {k: {n: v for n, v in d.items() if "2048" not in n and "1024" not in n}
-             for k, d in trace.items()}
-    assert reader.read({"trace": short}) is None  # no long tier, no post stage counted
-
-
-@pytest.mark.skipif(not RECORDED.exists(), reason="no recorded chip trace in this checkout")
-def test_long_tier_reader_on_the_recorded_chip_trace():
-    """The recorded excerpt predates the shape-named executables and
-    holds no tier wider than 512: the reader finds nothing and does not
-    raise, where ``matcher_device_ms_per_window`` reads a number."""
-    rec = json.loads(gzip.decompress(RECORDED.read_bytes()))
-    ctx = {"trace": trace_reduce.reduce(rec["events"])}
-    cell = harness.Cell(BODIES)
-    assert cell.reader("long_tier_device_ms_per_window").read(ctx) is None
-    assert cell.reader("matcher_device_ms_per_window").read(ctx) > 0

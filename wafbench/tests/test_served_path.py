@@ -47,7 +47,7 @@ def test_last_line_has_the_contracts_keys_and_the_parent_never_imports_jax(trace
     assert rc == 0, err[-2000:]
     last = lines[-1]
     assert KEYS <= set(last)
-    assert set(last) - KEYS <= {"breakdown", "failed_checks"}
+    assert set(last) - KEYS <= {"breakdown", "failed_checks", "compared"}
     assert ("breakdown" in last) == (trace == "1")
     assert last["correct"] is False  # a rehearsal never says true
     assert last["failed_checks"] == ["not_on_tpu"]
@@ -71,6 +71,12 @@ def test_last_line_has_the_contracts_keys_and_the_parent_never_imports_jax(trace
     checks = [ln for ln in lines if "check" in ln]
     assert {"verdicts_that_differ", "growth.compile_cache.misses"} <= {c["check"] for c in checks}
     assert all({"value", "limit", "ok"} <= set(c) for c in checks)
+    # and again, last in the result line and as the last lines of standard error
+    assert list(last)[-1] == "compared"
+    assert last["compared"] == {c["check"]: {"value": c["value"], "limit": c["limit"]} for c in checks}
+    said = [ln for ln in err.splitlines() if ln.startswith("compared ")]
+    assert [ln.split()[1] for ln in said] == [c["check"] for c in checks]
+    assert err.splitlines()[-2].startswith("compared ")  # the test's own JAX line comes after
     assert "NO_JAX_IN_PARENT" in err
 
 

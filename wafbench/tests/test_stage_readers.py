@@ -40,6 +40,7 @@ WANT = {
     "lane_wait_ms_per_req": LANE_WAIT_MS,
     "dispatch_wait_ms_per_window": 0.25 + 2.0 + 0.125,
     "batcher_route_ms_per_window": 0.5 + 0.25,
+    "assemble_ms_per_window": 0.75,
     "tier_enqueue_ms_per_window": 3.0 + 1.0,
     "prefilter_confirm_ms_per_window": 25.0,
     "prefilter_wait_ms_per_window": 20.0,
@@ -87,9 +88,23 @@ def test_post_device_ms_per_window():
     assert matcher.read(ctx(trace=trace)) == pytest.approx(25.0)
 
 
+PREFILTER = {"prefilter_false_positive_share", "prefilter_confirm_ms_per_window",
+             "prefilter_wait_ms_per_window"}
+
+
 def test_the_cells_list_their_stage_metrics():
-    crs = {m["name"] for m in harness.Cell(CELL).metrics("per_layer")}
-    sample = {m["name"] for m in harness.Cell("sample.salted-c2").metrics("per_layer")}
-    assert set(WANT) | {"post_device_ms_per_window"} <= crs
-    assert crs - sample == {"prefilter_false_positive_share", "prefilter_confirm_ms_per_window",
-                            "prefilter_wait_ms_per_window"}
+    """Every cell lists every stage reader; the prefilter's, the cells
+    whose rule set has a prefiltered group (PR 32: the hand-read
+    ``crs-bodies.api-2k-c1`` and ``sample.zipf-c2`` among them)."""
+    bench = harness.Cell(CELL).bench
+    listed = {w["name"]: {m["name"] for m in harness.Cell(w["name"]).metrics("per_layer")}
+              for w in bench["workloads"]}
+    for name, metrics in listed.items():
+        crs = name.startswith("crs-")
+        assert (set(WANT) | {"post_device_ms_per_window"}) - PREFILTER <= metrics, name
+        assert (PREFILTER <= metrics) if crs else not (PREFILTER & metrics), name
+    assert listed[CELL] - listed["sample.salted-c2"] == PREFILTER
+    # the rolling medians since process start and the copy of the matcher's time are gone
+    gone = {"batcher_host_stage_p50_ms", "native_window_p50_ms", "long_tier_device_ms_per_window"}
+    assert not gone & {m["name"] for m in bench["per_layer"]}
+    assert not [g for g in gone if (harness.REPO / "wafbench" / "layer_metrics" / f"{g}.py").exists()]
