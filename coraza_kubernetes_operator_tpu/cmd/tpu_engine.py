@@ -305,6 +305,18 @@ def build_config(argv: list[str] | None = None) -> SidecarConfig:
         " for unlisted tenants (default $CKO_TENANT_WEIGHTS or all 1)",
     )
     p.add_argument(
+        "--trust-tenant-header",
+        action="store_true",
+        help="honor X-Waf-Tenant (filter mode) and per-request/header"
+        " tenant selection (bulk mode): a request is judged by the rule"
+        " set of the --cache-server-instance it names. The listener is"
+        " unauthenticated: enable ONLY behind a proxy that sets or"
+        " strips the header, else anyone who can reach the port can"
+        " probe other tenants' rule sets or pick a lenient one (a WAF"
+        " bypass). Off: the header is ignored and the first instance"
+        " answers everything",
+    )
+    p.add_argument(
         "--lane-delay-ms",
         type=float,
         default=None,
@@ -383,6 +395,7 @@ def build_config(argv: list[str] | None = None) -> SidecarConfig:
         audit_max_bytes=args.audit_max_bytes,
         slo_p99_ms=args.slo_p99_ms,
         tenant_weights=args.tenant_weights,
+        trust_tenant_header=args.trust_tenant_header,
         lane_delay_ms=args.lane_delay_ms,
         adaptive_enabled=not args.disable_adaptive,
         metrics_auth_token=metrics_auth_token or None,

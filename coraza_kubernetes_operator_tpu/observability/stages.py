@@ -152,7 +152,7 @@ class WindowStages:
 
     __slots__ = (
         "window_id", "lane", "n_req", "reads", "spans", "_open", "t_first",
-        "t_last", "aborted_at", "closed", "replies_left",
+        "t_last", "aborted_at", "closed", "replies_left", "ruleset",
     )
 
     def __init__(self, lane: str, n_req: int = 0):
@@ -169,6 +169,9 @@ class WindowStages:
         self.aborted_at: str | None = None
         self.closed = False
         self.replies_left = 0
+        # Rule-set uuid of the engine group the window was formed for
+        # (a frontend that trusts the tenant header); None otherwise.
+        self.ruleset: str | None = None
 
     # -- stamping ----------------------------------------------------------
 
@@ -257,6 +260,8 @@ class WindowStages:
             chain.append((name, t0, t1, _CHAIN_TRACK.get(name, "pipeline")))
             t = t1
         args = {"window_id": self.window_id, "lane": self.lane, "window": self.n_req}
+        if self.ruleset is not None:
+            args["ruleset"] = self.ruleset
         window = (self.window_id, stamped)
         for ctx in contexts:
             t_in = ctx.t_submit or ctx.t_accept

@@ -134,7 +134,8 @@ class _FairQueue:
     Items are the batcher's queue entries: ``(request, tenant, fut,
     span, no_cache, t_submit)`` tuples (cost 1, bucketed by tenant), pre-assembled
     ``_BlobWindow`` windows (cost 1 — one already-packed unit, bucketed
-    under the default tenant), and ``None`` shutdown sentinels (a
+    by its engine group's key; under the default tenant where it names
+    none), and ``None`` shutdown sentinels (a
     control channel with absolute priority so stop() is never stuck
     behind a backlog).
 
@@ -164,7 +165,7 @@ class _FairQueue:
     @staticmethod
     def _tenant_of(item) -> str | None:
         if isinstance(item, _BlobWindow):
-            return None
+            return item.group.key if item.group is not None else None
         return item[1]
 
     def put(self, item) -> None:
@@ -396,6 +397,12 @@ class _BlobWindow:
     # one at submit and closes it when the future is set.
     stages: WindowStages | None = None
     frontend_stages: bool = False
+    # The engine group whose tenants' requests the window holds
+    # (sidecar/tenants.py:EngineGroup; a frontend that trusts the tenant
+    # header keeps one window per group and lane). It pins the engine the
+    # window is judged by. None: the default tenant's engine, resolved
+    # when the window is dispatched.
+    group: object = None
 
 
 @dataclass
@@ -590,8 +597,9 @@ class MicroBatcher:
         self.on_window_fault = None  # (engine, err, requests_fn|None) -> None
         # Verdict cache (sidecar/verdict_cache.py): consulted at
         # batch-assembly time — AFTER the quarantine check (quarantine
-        # wins), and never for trusted-tenant or ``no_cache`` (deadline-
-        # header) rows. Hits resolve their futures during dispatch;
+        # wins), and never for ``no_cache`` (deadline-header) rows; for
+        # every tenant, keyed by the rule set of the engine that serves
+        # the row. Hits resolve their futures during dispatch;
         # misses are deduped in-window (identical fingerprints ride the
         # device once, verdicts scattered to every requester at collect)
         # and inserted when their device verdicts land.
@@ -737,13 +745,13 @@ class MicroBatcher:
             self._drain_deadline_t = t
         return t
 
-    def _drain_eval(self, requests, tenant=None):
+    def _drain_eval(self, requests, tenant=None, group=None):
         """Evaluate drained requests off the device path; None on any
         failure (the caller then fails the future the legacy way)."""
         if time.monotonic() >= self._drain_deadline():
             return None
         try:
-            engine = self._engine_fn(tenant)
+            engine = group.engine if group is not None else self._engine_fn(tenant)
             if engine is None:
                 return None
             if self.drain_evaluate is not None:
@@ -785,7 +793,7 @@ class MicroBatcher:
             log.error("drain blob materialization failed", err)
             reqs = None
         if reqs is not None:
-            verdicts = self._drain_eval(reqs)
+            verdicts = self._drain_eval(reqs, group=bw.group)
         if verdicts is not None:
             self.drained_requests += bw.n_req
             _resolve(bw.fut.set_result, list(verdicts))
@@ -834,13 +842,17 @@ class MicroBatcher:
 
     def submit_window(
         self, blob: bytes | bytearray, n_req: int, spans=None,
-        lane: str = LANE_BULK, stages: WindowStages | None = None
+        lane: str = LANE_BULK, stages: WindowStages | None = None,
+        group=None,
     ) -> Future:
         """Enqueue a pre-assembled ingest window (request blob in the
         ``native.serialize_requests`` format). Dispatched as its own
         window — never coalesced with per-request submissions — on the
-        default tenant's engine pinned at dispatch time (reload-safe
-        draining, same as per-request windows). The Future resolves to
+        engine its ``group`` pins (a frontend that trusts the tenant
+        header forms one window per engine group and lane) or, without
+        one, on the default tenant's engine pinned at dispatch time
+        (reload-safe draining, same as per-request windows). Either way
+        a reload lands on the next window. The Future resolves to
         the window's ``list[Verdict]``. ``spans`` optionally carries one
         flight-recorder context per blob request index (or None); the
         assembling frontend names the ``lane`` it already accumulates
@@ -855,6 +867,7 @@ class MicroBatcher:
             blob=blob, n_req=n_req, fut=fut, spans=spans, lane=lane,
             stages=stages or WindowStages(lane, n_req),
             frontend_stages=stages is not None,
+            group=group,
         )
         bw.stages.begin("queue_wait")
         self._queues[lane].put(bw)
@@ -881,8 +894,8 @@ class MicroBatcher:
 
     def tenant_pending(self, tenant: str | None) -> int:
         """Queued submissions attributed to one tenant across both
-        lanes (tenant-scoped admission control; blob windows ride the
-        default tenant's bucket)."""
+        lanes (tenant-scoped admission control; a blob window rides its
+        engine group's bucket, the default tenant's where it has none)."""
         total = 0
         for q in self._queues.values():
             total += q.tenant_backlog().get(tenant, 0)
@@ -1081,9 +1094,11 @@ class MicroBatcher:
                 # collect stage — it never rides a device window again.
                 quarantined.setdefault(key, []).append(idx)
                 continue
-            if vcache is not None and tenant is None and not _no_cache:
-                # Cache-eligible row: quarantine already said no, the
-                # default tenant serves it, and no deadline rides it.
+            if vcache is not None and not _no_cache:
+                # Cache-eligible row: quarantine already said no and no
+                # deadline rides it. Whatever its tenant, the key names
+                # the rule set of the engine that serves it, so a verdict
+                # of one rule text never answers a request of another.
                 fp = fingerprint(_req)
                 if key not in uuid_cache:
                     uuid_cache[key] = self._cache_uuid(engine)
@@ -1160,12 +1175,18 @@ class MicroBatcher:
         return _WindowRecord(window=window, groups=out_groups, cache_hits=cache_hits)
 
     def _dispatch_blob(self, bw: _BlobWindow) -> _WindowRecord:
-        """Dispatch a pre-assembled ingest window: one engine (default
-        tenant, pinned here — a reload lands on the NEXT window), one
-        ``prepare_blob`` call. Engines without the blob API (test stubs)
-        materialize the requests and evaluate synchronously."""
+        """Dispatch a pre-assembled ingest window: one engine (its
+        group's; the default tenant's where it names none, pinned here —
+        a reload lands on the NEXT window), one ``prepare_blob`` call.
+        Engines without the blob API (test stubs) materialize the
+        requests and evaluate synchronously."""
         rec = bw.stages
-        engine = self._engine_fn(None)
+        group = bw.group
+        if group is None:
+            engine = self._engine_fn(None)
+        else:
+            engine = group.engine
+            rec.ruleset = group.uuid
         registry = self.quarantine
         if registry is not None and not len(registry):
             registry = None

@@ -17,11 +17,18 @@ stdlib event loop otherwise):
   ``cko_tensorize`` as one contiguous blob via
   ``MicroBatcher.submit_window`` — zero per-request ``HttpRequest``
   materialization on the hot path.
+- **Tenants ride the same windows**: with ``trust_tenant_header`` on,
+  a request's ``X-Waf-Tenant`` value is looked up (no lock) in the
+  tenant manager's engine-group table and its bytes go into the window
+  of that *(engine group, lane)*: tenants on one rule text share one
+  engine, so one socket read closes one window per resident engine and
+  lane it touched. An unknown tenant is answered by the failure policy
+  without entering a window.
 - **Python path preserved** for everything the blob path cannot carry:
-  per-request deadlines (X-CKO-Deadline-Ms), tenant routing
-  (trust_tenant_header), the control endpoints, and bulk mode. Those
-  run ``TpuEngineSidecar``'s shared reply builders on worker pools, so
-  verdict mapping cannot drift from the threaded frontend.
+  per-request deadlines (X-CKO-Deadline-Ms), the control endpoints,
+  and bulk mode. Those run ``TpuEngineSidecar``'s shared reply
+  builders on worker pools, so verdict mapping cannot drift from the
+  threaded frontend.
 - **Liveness is never queued**: /waf/v1/healthz and readyz answer
   inline on the event loop; stats/metrics/rollback run on a dedicated
   small control pool separate from the evaluation pool, so a saturated
@@ -310,6 +317,34 @@ def _materialize(
     )
 
 
+class _OpenWindow:
+    """A window under assembly: the requests of one priority lane and,
+    where the tenant header is trusted, of one engine group
+    (sidecar/tenants.py:EngineGroup; None: the default tenant's engine
+    at dispatch). Loop-thread only."""
+
+    __slots__ = ("key", "lane", "group", "buf", "futs", "traces", "stages",
+                 "timer", "tenants")
+
+    def __init__(self, key, lane: str, group, t_read: float):
+        self.key = key
+        self.lane = lane
+        self.group = group
+        self.buf = bytearray()
+        self.futs: list[asyncio.Future] = []
+        # Flight-recorder contexts aligned with futs. Lazily
+        # materialized: None until some request in the window is traced,
+        # so the sampling-off hot path never touches it.
+        self.traces: list | None = None
+        # The window's stage record (observability/stages.py): it starts
+        # at the read that delivered the window's first request.
+        self.stages = WindowStages(lane)
+        self.stages.begin("lane_wait", t_read)
+        self.timer: asyncio.TimerHandle | None = None
+        # Tenant of each request, kept only for the audit log's label.
+        self.tenants: list[str] | None = None
+
+
 class AsyncIngestFrontend:
     """Single-acceptor asyncio HTTP/1.1 frontend for TpuEngineSidecar."""
 
@@ -336,29 +371,15 @@ class AsyncIngestFrontend:
         self._ctl_pool = ThreadPoolExecutor(
             max_workers=4, thread_name_prefix="cko-ingest-ctl"
         )
-        # Windows under assembly, one per priority lane (ISSUE 16):
+        # Windows under assembly, one per (engine group, priority lane):
         # headers-only requests accumulate in the interactive window,
         # bodied ones in the bulk window, so a bodied flood never rides
-        # (or delays) a headers-only window. Loop-thread only — no locks
-        # anywhere on the hot path.
-        self._win_buf = {lane: bytearray() for lane in LANES}
-        self._win_futs: dict[str, list[asyncio.Future]] = {
-            lane: [] for lane in LANES
-        }
-        # Flight-recorder contexts aligned with _win_futs. Lazily
-        # materialized: None until some request in the window is traced,
-        # so the sampling-off hot path never touches it.
-        self._win_traces: dict[str, list | None] = {lane: None for lane in LANES}
-        # The stage record of each lane's window under assembly
-        # (observability/stages.py): made when the window opens.
-        self._win_stages: dict[str, WindowStages | None] = {
-            lane: None for lane in LANES
-        }
+        # (or delays) a headers-only window (ISSUE 16); the group is None
+        # unless the tenant header is trusted, so there are two keys
+        # then. Loop-thread only — no locks anywhere on the hot path.
+        self._open: dict[tuple, _OpenWindow] = {}
         self._stage_stats = sidecar.batcher.stage_stats
         self._tracer = sidecar.tracer
-        self._win_timer: dict[str, asyncio.TimerHandle | None] = {
-            lane: None for lane in LANES
-        }
         self._inflight_windows = 0
         # Counters (written on the loop thread; racy cross-thread reads
         # are fine for metrics).
@@ -372,6 +393,17 @@ class AsyncIngestFrontend:
         self.window_requests_total = 0
         self.lane_windows_total = {lane: 0 for lane in LANES}
         self.python_path_requests_total = 0
+        # Socket reads that delivered a request into a blob window (one
+        # read closes one window per engine group and lane it touched).
+        self.window_reads_total = 0
+        self._last_window_read = 0.0
+        # Requests that carried a trusted tenant header; those of them
+        # that rode a blob window; those naming a tenant the deployment
+        # does not have; blob windows per engine group's key.
+        self.tenant_requests_total = 0
+        self.tenant_blob_requests_total = 0
+        self.tenant_unknown_total = 0
+        self.group_windows_total: dict[str, int] = {}
         self._render_cache: dict = {}
 
     @property
@@ -862,16 +894,16 @@ class AsyncIngestFrontend:
         # Threaded parity: GET bodies are consumed for framing but not
         # evaluated (do_GET calls _handle_filter(b"")).
         eval_body = body if method != b"GET" else b""
+        trusted = sc.config.trust_tenant_header
+        tenant_b = special.get(b"x-waf-tenant") if trusted else None
+        if tenant_b:
+            self.tenant_requests_total += 1
         deadline_s = _deadline_from(special)
-        if deadline_s is not None or sc.config.trust_tenant_header:
-            # Python path: per-request deadlines and tenant routing need
-            # the object pipeline (per-tenant engines, deadline-aware
-            # fallback rescue).
+        if deadline_s is not None:
+            # Python path: a per-request deadline needs the object
+            # pipeline (deadline-aware fallback rescue).
             self.python_path_requests_total += 1
-            tenant = None
-            if sc.config.trust_tenant_header:
-                t = special.get(b"x-waf-tenant")
-                tenant = t.decode("latin-1", "replace") if t else None
+            tenant = tenant_b.decode("latin-1", "replace") if tenant_b else None
             req = _materialize(method, target_s, version, pairs, eval_body, remote_b)
             return (
                 self._spawn(
@@ -879,6 +911,19 @@ class AsyncIngestFrontend:
                 ),
                 None,
             )
+        group = None
+        if trusted:
+            # The tenant's engine group, off the manager's table: one
+            # attribute read and a dict probe, no lock.
+            groups = sc.tenants.groups
+            group = groups.lookup(tenant_b)
+            if group is None:
+                # Unknown tenant, or one with no rule set loaded: the
+                # failure policy answers, and no window is entered.
+                if tenant_b and tenant_b.strip(b"/") not in groups.known:
+                    self.tenant_unknown_total += 1
+                sc._span_degraded(ctx, "unavailable", "unavailable")
+                return self._done(self._finish_trace(sc.unavailable_reply(), ctx)), None
         # -- hot path: slice the wire bytes straight into the native
         # batch-blob record (native.serialize_requests wire format; zero
         # HttpRequest materialization). Lane split at the same point:
@@ -886,7 +931,11 @@ class AsyncIngestFrontend:
         # ones the bulk window.
         t0 = _time.perf_counter()
         lane = LANE_BULK if eval_body else LANE_INTERACTIVE
-        buf = self._win_buf[lane]
+        key = (group, lane)
+        win = self._open.get(key)
+        if win is None:
+            win = self._open[key] = _OpenWindow(key, lane, group, t_read)
+        buf = win.buf
         buf += _pack("<I", len(method))
         buf += method
         buf += _pack("<I", len(target))
@@ -904,36 +953,43 @@ class AsyncIngestFrontend:
         buf += _pack("<I", len(remote_b))
         buf += remote_b
         fut = self._loop.create_future()
-        futs = self._win_futs[lane]
+        futs = win.futs
         futs.append(fut)
-        rec = self._win_stages[lane]
-        if rec is None:
-            # The lane's window opens: its record starts at the read
-            # that delivered its first request.
-            rec = self._win_stages[lane] = WindowStages(lane)
-            rec.begin("lane_wait", t_read)
+        rec = win.stages
         reads = rec.reads
         if not reads or reads[-1][0] != t_read:
             # One stamp per socket read: this request is the first that
             # a new read delivered into the window.
             reads.append([t_read, len(futs) - 1])
+        if t_read != self._last_window_read:
+            # A read's requests are routed one after another, so a new
+            # stamp is a new read.
+            self._last_window_read = t_read
+            self.window_reads_total += 1
+        if tenant_b:
+            self.tenant_blob_requests_total += 1
+        if group is not None and sc.audit is not None:
+            if win.tenants is None:
+                win.tenants = []
+            win.tenants.append(
+                tenant_b.decode("latin-1", "replace").strip("/")
+                if tenant_b else group.key
+            )
         if ctx is not None:
-            if self._win_traces[lane] is None:
-                self._win_traces[lane] = [None] * (len(futs) - 1)
-            self._win_traces[lane].append(ctx)
+            if win.traces is None:
+                win.traces = [None] * (len(futs) - 1)
+            win.traces.append(ctx)
             ctx.event("parse", t_parse, _time.monotonic(), track="frontend")
-        elif self._win_traces[lane] is not None:
-            self._win_traces[lane].append(None)
+        elif win.traces is not None:
+            win.traces.append(None)
         self.parse_s += _time.perf_counter() - t0
         if len(futs) >= sc.config.max_batch_size:
-            self._flush_window(lane)
-        elif self._win_timer[lane] is None:
+            self._flush_window(win)
+        elif win.timer is None:
             # Live per-lane delay (scheduler-tuned): the interactive
             # window closes on its own (typically shorter) timer.
             delay = max(sc.batcher.lane_delay_s[lane], 0.0)
-            self._win_timer[lane] = self._loop.call_later(
-                delay, self._flush_window, lane
-            )
+            win.timer = self._loop.call_later(delay, self._flush_window, win)
         return fut, rec
 
     def _route_api(self, method, path, special, body, query=""):
@@ -1088,35 +1144,30 @@ class AsyncIngestFrontend:
 
     # -- window assembly + dispatch -------------------------------------------
 
-    def _flush_window(self, lane: str | None = None) -> None:
-        if lane is None:  # stop()/halt: close out every lane
-            for each in LANES:
+    def _flush_window(self, win: _OpenWindow | None = None) -> None:
+        if win is None:  # stop()/halt: close out every window
+            for each in list(self._open.values()):
                 self._flush_window(each)
             return
-        timer = self._win_timer[lane]
-        if timer is not None:
-            timer.cancel()
-            self._win_timer[lane] = None
-        futs = self._win_futs[lane]
-        if not futs:
-            return
+        if win.timer is not None:
+            win.timer.cancel()
+            win.timer = None
+        if self._open.get(win.key) is win:
+            del self._open[win.key]
         # Ownership handoff, not a copy: the assembled bytearray itself
-        # rides to the batcher (a fresh one replaces it for the next
-        # window) and reaches C++ through the buffer protocol — the old
-        # bytes() here re-paid every window's bytes once per flush.
-        blob = self._win_buf[lane]
-        spans = self._win_traces[lane]
-        rec = self._win_stages[lane]
-        self._win_futs[lane] = []
-        self._win_buf[lane] = bytearray()
-        self._win_traces[lane] = None
-        self._win_stages[lane] = None
+        # rides to the batcher (the next window of this key opens with a
+        # fresh one) and reaches C++ through the buffer protocol — the
+        # old bytes() here re-paid every window's bytes once per flush.
+        blob, futs, spans, rec, lane = win.buf, win.futs, win.traces, win.stages, win.lane
         rec.close_lane(len(futs))
         self.windows_total += 1
         self.window_requests_total += len(futs)
         self.lane_windows_total[lane] += 1
+        if win.group is not None:
+            counts = self.group_windows_total
+            counts[win.group.key] = counts.get(win.group.key, 0) + 1
         try:
-            self._dispatch_window(blob, futs, spans, lane, rec)
+            self._dispatch_window(blob, futs, spans, lane, rec, win.group, win.tenants)
         except Exception as err:
             # Dispatch containment: a routing bug answers this window
             # 500 instead of leaving futures (and connections) hanging.
@@ -1129,14 +1180,14 @@ class AsyncIngestFrontend:
 
     def _dispatch_window(
         self, blob: bytes | bytearray, futs: list, spans, lane: str,
-        rec: WindowStages,
+        rec: WindowStages, group=None, tenants=None,
     ) -> None:
         """Route one assembled window. Runs on the loop thread — every
         step here is a cheap probe; blocking work goes to the batcher or
         the evaluation pool. A window that is not submitted to the
         batcher leaves the promoted path here (``rec.abort``)."""
         sc = self.sidecar
-        engine = sc.tenants.engine_for(None)
+        engine = group.engine if group is not None else sc.tenants.engine_for(None)
         if engine is None:
             rec.abort(self._stage_stats)
             self._answer_all_traced(
@@ -1165,7 +1216,7 @@ class AsyncIngestFrontend:
             return
         self._inflight_windows += 1
         wfut = sc.batcher.submit_window(
-            blob, len(futs), spans=spans, lane=lane, stages=rec
+            blob, len(futs), spans=spans, lane=lane, stages=rec, group=group
         )
         # Same budget ladder as the threaded bulk path: cold engines get
         # the compile budget; warmed ones the strict timeout plus a
@@ -1178,7 +1229,8 @@ class AsyncIngestFrontend:
         )
         wfut.add_done_callback(
             lambda f: self._call_soon(
-                self._window_done, f, futs, blob, engine, handle, spans, rec
+                self._window_done, f, futs, blob, engine, handle, spans, rec,
+                tenants,
             )
         )
 
@@ -1191,7 +1243,9 @@ class AsyncIngestFrontend:
             futs, spans, self.sidecar.unavailable_reply, "error", "window_timeout"
         )
 
-    def _window_done(self, wfut, futs, blob, engine, handle, spans, rec) -> None:
+    def _window_done(
+        self, wfut, futs, blob, engine, handle, spans, rec, tenants=None
+    ) -> None:
         # The collector left loop_hop running when it set the future; a
         # window it failed is closed already and this stamps nothing.
         rec.next("loop_hop", "reply_write")
@@ -1199,7 +1253,7 @@ class AsyncIngestFrontend:
         handle.cancel()
         sc = self.sidecar
         try:
-            self._window_done_inner(wfut, futs, blob, engine, spans, rec)
+            self._window_done_inner(wfut, futs, blob, engine, spans, rec, tenants)
         except Exception as err:
             log.error("ingest window completion failed", err)
             rec.abort(self._stage_stats)
@@ -1209,7 +1263,9 @@ class AsyncIngestFrontend:
                     f.set_result(reply)
             sc.governor.count("conn_errors_total")
 
-    def _window_done_inner(self, wfut, futs, blob, engine, spans, rec) -> None:
+    def _window_done_inner(
+        self, wfut, futs, blob, engine, spans, rec, tenants=None
+    ) -> None:
         sc = self.sidecar
         if wfut.cancelled():
             self._answer_all(futs, sc.unavailable_reply)
@@ -1235,7 +1291,7 @@ class AsyncIngestFrontend:
                 for f, v in zip(futs, verdicts):
                     if not f.done():
                         f.set_result(sc.verdict_filter_reply(v))
-            self._submit_eval(sc.record_window, engine, blob, verdicts, True)
+            self._submit_eval(sc.record_window, engine, blob, verdicts, True, tenants)
             return
         if isinstance(err, EngineUnavailable):
             self._answer_all_traced(
@@ -1337,4 +1393,15 @@ class AsyncIngestFrontend:
             "lane_windows": dict(self.lane_windows_total),
             "python_path_requests": self.python_path_requests_total,
             "inflight_windows": self._inflight_windows,
+            # Growth over an interval: blob_windows_total over
+            # window_reads_total is the windows one read closes (one per
+            # engine group and lane), tenant_blob_requests_total over
+            # tenant_requests_total the share of trusted tenant requests
+            # that rode them (python_path_requests_total is the rest).
+            "window_reads_total": self.window_reads_total,
+            "blob_windows_total": self.windows_total,
+            "tenant_requests_total": self.tenant_requests_total,
+            "tenant_blob_requests_total": self.tenant_blob_requests_total,
+            "python_path_requests_total": self.python_path_requests_total,
+            "group_blob_windows": dict(self.group_windows_total),
         }

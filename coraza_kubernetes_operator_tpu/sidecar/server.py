@@ -58,6 +58,7 @@ from .degraded import (
     BREAKER_CODES,
     MODE_BROKEN,
     MODE_CODES,
+    MODE_PROMOTED,
     BreakerOpen,
     CircuitBreaker,
     DegradedModeManager,
@@ -1694,8 +1695,41 @@ class TpuEngineSidecar:
         )
 
     def serving_mode(self, tenant: str | None = None) -> str:
-        """cold | fallback | promoted | broken (for the given tenant)."""
-        return self.degraded.mode_for(self.tenants.engine_for(tenant))
+        """cold | fallback | promoted | broken: for the given tenant, or
+        (None) for the sidecar: the default tenant's mode, and
+        ``promoted`` only when every resident engine is (a deployment
+        of several rule sets is not promoted while one of its loaded
+        instances still answers from the host fallback)."""
+        mode = self.degraded.mode_for(self.tenants.engine_for(tenant))
+        if tenant is None and mode == MODE_PROMOTED:
+            for group in self.tenants.groups.groups:
+                other = self.degraded.mode_for(group.engine)
+                if other != MODE_PROMOTED:
+                    return other
+        return mode
+
+    def _tenant_groups_stats(self) -> dict:
+        """The engine groups behind the tenants (tenants on one rule
+        text share one resident engine) and what the async frontend
+        routed to each: its own block, because every key of ``tenants``
+        is an instance."""
+        frontend = self._frontend
+        windows = frontend.group_windows_total if frontend is not None else {}
+        groups = self.tenants.groups.groups
+        return {
+            "trusted": bool(self.config.trust_tenant_header),
+            "resident_engines": len(groups),
+            "unknown_total": frontend.tenant_unknown_total if frontend is not None else 0,
+            "groups": {
+                g.key: {
+                    "uuid": g.uuid,
+                    "tenants": len(g.tenants),
+                    "mode": self.degraded.mode_for(g.engine),
+                    "blob_windows": windows.get(g.key, 0),
+                }
+                for g in groups
+            },
+        }
 
     def _resident_engine_objects(self) -> list:
         """DISTINCT serving engines across tenants (dedupe by identity —
@@ -2356,7 +2390,7 @@ class TpuEngineSidecar:
             timeout = max(0.001, min(timeout, deadline_s - _time.monotonic()))
         # Deadline-header requests bypass the verdict cache: their
         # cancel/rescue dance must observe the unmodified device path
-        # (trusted-tenant requests are excluded inside the batcher).
+        # (a tenant's requests probe it under their engine's rule set).
         fut = self.batcher.submit(
             request,
             tenant=tenant,
@@ -2426,7 +2460,8 @@ class TpuEngineSidecar:
         self._m_requests.inc(len(verdicts) - n_deny, action="allow")
 
     def record_window(
-        self, engine, blob: bytes, verdicts: list[Verdict], counted: bool = False
+        self, engine, blob: bytes, verdicts: list[Verdict], counted: bool = False,
+        tenants: list[str] | None = None,
     ) -> None:
         """Batch accounting for blob-backed windows (bulk fast path and
         async-ingest filter windows): metrics in two increments, audit
@@ -2459,7 +2494,10 @@ class TpuEngineSidecar:
                     status=v.status,
                     interrupted=v.interrupted,
                     matched=[meta.get(rid, {"id": rid}) for rid in v.matched_ids],
-                    tenant=self.tenants.default_tenant or "",
+                    tenant=(
+                        tenants[i] if tenants is not None and i < len(tenants)
+                        else self.tenants.default_tenant or ""
+                    ),
                 )
             )
 
@@ -2772,6 +2810,7 @@ class TpuEngineSidecar:
             },
             "resident_engines": self.tenants.resident_engines(),
             "engine_dedup_hits": self.tenants.engine_dedup_hits,
+            "tenant_groups": self._tenant_groups_stats(),
             "automata": self._automata_summary(),
             # Per window the matcher tiers launched, their cells (rows x
             # width) and real bytes; bodied requests by body processor.

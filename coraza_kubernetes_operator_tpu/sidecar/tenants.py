@@ -77,6 +77,64 @@ class SharedEngineFactory:
             return len(self._by_hash)
 
 
+class EngineGroup:
+    """The tenants that one resident engine serves (tenants on one rule
+    text share it: 32 tenants over 4 texts are 4 groups). ``key`` is the
+    first of them in deployment order; ``uuid`` the rule-set uuid that
+    tenant serves the engine under (the verdict cache's key component,
+    None for a seeded engine). A group pins its engine: a window formed
+    under it is judged by the rule text its tenants served when the
+    request was read, whatever reloads meanwhile."""
+
+    __slots__ = ("key", "engine", "uuid", "tenants")
+
+    def __init__(self, key: str, engine, uuid):
+        self.key = key
+        self.engine = engine
+        self.uuid = uuid
+        self.tenants: list[str] = []
+
+
+class TenantGroups:
+    """An immutable tenant -> engine-group table. The ingest hot path
+    reads ``TenantManager.groups`` (one attribute read, no lock) and
+    looks a request's raw ``X-Waf-Tenant`` bytes up in ``by_header``; the
+    manager swaps the whole table on every engine transition."""
+
+    __slots__ = ("by_header", "by_engine", "groups", "known", "default")
+
+    def __init__(self, reloaders: dict, default_tenant: str | None):
+        self.by_header: dict[bytes, EngineGroup] = {}
+        self.by_engine: dict[int, EngineGroup] = {}
+        self.known = frozenset(k.encode("latin-1", "replace") for k in reloaders)
+        for key, r in reloaders.items():
+            engine = r.engine
+            if engine is None:
+                continue
+            group = self.by_engine.get(id(engine))
+            if group is None:
+                group = self.by_engine[id(engine)] = EngineGroup(
+                    key, engine, r.current_uuid
+                )
+            group.tenants.append(key)
+            self.by_header[key.encode("latin-1", "replace")] = group
+        self.groups = tuple(self.by_engine.values())
+        self.default = self.by_header.get(
+            (default_tenant or "").encode("latin-1", "replace")
+        )
+
+    def lookup(self, header: bytes | None) -> EngineGroup | None:
+        """The group serving the tenant a header value names (None or
+        empty: the default tenant); None for a tenant that is unknown or
+        has no rule set loaded."""
+        if not header:
+            return self.default
+        group = self.by_header.get(header)
+        if group is None and (header[:1] == b"/" or header[-1:] == b"/"):
+            group = self.by_header.get(header.strip(b"/"))
+        return group
+
+
 class TenantManager:
     """Owns one RuleReloader per tenant key; polls them on a shared thread."""
 
@@ -104,16 +162,22 @@ class TenantManager:
             if isinstance(engine_factory, SharedEngineFactory)
             else SharedEngineFactory(engine_factory)
         )
-        self._on_swap = on_swap  # forwarded to every tenant's reloader
+        self._on_swap = on_swap  # forwarded after every tenant's swap
+        # Tenant -> engine-group table for the ingest hot path: rebuilt
+        # whole, under its own lock, whenever an engine is seeded or
+        # swapped; read without any lock.
+        self._groups_lock = threading.Lock()
+        self.default_tenant = tenant_keys[0].strip("/") if tenant_keys else None
+        self.groups = TenantGroups({}, self.default_tenant)
         self._on_persist = on_persist  # likewise (durable-state snapshot)
         # Staged-rollout manager (sidecar/rollout.py), shared across
         # tenants: one shadow-mirror router and one set of outcome
         # counters; each tenant's reloader stages its own candidates.
         self._rollout = rollout
+        # default_tenant (above) is normalized like the reloader keys, so
+        # the two never diverge.
         for key in tenant_keys:
             self.add(key)
-        # Normalized like the reloader keys, so the two never diverge.
-        self.default_tenant = tenant_keys[0].strip("/") if tenant_keys else None
 
     def add(self, key: str) -> None:
         key = key.strip("/")
@@ -125,15 +189,31 @@ class TenantManager:
                 instance_key=key,
                 poll_interval_s=self.poll_interval_s,
                 engine_factory=self._engine_factory,
-                on_swap=self._on_swap,
+                on_swap=self._swapped,
                 rollout=self._rollout,
                 on_persist=self._on_persist,
             )
+        self._regroup()
 
     def seed(self, key: str, engine: WafEngine) -> None:
         self.add(key)
         with self._lock:
             self._reloaders[key.strip("/")].seed(engine)
+        self._regroup()
+
+    def _regroup(self) -> None:
+        with self._groups_lock:
+            with self._lock:
+                reloaders = dict(self._reloaders)
+            self.groups = TenantGroups(reloaders, self.default_tenant)
+
+    def _swapped(self, engine) -> None:
+        """Every reloader's ``on_swap``: the table first, so that the
+        caller's hook (and every request read after it) sees the engine
+        that swapped in."""
+        self._regroup()
+        if self._on_swap is not None:
+            self._on_swap(engine)
 
     @property
     def tenants(self) -> list[str]:
@@ -149,10 +229,13 @@ class TenantManager:
     def ruleset_uuid_for(self, engine) -> str | None:
         """The ruleset uuid some tenant currently serves ``engine``
         under, or None (seeded/unknown engines). Cache-key component for
-        the verdict cache (sidecar/verdict_cache.py); O(tenants) scan,
-        memoized per window by the batcher."""
+        the verdict cache (sidecar/verdict_cache.py): read off the group
+        table; the scan is for an engine seeded behind the manager."""
         if engine is None:
             return None
+        group = self.groups.by_engine.get(id(engine))
+        if group is not None and group.engine is engine:
+            return group.uuid
         with self._lock:
             reloaders = list(self._reloaders.values())
         for r in reloaders:
@@ -168,9 +251,7 @@ class TenantManager:
     def resident_engines(self) -> int:
         """Count of DISTINCT engine objects across tenants (dedupe: 32
         tenants on 4 rulesets report 4)."""
-        with self._lock:
-            reloaders = list(self._reloaders.values())
-        return len({id(r.engine) for r in reloaders if r.engine is not None})
+        return len(self.groups.groups)
 
     @property
     def engine_dedup_hits(self) -> int:

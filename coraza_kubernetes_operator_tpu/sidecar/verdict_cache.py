@@ -17,9 +17,12 @@ forced rollback, warm restore) — the uuid key component is defense in
 depth, not the primary correctness mechanism.
 
 Never consulted for quarantine-matched rows (quarantine wins — the
-batcher checks the registry first), deadline-header requests, or
-trusted-tenant requests (both ride the Python object path with
-``no_cache``/tenant markers). A fingerprint quarantined AFTER its
+batcher checks the registry first) or deadline-header requests (they
+ride the Python object path marked ``no_cache``). The batcher leaves
+the ``tenant`` component None and names, in ``ruleset_uuid``, the rule
+set of the engine that serves the row: tenants on one rule text share
+an engine and may share its verdicts, tenants on different texts never
+can. A fingerprint quarantined AFTER its
 verdict was cached is evicted via ``evict_fingerprint`` — a cached
 allow must not outlive its quarantine.
 

@@ -3,7 +3,8 @@
     python3 hack/stage_probe.py --workload sample.salted-c2 --seed 7 \
         --windows off,profile,off,profile --seconds 5 [--trace-sample-rate 1]
 
-Starts the cell's sidecar as ``wafbench.harness`` does (same rule set,
+Starts the cell's sidecar as ``wafbench.harness`` does (the rule texts
+of every instance the configuration states and its own ``sidecar_args``,
 same traffic, same warm-up; the pieces are the harness's own), but from
 the SHIPPED command alone — ``python -m …cmd.tpu_engine`` with
 ``--metrics-auth-token-file`` — so that the profiler is driven through
@@ -22,7 +23,10 @@ of ``automata.prefilter`` (``native_hits`` against ``hits``) and of
 every warm window launched from its engine's table; ``launch_plan_misses``
 and ``misses`` flat). The ``warm`` line carries the engine's matcher
 layout from ``automata`` (``flat_bins``, ``flat_slots``, ``flat_groups``,
-``per_bank_kernels``). What
+``per_bank_kernels``) and, from the ``frontend`` counters' growth over
+the last warm round, ``tenant_blob_path_share`` and
+``engine_windows_per_read`` (also on every window's line, with the
+counters themselves under ``frontend``). What
 ``wafbench.run --trace 1`` reports for the same stages also holds its
 traced intervals; this is the untraced reading to hold it against.
 The result is no benchmark line: nothing is checked for correctness
@@ -53,6 +57,17 @@ LAUNCH_COUNTERS = ("launch_plan_hits", "launch_plan_misses", "device_windows",
 # Where the engine scans its dense-DFA blocks (``automata_summary``):
 # fused flat bins, and the blocks left on one kernel a bank.
 MATCHER_LAYOUT = ("flat_bins", "flat_slots", "flat_groups", "per_bank_kernels")
+# Growth of these says whether tenant requests rode the blob windows and
+# how many windows one socket read closed (sidecar/ingest.py); the two
+# benchmark metrics that read them give the ratios.
+FRONTEND_COUNTERS = ("window_reads_total", "blob_windows_total", "tenant_requests_total",
+                     "tenant_blob_requests_total", "python_path_requests_total")
+FRONTEND_METRICS = ("tenant_blob_path_share", "engine_windows_per_read")
+
+
+def frontend_ratios(cell, before: dict, after: dict) -> dict:
+    ctx = {"before": before, "after": after}
+    return {m: cell.reader(m).read(ctx) for m in FRONTEND_METRICS}
 
 
 def stage_ms(before: dict, after: dict) -> dict:
@@ -101,21 +116,22 @@ def main() -> int:
     cache = RuleSetCache()
     server = RuleSetCacheServer(cache, host="127.0.0.1", port=0)
     server.start()
-    cache.put(harness.INSTANCE, cell.rules_text())
+    for instance, text in cell.rules_texts().items():
+        cache.put(instance, text)
     token_file = work / "token"
     token_file.write_text(TOKEN + "\n")
-    argv = ["--cache-server-instance", harness.INSTANCE,
-            "--cache-server-cluster", f"127.0.0.1:{server.port}",
-            "--bind-address", "127.0.0.1", "--metrics-auth-token-file", str(token_file),
-            "--trace-sample-rate", str(args.trace_sample_rate)]
-    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        argv += ["--compile-cache-dir", str(harness.WORK / "jax_cache")]
     port = harness.free_port()
+    # The deployment's own command line (instances, the harness's five
+    # flags, the configuration's sidecar_args), then the probe's two.
+    argv = cell.sidecar_argv(
+        server.port, port,
+        None if os.environ.get("JAX_COMPILATION_CACHE_DIR") else harness.WORK / "jax_cache")
+    argv += ["--metrics-auth-token-file", str(token_file),
+             "--trace-sample-rate", str(args.trace_sample_rate)]
     log_path = work / "sidecar.log"
     with open(log_path, "wb") as fh:
         proc = subprocess.Popen(
-            [sys.executable, "-m", "coraza_kubernetes_operator_tpu.cmd.tpu_engine", *argv,
-             "--port", str(port)],
+            [sys.executable, "-m", "coraza_kubernetes_operator_tpu.cmd.tpu_engine", *argv],
             cwd=REPO, env=dict(os.environ, CKO_NATIVE_LIB=str(lib)), stdout=fh,
             stderr=subprocess.STDOUT)
     sc = harness.Sidecar(port, proc, log_path, work)
@@ -125,7 +141,7 @@ def main() -> int:
                     lambda: sc.get("/waf/v1/readyz")[0] == 200)
         sc.wait_for("promotion", harness.T_PROMOTE_S, "promotion",
                     lambda: (s := sc.stats())["serving_mode"] == "promoted"
-                    and s["compile_cache"]["inflight"] == 0)
+                    and s["compile_cache"]["inflight"] == 0 and not cell.not_loaded(s))
         harness.send_sequential(sc, traffic, traffic.prime, "prime")
         sc.settle("prime")
         for i in range(harness.WARM_ROUNDS_MAX):
@@ -137,7 +153,10 @@ def main() -> int:
                 break
         harness.emit({"phase": "warm", "device": after["device"],
                       "sample_rate": after["tracing"]["sample_rate"],
-                      "automata": {k: after["automata"].get(k) for k in MATCHER_LAYOUT}})
+                      "automata": {k: after["automata"].get(k) for k in MATCHER_LAYOUT},
+                      "instances": len(cell.instances()),
+                      "resident_engines": after["resident_engines"],
+                      **frontend_ratios(cell, before, after)})
         for k, mode in enumerate(args.windows.split(",")):
             trace_dir = work / f"trace{k}"
             before = sc.stats()
@@ -162,7 +181,10 @@ def main() -> int:
                         prefilter={k: v - before["automata"]["prefilter"].get(k, 0) for k, v
                                    in after["automata"]["prefilter"].items()},
                         compile_cache={k: after["compile_cache"][k] - before["compile_cache"][k]
-                                       for k in LAUNCH_COUNTERS})
+                                       for k in LAUNCH_COUNTERS},
+                        frontend={**{k: after["frontend"].get(k, 0) - before["frontend"].get(k, 0)
+                                     for k in FRONTEND_COUNTERS},
+                                  **frontend_ratios(cell, before, after)})
             harness.emit(line)
         proc.send_signal(signal.SIGTERM)
         return proc.wait(timeout=harness.T_EXIT_S)

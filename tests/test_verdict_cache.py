@@ -244,18 +244,23 @@ def test_in_window_dedup_scatters_to_all_requesters():
         b.stop()
 
 
-def test_trusted_tenant_and_deadline_rows_bypass_cache():
+def test_tenant_rows_probe_their_engines_rule_set_and_deadline_rows_bypass_cache():
+    """A tenant's rows are cache-eligible like the default tenant's,
+    under the rule set of the engine that serves them (PR 33: before,
+    only tenant None was); a deadline row still never touches it."""
     eng = _CountingEngine()
     b = _batcher(eng)
     b.start()
     try:
         for _ in range(2):
             b.submit(HttpRequest(uri="/t"), tenant="ns/name").result(timeout=10)
+        vc = b.verdict_cache
+        assert eng.rows_evaluated == 1  # the repeat was a hit
+        assert vc.hits_total == 1 and vc.misses_total == 1 and len(vc) == 1
         for _ in range(2):
             b.submit(HttpRequest(uri="/d"), no_cache=True).result(timeout=10)
-        assert eng.rows_evaluated == 4  # every row rode the device
-        vc = b.verdict_cache
-        assert vc.hits_total == 0 and vc.misses_total == 0 and len(vc) == 0
+        assert eng.rows_evaluated == 3  # every deadline row rode the device
+        assert vc.hits_total == 1 and vc.misses_total == 1 and len(vc) == 1
     finally:
         b.stop()
 
