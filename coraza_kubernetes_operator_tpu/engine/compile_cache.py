@@ -153,8 +153,7 @@ def model_signature(model) -> tuple:
 def stage_key(jitted, model_sig: tuple, operands: tuple, static_kwargs: dict) -> tuple:
     """The executable-cache key of ``jitted(model, *operands,
     **static_kwargs)``, composed from the model's signature and a walk
-    over the window's own operands only. Equal to
-    ``ExecutableCache.key_for(jitted, (model,) + operands, static_kwargs)``."""
+    over the window's own operands only."""
     name = getattr(jitted, "__name__", None) or str(jitted)
     return (
         name,
@@ -275,33 +274,6 @@ class ExecutableCache:
         )
         return compiled
 
-    def key_for(self, jitted, args: tuple, static_kwargs: dict) -> tuple:
-        """The key of ``jitted(*args, **static_kwargs)``: the first
-        argument (the model: constant between engine swaps) and the rest
-        (the window's operands) are signed apart, so that a caller that
-        keeps the first half composes the same key from the second
-        alone (:func:`stage_key`)."""
-        return stage_key(
-            jitted, batch_signature(args[:1], ()), args[1:], static_kwargs
-        )
-
-    def call(self, jitted, args: tuple, static_kwargs: dict, dyn_kwargs: dict):
-        """Evaluate ``jitted(*args, **static_kwargs, **dyn_kwargs)``
-        through the executable cache: AOT-compile on first sight of the
-        signature, then call the compiled object directly (tables and
-        batch tensors are runtime operands — new values at the same
-        shapes never retrace)."""
-        key = self.key_for(jitted, args + (dyn_kwargs.get("cached"),), static_kwargs)
-        compiled = self._lookup(key, count_hit=False)
-        was_resident = compiled is not None
-        if compiled is None:
-            compiled = self._compile(
-                key, jitted, args, {**static_kwargs, **dyn_kwargs}
-            )
-        return self.run(
-            key, compiled, jitted, args, static_kwargs, dyn_kwargs, was_resident
-        )
-
     def run(
         self,
         key: tuple,
@@ -309,15 +281,13 @@ class ExecutableCache:
         jitted,
         args: tuple,
         static_kwargs: dict,
-        dyn_kwargs: dict,
-        was_resident: bool = True,
     ):
         """Call a resolved executable (``WafEngine`` keeps them per
         window shape and calls here directly; nothing walks ``args``).
         Falls back to the plain jit dispatch on any AOT argument
         rejection (counted, logged once per key)."""
         try:
-            out = compiled(*args, **dyn_kwargs)
+            out = compiled(*args)
         except (TypeError, ValueError) as err:
             with self._lock:
                 self.bypasses += 1
@@ -325,13 +295,12 @@ class ExecutableCache:
                 self._bypassed_keys.add(key)
             if first:  # once per key: a persistent rejection must not
                 log.error("AOT call bypassed to jit dispatch", err)  # flood logs
-            return jitted(*args, **static_kwargs, **dyn_kwargs)
-        if was_resident:
-            # Count the hit only AFTER the compiled call succeeded: a
-            # persistently-rejecting signature must read as bypasses, not
-            # as a 100%-hit cache, on the cko_compile_cache_* gauges.
-            with self._lock:
-                self.hits += 1
+            return jitted(*args, **static_kwargs)
+        # Count the hit only AFTER the compiled call succeeded: a
+        # persistently-rejecting signature must read as bypasses, not
+        # as a 100%-hit cache, on the cko_compile_cache_* gauges.
+        with self._lock:
+            self.hits += 1
         return out
 
     def warm(
@@ -339,16 +308,18 @@ class ExecutableCache:
         jitted,
         args: tuple,
         static_kwargs: dict,
-        dyn_kwargs: dict,
         key: tuple | None = None,
     ) -> bool:
-        """AOT-lower and compile WITHOUT executing (the promotion-probe
-        pre-warm). Returns True when this call minted a new executable."""
+        """AOT-lower and compile ``jitted(*args, **static_kwargs)``
+        WITHOUT executing (the promotion-probe pre-warm; tables and
+        window slabs are runtime operands — new values at the same
+        shapes never retrace). Returns True when this call minted a new
+        executable."""
         if key is None:
-            key = self.key_for(jitted, args + (dyn_kwargs.get("cached"),), static_kwargs)
+            key = stage_key(jitted, model_signature(args[0]), args[1:], static_kwargs)
         if self._lookup(key, count_hit=False) is not None:
             return False
-        self._compile(key, jitted, args, {**static_kwargs, **dyn_kwargs})
+        self._compile(key, jitted, args, static_kwargs)
         return True
 
     # -- introspection ------------------------------------------------------

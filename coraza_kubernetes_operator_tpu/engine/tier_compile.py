@@ -40,22 +40,20 @@ from .compile_cache import EXEC_CACHE, model_signature, stage_key
 
 log = get_logger("engine.tier_compile")
 
-# A compile spec is (label, cost, jitted, args, static_kwargs, dyn_kwargs):
+# A compile spec is (label, cost, jitted, args, static_kwargs):
 # label is the stable human name ("post", "match:1024x64"), cost the
 # smallest-first sort key (~rows x width; 0 for the post stage).
 
 
 def spec_key(spec, model_sig: tuple | None = None) -> tuple:
-    """The EXEC_CACHE key a spec's dispatch will use (same composition
-    as ``ExecutableCache.call``/``warm``: the ``cached`` dyn kwarg rides
-    the key because its shapes change the trace). This walks the spec's
+    """The EXEC_CACHE key a spec's dispatch will use. This walks the spec's
     first argument, the whole model; a caller that keeps that half
     (``compile_cache.model_signature``; ``WafEngine`` does, beside its
     model) passes it and pays for the window's operands alone."""
-    _label, _cost, jitted, args, statics, dyn = spec
+    _label, _cost, jitted, args, statics = spec
     if model_sig is None:
         model_sig = model_signature(args[0])
-    return stage_key(jitted, model_sig, args[1:] + (dyn.get("cached"),), statics)
+    return stage_key(jitted, model_sig, args[1:], statics)
 
 
 class TierCompiler:
@@ -110,10 +108,10 @@ class TierCompiler:
         return EXEC_CACHE._lookup(key, count_hit=False) is not None
 
     def _compile_one(self, key: tuple, spec) -> bool:
-        label, _cost, jitted, args, statics, dyn = spec
+        label, _cost, jitted, args, statics = spec
         t0 = time.perf_counter()
         try:
-            minted = EXEC_CACHE.warm(jitted, args, statics, dyn, key=key)
+            minted = EXEC_CACHE.warm(jitted, args, statics, key=key)
         finally:
             with self._lock:
                 self._inflight.pop(key, None)

@@ -160,9 +160,11 @@ def tier_tensors(tensors, kind_lut=None, cache=None):
     tensorizer — both produce identical row layouts); output is
     ``(tiers, numvals, masks)`` where tiers is a tuple of per-tier
     9-tuples ``(data, lengths, kind1, kind2, kind3, req_id, vdata,
-    vlengths, uid)``: the first, second and last three go to the tier's
-    ``match_tier_packed`` and the kinds, req_id and uid to the window's
-    ``eval_post_tiered`` (``WafEngine._tier_specs``). masks is the
+    vlengths, uid)``: ``data``, ``lengths``, ``vdata`` and ``vlengths``
+    go to the tier's ``match_tier_packed`` in the tier's match slab and
+    the kinds, req_id and uid to the window's ``eval_post_tiered`` in
+    its post slab (``native/arena.py:stage_window`` lays them out by
+    the layout the native path's arena stages in). masks is the
     aligned static block-bitmask tuple (entries None when kind_lut is
     absent).
 
@@ -405,10 +407,11 @@ class InFlightBatch:
     device_s: float = 0.0
     decode_s: float = 0.0
     assemble_s: float = 0.0
-    # Staging-arena lease backing this window's tier tensors (tiered
-    # native path only). collect() releases it after device_get — the
-    # device has consumed the host buffers by then, so the arena may
-    # recycle them into the next window.
+    # The slabs backing this window's operands (native/arena.py: the
+    # tiered native path's arena lease, or the set ``stage_window`` laid
+    # a Python-tensorized window out in). collect() releases it after
+    # device_get — the device has consumed the host slabs by then, so
+    # the arena may recycle them into the next window.
     arena_lease: object = None
     # An injected readback hang (testing/faults.py) is in progress.
     hung: bool = False
@@ -560,7 +563,9 @@ class WafEngine:
         # tiers launched, their cells (rows x width) and the bytes in
         # them that are not padding (``tiering_summary``); bodied
         # requests the Python extractor read (``body_summary``).
-        self._tiering = {"windows": 0, "tiers": 0, "cells": 0, "real_bytes": 0}
+        self._tiering = {
+            "windows": 0, "tiers": 0, "cells": 0, "real_bytes": 0, "host_operands": 0,
+        }
         self._bodies = np.zeros(len(BODY_COUNTERS), dtype=np.int64)
         # Host-tier-path helpers: _dev_col_of[orig_gid] = device hit
         # column (inverse of model.group_order), and per matcher block
@@ -806,19 +811,18 @@ class WafEngine:
 
     def _enqueue(self, rec, since: int, n_live: int, tensors) -> InFlightBatch:
         """The device half shared by ``prepare`` and ``prepare_blob``:
-        enqueue the assembled batch, hand it its staging lease, and read
+        enqueue the assembled batch from its staging lease, and read
         its host-stage sums off the record (spans from ``since`` on)."""
         tiers, numvals, masks, cached, mkeys, lease = tensors
         try:
             inflight = self._dispatch_tiers(
                 tiers, numvals, n_live, masks=masks, cached=cached,
-                miss_keys=mkeys, rec=rec,
+                miss_keys=mkeys, rec=rec, staged=lease,
             )
         except BaseException:
             if lease is not None:
                 lease.release()
             raise
-        inflight.arena_lease = lease
         inflight.assemble_s = rec.total(("assemble",), since)
         inflight.host_s = rec.total(HOST_STAGES, since)
         return inflight
@@ -1083,10 +1087,20 @@ class WafEngine:
         pairs)`` where pairs is the per-tier ``(kind1, kind2, kind3,
         req_id, uid)`` tuple the post stage consumes.
 
-        The post spec's hit arrays are zero-filled PLACEHOLDERS: only
+        Which operands travel where (``models/slab.py`` is the one
+        layout; the specs take their signature from it, as the staging
+        arena takes its buffers): a matcher's one window operand is its
+        tier's match slab, ``data``, ``vdata``, ``lengths`` and
+        ``vlengths`` in one ``uint8`` block; the post stage's are the
+        tiers' packed hit rows and the window's post slab, every tier's
+        pair rows, ``numvals`` and the ``cached`` blocks in one ``int32``
+        block whose ``layout`` is a static argument.
+
+        The window operands are zero-filled PLACEHOLDERS: only
         shapes/dtypes enter the executable-cache key and the lowered
         program, so warming with zeros mints exactly the executable the
-        real dispatch calls with live matcher output."""
+        real dispatch calls with live slabs and matcher output."""
+        from ..models.slab import match_slab_shape, post_layout, post_slab_words
         from ..models.waf_model import stage_executable
 
         if masks is None:
@@ -1102,29 +1116,29 @@ class WafEngine:
         match_specs = []
         for t, mask in zip(tiers, masks):
             u, length = t[0].shape
+            slab = np.zeros(match_slab_shape(u, length, t[6].shape[0]), dtype=np.uint8)
             match_specs.append(
                 (
                     f"match:{u}x{length}",
                     float(u) * float(length),
                     stage_executable("match", f"{u}x{length}"),
-                    (self.model, t[0], t[1], t[6], t[7]),
+                    (self.model, slab),
                     {"mask": mask},
-                    {},
                 )
             )
         pairs = self._tier_pairs(tiers)
         ph_hits = tuple(
             np.zeros((t[0].shape[0], pb), dtype=np.uint8) for t in tiers
         )
+        layout = post_layout(tiers, numvals, cached)
         post_spec = (
             "post",
             0.0,  # sorts first: every verdict needs the post stage
             stage_executable(
                 "eval_post", "_".join(f"{t[0].shape[0]}x{t[0].shape[1]}" for t in tiers)
             ),
-            (self.model, ph_hits, pairs, numvals),
-            {"max_phase": max_phase},
-            {"cached": cached},
+            (self.model, ph_hits, np.zeros(post_slab_words(layout), dtype=np.int32)),
+            {"max_phase": max_phase, "layout": layout},
         )
         return match_specs, post_spec, pairs
 
@@ -1138,6 +1152,7 @@ class WafEngine:
         cached=None,
         miss_keys=None,
         rec=None,
+        staged=None,
     ) -> InFlightBatch:
         """Enqueue one tiered batch (no host sync on the device path)
         and return the in-flight handle. The single dispatch site shared
@@ -1155,7 +1170,25 @@ class WafEngine:
         their compiles land — per-tier degraded-mode promotion. Both
         paths are bit-identical: packbits over the group-hit columns is
         lossless and the host twins are differential-tested against the
-        device stages."""
+        device stages.
+
+        What travels, and when (a host array handed to a launch is one
+        transfer; their count is a window's fixed cost): ``staged`` is
+        the window laid out in slabs (``native/arena.py``: the native
+        path's lease; a window of the Python tensorizer is staged here,
+        by copy), and ``tiers``, ``numvals``, ``cached`` are its views.
+        Inside ``tier_enqueue`` each matcher is launched on its tier's
+        match slab, one operand. ``post_enqueue`` hands the post
+        executable the matchers' hit rows (device arrays, but for the
+        tiers whose rows the confirm repacked or a host twin computed)
+        and the post slab as its one host operand. The slab is NOT put
+        on the device early, though nothing in it waits for a matcher:
+        a ``jax.device_put`` of its own costs more host time than an
+        operand of a launch that happens anyway (0.27 against 0.17 ms
+        alone, and the gap grows beside a second lane; PERF.md §6,
+        PR 36), and in the host-bound cells nothing hides it.
+        ``tiering.host_operands`` counts them all: tiers + 1 a window,
+        and one more a repacked tier."""
         from ..testing.faults import on_device_dispatch
 
         if rec is None:
@@ -1169,6 +1202,11 @@ class WafEngine:
             on_device_dispatch(warmed=self.warmed)
             if masks is None:
                 masks = (None,) * len(tiers)
+            if staged is None:
+                from ..native.arena import stage_window
+
+                staged = stage_window(tiers, numvals, cached)
+                tiers, numvals, cached = staged.tiers, staged.numvals, staged.cached
             counts = self._tiering
             counts["windows"] += 1
             for tier in tiers:
@@ -1182,16 +1220,21 @@ class WafEngine:
             device = True
             tier_hits = []
             from_device = []
-            for stage, tier, mask in zip(match_stages, tiers, masks):
-                hits = self._launch(
-                    stage, (model, tier[0], tier[1], tier[6], tier[7]), {"mask": mask}, {}
-                )
+            for stage, tier, slab, mask in zip(
+                match_stages, tiers, staged.match_slabs, masks
+            ):
+                hits = self._launch(stage, (model, slab))
                 from_device.append(hits is not None)
                 if hits is None:
                     device = False
                     hits = self._host_tier_hits(tier, mask)
                 tier_hits.append(hits)
             tier_hits = tuple(tier_hits)
+            host_operands = sum(from_device)
+            post_slab = None
+            if post_stage[2] is not None:  # resident: the device answers
+                post_slab = staged.post_slab
+                host_operands += 1
         # Prefilter confirm (two-level automata): device matcher rows for
         # prefiltered groups are OVER-approximate — re-check positives
         # against the exact DFAs and clear the false ones before anything
@@ -1204,18 +1247,15 @@ class WafEngine:
         # shapes/bit layout, so a mixed window still shares the one post
         # executable.
         with rec.stage("post_enqueue"):
-            pairs = self._tier_pairs(tiers)
-            packed = self._launch(
-                post_stage,
-                (model, tier_hits, pairs, numvals),
-                {"max_phase": max_phase},
-                {"cached": cached},
-            )
-            if packed is None:
+            if post_slab is None:
                 device = False
                 packed = self._host_post(
-                    tier_hits, pairs, numvals, max_phase, cached
+                    tier_hits, self._tier_pairs(tiers), numvals, max_phase, cached
                 )
+            else:
+                host_operands += sum(isinstance(hp, np.ndarray) for hp in tier_hits)
+                packed = self._launch(post_stage, (model, tier_hits, post_slab))
+            counts["host_operands"] += host_operands
         return InFlightBatch(
             out=(packed, tier_hits) if cached is not None else packed,
             n_live=n_requests,
@@ -1225,13 +1265,14 @@ class WafEngine:
             cache_pop=cached is not None,
             device=device,
             stages=rec,
+            arena_lease=staged,
         )
 
     def _resolve_launch(self, tiers, numvals, max_phase, masks, cached):
         """What a window of this shape launches: ``(match_stages,
-        post_stage)``, each stage ``(key, jitted, compiled)`` with
-        ``compiled`` None where the executable is not resident (lazy
-        mode: the host twin answers that stage).
+        post_stage)``, each stage ``(key, jitted, compiled, statics)``
+        with ``compiled`` None where the executable is not resident
+        (lazy mode: the host twin answers that stage).
 
         Resolved once per (model, window shape): the window's signature
         — shapes and dtypes of its own operands, the masks,
@@ -1272,22 +1313,22 @@ class WafEngine:
             TIER_COMPILER.compile_all(specs, keys)
             ready = dict.fromkeys(keys, True)
         stages = [
-            (k, s[2], EXEC_CACHE._lookup(k, count_hit=False) if ready[k] else None)
+            (k, s[2], EXEC_CACHE._lookup(k, count_hit=False) if ready[k] else None, s[4])
             for s, k in zip(specs, keys)
         ]
         plan = (stages[:-1], stages[-1])
-        if all(compiled is not None for _k, _fn, compiled in stages):
+        if all(stage[2] is not None for stage in stages):
             table[sig] = plan
         return plan
 
     @staticmethod
-    def _launch(stage, args: tuple, statics: dict, dyn: dict):
+    def _launch(stage, args: tuple):
         """Enqueue one resolved stage on the device; None where its
         executable is not resident and the host twin has to answer."""
-        key, jitted, compiled = stage
+        key, jitted, compiled, statics = stage
         if compiled is None:
             return None
-        return EXEC_CACHE.run(key, compiled, jitted, args, statics, dyn)
+        return EXEC_CACHE.run(key, compiled, jitted, args, statics)
 
     def _confirm_prefilter(self, tier_hits, tiers, from_device, rec):
         """Confirm device prefilter positives against the exact DFAs.
@@ -1427,9 +1468,13 @@ class WafEngine:
     def tiering_summary(self) -> dict:
         """What the windows' tiering came to, cumulative: windows
         dispatched, matcher tiers launched (one executable call each),
-        their cells (unique rows x width, as bucketed) and the real
-        bytes in them. 1 - real_bytes / cells is the share of the
-        matchers' bytes that was padding."""
+        their cells (unique rows x width, as bucketed), the real
+        bytes in them, and the host arrays handed to a launch or a
+        ``device_put`` (one transfer each). 1 - real_bytes / cells is
+        the share of the matchers' bytes that was padding;
+        host_operands / windows reads tiers + 1 (a match slab a tier,
+        the post slab), and one more for a tier whose hit rows the
+        prefilter confirm repacked."""
         return dict(self._tiering)
 
     def body_summary(self) -> dict:

@@ -48,6 +48,7 @@ from ..ops.dfa_gather import (
 )
 from ..ops.segment import SegmentBlock, build_segment_block, match_segment_block
 from ..ops.transforms import apply_device_pipeline
+from .slab import unpack_match_slab, unpack_post_slab
 
 _BIG = np.int32(2**31 - 1)
 
@@ -1216,10 +1217,7 @@ def _pack_verdicts(out) -> jnp.ndarray:
 @partial(jax.jit, static_argnames=("mask",))
 def match_tier_packed(
     model: WafModel,
-    data: jnp.ndarray,  # [U, L] uint8 unique-value rows
-    lengths: jnp.ndarray,  # [U]
-    variant_data: jnp.ndarray,  # [H, U, L]
-    variant_lengths: jnp.ndarray,  # [H, U]
+    slab: jnp.ndarray,  # uint8 [1 + H + E, U, L]: the tier's match slab
     mask: int | None = None,
 ) -> jnp.ndarray:
     """One tier's matcher stage, the executable a window launches per
@@ -1228,19 +1226,22 @@ def match_tier_packed(
     hot paths, so the rows collapse before they reach a matcher),
     bit-packed to [U, PB] uint8 (np.packbits layout — the same format
     the value cache stores and ``eval_post_tiered`` / the host post path
-    unpack)."""
+    unpack). The tier arrives as ONE host operand, its match slab
+    (``models/slab.py``): ``data [U, L]``, ``vdata [H, U, L]`` and the
+    ``int32`` ``lengths [U]`` / ``vlengths [H, U]`` are static slices of
+    it."""
+    data, lengths, variant_data, variant_lengths = unpack_match_slab(slab)
     hits_u = match_tier(model, data, lengths, variant_data, variant_lengths, mask=mask)
     return jnp.packbits(hits_u.astype(jnp.uint8), axis=1)
 
 
-@partial(jax.jit, static_argnames=("max_phase",))
+@partial(jax.jit, static_argnames=("max_phase", "layout"))
 def eval_post_tiered(
     model: WafModel,
     tier_hits,  # tuple of [U, PB] uint8 per tier (packed matcher rows)
-    pairs,  # tuple of (kind1, kind2, kind3, req_id, uid) per tier
-    numvals: jnp.ndarray,
+    slab: jnp.ndarray,  # int32 [words]: the window's post slab
     max_phase: int = 2,
-    cached=None,  # aligned tuple of [Uc, PB] uint8 or None per tier
+    layout: tuple = (),  # slab.post_layout: (((P, Uc), ...), B, NV, PB)
 ) -> jnp.ndarray:
     """The post stage, one executable a window: unpack each tier's
     packed hit rows (matcher output or host-computed — same shapes, same
@@ -1250,7 +1251,14 @@ def eval_post_tiered(
     touches a matcher), expand to per-(target, kinds) pair rows via
     ``uid``, and run ONE global ``post_match`` + ``_pack_verdicts``.
     Request atomicity holds because req_id is global across tiers and
-    post_match is the only cross-row stage."""
+    post_match is the only cross-row stage. Everything but the hit rows
+    arrives as ONE operand, the window's post slab (``models/slab.py``;
+    ``layout`` says where each field lies): per tier ``(kind1, kind2,
+    kind3, req_id, uid)``, ``numvals``, and per tier the cached rows
+    ``[Uc, PB] uint8`` where the value cache is on. Nothing in it waits
+    for a matcher, so the engine puts it on the device before it waits
+    for one."""
+    pairs, numvals, cached = unpack_post_slab(slab, layout)
     g = model.e_lg.shape[0]
     hits, k1s, k2s, k3s, rids = [], [], [], [], []
     for ti, (hp, (k1, k2, k3, rid, uid)) in enumerate(zip(tier_hits, pairs)):
@@ -1318,7 +1326,7 @@ def matched_id_lists(
 # that). The name is in the persistent compile cache's key.
 _STAGE_FNS = {
     "match": (match_tier_packed, ("mask",)),
-    "eval_post": (eval_post_tiered, ("max_phase",)),
+    "eval_post": (eval_post_tiered, ("max_phase", "layout")),
 }
 _stage_executables: dict[tuple[str, str], object] = {}
 

@@ -561,13 +561,15 @@ class NativeTensorizer:
         partitioning, and the dedup all run in ONE GIL-released C++ call
         (cko_plan_new); Python keeps only the value-cache probe; a second
         GIL-released call (cko_plan_export) scatters every tier straight
-        into reusable page-aligned buffers, zeroing only pad regions.
+        into reusable page-aligned slabs, zeroing only pad regions.
 
         Returns ``(tiers, numvals, masks, cached, miss_keys, lease)`` —
         the first five bit-identical to ``WafEngine.tier_cached(
-        tensorize_blob(blob, n_req))``, plus the arena lease the caller
-        releases once the window's device step has consumed the host
-        buffers (``WafEngine.collect``)."""
+        tensorize_blob(blob, n_req))`` and views into the lease's slabs
+        (one match slab a tier, one post slab a window: what a launch
+        hands the device, ``native/arena.py``), plus the arena lease the
+        caller releases once the window's device step has consumed the
+        host slabs (``WafEngine.collect``)."""
         assert self.tiered
         lib = self._lib
         t0 = time.perf_counter()
@@ -611,11 +613,10 @@ class NativeTensorizer:
             # from C++; the probe decides which unique rows the matcher
             # must run (miss) vs which replay packed hit rows (found).
             miss_lists: list[list[int]] = [None] * nt  # type: ignore[list-item]
+            found_rows: list[list] = []
             if cache is None:
-                cached = None
                 miss_keys = None
             else:
-                cached_l = []
                 miss_keys = []
                 for ti in range(nt):
                     n_uniq = int(meta[ti, 2])
@@ -635,14 +636,7 @@ class NativeTensorizer:
                     found, miss = cache.lookup(ukeys)
                     miss_lists[ti] = miss
                     miss_keys.append([ukeys[j] for j in miss])
-                    cpk = np.zeros(
-                        (_bucket_rows(max(1, len(found))), cache.packed_len),
-                        dtype=np.uint8,
-                    )
-                    for r, (_j, row) in enumerate(sorted(found.items())):
-                        cpk[r] = row
-                    cached_l.append(cpk)
-                cached = tuple(cached_l)
+                    found_rows.append([row for _j, row in sorted(found.items())])
 
             h = max(1, self._n_host)
             b = _bucket(max(1, n_req))
@@ -657,11 +651,21 @@ class NativeTensorizer:
                 )
                 u = _bucket_rows(max(1, n_miss))
                 p = _bucket_rows(max(1, n_pairs))
+                uc = 0 if cache is None else _bucket_rows(max(1, len(found_rows[ti])))
                 # u_pad (found-row uid base) == the bucketed miss count.
                 dims[ti * 4 : ti * 4 + 4] = (u, p, u, n_miss)
-                shapes.append((u, length, p))
+                shapes.append((u, length, p, uc))
 
-            lease = self._arena.checkout((tuple(shapes), h, b, self._nv))
+            lease = self._arena.checkout(
+                (tuple(shapes), h, b, self._nv,
+                 0 if cache is None else cache.packed_len)
+            )
+            # The cached hit rows ride the window's post slab: a view a
+            # tier, rows past the found ones zero as a fresh block's.
+            for rows, view in zip(found_rows, lease.cached or ()):
+                if rows:
+                    view[: len(rows)] = rows
+                view[len(rows) :] = 0
             ptrs = np.zeros(nt * 9, dtype=np.uint64)
             for ti, bufs in enumerate(lease.tiers):
                 for k in range(9):
@@ -699,8 +703,9 @@ class NativeTensorizer:
             raise
         finally:
             lib.cko_plan_free(plan)
-        tiers = tuple(lease.tiers[: nt])
+        tiers = lease.tiers
         numvals = lease.numvals
+        cached = lease.cached
         dt = time.perf_counter() - t0
         with self._stats_lock:
             self.windows_total += 1

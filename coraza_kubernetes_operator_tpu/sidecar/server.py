@@ -1460,6 +1460,10 @@ class TpuEngineSidecar:
             "Bytes of matcher input that were not padding",
         ).set_function(lambda: self._engine_stat("tiering_summary", "real_bytes"))
         self.metrics.gauge(
+            "cko_tiering_host_operands_total",
+            "Host arrays handed to a launch or a device_put, one transfer each",
+        ).set_function(lambda: self._engine_stat("tiering_summary", "host_operands"))
+        self.metrics.gauge(
             "cko_bodies_json_total",
             "Bodied requests read by the JSON body processor",
         ).set_function(lambda: self._engine_stat("body_summary", "json_total"))

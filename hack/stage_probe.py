@@ -21,7 +21,14 @@ milliseconds per window (``lane_wait``: per request), and after − before
 of ``automata.prefilter`` (``native_hits`` against ``hits``) and of
 ``compile_cache`` (``launch_plan_hits`` against ``device_windows``:
 every warm window launched from its engine's table; ``launch_plan_misses``
-and ``misses`` flat). The ``warm`` line carries the engine's matcher
+and ``misses`` flat), and of ``tiering`` (the default tenant's engine):
+``host_operands`` against ``windows`` and ``tiers``, the host arrays a
+window handed to a launch, one transfer each. ``host_operands_per_window``
+should read tiers a window + 1 (one match slab a tier, the post slab),
+and up to one more a tier where the prefilter confirm repacked the
+tier's hit rows (the CRS cells): at most 2 x tiers + 1, where it read
+10 to 20 before the slabs. The ``warm`` line carries the same over the
+last warm round, the engine's matcher
 layout from ``automata`` (``flat_bins``, ``flat_slots``, ``flat_groups``,
 ``per_bank_kernels``) and, from the ``frontend`` counters' growth over
 the last warm round, ``tenant_blob_path_share`` and
@@ -63,6 +70,17 @@ MATCHER_LAYOUT = ("flat_bins", "flat_slots", "flat_groups", "per_bank_kernels")
 FRONTEND_COUNTERS = ("window_reads_total", "blob_windows_total", "tenant_requests_total",
                      "tenant_blob_requests_total", "python_path_requests_total")
 FRONTEND_METRICS = ("tenant_blob_path_share", "engine_windows_per_read")
+TIERING_COUNTERS = ("windows", "tiers", "host_operands")
+
+
+def tiering_growth(before: dict, after: dict) -> dict:
+    """after − before of the default engine's ``tiering`` counters, and
+    the host operands a window (None where it dispatched none)."""
+    out = {k: after["tiering"].get(k, 0) - before["tiering"].get(k, 0)
+           for k in TIERING_COUNTERS}
+    out["host_operands_per_window"] = (
+        out["host_operands"] / out["windows"] if out["windows"] else None)
+    return out
 
 
 def frontend_ratios(cell, before: dict, after: dict) -> dict:
@@ -156,6 +174,7 @@ def main() -> int:
                       "automata": {k: after["automata"].get(k) for k in MATCHER_LAYOUT},
                       "instances": len(cell.instances()),
                       "resident_engines": after["resident_engines"],
+                      "tiering": tiering_growth(before, after),
                       **frontend_ratios(cell, before, after)})
         for k, mode in enumerate(args.windows.split(",")):
             trace_dir = work / f"trace{k}"
@@ -182,6 +201,7 @@ def main() -> int:
                                    in after["automata"]["prefilter"].items()},
                         compile_cache={k: after["compile_cache"][k] - before["compile_cache"][k]
                                        for k in LAUNCH_COUNTERS},
+                        tiering=tiering_growth(before, after),
                         frontend={**{k: after["frontend"].get(k, 0) - before["frontend"].get(k, 0)
                                      for k in FRONTEND_COUNTERS},
                                   **frontend_ratios(cell, before, after)})

@@ -358,7 +358,7 @@ def _old_key(jitted, args, statics):
 def _window(
     rows=16,
     width=32,
-    dtype="uint8",
+    requests=8,
     mask=None,
     max_phase=2,
     cached_rows=None,
@@ -373,7 +373,7 @@ def _window(
     def tier(u):
         i32 = lambda *shape: np.full(shape, fill, dtype=np.int32)  # noqa: E731
         return (
-            np.full((u, width), fill, dtype=dtype),
+            np.full((u, width), fill, dtype=np.uint8),
             i32(u),
             i32(u),
             i32(u),
@@ -388,7 +388,7 @@ def _window(
     cached = None
     if cached_rows is not None:
         cached = tuple(np.zeros((cached_rows, 4), dtype=np.uint8) for _ in tiers)
-    return tiers, np.zeros((8, 4), dtype=np.int32), (mask,) * n_tiers, cached, max_phase
+    return tiers, np.zeros((requests, 4), dtype=np.int32), (mask,) * n_tiers, cached, max_phase
 
 
 def _keys(eng, window):
@@ -405,9 +405,7 @@ def _keys(eng, window):
     specs = match_specs + [post_spec]
     composed = tuple(spec_key(s, eng._model_sig) for s in specs)
     whole = tuple(spec_key(s) for s in specs)
-    old = tuple(
-        _old_key(s[2], s[3] + (s[5].get("cached"),), s[4]) for s in specs
-    )
+    old = tuple(_old_key(s[2], s[3], s[4]) for s in specs)
     table = shape_signature((tiers, numvals, cached), (masks, max_phase))
     return composed, whole, old, table
 
@@ -416,7 +414,7 @@ _BASE = dict(cached_rows=16)
 _VARIANTS = {
     "tier_rows": dict(rows=32),
     "tier_width": dict(width=64),
-    "window_tensor_dtype": dict(dtype="int8"),
+    "request_bucket": dict(requests=16),
     "mask": dict(mask=5),
     "max_phase": dict(max_phase=1),
     "cached_bucket": dict(cached_rows=32),
@@ -516,7 +514,7 @@ def test_table_hit_keeps_the_aot_rejection_fallback(monkeypatch):
 
     _generation, table = eng._launch_table
     (sig, (match_stages, post_stage)), = table.items()
-    table[sig] = ([(k, fn, rejecting) for k, fn, _c in match_stages], post_stage)
+    table[sig] = ([(k, fn, rejecting, st) for k, fn, _c, st in match_stages], post_stage)
 
     before = EXEC_CACHE.stats()
     assert [(v.interrupted, v.rule_id) for v in eng.evaluate(reqs)] == want

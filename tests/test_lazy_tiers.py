@@ -119,7 +119,7 @@ class _RecordingCache:
     def _lookup(self, key, count_hit=False):
         return None
 
-    def warm(self, jitted, args, statics, dyn, key=None):
+    def warm(self, jitted, args, statics, key=None):
         self.warm_order.append(getattr(jitted, "__name__", "?"))
         return True
 

@@ -192,7 +192,8 @@ def test_blob_handoff_pins_buffer(engine):
 
 # -- staging arena ------------------------------------------------------------
 
-_SIG = (((8, 32, 16), (4, 64, 8)), 2, 8, 4)
+# (((U, L, P, Uc), ...per tier), H, B, NV, PB)
+_SIG = (((8, 32, 16, 2), (4, 64, 8, 1)), 2, 8, 4, 5)
 
 
 def test_arena_same_shape_reuse_allocates_nothing():
@@ -273,14 +274,89 @@ def test_arena_concurrent_leases_never_share_buffers():
     assert arena.stats()["reuses_total"] == 2
 
 
-def test_arena_buffers_page_aligned():
+def _extent(a):
+    """[first byte, one past the last) of a C-contiguous array."""
+    assert a.flags["C_CONTIGUOUS"]
+    return a.ctypes.data, a.ctypes.data + a.nbytes
+
+
+def _assert_lies_in_slabs(lease):
+    """Every view of the lease lies in one of its slabs, on a boundary
+    of its item size, and no two views share a byte; each slab starts
+    on a page."""
+    slabs = [_extent(s) for s in (*lease.match_slabs, lease.post_slab)]
+    for lo, _hi in slabs:
+        assert lo % 4096 == 0
+    views = [a for t in lease.tiers for a in t] + [lease.numvals]
+    views += [c for c in lease.cached or () if c is not None]
+    spans = []
+    for a in views:
+        lo, hi = _extent(a)
+        assert lo % a.itemsize == 0
+        assert any(s_lo <= lo and hi <= s_hi for s_lo, s_hi in slabs), a.shape
+        if hi > lo:
+            spans.append((lo, hi))
+    spans.sort()
+    for (_lo, hi), (lo, _hi) in zip(spans, spans[1:]):
+        assert hi <= lo, "two views overlap"
+    # The match operands lie in the tier's own slab, the pair rows,
+    # numvals and the cached blocks in the window's one post slab.
+    post_lo, post_hi = slabs[-1]
+    for t, (m_lo, m_hi) in zip(lease.tiers, slabs):
+        for k, a in enumerate(t):
+            lo, hi = _extent(a)
+            s_lo, s_hi = (m_lo, m_hi) if k in (0, 1, 6, 7) else (post_lo, post_hi)
+            assert s_lo <= lo and hi <= s_hi, k
+    # Post-slab fields start on a 64-byte line.
+    for a in [a for t in lease.tiers for k, a in enumerate(t) if k in (2, 3, 4, 5, 8)]:
+        assert a.ctypes.data % 64 == 0
+
+
+def test_arena_views_lie_in_page_aligned_slabs():
     arena = StagingArena(max_sets=1)
     lease = arena.checkout(_SIG)
-    for t in lease.tiers:
-        for a in t:
-            assert a.ctypes.data % 4096 == 0
-    assert lease.numvals.ctypes.data % 4096 == 0
+    assert len(lease.match_slabs) == 2 and lease.post_slab.dtype == np.int32
+    for slab, (u, length, _p, _uc) in zip(lease.match_slabs, _SIG[0]):
+        assert slab.dtype == np.uint8 and slab.shape[1:] == (u, length)
+    assert [c.shape for c in lease.cached] == [(2, 5), (1, 5)]
+    _assert_lies_in_slabs(lease)
     lease.release()
+    # With the value cache off the post slab carries no cached block.
+    off = arena.checkout((((8, 32, 16, 0),), 1, 8, 4, 0))
+    assert off.cached is None
+    _assert_lies_in_slabs(off)
+
+
+@pytest.mark.parametrize("cache_on", [False, True])
+def test_tier_blob_lease_lies_in_slabs(engine, cache_on):
+    """A served window's nine views a tier, ``numvals`` and the cached
+    blocks ARE the slabs a launch hands the device: what C++ exported
+    through the views is what the slabs hold."""
+    from coraza_kubernetes_operator_tpu.models.slab import match_views, post_views, post_layout
+
+    reqs = _random_requests(48, 31)
+    blob = serialize_requests(reqs)
+    cache = engine.value_cache if cache_on else None
+    tiers, numvals, _masks, cached, _miss, lease = engine._native.tier_blob(
+        blob, len(reqs), engine._kind_block_lut, cache
+    )
+    try:
+        assert tiers is lease.tiers and numvals is lease.numvals
+        assert (cached is None) == (not cache_on) and cached is lease.cached
+        _assert_lies_in_slabs(lease)
+        pairs, nv, cpk = post_views(
+            lease.post_slab, post_layout(tiers, numvals, cached)
+        )
+        assert (nv == numvals).all()
+        for ti, (t, slab) in enumerate(zip(tiers, lease.match_slabs)):
+            for got, want in zip(match_views(slab), (t[0], t[1], t[6], t[7])):
+                assert got.shape == want.shape and (got == want).all()
+            for got, want in zip(pairs[ti], (t[2], t[3], t[4], t[5], t[8])):
+                assert got.ctypes.data == want.ctypes.data
+            if cache_on:
+                assert cpk[ti].ctypes.data == cached[ti].ctypes.data
+    finally:
+        lease.release()
 
 
 def test_arena_transient_mode():

@@ -168,6 +168,7 @@ def test_pallas_kernel_compiles_for_v5e(crs_lite, described, operand, family, ro
 
 
 def test_post_stage_compiles_for_v5e(crs_lite, described, operand):
+    from coraza_kubernetes_operator_tpu.models.slab import post_slab_words
     from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
 
     # As the engine dispatches it: under the module name a trace
@@ -176,15 +177,15 @@ def test_post_stage_compiles_for_v5e(crs_lite, described, operand):
     model = crs_lite.model
     packed = (int(model.e_lg.shape[0]) + 7) // 8
     n_vars = crs_lite.compiled.numvars.n_vars
-    pair = operand((512,), jnp.int32)
+    # The window's post slab: 512 pair rows, 16 requests, 256 cached rows.
+    layout = (((512, 256),), 16, n_vars, packed)
     text = (
         eval_post_tiered.lower(
             described(model),
             (operand((ROWS_WINDOW, packed), jnp.uint8),),
-            ((pair,) * 5,),
-            operand((16, n_vars), jnp.int32),
+            operand((post_slab_words(layout),), jnp.int32),
             max_phase=2,
-            cached=(operand((256, packed), jnp.uint8),),
+            layout=layout,
         )
         .compile()
         .as_text()
@@ -197,6 +198,7 @@ def test_canary_matcher_compiles_for_v5e(crs_lite, described, operand):
     tier of ops/segment.py, and every flat bin in one program — at
     the promotion canary's shape, which every cold sidecar compiles
     before it may serve from the device."""
+    from coraza_kubernetes_operator_tpu.models.slab import match_slab_shape
     from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
 
     model = crs_lite.model
@@ -205,10 +207,7 @@ def test_canary_matcher_compiles_for_v5e(crs_lite, described, operand):
     match_tier_packed = stage_executable("match", f"{u}x{width}")
     compiled = match_tier_packed.lower(
         described(model),
-        operand((u, width), jnp.uint8),
-        operand((u,), jnp.int32),
-        operand((h, u, width), jnp.uint8),
-        operand((h, u), jnp.int32),
+        operand(match_slab_shape(u, width, h), jnp.uint8),
         mask=None,
     ).compile()
     text = compiled.as_text()
@@ -231,16 +230,14 @@ def test_long_matcher_compiles_for_v5e(crs_lite, described, operand, rows, width
     kernel (the bins sit at the edge of their VMEM plan there, one
     ``int32`` data tile a pipeline; ``_PALLAS_MAX_LEN``), none falls to
     the XLA scan, and the program fits beside the tables."""
+    from coraza_kubernetes_operator_tpu.models.slab import match_slab_shape
     from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
 
     model = crs_lite.model
     h = max(1, len(crs_lite._host_pipelines))
     compiled = stage_executable("match", f"{rows}x{width}").lower(
         described(model),
-        operand((rows, width), jnp.uint8),
-        operand((rows,), jnp.int32),
-        operand((h, rows, width), jnp.uint8),
-        operand((h, rows), jnp.int32),
+        operand(match_slab_shape(rows, width, h), jnp.uint8),
         mask=None,
     ).compile()
     text = compiled.as_text()
