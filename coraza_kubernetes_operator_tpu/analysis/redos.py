@@ -230,12 +230,14 @@ def ast_has_nullable_loop(node: object) -> bool:
     return False
 
 
-@lru_cache(maxsize=4096)
+@lru_cache(maxsize=32768)
 def pattern_has_eda(pattern: str, case_insensitive: bool = False) -> bool | None:
     """EDA verdict for a raw pattern string; None when it cannot be parsed
     by the RE2-subset front end or is too large to analyze. Cached
     process-wide: CRS repeats the same pattern across paranoia levels and
-    the reload gate re-analyzes the same document version repeatedly."""
+    the reload gate re-analyzes the same document version repeatedly (the
+    cache holds more patterns than a CRS plus a 5,000-rule feed has: a
+    document walked in order through a smaller LRU never hits)."""
     try:
         ast = parse_regex(pattern, case_insensitive=case_insensitive)
     except RegexParseError:

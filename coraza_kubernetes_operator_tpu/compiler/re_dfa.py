@@ -110,11 +110,11 @@ class DFA:
         sig0 = np.concatenate(
             [me[:, None].astype(np.int64), emit.astype(np.int64)], axis=1
         )
-        _, block = np.unique(sig0, axis=0, return_inverse=True)
+        block = _row_ids(sig0)
         n_blocks = int(block.max()) + 1 if n_states else 0
         while True:
             sig = np.concatenate([block[:, None], block[trans]], axis=1)
-            _, new_block = np.unique(sig, axis=0, return_inverse=True)
+            new_block = _row_ids(sig)
             n_new = int(new_block.max()) + 1 if n_states else 0
             block = new_block
             if n_new == n_blocks:
@@ -136,7 +136,7 @@ class DFA:
         colsig = np.concatenate(
             [trans2.astype(np.int64), emit2.astype(np.int64)], axis=0
         ).T  # [C, 2*S']
-        _, cinv = np.unique(colsig, axis=0, return_inverse=True)
+        cinv = _row_ids(colsig)
         n_cls = int(cinv.max()) + 1 if cinv.size else 0
         cu, cfirst = np.unique(cinv, return_index=True)
         corder = np.argsort(cfirst, kind="stable")
@@ -166,17 +166,37 @@ class DFA:
         return bool(self.match_end[s])
 
 
+def _row_ids(rows: np.ndarray) -> np.ndarray:
+    """An id per row of ``rows`` [R, K]: equal rows share one. A few
+    hundred rows go through a dict of their bytes: ``np.unique`` over an
+    axis costs a third of a millisecond however few the rows, and a chain
+    of n states refines in n rounds (a 5,000-rule feed of 27-state
+    patterns: 35 of its 46 s of install, PR 37)."""
+    if len(rows) > 512:
+        return np.unique(rows, axis=0, return_inverse=True)[1].reshape(-1)
+    ids: dict[bytes, int] = {}
+    return np.fromiter(
+        (ids.setdefault(r.tobytes(), len(ids)) for r in np.ascontiguousarray(rows)),
+        dtype=np.int64, count=len(rows),
+    )
+
+
 def _byte_classes(nfa: PositionNFA) -> tuple[np.ndarray, list[int]]:
     """Partition bytes into equivalence classes by (position-class membership
     vector, word-ness, newline-ness). Returns (classmap[256], representatives)."""
-    signatures: dict[tuple, int] = {}
+    # One row of 256 bits per DISTINCT class mask (a literal's positions
+    # repeat their letters' masks), packed down the rows: a byte's
+    # signature is its column.
+    masks = list(dict.fromkeys([*nfa.classes, WORD, 1 << 0x0A]))
+    bits = np.unpackbits(
+        np.frombuffer(b"".join(m.to_bytes(32, "little") for m in masks), dtype=np.uint8)
+        .reshape(len(masks), 32), axis=1, bitorder="little")  # [masks, 256]
+    columns = np.ascontiguousarray(np.packbits(bits, axis=0).T)  # [256, ceil(masks / 8)]
+    signatures: dict[bytes, int] = {}
     classmap = np.zeros(256, dtype=np.int32)
     reps: list[int] = []
     for b in range(256):
-        sig = tuple(cls >> b & 1 for cls in nfa.classes) + (
-            bool(WORD >> b & 1),
-            b == 0x0A,
-        )
+        sig = columns[b].tobytes()
         cls_id = signatures.get(sig)
         if cls_id is None:
             cls_id = len(signatures)
@@ -197,13 +217,21 @@ def compile_nfa_dfa(nfa: PositionNFA, max_states: int = 8192, ast: object | None
     """
     classmap, reps = _byte_classes(nfa)
 
+    from .re_nfa import TRUE_DNF
+
     # The 4 reachable prev-byte contexts: none, word, non-word, newline.
     ctxs = [_PREV_NONE, (True, True, False), (True, False, False), (True, False, True)]
+    # A pattern none of whose guards reads the previous byte (no \b, ^, $:
+    # every guard is TRUE or empty) has one context, not four: the states
+    # the other three would mint are the ones minimization merges again,
+    # and a feed of thousands of such patterns pays for them (PR 37).
+    guards = [nfa.empty_dnf, *nfa.entries.values(), *nfa.accepts.values(),
+              *(d for out in nfa.edges.values() for d in out.values())]
+    if all(d == TRUE_DNF or not d for d in guards):
+        ctxs = ctxs[:1]
     ctx_index = {c: i for i, c in enumerate(ctxs)}
     n_ctx = len(ctxs)
     n_reps = len(reps)
-
-    from .re_nfa import TRUE_DNF
 
     _dnf_cache: dict[tuple, bool] = {}
 
@@ -249,7 +277,7 @@ def compile_nfa_dfa(nfa: PositionNFA, max_states: int = 8192, ast: object | None
                         m |= 1 << q
                 tgt_mask[p][ci][ri] = m
 
-    rep_ctx = [ctx_index[_prev_ctx_of(b)] for b in reps]
+    rep_ctx = [ctx_index[_prev_ctx_of(b)] if n_ctx > 1 else 0 for b in reps]
 
     # DFA state: (position bitmask, ctx id).
     initial = (0, ctx_index[_PREV_NONE])

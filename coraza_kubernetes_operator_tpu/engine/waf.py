@@ -1443,8 +1443,12 @@ class WafEngine:
         and metrics: which groups run where (the plan's verdict),
         how many device banks each tier produced, where the dense-DFA
         blocks are scanned (fused flat bins, or one kernel per bank for
-        the blocks no bin covers), and how the prefilter's
-        over-approximation is paying off at runtime."""
+        the blocks no bin covers), the size of the model they serve
+        (compiled rules; the conv tier's output columns, summed over
+        its blocks), and how the prefilter's over-approximation is
+        paying off at runtime."""
+        from ..ops.segment import conv_n2_cols
+
         plan = self.automata_plan
         counts = plan.counts()
         model = self.model
@@ -1453,6 +1457,8 @@ class WafEngine:
         return {
             "enabled": plan.enabled,
             "tiers": counts,
+            "rules": len(self.rule_meta),
+            "segment_columns": sum(conv_n2_cols(sb.spec) for sb in model.segs),
             "gather_banks": len(model.gather_banks),
             "pre_banks": len(model.pre_banks),
             "flat_bins": len(model.flat_banks),

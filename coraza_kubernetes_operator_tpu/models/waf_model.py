@@ -761,6 +761,9 @@ def segment_tier_hits(
         pids = sorted({pid for _, _, pid in kept})
         pid_ix = {pid: i for i, pid in enumerate(pids)}
         nc = -(-t // rows_fit)
+        # Chunks of equal size: 32 rows that fit 24 at a time are two
+        # chunks of 16, not two of 24 with a third of the conv on padding.
+        rows_fit = -(-(-(-t // nc)) // 8) * 8  # ceil(t / nc), up to a multiple of 8
         tp = nc * rows_fit
         stacked_d, stacked_l = [], []
         for pid in pids:

@@ -69,14 +69,16 @@ from ..compiler.re_dfa import DFA
 
 _LANE = 128
 # Per-kernel VMEM ceiling. The chip enforces a 16MB scoped-vmem limit at
-# COMPILE time (observed: a 3584-slot bin at L=2048 rejected at
-# 16.09M/16.00M with a clean compile error — not the round-4 style
-# runtime fault). The estimator below is calibrated against that
-# measurement; the default budget keeps ~1MB of margin under the real
-# limit. Env-tunable for validation runs.
+# COMPILE time (observed: a 3584-slot bin over two pipelines at L=2048
+# rejected at 16.09M/16.00M with a clean compile error — not the
+# round-4 style runtime fault). The estimator below is calibrated
+# against that measurement; the default budget keeps ~1MB of margin
+# under the real limit. Env-tunable for validation runs.
 import os as _os
 
 _FLAT_VMEM_BUDGET = int(_os.environ.get("CKO_FLAT_VMEM_MB", "15")) * 2**20
+CHIP_SCOPED_VMEM_BYTES = 16 * 2**20  # what a v5e's compiler gives one kernel
+MAX_BIN_SLOTS = 6144  # a bin's slot digits are base 256, two of them: far below 65536
 _BLOCK_B = 128
 _DEAD_S = float(2**30)  # pad-group state count: hit threshold never reached
 _DIGIT = 256  # whole numbers below it are exact in bf16: the matmuls' digit base
@@ -201,20 +203,27 @@ def _layout_stats(pieces) -> tuple[int, int, int, int]:
 
 # Widest buffer the Pallas kernel accepts; wider tiers run the XLA
 # formulation (they carry few rows — the body tier is ~128 — so grid
-# parallelism is nil there anyway). The real ceiling is the chip's 16MB
-# scoped-vmem limit, which the compiler enforces with a clean
-# compile-time error (observed: a 3584-slot bin at L=2048 rejected at
-# 16.09M/16.00M), so an over-budget combination fails visibly at
-# compile, never as a runtime fault. 2048 with the default 11MB plan
-# (bins <= ~2304 slots) is hardware-validated in the full serve loop;
-# lower CKO_FLAT_MAX_LEN if a custom ruleset's bins hit the compile
-# error on long tiers.
+# parallelism is nil there anyway). The planner sizes every bin for
+# THIS width (``length_hint``: the data tiles are the one term of
+# ``flat_vmem_bytes`` that grows with it), so a bin it makes fits at
+# every width the engine launches it at, and a rule set of any size
+# needs no knob: what does not fit one bin goes to the next. The
+# ceiling is the chip's 16MB scoped-vmem limit, which the compiler
+# enforces with a clean compile-time error, never a runtime fault.
+# Measured (PR 37): crs-lite plus a 5,000-rule feed plans 18 bins, 16
+# of them 3,456 slots x 128 groups on one pipeline (estimate 14.2MB of
+# the 15MB budget at 2048), and all 18 compile for a v5e at widths 512
+# and 2048 (chip calls and tests/test_tpu_compile.py); the one refusal
+# on record, a 3,584-slot bin over two pipelines at 2048 (16.09M of
+# 16.00M), reads 16.7MB on the estimator, so the planner never makes it
+# (tests/test_custom_feed.py). CKO_FLAT_MAX_LEN below is for validation
+# runs; no deployment has to set it.
 _PALLAS_MAX_LEN = int(_os.environ.get("CKO_FLAT_MAX_LEN", "2048"))
 
 
 def plan_flat_bins(
     bank_dfas: list[tuple[int, int, list[DFA]]],
-    max_slots: int = 6144,
+    max_slots: int = MAX_BIN_SLOTS,
     budget: int = _FLAT_VMEM_BUDGET,
     length_hint: int = _PALLAS_MAX_LEN,
 ) -> tuple[list[list[tuple[int, int, int, int, list[DFA]]]], set[int]]:

@@ -495,15 +495,56 @@ def match_segment_block(
     # CRS-grade corpus (alternation products over shared token
     # vocabularies, paranoia-level near-duplicates) collapses ~10-40x
     # here; without it the conv pays one column per branch.
-    final_alloc: dict[tuple, tuple[int, int]] = {}
+    final_chans: dict[tuple, list[int]] = {}
     final_gidsets: dict[tuple, list[set[int]]] = {}
     for gk, items in finals.items():
         uniq: dict[int, set[int]] = {}
         for bi, c in items:
             uniq.setdefault(c, set()).add(spec.branches[bi][0])
-        chans = list(uniq)
-        final_alloc[gk] = alloc(chans)
-        final_gidsets[gk] = [uniq[c] for c in chans]
+        final_chans[gk] = list(uniq)
+        final_gidsets[gk] = [uniq[c] for c in final_chans[gk]]
+    # Finals of ONE structure are batched over their suffixes: a site's
+    # feed of thousands of rules is a few patterns with other tokens, so
+    # every rule brings a suffix of its own (its second token) under a
+    # handful of structures, and one AND-any per distinct suffix would
+    # be thousands of one-column slices and conds in the program (PR 37:
+    # 3,500 of them made the canary matcher 328k HLO lines and six
+    # minutes of compile). A suffix's finals share a bucket when first
+    # segment geometry, anchor and column count agree; the members of a
+    # structure are ordered by the buckets they feed, so a bucket reads
+    # contiguous runs of the structure's suffix bitmap and of m_all.
+    sid_buckets: dict[int, list[tuple]] = {}
+    for gk in finals:
+        sid_buckets.setdefault(gk[0], []).append(gk[1:] + (len(final_chans[gk]),))
+    final_runs: list[tuple] = []  # (sig_key, i0, i1, bucket, finals keys)
+    for sig_key, members in struct.items():
+        members.sort(key=lambda m: tuple(sorted(sid_buckets.get(m[1], ()))))
+        seen_buckets: dict[tuple, list[int]] = {}
+        for i, (_skey, sid) in enumerate(members):
+            for bk in sid_buckets.get(sid, ()):
+                seen_buckets.setdefault(bk, []).append(i)
+        for bk, at in seen_buckets.items():
+            i0 = at[0]
+            for a, b in zip(at, at[1:] + [None]):
+                if b != a + 1:
+                    gks = [(members[i][1],) + bk[:3] for i in range(i0, a + 1)]
+                    final_runs.append((sig_key, i0, a + 1, bk, gks))
+                    i0 = b
+    # A run of ns suffixes with k columns each is laid out block-major
+    # (column j of every suffix side by side: k AND-anys over [T, Q, ns])
+    # unless it is one suffix, or has more columns than suffixes (the
+    # CRS shape: one shared suffix under a vocabulary of first tokens),
+    # where every suffix keeps its own slice and AND-any.
+    final_alloc: dict[tuple, tuple[int, int]] = {}
+    run_alloc: list[tuple[int, int] | None] = []
+    for _sig_key, i0, i1, bk, gks in final_runs:
+        ns, k = i1 - i0, bk[3]
+        if ns == 1 or k > ns:
+            for gk in gks:
+                final_alloc[gk] = alloc(final_chans[gk])
+            run_alloc.append(None)
+        else:
+            run_alloc.append(alloc([final_chans[gk][j] for j in range(k) for gk in gks]))
     struct_alloc: dict[tuple, list[tuple[int, int]]] = {}
     for sig_key, members in struct.items():
         chan_cols = [
@@ -713,7 +754,7 @@ def match_segment_block(
     # Right-to-left evaluation, batched over the group's distinct
     # suffixes: s[t, p, i] = "suffix i fully matches with its first
     # element's real bytes starting at padded position p".
-    s_store: dict[int, jnp.ndarray] = {}
+    s_struct: dict[tuple, jnp.ndarray] = {}
     for sig_key, members in struct.items():
         sig_ops, a_end = sig_key
         ns = len(members)
@@ -740,8 +781,7 @@ def match_segment_block(
             else:  # gapcls
                 _, ivs, lo, hi = op
                 s = gap_cls(s, ivs, lo, hi, forward=True)
-        for i, (_skey, sid) in enumerate(members):
-            s_store[sid] = s[:, :, i]
+        s_struct[sig_key] = s
 
     # Concatenate bucket outputs (bucket order) and map columns to groups
     # with one matmul — no scatter (TPU scatter lowering serializes).
@@ -753,47 +793,60 @@ def match_segment_block(
             cols.append(run_bucket(sig, idxs))  # [T, len(idxs)]
             col_groups.extend(spec.branches[bi][0] for bi in idxs)
         iota2 = iota  # [1, Q]
-        gj_per_group: list[jnp.ndarray] = []
-        for (sid, n_lead, n_real, a_start), _items in finals.items():
-            s2 = s_store[sid]  # [T, Q], indexed by real start of the NEXT element
-            g = (
-                (iota2 >= 1)
-                & (iota2 + n_real <= len1)
-                & _lshift_fill(s2, n_real, False)
-            )
-            if a_start:
-                g = g & (iota2 == 1)
-            gj_per_group.append(_lshift_fill(g, n_lead, False))  # window-start idx
 
-        for gj, key in zip(gj_per_group, finals):
-            a0, a1 = final_alloc[key]
-            m = mslice(a0, a1)  # [T, Q, NB]
+        def final_cols(a0: int, a1: int, gate: jnp.ndarray) -> jnp.ndarray:
+            """AND-any of conv columns ``a0:a1`` ([T, Q, NB]) under
+            ``gate`` ([T, Q, 1] or [T, Q, NB]) -> [T, NB].
 
-            # Prefilter gate (as in the bucketed tier): if none of this
-            # group's first segments matched anywhere in the block, skip
-            # the AND-any reduction entirely — benign-heavy traffic pays
-            # only the cheap any() read. ONLY for small column groups:
-            # the any() itself is a full read of the slice, and a
-            # many-hundred-column group in a serving-sized batch almost
-            # always has some hit somewhere, so the gate would pay a
-            # whole extra [T, Q, NB] pass (profiled at ~1.1 ms/step as
-            # fusion.406) to skip nothing.
-            def run_final(_, m=m, gj=gj):
-                return jnp.any(m & gj[:, :, None], axis=1)  # [T, NB]
+            Prefilter gate (as in the bucketed tier): if none of these
+            first segments matched anywhere in the block, skip the
+            AND-any reduction entirely — benign-heavy traffic pays only
+            the cheap any() read. ONLY for small column groups: the
+            any() itself is a full read of the slice, and a
+            many-hundred-column group in a serving-sized batch almost
+            always has some hit somewhere, so the gate would pay a
+            whole extra [T, Q, NB] pass (profiled at ~1.1 ms/step as
+            fusion.406) to skip nothing."""
+            m = mslice(a0, a1)
+
+            def run_final(_):
+                return jnp.any(m & gate, axis=1)  # [T, NB]
 
             if a1 - a0 > 64:
-                cols.append(run_final(None))
-            else:
-                no_match = jnp.broadcast_to(
-                    m_all[:, 0, :1] & False, (t, a1 - a0)
-                )
-                cols.append(
-                    jax.lax.cond(
-                        jnp.any(m), run_final, lambda _, z=no_match: z, None
+                return run_final(None)
+            no_match = jnp.broadcast_to(m_all[:, 0, :1] & False, (t, a1 - a0))
+            return jax.lax.cond(jnp.any(m), run_final, lambda _: no_match, None)
+
+        for (sig_key, i0, i1, bk, gks), block in zip(final_runs, run_alloc):
+            n_lead, n_real, a_start, k = bk
+            if block is None:
+                for i, gk in zip(range(i0, i1), gks):
+                    # [T, Q], indexed by real start of the NEXT element
+                    s2 = s_struct[sig_key][:, :, i]
+                    g = (
+                        (iota2 >= 1)
+                        & (iota2 + n_real <= len1)
+                        & _lshift_fill(s2, n_real, False)
                     )
-                )
-        for gk in finals:
-            col_groups.extend(final_gidsets[gk])  # deduped: one col → gid set
+                    if a_start:
+                        g = g & (iota2 == 1)
+                    gj = _lshift_fill(g, n_lead, False)  # window-start idx
+                    cols.append(final_cols(*final_alloc[gk], gj[:, :, None]))
+                    col_groups.extend(final_gidsets[gk])  # deduped: one col → gid set
+                continue
+            ns = i1 - i0
+            g3 = (
+                (iota3 >= 1)
+                & (iota3 + n_real <= len3)
+                & _lshift3(s_struct[sig_key][:, :, i0:i1], n_real)
+            )
+            if a_start:
+                g3 = g3 & (iota3 == 1)
+            gj3 = _lshift3(g3, n_lead)  # [T, Q, ns], window-start idx
+            for j in range(k):
+                a0 = block[0] + j * ns
+                cols.append(final_cols(a0, a0 + ns, gj3))
+                col_groups.extend(final_gidsets[gk][j] for gk in gks)
         bh_all = jnp.concatenate(cols, axis=1)
         b2g = np.zeros((len(col_groups), spec.n_groups), dtype=np.float32)
         for ci, gid in enumerate(col_groups):

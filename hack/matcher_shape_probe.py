@@ -43,6 +43,7 @@ def main(argv=None) -> int:
     import numpy as np
 
     from coraza_kubernetes_operator_tpu.engine.waf import WafEngine
+    from coraza_kubernetes_operator_tpu.models.slab import match_slab_shape, match_views
     from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
     from wafbench.harness import read_rules
 
@@ -54,17 +55,21 @@ def main(argv=None) -> int:
     # What each call launches: one Pallas kernel a flat bin and one a
     # dense-DFA block no bin covers (none for crs-lite since PR 31).
     layout = engine.automata_summary()
-    print(json.dumps({k: layout[k] for k in ("flat_bins", "flat_slots", "flat_groups",
-                                             "per_bank_kernels")}), flush=True)
+    print(json.dumps({k: layout[k] for k in ("rules", "segment_columns", "flat_bins", "flat_slots",
+                                             "flat_groups", "per_bank_kernels")}), flush=True)
     model = jax.device_put(engine.model)
     h = max(1, len(engine._host_pipelines))
     rng = np.random.default_rng(28)
 
     def operands(rows: int, width: int, length: int):
-        data = np.zeros((rows, width), np.uint8)
+        """The tier's one match slab (``models/slab.py``), on the device."""
+        slab = np.zeros(match_slab_shape(rows, width, h), np.uint8)
+        data, lengths, vdata, vlengths = match_views(slab)
         data[:, :length] = rng.integers(0x20, 0x7F, (rows, length), dtype=np.uint8)
-        lengths = np.full(rows, length, np.int32)
-        return jax.device_put((data, lengths, np.stack([data] * h), np.stack([lengths] * h)))
+        lengths[:] = length
+        vdata[:] = data
+        vlengths[:] = length
+        return (jax.device_put(slab),)
 
     def compile_one(shape: str):
         rows, width = map(int, shape.split("x"))

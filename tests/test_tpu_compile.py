@@ -244,3 +244,42 @@ def test_long_matcher_compiles_for_v5e(crs_lite, described, operand, rows, width
     assert text.count("tpu_custom_call") == _pallas_calls(model) == FLAT_BINS
     assert f"HloModule jit_cko_match_{rows}x{width}" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
+
+
+# --- the widest bin a custom feed makes (PR 37) ------------------------------
+#
+# A site's feed of path patches (wafbench's ``crs-lite-pl2-custom5k``:
+# 2,000 DFAs of 27 states on one pipeline) fills bins to the planner's
+# budget: 128 groups, 3,456 slots, 14.2 MB on the estimator where
+# crs-lite's widest bin holds 1,664 slots. The planner sizes a bin for
+# the widest buffer the kernel takes, so it has to compile there too.
+
+
+@pytest.fixture(scope="module")
+def feed_bin(on_chip):
+    from coraza_kubernetes_operator_tpu.compiler import compile_regex_dfa
+    from coraza_kubernetes_operator_tpu.ops.dfa_flat import (
+        _layout_stats,
+        build_flat_bank,
+        plan_flat_bins,
+    )
+    from wafbench.tools.freeze_custom import feed_rules
+
+    dfas = [compile_regex_dfa(r["pattern"]) for r in feed_rules(400, 37)
+            if r["template"] == "a"]
+    bins, rejected = plan_flat_bins([(0, 0, dfas)])
+    assert not rejected and len(bins) == 2
+    return build_flat_bank(max(bins, key=lambda bn: _layout_stats(bn)[0]))
+
+
+@pytest.mark.parametrize("rows,width", [(ROWS_WINDOW, WIDTH_WINDOW), (ROWS_BODIES, WIDTH_BODIES)])
+def test_the_widest_bin_of_a_custom_feed_compiles_for_v5e(feed_bin, described, operand,
+                                                          rows, width):
+    from coraza_kubernetes_operator_tpu.ops.dfa_flat import scan_flat_bank
+
+    assert (feed_bin.n_slots, feed_bin.n_groups) == (3456, 128)
+    text = _compile(
+        lambda b, d, n: scan_flat_bank(b, {0: (d, n)}),
+        described(feed_bin), operand((rows, width), jnp.uint8), operand((rows,), jnp.int32),
+    )
+    assert text.count("tpu_custom_call") == 1, "dispatch fell back off the Pallas kernel"
