@@ -2,8 +2,9 @@
 run per device window), in the traced interval. Its module name holds
 ``eval_post`` and no other executable's does."""
 
+from wafbench.layer_metrics._trace_windows import POST_STAGE
+
 SOURCE = "device_trace"
-POST_STAGE = "eval_post"
 
 
 def read(ctx):

@@ -47,7 +47,8 @@ def test_last_line_has_the_contracts_keys_and_the_parent_never_imports_jax(trace
     assert rc == 0, err[-2000:]
     last = lines[-1]
     assert KEYS <= set(last)
-    assert set(last) - KEYS <= {"breakdown", "failed_checks", "compared"}
+    assert set(last) - KEYS <= {"breakdown", "failed_checks", "compared", "trace_cost"}
+    assert ("trace_cost" in last) == (trace == "1")
     assert ("breakdown" in last) == (trace == "1")
     assert last["correct"] is False  # a rehearsal never says true
     assert last["failed_checks"] == ["not_on_tpu"]

@@ -129,9 +129,10 @@ def reduce(events: dict) -> dict:
     spans += [(s, s + d) for _t, _n, s, d in host]
     if not spans:
         return {"device_plane": False, "window_s": 0.0, "busy_s": 0.0, "device_ops": [],
-                "idle_gaps": [], "module_busy_s": {}, "module_runs": {}, "devices": 0}
+                "idle_gaps": [], "module_busy_s": {}, "module_runs": {}, "module_events": [],
+                "devices": 0}
     t0, t1 = min(a for a, _ in spans), max(b for _, b in spans)
-    busy_ns, ops, modules, runs, gaps = 0.0, {}, {}, {}, {}
+    busy_ns, ops, modules, runs, gaps, module_events = 0.0, {}, {}, {}, {}, []
     starts: dict[str, list] = {}  # per host thread, when its events began
     for thread, _n, s, _d in host:
         starts.setdefault(thread, []).append(s)
@@ -145,6 +146,8 @@ def reduce(events: dict) -> dict:
         for name, _s, d in dev["modules"]:
             modules[name] = modules.get(name, 0.0) + d / 1e9
             runs[name] = runs.get(name, 0) + 1
+        module_events.append([[name, (s - t0) / 1e9, d / 1e9]
+                              for name, s, d in sorted(dev["modules"], key=lambda e: e[1])])
         edges = [t0] + [x for ab in merged for x in ab] + [t1]
         idle = sorted(((edges[i + 1] - edges[i], edges[i], edges[i + 1])
                        for i in range(0, len(edges), 2)), reverse=True)[: 4 * TOP]
@@ -181,6 +184,8 @@ def reduce(events: dict) -> dict:
         "idle_gaps": _top(gaps),
         "module_busy_s": modules,
         "module_runs": runs,
+        # per device plane, every executable run in the order it ran: [name, start_s, seconds]
+        "module_events": module_events,
     }
 
 
