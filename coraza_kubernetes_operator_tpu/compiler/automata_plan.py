@@ -87,6 +87,8 @@ class GroupTier:
     approx: DFA | None = None  # prefilter automaton when kind == "prefiltered"
     approx_states: int = 0
     approx_width: int = 0
+    # segment: runs past MAX_SEG_LEN its plan cut into adjacent pieces
+    splits: int = 0
 
 
 @dataclass
@@ -164,9 +166,17 @@ def plan_automata(
         dfa = grp.dfa
         pid = crs.group_pipeline[gid]
         n = dfa.n_states
-        if plan_segments(dfa.ast) is not None:
+        seg_plan = plan_segments(dfa.ast)
+        if seg_plan is not None:
             plan.tiers.append(
-                GroupTier(gid, "segment", n, pid, reason="conv segment plan")
+                GroupTier(
+                    gid,
+                    "segment",
+                    n,
+                    pid,
+                    reason="conv segment plan",
+                    splits=seg_plan.splits,
+                )
             )
             continue
         if dfa.always_match:

@@ -11,12 +11,16 @@ parsed regex AST (``re_parser``) it either produces an **exact** plan
 
     Branch = Seg (class positions, incl. \\b context) · Gap (class, lo, hi) · …
 
-or returns ``None``, in which case the group stays on the DFA tier. The
-decomposition is the TPU-shaped analog of Hyperscan's literal+FDR
-decomposition (the engine behind the reference's Coraza/aho-corasick
-dependency chain, reference ``go.mod:52``) — but lowered to convolution
-instead of SIMD shift-or, because on TPU the systolic array is the fast
-path and convs are its native diet.
+or returns ``None``, in which case the group stays on the DFA tier. A
+run of more than ``MAX_SEG_LEN`` real positions (a 26-byte path, a
+50-byte CRS literal) is cut into adjacent ``Seg`` pieces with no gap
+between them, full pieces from the left and the remainder last, so the
+cap bounds a *piece* (and with it the conv kernel's width), not the
+run. The decomposition is the TPU-shaped analog of Hyperscan's
+literal+FDR decomposition (the engine behind the reference's
+Coraza/aho-corasick dependency chain, reference ``go.mod:52``) — but
+lowered to convolution instead of SIMD shift-or, because on TPU the
+systolic array is the fast path and convs are its native diet.
 
 Exactness contract: every accepted plan matches byte-for-byte the same
 inputs as the source regex under search semantics (differentially tested
@@ -41,6 +45,12 @@ NONWORD = ALL_BYTES & ~WORD
 # the SAME pattern on the DFA tier determinizes to ~4-6k states and
 # scans on the serializing gather path (measured ~4x the whole step).
 MAX_BRANCHES = 128
+# The longest PIECE, not the longest run: a block's conv kernel is as
+# wide as its longest segment (``SegmentSpec.w``) and every column of
+# the block pays that width, so a longer run of real positions is cut
+# into ceil(n / MAX_SEG_LEN) adjacent pieces (``_split_run``) that the
+# chain joins at their exact offsets. What bounds a run is then
+# MAX_ELEMENTS: a branch whose pieces and gaps pass it stays dense.
 MAX_SEG_LEN = 24
 MAX_ELEMENTS = 12
 # Bounded class-gaps: spans <= the unroll cap use shift-unrolled ORs;
@@ -92,6 +102,7 @@ class SegmentPlan:
 
     branches: tuple[Branch, ...]
     always: bool = False  # pattern matches the empty string (search ⇒ always)
+    splits: int = 0  # runs past MAX_SEG_LEN that were cut into adjacent pieces
 
 
 class _Reject(Exception):
@@ -284,18 +295,48 @@ def _resolve_asserts(elems: list[tuple]) -> tuple[list[tuple], bool, bool] | Non
 # ---------------------------------------------------------------------------
 
 
-def _normalize(elems: list[tuple], anchored_start: bool, anchored_end: bool) -> Branch:
+def _split_run(run: list[int], lead: int, trail: int) -> list[Seg]:
+    """One run of class positions as ``Seg``s of at most ``MAX_SEG_LEN``
+    real positions: full pieces from the left and the remainder last
+    (26 → 24 + 2, 50 → 24 + 24 + 2), the lead context on the first piece
+    and the trailing context on the last. Exact: the chain starts each
+    piece where the one before it ended (``n_real`` on), so the pieces
+    match ⇔ the whole run matches at that start.
+
+    Cut from the left, not into equal pieces: rules of one template end
+    alike (``…\\.php``), so their remainders intern to one conv column
+    and one chain suffix (``ops/segment.py``), where equal pieces give
+    every rule a second column and a suffix of its own."""
+    n_real = len(run) - lead - trail
+    starts = [0, *range(lead + MAX_SEG_LEN, lead + n_real, MAX_SEG_LEN)]
+    ends = [*starts[1:], len(run)]
+    last = len(starts) - 1
+    return [
+        Seg(
+            tuple(run[a:b]),
+            n_lead=lead if i == 0 else 0,
+            n_trail=trail if i == last else 0,
+        )
+        for i, (a, b) in enumerate(zip(starts, ends))
+    ]
+
+
+def _normalize(
+    elems: list[tuple], anchored_start: bool, anchored_end: bool
+) -> tuple[Branch, int]:
+    """The branch, and how many of its runs were split."""
     elements: list = []
     run: list[int] = []
     lead = 0
     trail = 0
+    splits = 0
 
     def flush_run():
-        nonlocal run, lead, trail
+        nonlocal run, lead, trail, splits
         if run:
-            if len(run) - lead - trail > MAX_SEG_LEN:
-                raise _Reject("segment longer than MAX_SEG_LEN")
-            elements.append(Seg(tuple(run), n_lead=lead, n_trail=trail))
+            segs = _split_run(run, lead, trail)
+            splits += len(segs) > 1
+            elements.extend(segs)
         run, lead, trail = [], 0, 0
 
     for e in elems:
@@ -336,7 +377,7 @@ def _normalize(elems: list[tuple], anchored_start: bool, anchored_end: bool) -> 
         if isinstance(el, Gap) and el.mask != ALL_BYTES and el.hi is not None:
             if el.hi - el.lo > MAX_BOUNDED_GAP_SPAN:
                 raise _Reject("wide bounded class gap")
-    return Branch(tuple(elements), anchored_start, anchored_end)
+    return Branch(tuple(elements), anchored_start, anchored_end), splits
 
 
 def plan_segments(ast) -> SegmentPlan | None:
@@ -350,13 +391,14 @@ def plan_segments(ast) -> SegmentPlan | None:
 
     branches: list[Branch] = []
     always = False
+    splits = 0
     try:
         for elems in raw:
             resolved = _resolve_asserts(elems)
             if resolved is None:
                 continue  # branch can never match
             out, a_start, a_end = resolved
-            branch = _normalize(out, a_start, a_end)
+            branch, n_split = _normalize(out, a_start, a_end)
             if not branch.elements:
                 if a_start and a_end:
                     raise _Reject("empty anchored branch (len==0 match)")
@@ -374,6 +416,7 @@ def plan_segments(ast) -> SegmentPlan | None:
             if not any(isinstance(el, Seg) and el.n_real > 0 for el in branch.elements):
                 raise _Reject("branch with only context positions")
             branches.append(branch)
+            splits += n_split
     except _Reject:
         return None
 
@@ -381,4 +424,4 @@ def plan_segments(ast) -> SegmentPlan | None:
         return SegmentPlan(branches=(), always=True)
     if not branches:
         return None  # no branch can ever match: leave to the DFA (never)
-    return SegmentPlan(branches=tuple(branches), always=always)
+    return SegmentPlan(branches=tuple(branches), always=always, splits=splits)

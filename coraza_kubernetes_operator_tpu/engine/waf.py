@@ -1445,8 +1445,9 @@ class WafEngine:
         blocks are scanned (fused flat bins, or one kernel per bank for
         the blocks no bin covers), the size of the model they serve
         (compiled rules; the conv tier's output columns, summed over
-        its blocks), and how the prefilter's over-approximation is
-        paying off at runtime."""
+        its blocks; the runs longer than one conv piece that were split
+        into chained pieces, and the groups that hold one), and how the
+        prefilter's over-approximation is paying off at runtime."""
         from ..ops.segment import conv_n2_cols
 
         plan = self.automata_plan
@@ -1459,6 +1460,8 @@ class WafEngine:
             "tiers": counts,
             "rules": len(self.rule_meta),
             "segment_columns": sum(conv_n2_cols(sb.spec) for sb in model.segs),
+            "segment_splits": sum(t.splits for t in plan.tiers),
+            "segment_split_groups": sum(1 for t in plan.tiers if t.splits),
             "gather_banks": len(model.gather_banks),
             "pre_banks": len(model.pre_banks),
             "flat_bins": len(model.flat_banks),

@@ -55,7 +55,8 @@ def main(argv=None) -> int:
     # What each call launches: one Pallas kernel a flat bin and one a
     # dense-DFA block no bin covers (none for crs-lite since PR 31).
     layout = engine.automata_summary()
-    print(json.dumps({k: layout[k] for k in ("rules", "segment_columns", "flat_bins", "flat_slots",
+    print(json.dumps({k: layout[k] for k in ("rules", "segment_columns", "segment_splits",
+                                             "segment_split_groups", "flat_bins", "flat_slots",
                                              "flat_groups", "per_bank_kernels")}), flush=True)
     model = jax.device_put(engine.model)
     h = max(1, len(engine._host_pipelines))

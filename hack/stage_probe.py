@@ -29,7 +29,8 @@ and up to one more a tier where the prefilter confirm repacked the
 tier's hit rows (the CRS cells): at most 2 x tiers + 1, where it read
 10 to 20 before the slabs. The ``warm`` line carries the same over the
 last warm round, the engine's matcher
-layout from ``automata`` (``rules`` and ``segment_columns``: the model
+layout from ``automata`` (``rules``, ``segment_columns`` and
+``segment_splits`` / ``segment_split_groups``: the model
 the table was read on; ``flat_bins``, ``flat_slots``, ``flat_groups``,
 ``per_bank_kernels``) and, from the ``frontend`` counters' growth over
 the last warm round, ``tenant_blob_path_share`` and
@@ -63,10 +64,11 @@ TOKEN = "stage-probe"
 LAUNCH_COUNTERS = ("launch_plan_hits", "launch_plan_misses", "device_windows",
                    "host_twin_windows", "hits", "misses", "bypasses")
 # The model the table is read on (``automata_summary``): compiled rules,
-# the conv tier's columns, and where the engine scans its dense-DFA
-# blocks: fused flat bins, and the blocks left on one kernel a bank.
-MATCHER_LAYOUT = ("rules", "segment_columns", "flat_bins", "flat_slots", "flat_groups",
-                  "per_bank_kernels")
+# the conv tier's columns and the long runs cut into chained pieces, and
+# where the engine scans its dense-DFA blocks: fused flat bins, and the
+# blocks left on one kernel a bank.
+MATCHER_LAYOUT = ("rules", "segment_columns", "segment_splits", "segment_split_groups",
+                  "flat_bins", "flat_slots", "flat_groups", "per_bank_kernels")
 # Growth of these says whether tenant requests rode the blob windows and
 # how many windows one socket read closed (sidecar/ingest.py); the two
 # benchmark metrics that read them give the ratios.

@@ -7,7 +7,11 @@ rule its own tokens. What grows with it is pinned here at 600 rules: the
 device engine against the plain host evaluator (exact status and rule
 id, first and last rule of the feed, a near-miss of every template),
 with more than 512 groups in the model and bins of more than 256 slots
-(the boundaries PR 31's slot digits tripped on); the finals tier of
+(the boundaries PR 31's slot digits tripped on: the path patches,
+26-byte literals, ride the conv tier as two chained pieces, so the bin
+is filled by template e, 40 patches whose directory may repeat — an
+unbounded repetition of a composite, which no segment plan holds);
+the finals tier of
 ``ops/segment.py`` batched over one structure's suffixes; the flat
 planner at the widths the engine launches a bin at; the hot-tier bank
 packing in linear time. JAX-free at the end: the cell resolves by name
@@ -33,6 +37,21 @@ NEW = CONFIGS / "crs-lite-pl2-custom5k"
 SAMPLE = (CONFIGS / "operator-sample" / "rules.conf").read_text()
 N_FEED, SEED = 600, 37
 TEMPLATES = "abcd"
+N_DENSE, DENSE_BASE_ID = 40, 9100000
+
+
+def _dense_rules(taken: set) -> list[dict]:
+    """Template e: ``(?i:/(?:<tok6>/)+<tok5>\\.php)``, 19 DFA states each."""
+    import random
+
+    rng = random.Random(38)
+    rules = []
+    for i in range(N_DENSE):
+        t = freeze_custom._tokens(rng, taken, 6, 5)
+        rules.append({"id": DENSE_BASE_ID + i, "template": "e", "variable": "REQUEST_URI",
+                      "pattern": rf"(?i:/(?:{t[0]}/)+{t[1]}\.php)",
+                      "transforms": "t:none,t:urlDecodeUni", "tokens": t})
+    return rules
 
 
 @pytest.fixture(scope="module")
@@ -41,13 +60,18 @@ def feed():
 
 
 @pytest.fixture(scope="module")
-def engine(feed):
+def dense(feed):
+    return _dense_rules({tok for r in feed for tok in r["tokens"]})
+
+
+@pytest.fixture(scope="module")
+def engine(feed, dense):
     from coraza_kubernetes_operator_tpu.engine import WafEngine
 
     with pytest.MonkeyPatch.context() as mp:
         for k in ("CKO_FLAT", "CKO_AUTOMATA", "CKO_NATIVE"):
             mp.delenv(k, raising=False)
-        return WafEngine(freeze_custom.feed_text(feed) + SAMPLE)
+        return WafEngine(freeze_custom.feed_text(feed + dense) + SAMPLE)
 
 
 def _request(rule: dict, near: bool):
@@ -62,6 +86,8 @@ def _request(rule: dict, near: bool):
     uri = "/app/view?page=2"
     if rule["template"] == "a":
         uri = f"/{t[0]}/{t[1]}/{t[2]}.php?page=2"
+    elif rule["template"] == "e":
+        uri = f"/{t[0]}/{t[0]}/{t[1]}.php?page=2"
     elif rule["template"] == "b":
         uri = f"/app/view?q={t[0]}%20(%20'{t[1]}"
     elif rule["template"] == "c":
@@ -100,7 +126,7 @@ def test_picks_hold_the_ends_of_the_feed_and_four_of_every_template():
 
 def test_the_model_is_past_the_boundaries(engine):
     auto = engine.automata_summary()
-    assert auto["rules"] == N_FEED + 2 == len(engine.rule_meta)
+    assert auto["rules"] == N_FEED + N_DENSE + 2 == len(engine.rule_meta)
     assert len(engine.compiled.groups) > 512
     assert auto["per_bank_kernels"] == 0
     assert max(fb.n_slots for fb in engine.model.flat_banks) > 256
@@ -108,17 +134,19 @@ def test_the_model_is_past_the_boundaries(engine):
     from coraza_kubernetes_operator_tpu.ops.segment import conv_n2_cols
 
     assert auto["segment_columns"] == sum(conv_n2_cols(s.spec) for s in engine.model.segs) > 512
-    # all four templates are in the model: the path patches on the
-    # dense-DFA side (26 bytes is past the conv's MAX_SEG_LEN), the rest
-    # in the conv tier
-    assert auto["tiers"]["dfa-hot"] >= 240 and auto["tiers"]["segment"] >= 360
+    # all four templates are in the conv tier, the path patches (26 bytes
+    # is past the conv's MAX_SEG_LEN) as two chained pieces each; what
+    # fills the bins is the 40 rules no segment plan holds
+    assert auto["tiers"]["segment"] == N_FEED + 2 and auto["tiers"]["dfa-hot"] == N_DENSE
+    assert auto["segment_split_groups"] == auto["segment_splits"] == 240
+    assert auto["flat_groups"] == N_DENSE
 
 
-def test_device_verdicts_equal_the_host_evaluators(engine, feed):
+def test_device_verdicts_equal_the_host_evaluators(engine, feed, dense):
     first_of = {tpl: next(r for r in feed if r["template"] == tpl) for tpl in TEMPLATES}
     last_of = {tpl: next(r for r in reversed(feed) if r["template"] == tpl) for tpl in TEMPLATES}
     rules = [feed[0], feed[-1], *first_of.values(), *last_of.values(), feed[255], feed[256],
-             feed[257], feed[511], feed[512], feed[513]]
+             feed[257], feed[511], feed[512], feed[513], dense[0], dense[13], dense[14], dense[-1]]
     reqs, want = [], []
     for r in rules:
         reqs += [_request(r, near=False), _request(r, near=True)]
@@ -164,7 +192,8 @@ def _dfas_of_27_states(n: int):
 
 @pytest.mark.parametrize("width", [512, 2048])
 def test_flat_planner_keeps_every_bin_inside_the_chips_budget(width):
-    """500 dfa-hot DFAs of 27 states (the feed's path patches): no block
+    """500 dfa-hot DFAs of 27 states (the feed's path patches scanned as
+    DFAs: what a feed of small rules with no segment plan is): no block
     rejected, every bin inside the scoped-VMEM limit the chip's compiler
     enforces, at the widths a bin is launched at."""
     from coraza_kubernetes_operator_tpu.ops import dfa_flat

@@ -36,8 +36,8 @@ from conftest import dfa_witness as _witness, load_native, native_engine
 
 CRS_CACHE_DIR = str(Path(__file__).resolve().parent / ".crs_cache")
 
-# crs-lite's prefiltered groups (docs/AUTOMATA.md; the wafbench cell's 12).
-N_CRS_LITE_GROUPS = 12
+# crs-lite's prefiltered groups (docs/AUTOMATA.md; the wafbench cell's 11).
+N_CRS_LITE_GROUPS = 11
 
 # One synthetic rule per native transform opcode, all over the 384-state
 # pattern tests/test_automata_routing.py prefilters. Pipelines the device

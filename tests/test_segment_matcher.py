@@ -5,6 +5,7 @@ every pattern the decomposer accepts is replayed against Python ``re``
 on randomized word soup plus targeted edge inputs, byte for byte.
 """
 
+import functools
 import random
 import re
 
@@ -337,3 +338,278 @@ def test_shared_classes_distinct_geometry_no_collision():
                     pi,
                     value,
                 )
+
+
+# -- runs past MAX_SEG_LEN: chained pieces (ISSUE 40) -------------------------
+#
+# A run of more than MAX_SEG_LEN real positions is cut into adjacent
+# pieces, full ones from the left and the remainder last; the chain joins
+# them at their exact offsets. Each run length builds ONE block of all
+# its variants (one jit a length), and every (length, variant) is a case
+# of its own against Python ``re`` on the length's whole row set.
+
+_WORDY = "select_table_name_from_information_schema_tables_where_table_schema_is_not_null"
+_OTHER = "wp_content_plugins_akismet_anti_spam_includes_class_akismet_admin_widget_php_x"
+RUN_LENGTHS = [24, 25, 26, 47, 48, 49, 50, 72, 73]
+# variant -> pattern of one run ``a`` (and a second run ``b`` for the gaps)
+RUN_VARIANTS = {
+    "plain": lambda a, b: a,
+    "nocase": lambda a, b: f"(?i:{a})",
+    "wordb_head": lambda a, b: rf"\b{a}",
+    "wordb_tail": lambda a, b: rf"{a}\b",
+    "wordb_both": lambda a, b: rf"\b{a}\b",
+    "start": lambda a, b: f"^{a}",
+    "end": lambda a, b: f"{a}$",
+    "start_end": lambda a, b: f"^{a}$",
+    "bounded_gap": lambda a, b: f"{a}.{{2,5}}{b}",
+    "unbounded_gap": lambda a, b: f"{a}[^/]*{b}",
+}
+
+
+def _left_cut(n: int) -> tuple[int, ...]:
+    from coraza_kubernetes_operator_tpu.compiler.segments import MAX_SEG_LEN
+
+    return (MAX_SEG_LEN,) * (n // MAX_SEG_LEN) + ((n % MAX_SEG_LEN,) if n % MAX_SEG_LEN else ())
+
+
+def _mutations(witness: bytes) -> list[bytes]:
+    """The witness at offset 0, mid-row and after a word byte; cut short
+    at every length; and with one byte dropped, doubled or changed at
+    every offset: a piece joined one position off, a run matched without
+    one of its pieces, or a near miss inside any one piece shows on one."""
+    out = [witness, b"~" + witness, witness + b"~", b"zz " + witness + b" zz",
+           b"x" + witness, witness + b"x", witness + b"_", b"9" + witness + b" ",
+           b" " + witness + b"9", b"(" + witness.upper() + b")", witness.title()]
+    out += [witness[:k] for k in range(len(witness))]
+    for k in range(len(witness)):
+        other = b"#" if witness[k : k + 1] != b"#" else b"%"
+        out.append(witness[:k] + witness[k + 1 :])
+        out.append(witness[:k] + witness[k : k + 1] + witness[k:])
+        out.append(witness[:k] + other + witness[k + 1 :])
+    return out
+
+
+def _pack(rows: list[bytes], max_len: int):
+    data = np.zeros((len(rows), max_len), dtype=np.uint8)
+    lengths = np.zeros(len(rows), dtype=np.int32)
+    for i, r in enumerate(rows):
+        data[i, : len(r)] = np.frombuffer(r, dtype=np.uint8)
+        lengths[i] = len(r)
+    return data, lengths
+
+
+@functools.lru_cache(maxsize=None)
+def _run_block(n: int):
+    """(patterns, plans, rows, hits, kernel width) of every variant at
+    run length ``n``: one block, one jit."""
+    a, b = _WORDY[:n], _OTHER[:n]
+    pats = {name: make(a, b) for name, make in RUN_VARIANTS.items()}
+    plans = {name: plan_segments(parse_regex(p)) for name, p in pats.items()}
+    wa, wb = a.encode(), b.encode()
+    rows = [b"", *_mutations(wa)]
+    for gap in (b"", b"a", b"ab", b"abcde", b"abcdef", b"a/b", b"?x=1&y="):
+        rows.append(wa + gap + wb)
+    rows += [wb + wa, wb + b"ab" + wa]
+    # the two-run witness with a near miss inside each piece of each run
+    two = wa + b"abc" + wb
+    for start in (0, n + 3):
+        for k in range(start, start + n, 12):
+            rows.append(two[:k] + b"#" + two[k + 1 :])
+    max_len = max(len(r) for r in rows) + 3
+    # hits that end at the row's last byte: the buffer has no slack there
+    rows += [b"." * (max_len - n) + wa, b" " * (max_len - len(two)) + two,
+             b"." * (max_len - n - 1) + wa + b"x"]
+    data, lengths = _pack(rows, max_len)
+    block = build_segment_block(list(plans.values()))
+    hits = np.asarray(match_segment_block(block.kernel, block.spec, data, lengths))
+    return pats, plans, rows, hits, block.spec.w
+
+
+@pytest.mark.parametrize("variant", list(RUN_VARIANTS))
+@pytest.mark.parametrize("n", RUN_LENGTHS)
+def test_long_runs_match_python_re(n, variant):
+    from coraza_kubernetes_operator_tpu.compiler.segments import MAX_SEG_LEN, Seg
+
+    pats, plans, rows, hits, w = _run_block(n)
+    plan = plans[variant]
+    assert plan is not None and len(plan.branches) == 1
+    segs = [el for el in plan.branches[0].elements if isinstance(el, Seg)]
+    # ``.{2,5}`` is two positions of the first run and a gap of 0 to 3
+    runs = {"bounded_gap": (n + 2, n), "unbounded_gap": (n, n)}.get(variant, (n,))
+    assert tuple(s.n_real for s in segs) == sum((_left_cut(r) for r in runs), ())
+    assert plan.splits == sum(r > MAX_SEG_LEN for r in runs)
+    # the contexts ride the end pieces only, and the kernel is no wider
+    # than one piece and its contexts
+    assert all(s.n_lead == 0 for s in segs[1:]) and all(s.n_trail == 0 for s in segs[:-1])
+    assert w <= MAX_SEG_LEN + 2
+    oracle = re.compile(pats[variant].encode())
+    want = [oracle.search(r) is not None for r in rows]
+    assert any(want) and not all(want)
+    col = list(RUN_VARIANTS).index(variant)
+    for i, r in enumerate(rows):
+        assert bool(hits[i, col]) == want[i], (pats[variant], r)
+
+
+_PATH = "/wp-content/plugins/akismet-anti-spam/includes/class-akismet-admin-widget.php"
+_L26, _L30 = _PATH[:26], _WORDY[:30]
+_PERIODIC = "ab" * 15  # pieces "ab" * 12 / "ab" * 3: the second also matches inside the first
+_P1, _P2 = _PERIODIC[:24].encode(), _PERIODIC[24:].encode()
+
+# (id, pattern, case-insensitive, real positions of the first branch's Segs, witnesses, extra rows)
+SPLIT_CASES = [
+    ("path26", re.escape(_L26), False, (24, 2), [_L26], []),
+    ("path26_nocase", re.escape(_L26), True, (24, 2), [_L26, _L26.upper(), _L26.title()], []),
+    ("path72", re.escape(_PATH[:72]), False, (24, 24, 24), [_PATH[:72]], []),
+    ("two_long_runs_and_a_class_gap", re.escape(_L26) + r"[^/]*" + _L30, False,
+     (24, 2, 24, 6), [_L26 + _L30, _L26 + "?x=1&y=" + _L30],
+     [(_L26 + "a/b" + _L30).encode(), (_L30 + _L26).encode()]),
+    ("two_long_runs_and_a_counted_gap", re.escape(_L26) + r".{2,5}" + _L30, False,
+     (24, 4, 24, 6), [_L26 + "ab" + _L30, _L26 + "abcde" + _L30],
+     [(_L26 + "a" + _L30).encode(), (_L26 + "abcdef" + _L30).encode()]),
+    ("periodic", _PERIODIC, False, (24, 6), [_PERIODIC],
+     # each piece, but not adjacent; adjacent but shifted by one; one
+     # period short (piece 1 at 0 and piece 2 at 22, not at 24)
+     [_P1 + b"x" + _P2, _P1 + _P1[:5], _P2 + _P1, _P1 + _P2[1:], b"ab" * 14, b"ab" * 14 + b"a",
+      b"b" + b"ab" * 14 + b"a", b"ab" * 16, b"ba" * 15 + b"b", _P1 + b"a" + _P2]),
+    ("input_ends_inside_the_last_piece", re.escape(_L26) + r"\d", False, (24, 3),
+     [_L26 + "7"], [_L26.encode()[:k] for k in (13, 24, 25)] + [_L26.encode()]),
+    ("class_run_of_30", r"[0-9a-f]{30}", False, (24, 6), ["0123456789abcdef" * 2],
+     [b"0123456789abcde" * 2, b"0123456789abcdeg" + b"0123456789abcde", b"f" * 29, b"f" * 30,
+      b"f" * 23 + b"g" + b"f" * 6 + b"g" + b"f" * 29]),
+    ("class_run_of_32_anchored", r"^[0-9a-f]{32}$", False, (24, 8), ["0123456789abcdef" * 2],
+     [b"f" * 31, b"f" * 33, b"f" * 24 + b"g" + b"f" * 7, b"f" * 32 + b" "]),
+    ("counted_run_past_the_cap", r"x[0-9]{26,28}y", False, (24, 3, 1),
+     ["x" + "1" * 26 + "y", "x" + "1" * 28 + "y"],
+     [b"x" + b"1" * 25 + b"y", b"x" + b"1" * 29 + b"y"]),
+    ("gap_first_branch", r"[0-9]*" + re.escape(_L26), False, (24, 2), [_L26, "42" + _L26], []),
+    ("alternation_of_long_runs", f"(?:{_WORDY[:40]}|{_OTHER[:30]})", False, (24, 16),
+     [_WORDY[:40], _OTHER[:30]], [(_WORDY[:24] + _OTHER[24:30]).encode()]),
+]
+
+
+@pytest.mark.parametrize("case", SPLIT_CASES, ids=[c[0] for c in SPLIT_CASES])
+def test_split_runs_match_python_re(case):
+    """Shapes of a split run other than one literal: class runs, counted
+    runs, a gap-first branch, a periodic text whose pieces also match one
+    position off; each a block of its own."""
+    from coraza_kubernetes_operator_tpu.compiler.segments import MAX_SEG_LEN, Seg
+
+    _name, pat, ci, pieces, witnesses, extra = case
+    plan = plan_segments(parse_regex(pat, case_insensitive=ci))
+    assert plan is not None and plan.splits >= 1
+    segs = [el for el in plan.branches[0].elements if isinstance(el, Seg)]
+    assert tuple(s.n_real for s in segs) == pieces
+    assert all(s.n_real <= MAX_SEG_LEN for br in plan.branches for s in br.elements
+               if isinstance(s, Seg))
+    block = build_segment_block([plan])
+
+    rows = [b"", *extra]
+    for w in witnesses:
+        rows += _mutations(w.encode())
+    data, lengths = _pack(rows, 8 * -(-(max(len(r) for r in rows) + 1) // 8))
+    hits = np.asarray(match_segment_block(block.kernel, block.spec, data, lengths))
+    oracle = re.compile(pat.encode(), re.IGNORECASE if ci else 0)
+    want = [oracle.search(r) is not None for r in rows]
+    assert any(want) and not all(want)
+    for i, r in enumerate(rows):
+        assert bool(hits[i, 0]) == want[i], (pat, r)
+
+
+def test_split_pieces_carry_the_contexts_on_the_ends_only():
+    from coraza_kubernetes_operator_tpu.compiler.segments import Seg
+
+    plan = plan_segments(parse_regex(r"\b" + _WORDY[:50] + r"\b"))
+    (branch,) = plan.branches
+    assert plan.splits == 1 and all(isinstance(el, Seg) for el in branch.elements)
+    assert [(s.n_lead, s.n_real, s.n_trail) for s in branch.elements] == [
+        (1, 24, 0), (0, 24, 0), (0, 2, 1)]
+    # a run of MAX_SEG_LEN is one piece, as before
+    whole = plan_segments(parse_regex(_WORDY[:24]))
+    assert whole.splits == 0 and len(whole.branches[0].elements) == 1
+
+
+def test_rules_of_one_template_share_their_remainder():
+    """Why the cut is from the left: path patches that end alike bring
+    one conv column each (their first 24 bytes) and share the remainder's
+    column and the one chain suffix it makes."""
+    from coraza_kubernetes_operator_tpu.ops.segment import conv_n2_cols
+
+    pats = [rf"(?i:/{a}/{b}/{c}\.php)" for a, b, c in
+            [("abcdef", "ghijklmn", "opqrs"), ("tuvwxy", "zabcdefg", "hijkl"),
+             ("mnopqr", "stuvwxyz", "abcde"), ("fghijk", "lmnopqrs", "tuvwx")]]
+    plans = [plan_segments(parse_regex(p)) for p in pats]
+    assert all(p.splits == 1 for p in plans)
+    spec = build_segment_block(plans).spec
+    assert spec.n_seg == len(pats) + 1 and conv_n2_cols(spec) == len(pats) + 1
+    assert len({(prog[1:], a_end) for _g, prog, _s, a_end in spec.branches}) == 1
+
+
+def test_a_run_of_more_pieces_than_max_elements_stays_dense():
+    from coraza_kubernetes_operator_tpu.compiler.segments import MAX_ELEMENTS, MAX_SEG_LEN
+
+    fits = "q" * (MAX_SEG_LEN * MAX_ELEMENTS)
+    plan = plan_segments(parse_regex(fits))
+    assert plan is not None and len(plan.branches[0].elements) == MAX_ELEMENTS
+    assert plan_segments(parse_regex(fits + "q")) is None
+    # and pieces count against the branch's other elements
+    assert plan_segments(parse_regex(r"a\d+" + "q" * (MAX_SEG_LEN * (MAX_ELEMENTS - 2)))) is not None
+    assert plan_segments(parse_regex(r"a\d+b" + "q" * (MAX_SEG_LEN * (MAX_ELEMENTS - 2)))) is None
+
+
+# What each rule text's groups plan to, pinned against the parent commit
+# (830c183, read there with the same digest): a text with no run past
+# MAX_SEG_LEN must give the plans it gave before ISSUE 40, object for
+# object, so its matcher executables are the ones that were measured.
+_CONFIGS = "wafbench/configs"
+_TENANT = _CONFIGS + "/operator-sample-tenants32/rules/text-{}.conf"
+# (id, rule file, groups, groups with no plan, digest of the parent's plans)
+UNSPLIT_TEXTS = [
+    ("sample", _CONFIGS + "/operator-sample/rules.conf", 2, 0, "4a11e33d779086a2"),
+    ("tenant-a", _TENANT.format("a"), 2, 0, "4a11e33d779086a2"),
+    ("tenant-b", _TENANT.format("b"), 1, 0, "f5e61c29948e9ab2"),
+    ("tenant-c", _TENANT.format("c"), 12, 0, "175e5cca748931ff"),
+    ("tenant-d", _TENANT.format("d"), 5, 1, "055908cb9a5cef47"),
+]
+
+
+@pytest.mark.parametrize("case", UNSPLIT_TEXTS, ids=[c[0] for c in UNSPLIT_TEXTS])
+def test_texts_with_no_long_run_plan_as_the_parent_did(case):
+    import hashlib
+    from pathlib import Path
+
+    from coraza_kubernetes_operator_tpu.compiler.automata_plan import plan_automata
+    from coraza_kubernetes_operator_tpu.compiler.ruleset import compile_rules
+
+    _name, rel, n_groups, n_dense, digest = case
+    crs = compile_rules((Path(__file__).resolve().parents[1] / rel).read_text())
+    plans = [plan_segments(g.dfa.ast) for g in crs.groups]
+    assert (len(plans), sum(p is None for p in plans)) == (n_groups, n_dense)
+    assert all(p.splits == 0 for p in plans if p is not None)
+    shape = [None if p is None else (p.branches, p.always) for p in plans]
+    assert hashlib.sha256(repr(shape).encode()).hexdigest()[:16] == digest
+    assert sum(t.splits for t in plan_automata(crs).tiers) == 0
+
+
+def test_crs_lite_has_fifteen_split_groups():
+    """Fifteen of crs-lite's 49 groups outside the conv tier were there
+    for a literal past MAX_SEG_LEN and nothing else; the 401-byte one
+    needs 17 pieces and stays dense."""
+    from pathlib import Path
+
+    from coraza_kubernetes_operator_tpu.compiler.automata_plan import plan_automata
+    from coraza_kubernetes_operator_tpu.compiler.ruleset import compile_rules_cached
+    from coraza_kubernetes_operator_tpu.compiler.segments import MAX_SEG_LEN, Seg
+    from coraza_kubernetes_operator_tpu.ftw.corpus import load_ruleset_text
+
+    cache = str(Path(__file__).resolve().parent / ".crs_cache")
+    crs = compile_rules_cached(load_ruleset_text(), cache)
+    tiers = plan_automata(crs).tiers
+    assert sum(1 for t in tiers if t.splits) == 15 and sum(t.splits for t in tiers) == 26
+    assert sum(1 for t in tiers if t.kind != "segment") == 34
+    assert all(t.kind == "segment" for t in tiers if t.splits)
+    longest = 0
+    for g in crs.groups:
+        plan = plan_segments(g.dfa.ast)
+        for br in plan.branches if plan is not None else ():
+            longest = max([longest, *(el.n_real for el in br.elements if isinstance(el, Seg))])
+    assert longest == MAX_SEG_LEN  # the kernel is no wider than it was

@@ -248,10 +248,11 @@ def test_long_matcher_compiles_for_v5e(crs_lite, described, operand, rows, width
 
 # --- the widest bin a custom feed makes (PR 37) ------------------------------
 #
-# A site's feed of path patches (wafbench's ``crs-lite-pl2-custom5k``:
-# 2,000 DFAs of 27 states on one pipeline) fills bins to the planner's
+# A feed of small dense rules on one pipeline (the DFAs here are those
+# of wafbench's ``crs-lite-pl2-custom5k`` path patches, 27 states each,
+# which the engine itself serves from the conv tier) fills bins to the planner's
 # budget: 128 groups, 3,456 slots, 14.2 MB on the estimator where
-# crs-lite's widest bin holds 1,664 slots. The planner sizes a bin for
+# crs-lite's widest bin holds 768 slots. The planner sizes a bin for
 # the widest buffer the kernel takes, so it has to compile there too.
 
 
@@ -283,3 +284,33 @@ def test_the_widest_bin_of_a_custom_feed_compiles_for_v5e(feed_bin, described, o
         described(feed_bin), operand((rows, width), jnp.uint8), operand((rows,), jnp.int32),
     )
     assert text.count("tpu_custom_call") == 1, "dispatch fell back off the Pallas kernel"
+
+
+# --- the same path patches as chained conv pieces (ISSUE 40) -----------------
+
+
+def test_split_path_patches_compile_as_one_conv_block_for_v5e(on_chip, described, operand):
+    """400 literals of 26 bytes, 24 + 2 each: 400 first pieces under the
+    one suffix their shared remainder makes, so the program is a few
+    hundred lines whatever the feed's size, and its conv output fits."""
+    from coraza_kubernetes_operator_tpu.compiler.re_parser import parse_regex
+    from coraza_kubernetes_operator_tpu.compiler.segments import plan_segments
+    from coraza_kubernetes_operator_tpu.ops.segment import (
+        build_segment_block,
+        conv_n2_cols,
+        match_segment_block,
+    )
+    from wafbench.tools.freeze_custom import feed_rules
+
+    rules = [r for r in feed_rules(1000, 37) if r["template"] == "a"]
+    plans = [plan_segments(parse_regex(r["pattern"])) for r in rules]
+    assert len(plans) == 400 and all(p is not None and p.splits == 1 for p in plans)
+    block = build_segment_block(plans)
+    assert conv_n2_cols(block.spec) == 401 and block.spec.w == 24
+    compiled = match_segment_block.lower(
+        described(block.kernel), block.spec,
+        operand((ROWS_WINDOW, WIDTH_WINDOW), jnp.uint8), operand((ROWS_WINDOW,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    assert "convolution" in text and text.count("conditional(") < 8
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30

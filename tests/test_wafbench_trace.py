@@ -1,0 +1,40 @@
+"""Tier-1 runs the fast tests of the benchmark's traced path and of its
+off-path limits.
+
+``wafbench/tests/test_trace_limits.py`` pins the two limits the harness
+waits under (``T_TRACE_S`` for the profiler's commands, ``T_CONTROL_S``
+for the rest) against a launcher stand-in, and how long a CRS mix's
+capture of ``trace_windows`` device windows lasts; ``test_trace_windows.py``
+that the matcher's time a window is read over the capture's whole
+windows; ``test_off_path.py`` that what one abandoned window moves is
+held to a hundredth of the window and every other counter to 0. The
+files are the benchmark's and stay where they are; their JAX-free tests
+are imported here, case by case, so that every PR runs them (as
+``tests/test_wafbench_deployment.py`` does). The whole runs on the CPU
+(each starts a sidecar) stay with ``pytest wafbench/tests``.
+"""
+
+from wafbench.tests.test_off_path import (  # noqa: F401
+    test_a_run_too_short_to_hold_a_share_is_exact_again,
+    test_a_twentieth_of_the_windows_off_the_device_path_is_not,
+    test_every_other_counter_is_exact,
+    test_one_abandoned_window_and_what_follows_from_it_is_correct,
+    test_the_answers_are_exact,
+    test_the_share_is_a_hundredth_and_covers_the_chain_and_nothing_else,
+)
+from wafbench.tests.test_trace_limits import (  # noqa: F401
+    stand_in,
+    test_a_capture_is_never_longer_than_the_mixs_cap,
+    test_a_crs_capture_holds_24_to_40_windows_whatever_a_window_takes,
+    test_a_mix_without_trace_windows_captures_its_seconds,
+    test_a_stop_between_the_two_limits_is_waited_for_and_the_line_says_what_it_cost,
+    test_a_stop_later_than_the_trace_limit_fails_and_names_command_limit_and_wait,
+    test_memory_is_still_held_to_the_control_limit,
+    test_the_limits_as_shipped,
+)
+from wafbench.tests.test_trace_windows import (  # noqa: F401
+    test_a_window_cut_at_an_edge_does_not_move_the_matchers_time,
+    test_the_reduction_keeps_every_executable_run_in_the_order_it_ran,
+    test_two_windows_in_flight_one_run_off_a_whole_number_is_scaled_to_it,
+    test_without_the_order_of_runs_the_whole_capture_is_read,
+)
