@@ -40,9 +40,25 @@ import time
 import jax
 import numpy as np
 
+from ..observability import device_scopes
 from ..utils import get_logger
 
 log = get_logger("engine.compile_cache")
+
+
+def _salt_persistent_keys() -> None:
+    """JAX strips an operation's metadata before it hashes a program for
+    the persistent cache, and a ``jax.named_scope`` is metadata: without
+    this an executable cached by a build with other scopes (or none)
+    would be served back carrying that build's names, and
+    ``device_scopes.count`` would read them. The registry's salt goes
+    into every key of this process through JAX's own hook for it."""
+    try:
+        from jax._src import cache_key
+
+        cache_key.custom_hook = lambda: device_scopes.CACHE_KEY_SALT
+    except ImportError as err:  # a JAX without the hook: names may then be stale
+        log.error("persistent compile cache keys not salted", err)
 
 # Where the persistent cache goes, in order of precedence:
 # 1. ``JAX_COMPILATION_CACHE_DIR`` — set from outside (an operator, a
@@ -89,8 +105,10 @@ def configure_persistent_cache(
 
     Thresholds drop to zero so every executable is eligible — the WAF
     model's per-tier executables are exactly the artifacts a cold
-    process needs back, whatever their size or compile time.
+    process needs back, whatever their size or compile time. Every key
+    carries the device scopes' salt (:func:`_salt_persistent_keys`).
     """
+    _salt_persistent_keys()  # whichever directory holds the cache, JAX's own included
     d = resolve_cache_dir(cache_dir, default)
     if d is None:
         return _configured_dir[0] if _configured_dir else None
@@ -162,6 +180,27 @@ def stage_key(jitted, model_sig: tuple, operands: tuple, static_kwargs: dict) ->
     )
 
 
+def _device_ops(compiled) -> dict | None:
+    """``device_scopes.count`` of a compiled executable, or None: its
+    text raising or unreadable is a boundary, never an exception into a
+    compile."""
+    try:
+        return device_scopes.count(compiled.as_text())
+    except Exception as err:
+        log.error("device operations not counted", err)
+        return None
+
+
+def _model_digest(key: tuple) -> str:
+    """Eight hex digits that tell one model's executables from
+    another's of the same name within this process (a stage key's second
+    member is the model's signature)."""
+    try:
+        return format(hash(key[1]) & 0xFFFFFFFF, "08x")
+    except (IndexError, TypeError):  # a key of another make
+        return ""
+
+
 class ExecutableCache:
     """Signature-keyed registry of AOT-compiled executables."""
 
@@ -206,6 +245,13 @@ class ExecutableCache:
         # a stage not resident, a table dropped with its model).
         self.launch_plan_hits = 0
         self.launch_plan_misses = 0
+        # What a launch of each resident ``cko_*`` executable is made of
+        # (``device_scopes.count`` of its optimized HLO, once, at its
+        # compile): key -> {"name", "model", "device_ops"}. ``device_ops``
+        # is None where the text could not be had or read, and
+        # ``scope_table_errors`` counts those.
+        self._device_ops: dict[tuple, dict] = {}
+        self.scope_table_errors = 0
 
     def note_window(self, out, on_device: bool) -> None:
         """Count one collected window (``WafEngine._collect``). ``out``
@@ -258,6 +304,10 @@ class ExecutableCache:
         finally:
             with self._lock:
                 self.inflight -= 1
+        name = getattr(jitted, "__name__", str(jitted))
+        scoped = name.startswith(device_scopes.EXECUTABLE_PREFIX)
+        device_ops = _device_ops(compiled) if scoped else None
+        t3 = time.perf_counter()
         with self._lock:
             self.misses += 1
             self.trace_s += t1 - t0
@@ -265,11 +315,19 @@ class ExecutableCache:
             # Keep exactly one resident executable per signature even if
             # two threads raced the compile.
             compiled = self._entries.setdefault(key, compiled)
+            if scoped and key not in self._device_ops:
+                self._device_ops[key] = {
+                    "name": name,
+                    "model": _model_digest(key),
+                    "device_ops": device_ops,
+                }
+                self.scope_table_errors += device_ops is None
         log.info(
             "compiled executable",
-            fn=getattr(jitted, "__name__", str(jitted)),
+            fn=name,
             trace_s=round(t1 - t0, 2),
             compile_s=round(t2 - t1, 2),
+            scopes_s=round(t3 - t2, 3),
             entries=len(self._entries),
         )
         return compiled
@@ -343,7 +401,32 @@ class ExecutableCache:
                 "launch_plan_hits": self.launch_plan_hits,
                 "launch_plan_misses": self.launch_plan_misses,
                 "persistent_dir": _configured_dir[0] if _configured_dir else None,
+                "executables": sorted(
+                    self._device_ops.values(), key=lambda e: (e["name"], e["model"])
+                ),
+                "scope_table_errors": self.scope_table_errors,
             }
+
+    def scope_tables(self) -> list[dict]:
+        """``{"name", "model", "table"}`` of every resident ``cko_*``
+        executable: instruction name -> scope path
+        (``device_scopes.table``), walked now from the executables'
+        text and kept nowhere. ``table`` is None where that fails."""
+        with self._lock:
+            resident = [
+                (meta, self._entries[key])
+                for key, meta in self._device_ops.items()
+                if key in self._entries
+            ]
+        out = []
+        for meta, compiled in sorted(resident, key=lambda r: (r[0]["name"], r[0]["model"])):
+            try:
+                names = device_scopes.table(compiled.as_text())
+            except Exception as err:  # boundary: a dump without this table
+                log.error("scope table unavailable", err, fn=meta["name"])
+                names = None
+            out.append({"name": meta["name"], "model": meta["model"], "table": names})
+        return out
 
     def snapshot(self) -> tuple[int, int, float]:
         """(hits, misses, compile_s) — for delta reporting."""
@@ -353,6 +436,7 @@ class ExecutableCache:
     def clear(self) -> None:
         with self._lock:
             self._entries.clear()
+            self._device_ops.clear()
             self.generation += 1
 
 

@@ -745,7 +745,10 @@ def segment_tier_hits(
     if bitmap_elems <= _SEG_CHUNK_ELEMS or not keep:
         return [
             match_segment_block(
-                segs[i].kernel, segs[i].spec, *transformed_for(seg_pipelines[i])
+                segs[i].kernel,
+                segs[i].spec,
+                *transformed_for(seg_pipelines[i]),
+                block_index=i,
             )
             if i in keep
             else zeros_for(i)
@@ -768,56 +771,71 @@ def segment_tier_hits(
         stacked_d, stacked_l = [], []
         for pid in pids:
             td, tl = transformed_for(pid)
-            stacked_d.append(
-                jnp.pad(td, ((0, tp - t), (0, 0))).reshape(nc, rows_fit, td.shape[1])
-            )
-            stacked_l.append(jnp.pad(tl, (0, tp - t)).reshape(nc, rows_fit))
+            with jax.named_scope("cko.seg.chunk"):
+                stacked_d.append(
+                    jnp.pad(td, ((0, tp - t), (0, 0))).reshape(
+                        nc, rows_fit, td.shape[1]
+                    )
+                )
+                stacked_l.append(jnp.pad(tl, (0, tp - t)).reshape(nc, rows_fit))
 
         def one_chunk(args):
             ds, ls = args
             return jnp.concatenate(
                 [
                     match_segment_block(
-                        seg.kernel, seg.spec, ds[pid_ix[pid]], ls[pid_ix[pid]]
+                        seg.kernel,
+                        seg.spec,
+                        ds[pid_ix[pid]],
+                        ls[pid_ix[pid]],
+                        block_index=i,
                     )
-                    for _, seg, pid in kept
+                    for i, seg, pid in kept
                 ],
                 axis=1,
             )
 
-        hits = jax.lax.map(
-            one_chunk,
-            (jnp.stack(stacked_d, axis=1), jnp.stack(stacked_l, axis=1)),
-        )
-        hits = hits.reshape(tp, hits.shape[2])[:t]
-        # Reassemble full column order, zero blocks for skipped segs.
-        out, off = [], 0
-        for i in range(len(segs)):
-            if i in keep:
-                g = segs[i].n_groups
-                out.append(hits[:, off : off + g])
-                off += g
-            else:
-                out.append(zeros_for(i))
+        # The blocks inside the map keep their own scopes: an operation
+        # stands under the innermost scope of its name.
+        with jax.named_scope("cko.seg.chunk"):
+            hits = jax.lax.map(
+                one_chunk,
+                (jnp.stack(stacked_d, axis=1), jnp.stack(stacked_l, axis=1)),
+            )
+            hits = hits.reshape(tp, hits.shape[2])[:t]
+            # Reassemble full column order, zero blocks for skipped segs.
+            out, off = [], 0
+            for i in range(len(segs)):
+                if i in keep:
+                    g = segs[i].n_groups
+                    out.append(hits[:, off : off + g])
+                    off += g
+                else:
+                    out.append(zeros_for(i))
         return out
     if bool(long_banks) and _SEG_BITMAP_ELEMS > 0:
-        long_cols = [
-            scan_dfa_bank(bank, *transformed_for(pid))
-            for bank, pid in zip(long_banks, long_bank_pipelines)
-        ]
-        lh = jnp.concatenate(long_cols, axis=1)  # [T, Gs] in long order
-        return [
-            jnp.dot(
-                lh.astype(jnp.bfloat16),
-                seg_perm.astype(jnp.bfloat16),
-                preferred_element_type=jnp.float32,
-            )
-            > 0
-        ]  # [T, Gs] in seg-column order
+        long_cols = []
+        for bank, pid in zip(long_banks, long_bank_pipelines):
+            td = transformed_for(pid)
+            with jax.named_scope("cko.seg.long"):
+                long_cols.append(scan_dfa_bank(bank, *td))
+        with jax.named_scope("cko.seg.long"):
+            lh = jnp.concatenate(long_cols, axis=1)  # [T, Gs] in long order
+            return [
+                jnp.dot(
+                    lh.astype(jnp.bfloat16),
+                    seg_perm.astype(jnp.bfloat16),
+                    preferred_element_type=jnp.float32,
+                )
+                > 0
+            ]  # [T, Gs] in seg-column order
     # Fallback disabled (or no long banks): direct conv regardless.
     return [
         match_segment_block(
-            segs[i].kernel, segs[i].spec, *transformed_for(seg_pipelines[i])
+            segs[i].kernel,
+            segs[i].spec,
+            *transformed_for(seg_pipelines[i]),
+            block_index=i,
         )
         if i in keep
         else zeros_for(i)
@@ -894,9 +912,11 @@ def match_tier(
             if slot >= 0:
                 transformed[pid] = (variant_data[slot], variant_lengths[slot])
             else:
-                transformed[pid] = apply_device_pipeline(
-                    data, lengths, model.pipelines[pid]
-                )
+                names = model.pipelines[pid]
+                with jax.named_scope("cko.transform"), jax.named_scope(
+                    "+".join(names) or "none"
+                ):
+                    transformed[pid] = apply_device_pipeline(data, lengths, names)
         return transformed[pid]
 
     per_block.extend(
@@ -923,12 +943,13 @@ def match_tier(
             if not any(block_on(p[0]) for p in fb.pieces):
                 continue
             sub = {p: transformed_for(p) for p in sorted(set(fb.seg_pipes))}
-            out = scan_flat_bank(fb, sub, name=f"cko_flat_bin{fi}")
-            col = 0
-            for blk, g_lo, g_hi in fb.pieces:
-                w = g_hi - g_lo
-                flat_cols.setdefault(blk, {})[g_lo] = out[:, col : col + w]
-                col += w
+            with jax.named_scope("cko.flat"):
+                out = scan_flat_bank(fb, sub, name=f"cko_flat_bin{fi}")
+                col = 0
+                for blk, g_lo, g_hi in fb.pieces:
+                    w = g_hi - g_lo
+                    flat_cols.setdefault(blk, {})[g_lo] = out[:, col : col + w]
+                    col += w
     # Dense-DFA blocks in the global column order: the generic banks,
     # then the two-level automata's dfa-hot gather banks, then its
     # approximate prefilter banks (whose columns the engine confirms on
@@ -954,19 +975,24 @@ def match_tier(
     )
     for blk, (bank, pid, scan, name) in enumerate(dense_blocks, start=n_segs):
         if not block_on(blk):
-            per_block.append(
-                jnp.zeros((data.shape[0], bank.n_groups), dtype=bool)
-            )
+            with jax.named_scope("cko.stitch"):
+                per_block.append(
+                    jnp.zeros((data.shape[0], bank.n_groups), dtype=bool)
+                )
         elif blk in model.flat_covered:
             pieces = flat_cols[blk]
-            per_block.append(
-                jnp.concatenate([pieces[k] for k in sorted(pieces)], axis=1)
-            )
+            with jax.named_scope("cko.stitch"):
+                per_block.append(
+                    jnp.concatenate([pieces[k] for k in sorted(pieces)], axis=1)
+                )
         else:
-            per_block.append(scan(bank, *transformed_for(pid), name=name))
-    if per_block:
-        return jnp.concatenate(per_block, axis=1)  # [T, G]
-    return jnp.zeros((data.shape[0], 1), dtype=bool)
+            td = transformed_for(pid)
+            with jax.named_scope("cko.dense"):
+                per_block.append(scan(bank, *td, name=name))
+    with jax.named_scope("cko.stitch"):
+        if per_block:
+            return jnp.concatenate(per_block, axis=1)  # [T, G]
+        return jnp.zeros((data.shape[0], 1), dtype=bool)
 
 
 def _unpack_hit_rows(packed: jnp.ndarray, g: int) -> jnp.ndarray:
@@ -1233,9 +1259,11 @@ def match_tier_packed(
     (``models/slab.py``): ``data [U, L]``, ``vdata [H, U, L]`` and the
     ``int32`` ``lengths [U]`` / ``vlengths [H, U]`` are static slices of
     it."""
-    data, lengths, variant_data, variant_lengths = unpack_match_slab(slab)
+    with jax.named_scope("cko.slab"):
+        data, lengths, variant_data, variant_lengths = unpack_match_slab(slab)
     hits_u = match_tier(model, data, lengths, variant_data, variant_lengths, mask=mask)
-    return jnp.packbits(hits_u.astype(jnp.uint8), axis=1)
+    with jax.named_scope("cko.stitch"):
+        return jnp.packbits(hits_u.astype(jnp.uint8), axis=1)
 
 
 @partial(jax.jit, static_argnames=("max_phase", "layout"))
@@ -1261,29 +1289,30 @@ def eval_post_tiered(
     ``[Uc, PB] uint8`` where the value cache is on. Nothing in it waits
     for a matcher, so the engine puts it on the device before it waits
     for one."""
-    pairs, numvals, cached = unpack_post_slab(slab, layout)
-    g = model.e_lg.shape[0]
-    hits, k1s, k2s, k3s, rids = [], [], [], [], []
-    for ti, (hp, (k1, k2, k3, rid, uid)) in enumerate(zip(tier_hits, pairs)):
-        hu = _unpack_hit_rows(hp, g)
-        if cached is not None and cached[ti] is not None:
-            hu = jnp.concatenate([hu, _unpack_hit_rows(cached[ti], g)], axis=0)
-        hits.append(jnp.take(hu, uid, axis=0))  # [P, G] pair rows
-        k1s.append(k1)
-        k2s.append(k2)
-        k3s.append(k3)
-        rids.append(rid)
-    out = post_match(
-        model,
-        jnp.concatenate(hits, axis=0),
-        jnp.concatenate(k1s),
-        jnp.concatenate(k2s),
-        jnp.concatenate(k3s),
-        jnp.concatenate(rids),
-        numvals,
-        max_phase,
-    )
-    return _pack_verdicts(out)
+    with jax.named_scope("cko.post.unpack"):
+        pairs, numvals, cached = unpack_post_slab(slab, layout)
+        g = model.e_lg.shape[0]
+        hits, k1s, k2s, k3s, rids = [], [], [], [], []
+        for ti, (hp, (k1, k2, k3, rid, uid)) in enumerate(zip(tier_hits, pairs)):
+            hu = _unpack_hit_rows(hp, g)
+            if cached is not None and cached[ti] is not None:
+                hu = jnp.concatenate([hu, _unpack_hit_rows(cached[ti], g)], axis=0)
+            hits.append(jnp.take(hu, uid, axis=0))  # [P, G] pair rows
+            k1s.append(k1)
+            k2s.append(k2)
+            k3s.append(k3)
+            rids.append(rid)
+        operands = (
+            jnp.concatenate(hits, axis=0),
+            jnp.concatenate(k1s),
+            jnp.concatenate(k2s),
+            jnp.concatenate(k3s),
+            jnp.concatenate(rids),
+        )
+    with jax.named_scope("cko.post.match"):
+        out = post_match(model, *operands, numvals, max_phase)
+    with jax.named_scope("cko.post.pack"):
+        return _pack_verdicts(out)
 
 
 def unpack_compact(packed: np.ndarray, n_rules: int, n_counters: int):

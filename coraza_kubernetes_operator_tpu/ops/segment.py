@@ -403,23 +403,28 @@ def conv_n2_cols(spec: SegmentSpec) -> int:
     return max(1, n2)
 
 
-@partial(jax.jit, static_argnames=("spec",))
+@partial(jax.jit, static_argnames=("spec", "block_index"))
 def match_segment_block(
     kernel: jnp.ndarray,  # [W, C, N] bf16
     spec: SegmentSpec,
     data: jnp.ndarray,  # [T, L] uint8 (zero padded past lengths)
     lengths: jnp.ndarray,  # [T] int32
+    block_index: int = 0,
 ) -> jnp.ndarray:
-    """Returns group hits [T, n_groups] bool."""
+    """Returns group hits [T, n_groups] bool. ``block_index`` is the
+    block's index in its model: it names the suffix structures in a device
+    trace (``cko.seg.suffix/b<block>.st<i>``,
+    observability/device_scopes.py) and changes no operation."""
     t, ln = data.shape
     w = spec.w
     q = ln + 2  # chain positions: window starts 0 .. L+1
-    # Front NUL pad (position 0) + right slack so every window is full.
-    dpad = jnp.pad(data, ((0, 0), (1, w))).astype(jnp.int32)  # [T, 1+L+W]
+    with jax.named_scope("cko.seg.embed"):
+        # Front NUL pad (position 0) + right slack so every window is full.
+        dpad = jnp.pad(data, ((0, 0), (1, w))).astype(jnp.int32)  # [T, 1+L+W]
 
-    # 1. embed: channel planes from comparisons only.
-    planes = [_channel_plane(c, dpad) for c in spec.channels]
-    embed = jnp.stack(planes, axis=-1).astype(jnp.bfloat16)  # [T, 1+L+W, C]
+        # 1. embed: channel planes from comparisons only.
+        planes = [_channel_plane(c, dpad) for c in spec.channels]
+        embed = jnp.stack(planes, axis=-1).astype(jnp.bfloat16)  # [T, 1+L+W, C]
 
     # --- static chain program (pure Python at trace time) ---
     # Two tiers:
@@ -575,29 +580,31 @@ def match_segment_block(
     # that built the windows in VMEM read 11.1–11.6 ms/step against
     # this conv's 6.9 on a v5e and was removed in PR 30; git history
     # has ops/segment_pallas.py.)
-    kernel_p = kernel[:, :, np.asarray(col_order)]  # [W, C, N2] tiny gather
-    # bf16 accumulation is exact here (integer partial sums ≤ 2W = 34
-    # ≪ 256) and halves the conv-output HBM traffic — the threshold is
-    # fused into each consumer, so every chain stage reads `out`, not a
-    # materialized bool.
-    out = jax.lax.conv_general_dilated(
-        embed,
-        kernel_p,
-        window_strides=(1,),
-        padding="VALID",
-        dimension_numbers=("NWC", "WIO", "NWC"),
-        preferred_element_type=jnp.bfloat16,
-    )  # [T, Q, N2]
-    m_all = out >= jnp.bfloat16(2.0 * w)  # equality; >= is safe (2W is the max)
+    with jax.named_scope("cko.seg.conv"):
+        kernel_p = kernel[:, :, np.asarray(col_order)]  # [W, C, N2] tiny gather
+        # bf16 accumulation is exact here (integer partial sums ≤ 2W = 34
+        # ≪ 256) and halves the conv-output HBM traffic — the threshold is
+        # fused into each consumer, so every chain stage reads `out`, not a
+        # materialized bool.
+        out = jax.lax.conv_general_dilated(
+            embed,
+            kernel_p,
+            window_strides=(1,),
+            padding="VALID",
+            dimension_numbers=("NWC", "WIO", "NWC"),
+            preferred_element_type=jnp.bfloat16,
+        )  # [T, Q, N2]
+        m_all = out >= jnp.bfloat16(2.0 * w)  # equality; >= is safe (2W is the max)
 
     def mslice(a0: int, a1: int) -> jnp.ndarray:
         """Columns [a0, a1) of the global allocation."""
         return m_all[:, :, a0:a1]
 
-    iota = jnp.arange(q, dtype=jnp.int32)[None, :]  # [1, Q]
-    len1 = 1 + lengths[:, None]  # [T, 1] position just past the last byte
-    iota3 = iota[..., None]  # [1, Q, 1]
-    len3 = len1[..., None]  # [T, 1, 1]
+    with jax.named_scope("cko.seg.embed"):
+        iota = jnp.arange(q, dtype=jnp.int32)[None, :]  # [1, Q]
+        len1 = 1 + lengths[:, None]  # [T, 1] position just past the last byte
+        iota3 = iota[..., None]  # [1, Q, 1]
+        len3 = len1[..., None]  # [T, 1, 1]
 
     # Gap-class tables are built eagerly OUTSIDE the cond-gated chains:
     # tracers created inside one cond branch must not be cached and reused
@@ -619,31 +626,33 @@ def match_segment_block(
     # class gaps.
     tri_excl = None
     _tabs_cache: dict[tuple, tuple] = {}
-    for _, prog, _, _ in spec.branches:
-        for el in prog:
-            if el[0] == "gapcls" and el[1] not in _tabs_cache:
-                in_c = _in_class(el[1], dpad)[:, :q]  # byte at p ∈ class
-                if q > _NCE_MATMUL_MAX_Q:
-                    non_i = (~in_c).astype(jnp.int32)
-                    # exclusive prefix sum: inclusive cumsum minus self.
-                    nce = jnp.cumsum(non_i, axis=1) - non_i
-                else:
-                    non_c = (~in_c).astype(jnp.bfloat16)
-                    if tri_excl is None:
-                        tri_excl = jnp.asarray(
-                            np.triu(np.ones((q, q), dtype=np.float32), 1),
-                            dtype=jnp.bfloat16,
-                        )  # [p', p]: p' < p
-                    # non-C bytes in [0, p): exclusive prefix sum via matmul.
-                    nce = jnp.dot(
-                        non_c, tri_excl, preferred_element_type=jnp.float32
-                    ).astype(jnp.int32)
-                _tabs_cache[el[1]] = (in_c, nce)
+    with jax.named_scope("cko.seg.embed"):
+        for _, prog, _, _ in spec.branches:
+            for el in prog:
+                if el[0] == "gapcls" and el[1] not in _tabs_cache:
+                    in_c = _in_class(el[1], dpad)[:, :q]  # byte at p ∈ class
+                    if q > _NCE_MATMUL_MAX_Q:
+                        non_i = (~in_c).astype(jnp.int32)
+                        # exclusive prefix sum: inclusive cumsum minus self.
+                        nce = jnp.cumsum(non_i, axis=1) - non_i
+                    else:
+                        non_c = (~in_c).astype(jnp.bfloat16)
+                        if tri_excl is None:
+                            tri_excl = jnp.asarray(
+                                np.triu(np.ones((q, q), dtype=np.float32), 1),
+                                dtype=jnp.bfloat16,
+                            )  # [p', p]: p' < p
+                        # non-C bytes in [0, p): exclusive prefix sum via matmul.
+                        nce = jnp.dot(
+                            non_c, tri_excl, preferred_element_type=jnp.float32
+                        ).astype(jnp.int32)
+                    _tabs_cache[el[1]] = (in_c, nce)
 
     def gap_cls_tabs(ivs: tuple):
         return _tabs_cache[ivs]
 
-    big = jnp.int32(1 << 20)
+    with jax.named_scope("cko.seg.embed"):
+        big = jnp.int32(1 << 20)
 
     def gap_cls(x: jnp.ndarray, ivs: tuple, lo: int, hi: int, forward: bool):
         """Class-gap op along axis 1 of [T, Q, NB]. Forward (suffix/RTL):
@@ -755,42 +764,45 @@ def match_segment_block(
     # suffixes: s[t, p, i] = "suffix i fully matches with its first
     # element's real bytes starting at padded position p".
     s_struct: dict[tuple, jnp.ndarray] = {}
-    for sig_key, members in struct.items():
-        sig_ops, a_end = sig_key
-        ns = len(members)
-        # Base: "the element AFTER the suffix may start at p" — one past
-        # the last byte for $-anchored branches, anywhere in range else.
-        s = jnp.broadcast_to(
-            (iota3 == len3) if a_end else (iota3 <= len3), (t, q, ns)
-        )
-        seg_slot = sum(1 for o in sig_ops if o[0] == "seg")
-        for op in reversed(sig_ops):
-            if op[0] == "seg":
-                seg_slot -= 1
-                _, n_lead, n_real = op
-                a0, a1 = struct_alloc[sig_key][seg_slot]
-                m = mslice(a0, a1)  # [T, Q, NS] at window starts
-                if n_lead:
-                    m = _rshift3(m, n_lead)  # index by real start
-                valid = (iota3 >= 1) & (iota3 + n_real <= len3)
-                s = m & valid & _lshift3(s, n_real)
-            elif op[0] == "gapany":
-                # s_k[p] = ∃d ∈ [lo, hi]: s[p + d] — log-shift OR spread.
-                _, lo, hi = op
-                s = _spread_or(s, lo, hi, forward=True)
-            else:  # gapcls
-                _, ivs, lo, hi = op
-                s = gap_cls(s, ivs, lo, hi, forward=True)
-        s_struct[sig_key] = s
+    for si, (sig_key, members) in enumerate(struct.items()):
+        with jax.named_scope("cko.seg.suffix"), jax.named_scope(f"b{block_index}.st{si:03d}"):
+            sig_ops, a_end = sig_key
+            ns = len(members)
+            # Base: "the element AFTER the suffix may start at p" — one past
+            # the last byte for $-anchored branches, anywhere in range else.
+            s = jnp.broadcast_to(
+                (iota3 == len3) if a_end else (iota3 <= len3), (t, q, ns)
+            )
+            seg_slot = sum(1 for o in sig_ops if o[0] == "seg")
+            for op in reversed(sig_ops):
+                if op[0] == "seg":
+                    seg_slot -= 1
+                    _, n_lead, n_real = op
+                    a0, a1 = struct_alloc[sig_key][seg_slot]
+                    m = mslice(a0, a1)  # [T, Q, NS] at window starts
+                    if n_lead:
+                        m = _rshift3(m, n_lead)  # index by real start
+                    valid = (iota3 >= 1) & (iota3 + n_real <= len3)
+                    s = m & valid & _lshift3(s, n_real)
+                elif op[0] == "gapany":
+                    # s_k[p] = ∃d ∈ [lo, hi]: s[p + d] — log-shift OR spread.
+                    _, lo, hi = op
+                    s = _spread_or(s, lo, hi, forward=True)
+                else:  # gapcls
+                    _, ivs, lo, hi = op
+                    s = gap_cls(s, ivs, lo, hi, forward=True)
+            s_struct[sig_key] = s
 
     # Concatenate bucket outputs (bucket order) and map columns to groups
     # with one matmul — no scatter (TPU scatter lowering serializes).
-    hits = jnp.zeros((t, spec.n_groups), dtype=bool)
+    with jax.named_scope("cko.seg.fold"):
+        hits = jnp.zeros((t, spec.n_groups), dtype=bool)
     if spec.branches:
         cols: list[jnp.ndarray] = []
         col_groups: list[int] = []
         for sig, idxs in buckets.items():
-            cols.append(run_bucket(sig, idxs))  # [T, len(idxs)]
+            with jax.named_scope("cko.seg.bucket"):
+                cols.append(run_bucket(sig, idxs))  # [T, len(idxs)]
             col_groups.extend(spec.branches[bi][0] for bi in idxs)
         iota2 = iota  # [1, Q]
 
@@ -817,57 +829,60 @@ def match_segment_block(
             no_match = jnp.broadcast_to(m_all[:, 0, :1] & False, (t, a1 - a0))
             return jax.lax.cond(jnp.any(m), run_final, lambda _: no_match, None)
 
-        for (sig_key, i0, i1, bk, gks), block in zip(final_runs, run_alloc):
-            n_lead, n_real, a_start, k = bk
-            if block is None:
-                for i, gk in zip(range(i0, i1), gks):
-                    # [T, Q], indexed by real start of the NEXT element
-                    s2 = s_struct[sig_key][:, :, i]
-                    g = (
-                        (iota2 >= 1)
-                        & (iota2 + n_real <= len1)
-                        & _lshift_fill(s2, n_real, False)
-                    )
-                    if a_start:
-                        g = g & (iota2 == 1)
-                    gj = _lshift_fill(g, n_lead, False)  # window-start idx
-                    cols.append(final_cols(*final_alloc[gk], gj[:, :, None]))
-                    col_groups.extend(final_gidsets[gk])  # deduped: one col → gid set
-                continue
-            ns = i1 - i0
-            g3 = (
-                (iota3 >= 1)
-                & (iota3 + n_real <= len3)
-                & _lshift3(s_struct[sig_key][:, :, i0:i1], n_real)
+        with jax.named_scope("cko.seg.final"):
+            for (sig_key, i0, i1, bk, gks), block in zip(final_runs, run_alloc):
+                n_lead, n_real, a_start, k = bk
+                if block is None:
+                    for i, gk in zip(range(i0, i1), gks):
+                        # [T, Q], indexed by real start of the NEXT element
+                        s2 = s_struct[sig_key][:, :, i]
+                        g = (
+                            (iota2 >= 1)
+                            & (iota2 + n_real <= len1)
+                            & _lshift_fill(s2, n_real, False)
+                        )
+                        if a_start:
+                            g = g & (iota2 == 1)
+                        gj = _lshift_fill(g, n_lead, False)  # window-start idx
+                        cols.append(final_cols(*final_alloc[gk], gj[:, :, None]))
+                        col_groups.extend(final_gidsets[gk])  # deduped: one col → gid set
+                    continue
+                ns = i1 - i0
+                g3 = (
+                    (iota3 >= 1)
+                    & (iota3 + n_real <= len3)
+                    & _lshift3(s_struct[sig_key][:, :, i0:i1], n_real)
+                )
+                if a_start:
+                    g3 = g3 & (iota3 == 1)
+                gj3 = _lshift3(g3, n_lead)  # [T, Q, ns], window-start idx
+                for j in range(k):
+                    a0 = block[0] + j * ns
+                    cols.append(final_cols(a0, a0 + ns, gj3))
+                    col_groups.extend(final_gidsets[gk][j] for gk in gks)
+        with jax.named_scope("cko.seg.fold"):
+            bh_all = jnp.concatenate(cols, axis=1)
+            b2g = np.zeros((len(col_groups), spec.n_groups), dtype=np.float32)
+            for ci, gid in enumerate(col_groups):
+                if isinstance(gid, set):
+                    for g in gid:
+                        b2g[ci, g] = 1
+                else:
+                    b2g[ci, gid] = 1
+            # bf16 matmul (exact: sums <= branches-per-group << 256); int8
+            # DotGeneral lowers off the MXU on TPU.
+            hits = (
+                jnp.dot(
+                    bh_all.astype(jnp.bfloat16),
+                    jnp.asarray(b2g, dtype=jnp.bfloat16),
+                    preferred_element_type=jnp.float32,
+                )
+                > 0
             )
-            if a_start:
-                g3 = g3 & (iota3 == 1)
-            gj3 = _lshift3(g3, n_lead)  # [T, Q, ns], window-start idx
-            for j in range(k):
-                a0 = block[0] + j * ns
-                cols.append(final_cols(a0, a0 + ns, gj3))
-                col_groups.extend(final_gidsets[gk][j] for gk in gks)
-        bh_all = jnp.concatenate(cols, axis=1)
-        b2g = np.zeros((len(col_groups), spec.n_groups), dtype=np.float32)
-        for ci, gid in enumerate(col_groups):
-            if isinstance(gid, set):
-                for g in gid:
-                    b2g[ci, g] = 1
-            else:
-                b2g[ci, gid] = 1
-        # bf16 matmul (exact: sums <= branches-per-group << 256); int8
-        # DotGeneral lowers off the MXU on TPU.
-        hits = (
-            jnp.dot(
-                bh_all.astype(jnp.bfloat16),
-                jnp.asarray(b2g, dtype=jnp.bfloat16),
-                preferred_element_type=jnp.float32,
-            )
-            > 0
-        )
     if spec.always:
         al = np.zeros(spec.n_groups, dtype=bool)
         for gid in spec.always:
             al[gid] = True
-        hits = hits | jnp.asarray(al)[None, :]
+        with jax.named_scope("cko.seg.fold"):
+            hits = hits | jnp.asarray(al)[None, :]
     return hits

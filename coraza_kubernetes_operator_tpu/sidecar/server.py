@@ -96,6 +96,24 @@ def _tier_compile_stats() -> dict:
     return TIER_COMPILER.stats()
 
 
+def _write_device_scopes(profile_dir: str) -> str | None:
+    """``device_scopes.json`` beside a profiler dump: instruction name ->
+    scope path of every resident ``cko_*`` executable, which is what
+    ``python -m ...observability.device_scopes <dump dir>`` joins the
+    capture's operations to. The path written, or None (a boundary: the
+    dump stands without it)."""
+    from ..engine.compile_cache import EXEC_CACHE
+
+    try:
+        path = os.path.join(profile_dir, "device_scopes.json")
+        with open(path, "w") as fh:
+            json.dump({"executables": EXEC_CACHE.scope_tables()}, fh)
+        return path
+    except Exception as err:
+        log.error("device_scopes.json not written", err, dir=profile_dir)
+        return None
+
+
 def _device_identity() -> dict | None:
     """``{"platform", "kind", "count"}`` as recorded from the arrays of
     the first all-device window this process collected, or None before
@@ -2063,8 +2081,12 @@ class TpuEngineSidecar:
                     500,
                     {"error": f"profiler stop failed: {type(err).__name__}: {err}"},
                 )
+            scopes_file = _write_device_scopes(self._profile_dir)
             log.info("device profiling stopped", dir=self._profile_dir)
-            return _json_reply(200, {"profiling": False, "dir": self._profile_dir})
+            return _json_reply(
+                200,
+                {"profiling": False, "dir": self._profile_dir, "device_scopes": scopes_file},
+            )
 
     def overloaded_reply(
         self, err: Overloaded, as_json: bool

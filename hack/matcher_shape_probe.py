@@ -15,6 +15,20 @@ bins, their slots and groups, blocks left on one kernel a bank). One
 JSON line per shape, all of them again in
 ``chiprun_out/matcher_shape_probe.json``. ROADMAP Speed 2's question
 ("fixed, or grows with rows?") is answered by the lines it prints.
+
+``--scopes`` prices each shape by device scope
+(``observability/device_scopes.py``): after the timed calls, which stay
+untraced, one ``jax.profiler`` capture (Python tracer off, no HLO protos,
+as the sidecar's ``/waf/v1/profile`` takes it) of ``--scope-calls`` more
+calls on full rows, reduced with ``reduce_by_scope`` against the
+executable's own table. The shape's line gains ``device_ops`` (what a
+launch is made of, static), ``scopes`` (per scope: ms a call, operations
+run a call, us an operation), ``dearest_structures`` (the ten suffix
+structures with most time) and ``capture`` (calls, the executable's
+``XLA Modules`` ms a call beside the operations' sum, how the events were
+joined, the stats an operation's event carries, the ten dearest
+instructions); the whole reduction goes to
+``chiprun_out/matcher_scopes_<shape>.json``.
 """
 
 from __future__ import annotations
@@ -37,16 +51,23 @@ def main(argv=None) -> int:
     ap.add_argument("shapes", nargs="+", help="<rows>x<width>")
     ap.add_argument("--rules", default=str(REPO / "wafbench/configs/crs-lite-pl2/rules"))
     ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--scopes", action="store_true", help="price each shape by device scope")
+    ap.add_argument("--scope-calls", type=int, default=8, help="calls in the --scopes capture")
     args = ap.parse_args(argv)
 
     import jax
     import numpy as np
 
+    from coraza_kubernetes_operator_tpu.engine.compile_cache import configure_persistent_cache
     from coraza_kubernetes_operator_tpu.engine.waf import WafEngine
     from coraza_kubernetes_operator_tpu.models.slab import match_slab_shape, match_views
     from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
+    from coraza_kubernetes_operator_tpu.observability import device_scopes
     from wafbench.harness import read_rules
 
+    # Where JAX_COMPILATION_CACHE_DIR holds a cache, its keys carry the scopes' salt:
+    # an executable another build cached would come back with that build's names.
+    configure_persistent_cache()
     dev = jax.devices()[0]
     print(json.dumps({"device": dev.device_kind, "platform": dev.platform,
                       "JAX_COMPILATION_CACHE_DIR": os.environ.get("JAX_COMPILATION_CACHE_DIR")}),
@@ -79,6 +100,52 @@ def main(argv=None) -> int:
         compiled = fn.lower(model, *operands(rows, width, width), mask=None).compile()
         return shape, compiled, time.perf_counter() - t0
 
+    dest = REPO / "chiprun_out"
+    dest.mkdir(exist_ok=True)
+
+    def priced(shape: str, compiled, ops) -> dict:
+        """One capture of ``--scope-calls`` calls, reduced by scope."""
+        t0 = time.perf_counter()
+        text = compiled.as_text()
+        t1 = time.perf_counter()
+        names, inherited = device_scopes.walk(text)
+        static = device_scopes.counts(names, inherited)
+        walk_s = time.perf_counter() - t1
+        trace_dir = REPO / "build" / f"matcher_scopes_trace_{shape}"  # a capture is tens of MB: not chiprun_out
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.enable_hlo_proto = False
+        jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+        for _ in range(args.scope_calls):
+            jax.block_until_ready(compiled(model, *ops))
+        t2 = time.perf_counter()
+        jax.profiler.stop_trace()
+        stop_s = time.perf_counter() - t2
+        events = device_scopes.extract(trace_dir)
+        name = f"cko_match_{shape}"
+        reduced = device_scopes.reduce_by_scope(events, {name: names}).get(name)
+        (dest / f"matcher_scopes_{shape}.json").write_text(
+            json.dumps({"device_ops": static, "op_stats": events["op_stats"], "reduced": reduced}))
+        gained = {"device_ops": static, "as_text_s": t1 - t0, "walk_s": walk_s, "text_bytes": len(text)}
+        if not reduced:  # the CPU has no device plane
+            return dict(gained, capture={"calls": 0, "stop_s": stop_s})
+        calls = reduced["runs"]
+        gained["scopes"] = {
+            scope: {"ms_per_call": 1e3 * cell["s"] / calls, "ops_per_call": cell["ops"] / calls,
+                    "us_per_op": 1e6 * cell["s"] / cell["ops"]}
+            for scope, cell in sorted(device_scopes.by_registry_scope(reduced["scopes"]).items(),
+                                      key=lambda kv: -kv[1]["s"])}
+        gained["dearest_structures"] = [
+            [path, 1e3 * sec / calls, n / calls]
+            for path, sec, n in device_scopes.dearest_beneath(reduced["scopes"], "cko.seg.suffix")]
+        gained["capture"] = {
+            "calls": calls, "stop_s": stop_s,
+            "module_ms_per_call": 1e3 * reduced["module_s"] / calls,
+            "ops_ms_per_call": 1e3 * reduced["ops_s"] / calls,
+            "joined_by": reduced["joined_by"], "op_stats": events["op_stats"],
+            "dearest_ops": [[instr, path, 1e3 * sec / calls] for instr, path, sec in reduced["dearest"]]}
+        return gained
+
     out = []
     with ThreadPoolExecutor(max_workers=len(args.shapes)) as pool:
         for shape, compiled, compile_s in pool.map(compile_one, args.shapes):
@@ -95,10 +162,10 @@ def main(argv=None) -> int:
                     ms.append(1e3 * (time.perf_counter() - t0))
                 line[name + "_ms"] = {"min": min(ms), "median": statistics.median(ms),
                                       "max": max(ms)}
+            if args.scopes:
+                line.update(priced(shape, compiled, operands(rows, width, width)))
             out.append(line)
             print(json.dumps(line), flush=True)
-    dest = REPO / "chiprun_out"
-    dest.mkdir(exist_ok=True)
     (dest / "matcher_shape_probe.json").write_text(json.dumps(out, indent=1) + "\n")
     return 0
 

@@ -7,13 +7,23 @@ for the rest) against a launcher stand-in, and how long a CRS mix's
 capture of ``trace_windows`` device windows lasts; ``test_trace_windows.py``
 that the matcher's time a window is read over the capture's whole
 windows; ``test_off_path.py`` that what one abandoned window moves is
-held to a hundredth of the window and every other counter to 0. The
+held to a hundredth of the window and every other counter to 0;
+``test_device_ops_readers.py`` (PR 41) that the three readers of the
+executables' operation counts weigh the matcher executables a capture
+ran by their runs and give nothing on a program without the counts. The
 files are the benchmark's and stay where they are; their JAX-free tests
 are imported here, case by case, so that every PR runs them (as
 ``tests/test_wafbench_deployment.py`` does). The whole runs on the CPU
 (each starts a sidecar) stay with ``pytest wafbench/tests``.
 """
 
+from wafbench.tests.test_device_ops_readers import (  # noqa: F401
+    test_a_program_without_the_block_gives_nothing,
+    test_an_executable_that_was_not_counted_is_left_out_and_none_counted_gives_nothing,
+    test_the_chain_scopes_are_names_the_program_registers,
+    test_the_three_are_listed_for_the_five_crs_cells_and_no_other,
+    test_two_matcher_shapes_are_weighed_by_their_runs_and_the_post_stage_is_left_out,
+)
 from wafbench.tests.test_off_path import (  # noqa: F401
     test_a_run_too_short_to_hold_a_share_is_exact_again,
     test_a_twentieth_of_the_windows_off_the_device_path_is_not,
