@@ -736,8 +736,9 @@ def segment_tier_hits(
 
     # Budget on the DUPLICATED column count (conv_n2_cols — what the
     # [T, Q, N2] conv output actually allocates), not the deduped
-    # kernel.shape[2]; the gapcls NCE tables are O(T·Q) since the
-    # cumsum fallback (ops/segment.py) and need no budget term.
+    # kernel.shape[2]; the gapcls NCE tables are O(T·Q) a class plus
+    # constant O(B²) triangular tables at every width
+    # (ops/segment.py:_excl_prefix_sum) and need no budget term.
     n_seg_cols = sum(conv_n2_cols(segs[i].spec) for i in keep)
     per_row = (data.shape[1] + 2) * max(1, n_seg_cols)
     bitmap_elems = t * per_row

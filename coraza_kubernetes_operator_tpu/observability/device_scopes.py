@@ -50,7 +50,8 @@ from typing import NamedTuple
 SCOPES = {
     "cko.slab": "match_tier_packed: the static slices and bitcasts of the tier's one operand",
     "cko.transform": "match_tier: a device transform pipeline (beneath: its transforms joined by +)",
-    "cko.seg.embed": "match_segment_block: dpad, channel planes, the bf16 stack; position iotas, gap-class NCE tables",
+    "cko.seg.embed": "match_segment_block: dpad, channel planes, the bf16 stack; position iotas, gap-class membership planes",
+    "cko.seg.nce": "match_segment_block: the gap classes' exclusive prefix counts (NCE), blocked triangular matmuls",
     "cko.seg.conv": "match_segment_block: conv_general_dilated and the compare that gives m_all",
     "cko.seg.bucket": "match_segment_block tier (b): signature-bucketed chains and their lax.cond gate",
     "cko.seg.suffix": "match_segment_block tier (a): right-to-left passes of a suffix structure (beneath: b<n>.st<i>)",

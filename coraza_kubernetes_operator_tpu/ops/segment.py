@@ -25,7 +25,9 @@ at once**:
    NCE latch for unbounded class gaps) run as log-shift passes —
    ``jnp.cumsum``/``lax.cummax`` lower to reduce-window on TPU, which
    profiled at a quarter of the block's runtime; log2(Q) elementwise
-   passes on a 66-long axis are ~free.
+   passes on a 66-long axis are ~free. The one prefix SUM left, the
+   gap classes' NCE counts, rides the MXU at every width: blocked
+   triangular matmuls (``_excl_prefix_sum``, scope ``cko.seg.nce``).
 
 Conv output columns are PERMUTED (and duplicated when shared) at trace
 time so every chain/final/solo consumer reads a contiguous slice of
@@ -52,11 +54,10 @@ import numpy as np
 from ..compiler.re_parser import ALL_BYTES
 from ..compiler.segments import Branch, Gap, Seg, SegmentPlan
 
-# Above this Q the NCE prefix sum uses jnp.cumsum instead of a [Q, Q]
-# triangular matmul — the table is O(Q²) HBM and on long-body buckets
-# (up to SecRequestBodyLimit) would be a request-triggerable multi-GB
-# allocation.
-_NCE_MATMUL_MAX_Q = 512
+# Positions a triangular table of the NCE prefix counts spans: a block's
+# total (≤ 256) is a whole number in bf16, and the table is O(B²)
+# whatever Q a request's body gives the tier.
+_PREFIX_BLOCK = 256
 
 
 # ---------------------------------------------------------------------------
@@ -359,6 +360,42 @@ def _lshift_fill(x: jnp.ndarray, k: int, fill) -> jnp.ndarray:
     return jnp.pad(x, ((0, 0), (0, k)), constant_values=fill)[:, k:]
 
 
+def _excl_prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """out[..., p] = sum of x[..., :p], as f32. ``x`` is bf16 [..., N] of
+    whole numbers ≤ 256 (exact in bf16); every partial sum is a whole
+    number accumulated in f32, so the result is exact while a row's total
+    stays under 2**24.
+
+    Blocked so that no table grows with N: inside a block of B = 256
+    positions one matmul with the strict upper-triangular [B, B] table;
+    across blocks the same function over the block totals. A total is at
+    most 256·256, which bf16 does not hold: it goes up as its two
+    base-256 digits (each ≤ 256), stacked into one call. N ≤ B is one
+    block: one [N, N] matmul and nothing else."""
+    *lead, n = x.shape
+    b = min(n, _PREFIX_BLOCK)
+    nb = -(-n // b)
+    if nb * b != n:
+        x = jnp.pad(x, [(0, 0)] * len(lead) + [(0, nb * b - n)])
+    xb = x.reshape(*lead, nb, b)
+    tri = jnp.asarray(np.triu(np.ones((b, b), dtype=np.float32), 1), dtype=jnp.bfloat16)  # [p', p]: p' < p
+    within = jnp.dot(xb, tri, preferred_element_type=jnp.float32)  # [..., NB, B]
+    if nb == 1:
+        return within.reshape(*lead, n)
+    totals = within[..., -1] + xb[..., -1]  # [..., NB] f32
+    hi = jnp.floor(totals / 256)
+    before = _excl_prefix_sum(jnp.stack([hi, totals - 256 * hi]).astype(jnp.bfloat16))
+    offset = 256 * before[0] + before[1]  # positions' worth before each block
+    return (within + offset[..., None]).reshape(*lead, nb * b)[..., :n]
+
+
+def _excl_prefix_count(mask: jnp.ndarray) -> jnp.ndarray:
+    """bool [..., Q] -> int32 [..., Q]: how many of mask[..., :p] are
+    set: the inclusive running count less the element itself, bit for
+    bit, on the MXU."""
+    return _excl_prefix_sum(mask.astype(jnp.bfloat16)).astype(jnp.int32)
+
+
 def _branch_signature(spec: SegmentSpec, prog: tuple, a_start: bool, a_end: bool):
     """Branches with identical signatures run as one batched chain: the op
     sequence with all *static shift amounts* (n_lead/n_real/gap bounds and
@@ -608,48 +645,26 @@ def match_segment_block(
 
     # Gap-class tables are built eagerly OUTSIDE the cond-gated chains:
     # tracers created inside one cond branch must not be cached and reused
-    # inside another trace.
-    #
-    # NCE (count of non-class bytes before p) is itself a prefix sum.
-    # For small Q it is one [Q, Q] triangular matmul, NOT jnp.cumsum:
-    # cumulative ops along a 66-long axis lower to reduce-window on TPU,
-    # which profiled at ~1/4 of this whole block's runtime, and Q is tiny
-    # so the O(Q²) matmul is ~free on the MXU (exact in bf16: sums ≤ Q ≪
-    # 256). Above _NCE_MATMUL_MAX_Q the [Q, Q] table would dominate HBM
-    # (and on large length buckets — up to SecRequestBodyLimit — attempt
-    # a multi-GB allocation), so the exclusive prefix sum falls back to
-    # jnp.cumsum: O(Q) memory, and at that Q the reduce-window cost is
-    # amortized over a proportionally larger block anyway. The table is
-    # built lazily — rulesets with no gapcls op never materialize it.
-    # M_cls[t, p', p] = (p' ≥ p ∧ NCE[p'] == NCE[p]) is the "suffix of p
-    # is class-clean through p'" reachability operand used by unbounded
-    # class gaps.
-    tri_excl = None
-    _tabs_cache: dict[tuple, tuple] = {}
-    with jax.named_scope("cko.seg.embed"):
-        for _, prog, _, _ in spec.branches:
-            for el in prog:
-                if el[0] == "gapcls" and el[1] not in _tabs_cache:
-                    in_c = _in_class(el[1], dpad)[:, :q]  # byte at p ∈ class
-                    if q > _NCE_MATMUL_MAX_Q:
-                        non_i = (~in_c).astype(jnp.int32)
-                        # exclusive prefix sum: inclusive cumsum minus self.
-                        nce = jnp.cumsum(non_i, axis=1) - non_i
-                    else:
-                        non_c = (~in_c).astype(jnp.bfloat16)
-                        if tri_excl is None:
-                            tri_excl = jnp.asarray(
-                                np.triu(np.ones((q, q), dtype=np.float32), 1),
-                                dtype=jnp.bfloat16,
-                            )  # [p', p]: p' < p
-                        # non-C bytes in [0, p): exclusive prefix sum via matmul.
-                        nce = jnp.dot(
-                            non_c, tri_excl, preferred_element_type=jnp.float32
-                        ).astype(jnp.int32)
-                    _tabs_cache[el[1]] = (in_c, nce)
-
-    def gap_cls_tabs(ivs: tuple):
-        return _tabs_cache[ivs]
+    # inside another trace. NCE[t, p] (count of non-class bytes before p)
+    # is monotone, so "NCE[p'] == NCE[p]" says the bytes [p, p') are all
+    # in the class: the reachability test of every class gap. The block's
+    # distinct classes are stacked and counted in one call.
+    classes = list(dict.fromkeys(
+        el[1] for _, prog, _, _ in spec.branches for el in prog if el[0] == "gapcls"
+    ))
+    nce_of: dict[tuple, jnp.ndarray] = {}
+    if classes:
+        with jax.named_scope("cko.seg.embed"):
+            outside = [~_in_class(ivs, dpad)[:, :q] for ivs in classes]  # byte at p ∉ class
+        with jax.named_scope("cko.seg.nce"):
+            # Stacked along the rows and split after the count: with the
+            # classes on an axis of their own from the start the TPU
+            # compiler lays the channel planes above out for this matmul
+            # and not for the conv (crs-lite 32x512 on a v5e: 266 relayout
+            # copies and 7 unfused concatenates in cko.seg.embed, 3.2 ms
+            # where it is 0.05).
+            nces = _excl_prefix_count(jnp.concatenate(outside)).reshape(len(classes), t, q)
+            nce_of = {ivs: nces[i] for i, ivs in enumerate(classes)}
 
     with jax.named_scope("cko.seg.embed"):
         big = jnp.int32(1 << 20)
@@ -661,8 +676,7 @@ def match_segment_block(
         Unbounded gaps use the NCE latch (monotone non-class counts) as a
         log-shift running min — lax.cummax/cummin lower to reduce-window
         on TPU, which profiled at ~1/4 of this block's runtime."""
-        _, nce = gap_cls_tabs(ivs)
-        nce3 = nce[..., None]
+        nce3 = nce_of[ivs][..., None]
 
         def clean(d: int) -> jnp.ndarray:
             if d == 0:

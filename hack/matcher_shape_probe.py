@@ -29,6 +29,15 @@ structures with most time) and ``capture`` (calls, the executable's
 joined, the stats an operation's event carries, the ten dearest
 instructions); the whole reduction goes to
 ``chiprun_out/matcher_scopes_<shape>.json``.
+
+``--opcodes cko.seg.embed,cko.seg.nce`` (with ``--scopes``) splits each
+named scope by what its operations are: the HLO opcode, and for a fusion
+the opcodes its computation holds that are not elementwise
+(``fusion(reduce-window)``, ``fusion(dot)``; ``fusion`` alone is
+comparisons and selects). Per kind the static count, and from the capture
+ms a call and operations run a call; ``reduce_window_instructions``
+counts every ``reduce-window`` of the optimized HLO by scope, inside
+fusions too.
 """
 
 from __future__ import annotations
@@ -53,6 +62,7 @@ def main(argv=None) -> int:
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--scopes", action="store_true", help="price each shape by device scope")
     ap.add_argument("--scope-calls", type=int, default=8, help="calls in the --scopes capture")
+    ap.add_argument("--opcodes", default="", help="scopes to split by opcode, comma-separated")
     args = ap.parse_args(argv)
 
     import jax
@@ -103,6 +113,45 @@ def main(argv=None) -> int:
     dest = REPO / "chiprun_out"
     dest.mkdir(exist_ok=True)
 
+    # What a fusion is named for: the opcodes in it that are not one pass of the VPU.
+    heavy = ("reduce-window", "dot", "convolution", "reduce", "concatenate", "pad", "gather",
+             "scatter", "dynamic-slice", "dynamic-update-slice", "sort", "transpose", "copy")
+
+    def by_opcode(text: str, events: dict, names: dict, calls: int) -> dict:
+        """``--opcodes``: each named scope's operations by kind."""
+        _entry, comps = device_scopes._parse(text)
+        kind, inside = {}, {}
+        for body in comps.values():
+            for i in body:
+                kind[i.name] = i.opcode
+                if i.fused:
+                    held = [j.opcode for j in comps.get(i.fused, ())]
+                    kind[i.name] = "fusion(" + ",".join(h for h in heavy if h in held) + ")"
+                    inside[i.name] = held.count("reduce-window")
+        reduce_windows: dict[str, int] = {}
+        for instr, path in names.items():
+            n = inside.get(instr, 0) + (kind.get(instr) == "reduce-window")
+            if n:
+                scope = device_scopes.scope_of(path)
+                reduce_windows[scope] = reduce_windows.get(scope, 0) + n
+        out = {"reduce_window_instructions": reduce_windows}
+        for scope in filter(None, args.opcodes.split(",")):
+            rows: dict[str, list] = {}  # kind -> [static, seconds, operations run]
+            for instr, path in names.items():
+                if device_scopes.scope_of(path) == scope:
+                    rows.setdefault(kind.get(instr, "?"), [0, 0.0, 0])[0] += 1
+            for dev in events["devices"]:
+                run = sorted(dev["ops"], key=lambda e: (e[1], -e[2]))
+                for e, self_ns in zip(run, device_scopes._self_ns(run)):
+                    instr = device_scopes.instruction_name(e[0])
+                    if device_scopes.scope_of(names.get(instr, "")) == scope:
+                        row = rows.setdefault(kind.get(instr, "?"), [0, 0.0, 0])
+                        row[1] += self_ns / 1e9
+                        row[2] += 1
+            out[scope] = {k: {"static": n, "ms_per_call": 1e3 * sec / calls, "ops_per_call": ran / calls}
+                          for k, (n, sec, ran) in sorted(rows.items(), key=lambda kv: -kv[1][1])}
+        return out
+
     def priced(shape: str, compiled, ops) -> dict:
         """One capture of ``--scope-calls`` calls, reduced by scope."""
         t0 = time.perf_counter()
@@ -127,6 +176,8 @@ def main(argv=None) -> int:
         (dest / f"matcher_scopes_{shape}.json").write_text(
             json.dumps({"device_ops": static, "op_stats": events["op_stats"], "reduced": reduced}))
         gained = {"device_ops": static, "as_text_s": t1 - t0, "walk_s": walk_s, "text_bytes": len(text)}
+        if args.opcodes:
+            gained["opcodes"] = by_opcode(text, events, names, reduced["runs"] if reduced else 1)
         if not reduced:  # the CPU has no device plane
             return dict(gained, capture={"calls": 0, "stop_s": stop_s})
         calls = reduced["runs"]

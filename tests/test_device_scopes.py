@@ -346,12 +346,12 @@ SecRule ARGS "@rx (e|fg)+h" "id:8,phase:2,deny,status:403"
 
 RULE_SETS = {
     "operator-sample": (lambda: SAMPLE.read_text(),  # two @rx rules, both on the conv tier
-                        {"cko.slab", "cko.transform", "cko.seg.embed", "cko.seg.conv", "cko.seg.suffix",
-                         "cko.seg.final", "cko.stitch", "cko.post.match", "cko.post.pack"}),
+                        {"cko.slab", "cko.transform", "cko.seg.embed", "cko.seg.nce", "cko.seg.conv",
+                         "cko.seg.suffix", "cko.seg.final", "cko.stitch", "cko.post.match", "cko.post.pack"}),
     "segment-heavy": (lambda: SEGMENT_HEAVY,
-                      {"cko.slab", "cko.transform", "cko.seg.embed", "cko.seg.conv", "cko.seg.bucket",
-                       "cko.seg.suffix", "cko.seg.final", "cko.seg.fold", "cko.flat", "cko.stitch",
-                       "cko.post.match", "cko.post.pack"}),
+                      {"cko.slab", "cko.transform", "cko.seg.embed", "cko.seg.nce", "cko.seg.conv",
+                       "cko.seg.bucket", "cko.seg.suffix", "cko.seg.final", "cko.seg.fold", "cko.flat",
+                       "cko.stitch", "cko.post.match", "cko.post.pack"}),
 }
 
 
@@ -432,6 +432,28 @@ def test_two_sites_of_one_shift_helper_keep_their_own_scope(canary):
         ds.scope_of(ds.scope_path(name)) for name in ds._OP_NAME.findall(text)
         if name.endswith("/pad") or "/jit(_pad)/" in name)
     assert pads["cko.seg.suffix"] and pads["cko.seg.final"], pads
+
+
+def test_the_prefix_counts_dot_stands_under_its_own_scope(canary):
+    """Both texts hold a class gap (``<script[^>]*>``): the matmul that
+    counts the bytes outside the class is how ``cko.seg.nce`` is seen to
+    engage, so it is priced there and nowhere else."""
+    name, text = next((n, t) for n, t in canary["texts"].items() if n.startswith("cko_match_"))
+    _entry, comps = ds._parse(text)
+    table = ds.table(text)
+    held_by = {i.name: op.name for body in comps.values() for op in body if op.fused
+               for i in comps.get(op.fused, ())}
+    dots = [i for body in comps.values() for i in body
+            if i.opcode == "dot" and i.op_name and "/cko.seg.nce/" in i.op_name]
+    assert dots, "no prefix-count matmul in the optimized HLO"
+    for dot in dots:
+        assert ds.scope_path(dot.op_name) == "cko.seg.nce"
+        assert table[held_by.get(dot.name, dot.name)] == "cko.seg.nce"
+    elsewhere = [i.name for body in comps.values() for i in body
+                 if i.opcode == "dot" and ds.scope_path(i.op_name) == "cko.seg.embed"]
+    assert not elsewhere, elsewhere
+    kept = next(e for e in canary["stats"]["executables"] if e["name"] == name)
+    assert kept["device_ops"]["by_scope"]["cko.seg.nce"] >= len({held_by.get(d.name, d.name) for d in dots})
 
 
 def test_names_change_metadata_and_nothing_else(canary, monkeypatch):

@@ -200,28 +200,35 @@ def test_finals_tier_matches_python_re():
     assert not got[len(texts) :].any()  # padding rows match nothing
 
 
-def test_gapcls_cumsum_path_at_large_q():
-    """Above _NCE_MATMUL_MAX_Q the NCE prefix sum must switch to the
-    O(Q) cumsum (no [Q, Q] table — a request-triggerable multi-GB
-    allocation on long-body buckets) and stay byte-exact vs Python re."""
-    pats = [(r"<script[^>]*>", True), (r"select\b.+\bfrom", True)]
+GAPCLS_PATTERNS = [(r"<script[^>]*>", True), (r"select\b.+\bfrom", True)]
+
+
+@pytest.mark.parametrize("max_len", [582, 2048])
+def test_gapcls_deep_in_a_wide_row_matches_python_re(max_len):
+    """A class gap whose match lies deep in a wide row (q = max_len + 2:
+    three blocks of the prefix count at 582, nine at 2048, the match in
+    the last) stays byte-exact vs Python re."""
+    pats = GAPCLS_PATTERNS
     plans = [plan_segments(parse_regex(p, case_insensitive=ci)) for p, ci in pats]
     block = build_segment_block(plans)
 
-    from coraza_kubernetes_operator_tpu.ops import segment as seg_mod
-
-    max_len = seg_mod._NCE_MATMUL_MAX_Q + 70  # q = max_len + 2 > threshold
+    deep = max_len - 42
     rng = random.Random(7)
     rows = [
         b"x" * max_len,
-        # positives with the match DEEP in the buffer (past the 512
-        # matmul/cumsum threshold) — must fit inside max_len
-        (b"z" * 540) + b"<script src=a>" + b"y" * 20,
-        b"select " + b"a" * 530 + b" from t",
+        # positives with the match DEEP in the buffer (past the first
+        # blocks of 256 positions) — must fit inside max_len
+        (b"z" * deep) + b"<script src=a>" + b"y" * 20,
+        b"select " + b"a" * (deep - 10) + b" from t",
         b"<script" + b">" * 1,  # short content, long bucket
         bytes(rng.randrange(32, 127) for _ in range(max_len)),
+        # the class gap spans block boundaries; one byte outside the
+        # class in the middle of it must not break the match: `[^>]*`
+        # restarts at the later `<script`
+        b"<script " + b"a" * 300 + b"<script " + b"b" * (deep - 320) + b">",
+        b"<script " + b"a" * (max_len - 8),  # never closed
     ]
-    assert all(len(c) <= max_len for c in rows[1:3])
+    assert all(len(c) <= max_len for c in rows)
     data = np.zeros((len(rows), max_len), dtype=np.uint8)
     lengths = np.zeros(len(rows), dtype=np.int32)
     for i, c in enumerate(rows):
@@ -234,6 +241,79 @@ def test_gapcls_cumsum_path_at_large_q():
         for i, c in enumerate(rows):
             want = oracle.search(c[:max_len]) is not None
             assert bool(hits[i, gi]) == want, (pat, i)
+    assert hits[1, 0] and hits[2, 1] and hits[5, 0] and not hits[6, 0]
+
+
+PREFIX_ROWS = {
+    "zeros": lambda rng, q: np.zeros((3, q), dtype=bool),
+    "ones": lambda rng, q: np.ones((3, q), dtype=bool),  # the count reaches q - 1
+    "random": lambda rng, q: rng.random((5, q)) < np.array([[0.5], [0.03], [0.97], [0.5], [0.999]]),
+}
+
+
+@pytest.mark.parametrize("rows", sorted(PREFIX_ROWS))
+@pytest.mark.parametrize("q", [1, 66, 255, 256, 257, 514, 2050, 8194, 131_074])
+def test_excl_prefix_count_is_cumsum_minus_self(q, rows):
+    """One block (q ≤ 256), two, three, nine, thirty-three, and 513 blocks
+    whose totals are themselves counted in three blocks: bit for bit the
+    exclusive prefix sum, with no table that grows with q."""
+    from coraza_kubernetes_operator_tpu.ops.segment import _excl_prefix_count
+
+    x = PREFIX_ROWS[rows](np.random.default_rng(q), q)
+    got = np.asarray(_excl_prefix_count(x))
+    assert got.dtype == np.int32 and got.shape == x.shape
+    np.testing.assert_array_equal(got, np.cumsum(x, axis=1) - x)
+
+
+def _jaxprs(closed):
+    """A closed jaxpr and every one beneath it, each with its constants."""
+    from jax.extend import core
+
+    yield closed.jaxpr, closed.consts
+    for eqn in closed.jaxpr.eqns:
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                if isinstance(sub, core.ClosedJaxpr):
+                    yield from _jaxprs(sub)
+                elif isinstance(sub, core.Jaxpr):
+                    yield from _jaxprs(core.ClosedJaxpr(sub, ()))
+
+
+@pytest.mark.parametrize("width", [2048, 131_072])
+def test_no_scan_primitive_and_no_table_that_grows_with_the_width(width):
+    """What the old width threshold stood for, held on the program: at a
+    body tier's width the class-gap block traces to no cumulative
+    primitive (they lower to reduce-window trees on a TPU) and to no
+    constant of more than B² elements (a [Q, Q] table is a
+    request-triggerable multi-GB allocation); and every matmul of the
+    prefix counts takes bf16 operands, so that what one pass of the MXU
+    computes is what the CPU computed in the tests above."""
+    import jax
+    import jax.numpy as jnp
+
+    from coraza_kubernetes_operator_tpu.ops import segment as seg_mod
+
+    plans = [plan_segments(parse_regex(p, case_insensitive=ci)) for p, ci in GAPCLS_PATTERNS]
+    block = build_segment_block(plans)
+    closed = jax.make_jaxpr(
+        lambda k, d, ln: match_segment_block(k, block.spec, d, ln)
+    )(block.kernel, jax.ShapeDtypeStruct((2, width), jnp.uint8), jax.ShapeDtypeStruct((2,), jnp.int32))
+    primitives, largest = set(), 0
+    for jaxpr, consts in _jaxprs(closed):
+        largest = max([largest] + [int(np.size(c)) for c in consts])
+        for eqn in jaxpr.eqns:
+            primitives.add(eqn.primitive.name)
+            largest = max([largest] + [int(np.size(v.val)) for v in eqn.invars if hasattr(v, "val")])
+    assert "dot_general" in primitives and "conv_general_dilated" in primitives
+    assert not {p for p in primitives if p.startswith(("cum", "reduce_window"))}, primitives
+    assert largest <= seg_mod._PREFIX_BLOCK ** 2, largest
+
+    counted = jax.make_jaxpr(seg_mod._excl_prefix_count)(jax.ShapeDtypeStruct((2, width + 2), jnp.bool_))
+    dots = [e for jaxpr, _ in _jaxprs(counted) for e in jaxpr.eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == (2 if width == 2048 else 3)  # a level a matmul: 9 blocks; 513, then 3
+    for eqn in dots:
+        assert all(v.aval.dtype == jnp.bfloat16 for v in eqn.invars)
+        assert eqn.outvars[0].aval.dtype == jnp.float32
 
 
 def test_conv_n2_cols_matches_trace_allocation():
