@@ -251,6 +251,11 @@ class ExecutableCache:
         # is None where the text could not be had or read, and
         # ``scope_table_errors`` counts those.
         self._device_ops: dict[tuple, dict] = {}
+        # What an executable's owner said of it (``describe``), listed
+        # beside it: the engine says how a matcher's conv tier is cut to
+        # its budget (``seg_plan``: ``SegTierPlan.summary``; None in the
+        # listing where nobody said).
+        self._described: dict[tuple, dict] = {}
         self.scope_table_errors = 0
 
     def note_window(self, out, on_device: bool) -> None:
@@ -402,10 +407,24 @@ class ExecutableCache:
                 "launch_plan_misses": self.launch_plan_misses,
                 "persistent_dir": _configured_dir[0] if _configured_dir else None,
                 "executables": sorted(
-                    self._device_ops.values(), key=lambda e: (e["name"], e["model"])
+                    (
+                        {**e, "seg_plan": None, **self._described.get(key, {})}
+                        for key, e in self._device_ops.items()
+                    ),
+                    key=lambda e: (e["name"], e["model"]),
                 ),
                 "scope_table_errors": self.scope_table_errors,
             }
+
+    def describe(self, key: tuple, **meta) -> dict:
+        """What the owner of the executable under ``key`` says of it,
+        listed beside it in ``stats()["executables"]`` once it is
+        resident (before or after its compile; dropped by ``clear``).
+        Returns all that was said so far: without ``meta`` a lookup."""
+        with self._lock:
+            said = self._described.setdefault(key, {})
+            said.update(meta)
+            return dict(said)
 
     def scope_tables(self) -> list[dict]:
         """``{"name", "model", "table"}`` of every resident ``cko_*``
@@ -437,6 +456,7 @@ class ExecutableCache:
         with self._lock:
             self._entries.clear()
             self._device_ops.clear()
+            self._described.clear()
             self.generation += 1
 
 

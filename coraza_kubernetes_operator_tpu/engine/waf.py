@@ -19,7 +19,7 @@ import numpy as np
 
 from ..compiler.ruleset import CompiledRuleSet, compile_rules
 from ..compiler.transforms_host import apply_pipeline
-from ..models.waf_model import WafModel, build_model
+from ..models.waf_model import WafModel, build_model, tier_seg_plan
 from ..observability.stages import HOST_STAGES
 from ..observability.stages import current as current_stages
 from ..utils import get_logger
@@ -565,6 +565,7 @@ class WafEngine:
         # requests the Python extractor read (``body_summary``).
         self._tiering = {
             "windows": 0, "tiers": 0, "cells": 0, "real_bytes": 0, "host_operands": 0,
+            "long_scan_launches": 0,
         }
         self._bodies = np.zeros(len(BODY_COUNTERS), dtype=np.int64)
         # Host-tier-path helpers: _dev_col_of[orig_gid] = device hit
@@ -1213,18 +1214,19 @@ class WafEngine:
                 counts["tiers"] += 1
                 counts["cells"] += tier[0].shape[0] * tier[0].shape[1]
                 counts["real_bytes"] += int(np.sum(tier[1]))
-            match_stages, post_stage = self._resolve_launch(
+            match_stages, post_stage, long_scans = self._resolve_launch(
                 tiers, numvals, max_phase, masks, cached
             )
             model = self.model
             device = True
             tier_hits = []
             from_device = []
-            for stage, tier, slab, mask in zip(
-                match_stages, tiers, staged.match_slabs, masks
+            for stage, tier, slab, mask, long_scan in zip(
+                match_stages, tiers, staged.match_slabs, masks, long_scans
             ):
                 hits = self._launch(stage, (model, slab))
                 from_device.append(hits is not None)
+                counts["long_scan_launches"] += long_scan and hits is not None
                 if hits is None:
                     device = False
                     hits = self._host_tier_hits(tier, mask)
@@ -1268,11 +1270,28 @@ class WafEngine:
             arena_lease=staged,
         )
 
+    def _describe_matchers(self, tiers, masks, keys) -> tuple[bool, ...]:
+        """Tell the executable cache how each tier's matcher cuts its
+        conv tier to the budget (``compile_cache.executables[].seg_plan``):
+        ``tier_seg_plan`` is the function ``match_tier`` traces with, of
+        the same statics. Returns, per tier, whether that is the long DFA
+        scan."""
+        long_scans = []
+        for t, mask, key in zip(tiers, masks, keys):
+            said = EXEC_CACHE.describe(key)
+            if "seg_plan" not in said:  # once a key: the plan walks every column
+                plan = tier_seg_plan(self.model, *t[0].shape, mask)
+                said = EXEC_CACHE.describe(key, seg_plan=plan.summary() if plan else None)
+            long_scans.append((said["seg_plan"] or {}).get("path") == "long")
+        return tuple(long_scans)
+
     def _resolve_launch(self, tiers, numvals, max_phase, masks, cached):
         """What a window of this shape launches: ``(match_stages,
-        post_stage)``, each stage ``(key, jitted, compiled, statics)``
-        with ``compiled`` None where the executable is not resident
-        (lazy mode: the host twin answers that stage).
+        post_stage, long_scans)``, each stage ``(key, jitted, compiled,
+        statics)`` with ``compiled`` None where the executable is not
+        resident (lazy mode: the host twin answers that stage);
+        ``long_scans`` says of each matcher whether its conv tier was
+        traced onto the long DFA scan (``seg_plan.path``).
 
         Resolved once per (model, window shape): the window's signature
         — shapes and dtypes of its own operands, the masks,
@@ -1316,7 +1335,8 @@ class WafEngine:
             (k, s[2], EXEC_CACHE._lookup(k, count_hit=False) if ready[k] else None, s[4])
             for s, k in zip(specs, keys)
         ]
-        plan = (stages[:-1], stages[-1])
+        long_scans = self._describe_matchers(tiers, masks, keys)
+        plan = (stages[:-1], stages[-1], long_scans)
         if all(stage[2] is not None for stage in stages):
             table[sig] = plan
         return plan
@@ -1446,7 +1466,9 @@ class WafEngine:
         the blocks no bin covers), the size of the model they serve
         (compiled rules; the conv tier's output columns, summed over
         its blocks; the runs longer than one conv piece that were split
-        into chained pieces, and the groups that hold one), and how the
+        into chained pieces, and the groups that hold one; the groups the
+        long banks hold, which a tier scans as DFAs where its plan says
+        ``long``), and how the
         prefilter's over-approximation is paying off at runtime."""
         from ..ops.segment import conv_n2_cols
 
@@ -1462,6 +1484,7 @@ class WafEngine:
             "segment_columns": sum(conv_n2_cols(sb.spec) for sb in model.segs),
             "segment_splits": sum(t.splits for t in plan.tiers),
             "segment_split_groups": sum(1 for t in plan.tiers if t.splits),
+            "segment_long_groups": sum(b.n_groups for b in model.long_banks),
             "gather_banks": len(model.gather_banks),
             "pre_banks": len(model.pre_banks),
             "flat_bins": len(model.flat_banks),
@@ -1483,7 +1506,9 @@ class WafEngine:
         the share of the matchers' bytes that was padding;
         host_operands / windows reads tiers + 1 (a match slab a tier,
         the post slab), and one more for a tier whose hit rows the
-        prefilter confirm repacked."""
+        prefilter confirm repacked. ``long_scan_launches`` counts the
+        launches of a matcher whose conv tier was traced onto the long
+        DFA scan: 0 while every tier rides the MXU."""
         return dict(self._tiering)
 
     def body_summary(self) -> dict:
@@ -1725,7 +1750,7 @@ class WafEngine:
         the persistent disk cache makes repeat processes cheap).
         Returns ``{"compiled": bool, "wall_s": float}``."""
 
-        from .tier_compile import TIER_COMPILER
+        from .tier_compile import TIER_COMPILER, spec_key
 
         if requests is None:
             requests = [warmup_request()]
@@ -1744,10 +1769,10 @@ class WafEngine:
             match_specs, post_spec, _pairs = self._tier_specs(
                 tiers, numvals, max_phase=2, masks=masks, cached=cached
             )
-            compiled = (
-                TIER_COMPILER.compile_all(match_specs + [post_spec]) > 0
-                or compiled
-            )
+            specs = match_specs + [post_spec]
+            keys = [spec_key(s, self._model_sig) for s in specs]
+            self._describe_matchers(tiers, masks, keys)
+            compiled = TIER_COMPILER.compile_all(specs, keys) > 0 or compiled
             if lease is not None:
                 lease.release()  # AOT compile only; nothing dispatched
         return {"compiled": compiled, "wall_s": time.perf_counter() - t0}

@@ -19,6 +19,7 @@ The reference delegates all of this to coraza-proxy-wasm per request
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -52,24 +53,27 @@ from .slab import unpack_match_slab, unpack_post_slab
 
 _BIG = np.int32(2**31 - 1)
 
-# Conv-tier match-bitmap element budgets (T * (L+2) * N2). A tier whose
-# whole bitmap exceeds the per-chunk budget is row-CHUNKED: the conv
-# matchers run inside a ``lax.map`` over row blocks sized to the budget,
-# so the MXU tier keeps serving arbitrarily many rows at bounded peak
-# HBM (the round-4 trace showed the 19k-row short tier falling off the
-# conv tier into 26 serializing long-bank DFA scans — ~60% of the whole
-# CRS-scale step — because the only options were one giant bitmap or
-# the scan fallback). The DFA long-bank fallback remains for the case a
-# SINGLE row's bitmap exceeds the budget (body-cap-width buffers, where
-# the scan carry's constant memory is the point). Setting
-# CKO_SEG_BITMAP_ELEMENTS=0 disables the fallback entirely (no long
-# banks are built — saves their HBM if length buckets are known-small).
-#
-# BEHAVIOR CHANGE (round 4): CKO_SEG_BITMAP_ELEMENTS no longer
-# thresholds conv-vs-DFA dispatch — only 0 vs nonzero matters (build the
-# long-bank fallback or not). The dispatch threshold is
-# CKO_SEG_CHUNK_ELEMENTS; pre-round-4 tunings of the old knob's numeric
-# value are no-ops and should move to CKO_SEG_CHUNK_ELEMENTS.
+# Conv-tier match-bitmap element budget (rows * (L+2) * N2 conv columns
+# alive at once). ``segment_tier_hits`` cuts a tier to it per traced
+# shape, from shapes alone (``plan_segment_tier``): the whole bitmap
+# under it runs direct; over it the rows are CHUNKED (the conv matchers
+# inside a ``lax.map`` over row blocks: the round-4 trace showed the
+# 19k-row short tier falling off the conv tier into 26 serializing
+# long-bank DFA scans, ~60% of the whole CRS-scale step, because the only
+# options were one giant bitmap or the scan fallback); where not even
+# eight rows of all columns fit, the columns are TILED as well, along
+# group boundaries, so a site's feed of thousands of rules under a
+# 2048-wide window still rides the MXU (PR 43: 12,498 columns at 2,050
+# positions are 25.6 M elements a row, and the window went to the long
+# scan at 763 ms a call): all the rows in one chunk of tiles while they
+# fit, then row chunks of tiles. The DFA long-bank fallback remains for
+# the case ONE row of ONE tile exceeds the budget, and, on a backend
+# that is no TPU, for a tier whose rows do not fit one chunk of tiles
+# (``_scan_past_one_chunk``: the CPU pays the conv's arithmetic in full,
+# the chip the scan's serial steps). CKO_SEG_BITMAP_ELEMENTS=0 builds no
+# long banks (saves their HBM if length buckets are known-small): such a
+# tier runs direct only where one row of the widest group is over the
+# budget.
 import os as _os
 
 _SEG_BITMAP_ELEMS = int(_os.environ.get("CKO_SEG_BITMAP_ELEMENTS", str(2**30)))
@@ -697,6 +701,144 @@ def build_model(crs: CompiledRuleSet, automata=None) -> WafModel:
     )
 
 
+@dataclass(frozen=True)
+class SegTierPlan:
+    """How one traced shape's conv tier is cut to ``_SEG_CHUNK_ELEMS``.
+
+    ``path``: ``direct`` (one conv a block over all rows), ``rows``
+    (``lax.map`` over row chunks), ``tiles`` (column tiles inside each row
+    chunk) or ``long`` (the DFA long-bank scan). ``tiles`` lists the
+    column tiles of a chunk, ``(block, g0, g1, conv columns)``, in the
+    order they run; the other paths run whole blocks."""
+
+    path: str
+    row_chunks: int
+    rows_per_chunk: int
+    tiles: tuple[tuple[int, int, int, int], ...]
+    columns: int
+
+    def summary(self) -> dict:
+        """What ``compile_cache.executables[].seg_plan`` shows."""
+        return {
+            "path": self.path,
+            "row_chunks": self.row_chunks,
+            "rows_per_chunk": self.rows_per_chunk,
+            "column_tiles": len(self.tiles),
+            "columns_per_tile_max": max((c for *_, c in self.tiles), default=0),
+            "columns": self.columns,
+        }
+
+
+def _equal_chunks(t: int, rows_fit: int) -> tuple[int, int]:
+    """(chunks, rows a chunk) for ``t`` rows at most ``rows_fit`` at a
+    time. Chunks of equal size: 32 rows that fit 24 at a time are two
+    chunks of 16, not two of 24 with a third of the conv on padding."""
+    nc = -(-t // rows_fit)
+    rows = -(-t // nc)
+    if rows_fit >= 8:
+        rows = -(-rows // 8) * 8  # up to a multiple of 8
+    return nc, rows
+
+
+def plan_segment_tier(
+    specs,
+    keep: tuple[int, ...],
+    t: int,
+    width: int,
+    long_ok: bool,
+    scan_past_one_chunk: bool = False,
+) -> SegTierPlan:
+    """The conv tier's plan for ``t`` rows of ``width`` bytes over the
+    kept blocks' ``specs``, from shapes alone. The budget counts the
+    DUPLICATED column count (``conv_n2_cols`` — what the [T, Q, N2] conv
+    output actually allocates), not the deduped ``kernel.shape[2]``; the
+    gapcls NCE tables are O(T·Q) a class plus constant O(B²) triangular
+    tables at every width (``ops/segment.py:_excl_prefix_sum``) and need
+    no budget term.
+
+    Tiles: the most rows a chunk (all of them, then every multiple of
+    eight downwards, then 4, 2, 1: fewest passes of the chains' many
+    small operations) at which a tile of the budget still holds the
+    widest group whole; ``long`` only where one row of the widest group
+    does not fit (and ``long_ok``: long banks were built). So a feed of
+    ten times the rules, or a tier twice as wide, is more tiles and not
+    another path. ``scan_past_one_chunk`` (``_scan_past_one_chunk``: any
+    backend but a TPU) sends a tier whose rows do not fit ONE chunk of
+    tiles to the long scan instead of row chunks of tiles."""
+    from ..ops.segment import conv_n2_cols, cut_column_tiles, widest_group_cols
+
+    q = width + 2
+    cols = {i: conv_n2_cols(specs[i]) for i in keep}
+    columns = sum(cols.values())
+    whole = tuple((i, 0, specs[i].n_groups, cols[i]) for i in keep)
+    per_row = q * max(1, columns)
+    if t * per_row <= _SEG_CHUNK_ELEMS or not keep:
+        return SegTierPlan("direct", 1, t, whole, columns)
+    rows_fit = _SEG_CHUNK_ELEMS // per_row // 8 * 8
+    if rows_fit >= 8:
+        return SegTierPlan("rows", *_equal_chunks(t, rows_fit), whole, columns)
+    widest = max(widest_group_cols(specs[i]) for i in keep)
+    t8 = -(-t // 8) * 8
+    one_chunk_only = long_ok and scan_past_one_chunk
+    for rows_fit in [t8] if one_chunk_only else [*range(t8, 0, -8), 4, 2, 1]:
+        nc, rows = _equal_chunks(t, rows_fit)
+        max_cols = _SEG_CHUNK_ELEMS // (rows * q)
+        if max_cols < widest:
+            continue
+        # A block that fits is a tile; a wider one is dealt group by group.
+        tiles: list[tuple[int, int, int, int]] = []
+        for i in keep:
+            if cols[i] <= max_cols:
+                tiles.append((i, 0, specs[i].n_groups, cols[i]))
+            else:
+                tiles += [(i, *tile) for tile in cut_column_tiles(specs[i], max_cols)]
+        return SegTierPlan("tiles", nc, rows, tuple(tiles), columns)
+    if long_ok:
+        return SegTierPlan("long", 1, t, (), columns)
+    # Fallback disabled (or no long banks): direct conv regardless.
+    return SegTierPlan("direct", 1, t, whole, columns)
+
+
+def _block_on(mask: int | None, i: int) -> bool:
+    """Whether the kind-partition ``mask`` scans block ``i`` (``match_tier``)."""
+    return mask is None or i >= 62 or (mask >> i) & 1 == 1
+
+
+def _scan_past_one_chunk() -> bool:
+    """Whether a tier whose rows do not fit one chunk of column tiles
+    takes the long scan (True) or row chunks of tiles (False). The two
+    cost differently by platform, as ``ops/``'s kernels do: on a v5e
+    row chunks of tiles are 5-8 times the scan's speed (crs-lite
+    ``256x8192``: 0.66 s against 3.20 s a call; ``64x32768``: 0.60
+    against 4.85; PR 43's chip run), because the scan's 8,192 or 32,768
+    serial steps wait on each other while the conv fills the MXU; on
+    XLA:CPU the scan is cheap and the conv's arithmetic is paid in full
+    (tier-1's ``256x8192`` window of crs-lite: 67 s on the scan, 496 s
+    in row chunks of tiles, and a crashed child with tiles of 124
+    columns)."""
+    return jax.default_backend() != "tpu"
+
+
+def tier_seg_plan(
+    model: WafModel, rows: int, width: int, mask: int | None = None
+) -> SegTierPlan | None:
+    """The plan ``match_tier`` traces its conv tier with at ``rows`` x
+    ``width`` under ``mask``: the same function of the same statics, so
+    the engine can say which plan an executable holds without tracing it
+    (``compile_cache.executables[].seg_plan``). None for a model without
+    segment blocks."""
+    if not model.segs:
+        return None
+    return plan_segment_tier(
+        [sb.spec for sb in model.segs],
+        tuple(i for i in range(len(model.segs)) if _block_on(mask, i)),
+        rows,
+        width,
+        long_ok=bool(model.long_banks) and _SEG_BITMAP_ELEMS > 0,
+        scan_past_one_chunk=_scan_past_one_chunk(),
+    )
+
+
 def segment_tier_hits(
     segs,
     seg_pipelines,
@@ -708,14 +850,19 @@ def segment_tier_hits(
     keep: tuple[int, ...] | None = None,
 ) -> list:
     """Hit blocks for the segment-routed groups, choosing the tier per
-    TRACE (shapes are static per bucket): the conv tier materializes
-    ~[T, L+2, N2] match-bitmap elements — linear in buffer length — so
-    beyond the per-chunk budget the rows are processed in ``lax.map``
-    row chunks (same MXU convs, bounded peak HBM); only when a SINGLE
-    row's bitmap exceeds the budget does the bucket stream through the
-    constant-memory DFA scan carry instead (same groups, same column
-    order after ``seg_perm``). Shared by the single-chip ``eval_waf``
-    and the rule-sharded path (``parallel/mesh.py``).
+    TRACE (shapes are static per bucket, ``plan_segment_tier``): the conv
+    tier materializes ~[T, L+2, N2] match-bitmap elements — linear in
+    buffer length — so beyond the per-chunk budget the rows are processed
+    in ``lax.map`` row chunks (same MXU convs, bounded peak HBM); where
+    eight rows of all kept columns do not fit, the columns are cut into
+    tiles along group boundaries, run one after another, over all the
+    rows while they fit one chunk and inside row chunks past that; only
+    when a SINGLE row of a single tile exceeds the budget (or, off the
+    TPU, when the rows do not fit one chunk of tiles) does the bucket
+    stream through the constant-memory DFA scan carry instead (same
+    groups, same column order after ``seg_perm``). Shared by the
+    single-chip ``eval_waf`` and the rule-sharded path
+    (``parallel/mesh.py``).
 
     ``keep`` (kind-partitioned matching) lists the seg-block indexes the
     caller's rows can actually reach; skipped blocks contribute all-False
@@ -723,7 +870,7 @@ def segment_tier_hits(
     False for such rows). The long-bank fallback ignores ``keep`` — it
     is the rare giant-buffer path and scans everything."""
     from ..ops.dfa import scan_dfa_bank
-    from ..ops.segment import conv_n2_cols, match_segment_block
+    from ..ops.segment import match_segment_block, tile_spec
 
     if not segs:
         return []
@@ -734,16 +881,15 @@ def segment_tier_hits(
     def zeros_for(i):
         return jnp.zeros((t, segs[i].n_groups), dtype=bool)
 
-    # Budget on the DUPLICATED column count (conv_n2_cols — what the
-    # [T, Q, N2] conv output actually allocates), not the deduped
-    # kernel.shape[2]; the gapcls NCE tables are O(T·Q) a class plus
-    # constant O(B²) triangular tables at every width
-    # (ops/segment.py:_excl_prefix_sum) and need no budget term.
-    n_seg_cols = sum(conv_n2_cols(segs[i].spec) for i in keep)
-    per_row = (data.shape[1] + 2) * max(1, n_seg_cols)
-    bitmap_elems = t * per_row
-    rows_fit = max(0, _SEG_CHUNK_ELEMS // max(1, per_row)) // 8 * 8
-    if bitmap_elems <= _SEG_CHUNK_ELEMS or not keep:
+    plan = plan_segment_tier(
+        [sb.spec for sb in segs],
+        keep,
+        t,
+        data.shape[1],
+        long_ok=bool(long_banks) and _SEG_BITMAP_ELEMS > 0,
+        scan_past_one_chunk=_scan_past_one_chunk(),
+    )
+    if plan.path == "direct":
         return [
             match_segment_block(
                 segs[i].kernel,
@@ -755,66 +901,7 @@ def segment_tier_hits(
             else zeros_for(i)
             for i in range(len(segs))
         ]
-    if rows_fit >= 8:
-        # Row-chunked conv tier: pad rows to a chunk multiple, stack the
-        # per-pipeline transformed buffers, and run every kept segment
-        # block on one chunk per lax.map step. Padding rows are all-NUL
-        # with length 0 — their hits are computed but never read (uid
-        # indexes only real unique rows).
-        kept = [(i, segs[i], seg_pipelines[i]) for i in keep]
-        pids = sorted({pid for _, _, pid in kept})
-        pid_ix = {pid: i for i, pid in enumerate(pids)}
-        nc = -(-t // rows_fit)
-        # Chunks of equal size: 32 rows that fit 24 at a time are two
-        # chunks of 16, not two of 24 with a third of the conv on padding.
-        rows_fit = -(-(-(-t // nc)) // 8) * 8  # ceil(t / nc), up to a multiple of 8
-        tp = nc * rows_fit
-        stacked_d, stacked_l = [], []
-        for pid in pids:
-            td, tl = transformed_for(pid)
-            with jax.named_scope("cko.seg.chunk"):
-                stacked_d.append(
-                    jnp.pad(td, ((0, tp - t), (0, 0))).reshape(
-                        nc, rows_fit, td.shape[1]
-                    )
-                )
-                stacked_l.append(jnp.pad(tl, (0, tp - t)).reshape(nc, rows_fit))
-
-        def one_chunk(args):
-            ds, ls = args
-            return jnp.concatenate(
-                [
-                    match_segment_block(
-                        seg.kernel,
-                        seg.spec,
-                        ds[pid_ix[pid]],
-                        ls[pid_ix[pid]],
-                        block_index=i,
-                    )
-                    for i, seg, pid in kept
-                ],
-                axis=1,
-            )
-
-        # The blocks inside the map keep their own scopes: an operation
-        # stands under the innermost scope of its name.
-        with jax.named_scope("cko.seg.chunk"):
-            hits = jax.lax.map(
-                one_chunk,
-                (jnp.stack(stacked_d, axis=1), jnp.stack(stacked_l, axis=1)),
-            )
-            hits = hits.reshape(tp, hits.shape[2])[:t]
-            # Reassemble full column order, zero blocks for skipped segs.
-            out, off = [], 0
-            for i in range(len(segs)):
-                if i in keep:
-                    g = segs[i].n_groups
-                    out.append(hits[:, off : off + g])
-                    off += g
-                else:
-                    out.append(zeros_for(i))
-        return out
-    if bool(long_banks) and _SEG_BITMAP_ELEMS > 0:
+    if plan.path == "long":
         long_cols = []
         for bank, pid in zip(long_banks, long_bank_pipelines):
             td = transformed_for(pid)
@@ -830,18 +917,69 @@ def segment_tier_hits(
                 )
                 > 0
             ]  # [T, Gs] in seg-column order
-    # Fallback disabled (or no long banks): direct conv regardless.
-    return [
-        match_segment_block(
-            segs[i].kernel,
-            segs[i].spec,
-            *transformed_for(seg_pipelines[i]),
-            block_index=i,
-        )
-        if i in keep
-        else zeros_for(i)
-        for i in range(len(segs))
-    ]
+    # Row-chunked conv tier: pad rows to a chunk multiple, stack the
+    # per-pipeline transformed buffers, and run every kept segment
+    # block on one chunk per lax.map step. Padding rows are all-NUL
+    # with length 0 — their hits are computed but never read (uid
+    # indexes only real unique rows).
+    pids = sorted({seg_pipelines[i] for i in keep})
+    pid_ix = {pid: i for i, pid in enumerate(pids)}
+    nc, rows_fit = plan.row_chunks, plan.rows_per_chunk
+    tp = nc * rows_fit
+    stacked_d, stacked_l = [], []
+    for pid in pids:
+        td, tl = transformed_for(pid)
+        with jax.named_scope("cko.seg.chunk"):
+            stacked_d.append(
+                jnp.pad(td, ((0, tp - t), (0, 0))).reshape(
+                    nc, rows_fit, td.shape[1]
+                )
+            )
+            stacked_l.append(jnp.pad(tl, (0, tp - t)).reshape(nc, rows_fit))
+    tiled = plan.path == "tiles"
+
+    def one_chunk(args):
+        """The chunk's tiles (whole blocks unless ``tiled``), their hits
+        side by side: a block's tiles are consecutive and in group order.
+        Column tiles run one after another: a tile's rows pass an
+        optimization barrier with the hits of the tile before, so no two
+        tiles' bitmaps are alive at once."""
+        out = []
+        for i, g0, g1, _cols in plan.tiles:
+            ds, ls = args
+            hits = match_segment_block(
+                segs[i].kernel,
+                tile_spec(segs[i].spec, g0, g1),
+                ds[pid_ix[seg_pipelines[i]]],
+                ls[pid_ix[seg_pipelines[i]]],
+                block_index=i,
+            )
+            if tiled:
+                with jax.named_scope("cko.seg.tile"):
+                    args, hits = jax.lax.optimization_barrier((args, hits))
+            out.append(hits)
+        with jax.named_scope("cko.seg.tile") if tiled else contextlib.nullcontext():
+            return jnp.concatenate(out, axis=1)
+
+    # The blocks inside the map keep their own scopes: an operation
+    # stands under the innermost scope of its name.
+    with jax.named_scope("cko.seg.chunk"):
+        stacked = (jnp.stack(stacked_d, axis=1), jnp.stack(stacked_l, axis=1))
+        if nc > 1:
+            hits = jax.lax.map(one_chunk, stacked)
+        else:  # every row in one chunk of tiles: no loop around them
+            hits = one_chunk((stacked[0][0], stacked[1][0]))[None]
+        hits = hits.reshape(tp, hits.shape[2])[:t]
+        # Reassemble full column order, zero blocks for skipped segs.
+        out, off = [], 0
+        for i in range(len(segs)):
+            if i in keep:
+                g = segs[i].n_groups
+                out.append(hits[:, off : off + g])
+                off += g
+            else:
+                out.append(zeros_for(i))
+    return out
 
 
 def _compare(cmp: jnp.ndarray, left: jnp.ndarray, right: jnp.ndarray) -> jnp.ndarray:
@@ -905,7 +1043,7 @@ def match_tier(
     n_segs = len(model.segs)
 
     def block_on(i: int) -> bool:
-        return mask is None or i >= 62 or (mask >> i) & 1 == 1
+        return _block_on(mask, i)
 
     def transformed_for(pid: int) -> tuple[jnp.ndarray, jnp.ndarray]:
         if pid not in transformed:

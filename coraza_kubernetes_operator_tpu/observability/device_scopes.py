@@ -58,6 +58,7 @@ SCOPES = {
     "cko.seg.final": "match_segment_block: gates g3 / gj3, AND-any reductions and their lax.cond",
     "cko.seg.fold": "match_segment_block: concatenation of columns, the b2g matmul, always",
     "cko.seg.chunk": "segment_tier_hits, row-chunked: pad / stack / reshape into chunks, the lax.map, reassembly",
+    "cko.seg.tile": "segment_tier_hits, column-tiled: the barrier that runs a chunk's column tiles one after another, their concatenation",
     "cko.seg.long": "segment_tier_hits, long-bank fallback: scan_dfa_bank over the long banks, the seg_perm matmul",
     "cko.flat": "scan_flat_bank: class maps, slot layout, the Pallas call cko_flat_bin<i>, unpacking columns",
     "cko.dense": "match_tier: a per-bank kernel for a dense-DFA block no bin covers (cko_dfa_bank<i> etc.)",

@@ -410,34 +410,117 @@ def _branch_signature(spec: SegmentSpec, prog: tuple, a_start: bool, a_end: bool
     return (tuple(sig), a_start, a_end)
 
 
+class _ConvColumns:
+    """The conv output columns a set of branches allocates — ``len(col_order)``
+    as ``match_segment_block`` will build it — counted branch by branch,
+    so that a block's groups can be dealt into column tiles."""
+
+    def __init__(self, spec: SegmentSpec):
+        self.spec = spec
+        self.n = 0
+        self._suffixes: set[tuple] = set()
+        self._finals: set[tuple] = set()
+
+    def cost(self, branches) -> tuple[int, set, set]:
+        """What taking ``branches`` (entries of ``spec.branches``) would
+        add; nothing is kept until ``take``."""
+        n, suffixes, finals = 0, set(), set()
+        for _gid, prog, a_start, a_end in branches:
+            if len(prog) >= 2 and prog[0][0] == "seg":
+                # suffix-deduped chains: one column per seg element per
+                # DISTINCT suffix (grouping by structural signature only
+                # changes slicing, not the total).
+                skey = (prog[1:], a_end)
+                if skey not in self._suffixes and skey not in suffixes:
+                    suffixes.add(skey)
+                    n += sum(1 for el in prog[1:] if el[0] == "seg")
+                # finals tier: one column per DISTINCT (suffix, geometry,
+                # anchor, first-segment) — cross-rule duplicates share it.
+                chan = prog[0][1]
+                fkey = (skey, *self.spec.seg_meta[chan], a_start, chan)
+                if fkey not in self._finals and fkey not in finals:
+                    finals.add(fkey)
+                    n += 1
+            else:
+                # signature-bucketed tier: one column per seg element.
+                n += sum(1 for el in prog if el[0] == "seg")
+        return n, suffixes, finals
+
+    def take(self, cost: tuple[int, set, set]) -> None:
+        n, suffixes, finals = cost
+        self.n += n
+        self._suffixes |= suffixes
+        self._finals |= finals
+
+
 def conv_n2_cols(spec: SegmentSpec) -> int:
     """Duplicated/permuted conv output column count — ``len(col_order)``
-    as ``match_segment_block`` will build it. The long-body budget in
+    as ``match_segment_block`` will build it. The budget in
     ``segment_tier_hits`` must use this, not ``kernel.shape[2]``: shared
     segments are duplicated per consumer slice, so N2 ≥ N and the conv
     output is ``[T, Q, N2]``, which is what actually occupies HBM."""
-    n2 = 0
-    suffix_ids: dict[tuple, int] = {}
-    finals_chans: dict[tuple, set[int]] = {}
-    for _, prog, a_start, a_end in spec.branches:
-        if len(prog) >= 2 and prog[0][0] == "seg":
-            skey = (prog[1:], a_end)
-            sid = suffix_ids.setdefault(skey, len(suffix_ids))
-            chan = prog[0][1]
-            nl, nr = spec.seg_meta[chan]
-            # finals tier: one column per DISTINCT (suffix, geometry,
-            # anchor, first-segment) — cross-rule duplicates share it.
-            finals_chans.setdefault((sid, nl, nr, a_start), set()).add(chan)
-        else:
-            # signature-bucketed tier: one column per seg element.
-            n2 += sum(1 for el in prog if el[0] == "seg")
-    n2 += sum(len(chans) for chans in finals_chans.values())
-    # suffix-deduped chains: one column per seg element per DISTINCT
-    # suffix (grouping by structural signature only changes slicing,
-    # not the total).
-    for ops, _ in suffix_ids:
-        n2 += sum(1 for el in ops if el[0] == "seg")
-    return max(1, n2)
+    cols = _ConvColumns(spec)
+    cols.take(cols.cost(spec.branches))
+    return max(1, cols.n)
+
+
+def _branches_by_group(spec: SegmentSpec) -> list[list[tuple]]:
+    by_group: list[list[tuple]] = [[] for _ in range(spec.n_groups)]
+    for br in spec.branches:
+        by_group[br[0]].append(br)
+    return by_group
+
+
+def widest_group_cols(spec: SegmentSpec) -> int:
+    """Conv columns of the block's widest single group: the narrowest a
+    column tile of this block can be (a group's chained pieces, its
+    suffix and its finals stay in one tile)."""
+    widest = 1
+    for branches in _branches_by_group(spec):
+        widest = max(widest, _ConvColumns(spec).cost(branches)[0])
+    return widest
+
+
+def cut_column_tiles(spec: SegmentSpec, max_cols: int) -> list[tuple[int, int, int]]:
+    """Deal the block's groups, in order, into column tiles ``(g0, g1,
+    conv columns)`` of at most ``max_cols`` columns each, cut along group
+    boundaries: a group's branches share one tile, so its chained pieces
+    and the suffix they end in are computed where its finals read them.
+    A suffix that groups of two tiles share costs its columns in both.
+    A single group wider than ``max_cols`` is a tile of its own (the
+    caller sees it from the count)."""
+    tiles: list[tuple[int, int, int]] = []
+    cols, g0 = _ConvColumns(spec), 0
+    for gid, branches in enumerate(_branches_by_group(spec)):
+        cost = cols.cost(branches)
+        if gid > g0 and cols.n + cost[0] > max_cols:
+            tiles.append((g0, gid, max(1, cols.n)))
+            cols, g0 = _ConvColumns(spec), gid
+            cost = cols.cost(branches)
+        cols.take(cost)
+    tiles.append((g0, spec.n_groups, max(1, cols.n)))
+    return tiles
+
+
+def tile_spec(spec: SegmentSpec, g0: int, g1: int) -> SegmentSpec:
+    """The block's program for groups ``g0:g1`` alone, their ids counted
+    from 0. The kernel is the block's own: a tile's ``col_order`` picks
+    the columns its branches read."""
+    if (g0, g1) == (0, spec.n_groups):
+        return spec
+    return SegmentSpec(
+        w=spec.w,
+        n_seg=spec.n_seg,
+        channels=spec.channels,
+        seg_meta=spec.seg_meta,
+        branches=tuple(
+            (gid - g0, prog, a_start, a_end)
+            for gid, prog, a_start, a_end in spec.branches
+            if g0 <= gid < g1
+        ),
+        always=tuple(gid - g0 for gid in spec.always if g0 <= gid < g1),
+        n_groups=g1 - g0,
+    )
 
 
 @partial(jax.jit, static_argnames=("spec", "block_index"))

@@ -1482,6 +1482,10 @@ class TpuEngineSidecar:
             "Host arrays handed to a launch or a device_put, one transfer each",
         ).set_function(lambda: self._engine_stat("tiering_summary", "host_operands"))
         self.metrics.gauge(
+            "cko_tiering_long_scan_launches_total",
+            "Matcher launches whose conv tier was traced onto the long DFA scan",
+        ).set_function(lambda: self._engine_stat("tiering_summary", "long_scan_launches"))
+        self.metrics.gauge(
             "cko_bodies_json_total",
             "Bodied requests read by the JSON body processor",
         ).set_function(lambda: self._engine_stat("body_summary", "json_total"))

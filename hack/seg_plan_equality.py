@@ -1,0 +1,70 @@
+"""Every plan of the conv tier against the direct conv, hit for hit, on the
+device JAX finds (PR 43: ``chiprun -- python3 hack/seg_plan_equality.py``).
+
+Tier-1 holds the same on the CPU (``tests/test_segment_column_tiles.py``,
+whose feed and requests this borrows); a TPU multiplies in bf16 and plans
+a tier past one chunk of tiles otherwise than the CPU does
+(``models/waf_model.py:_scan_past_one_chunk``), so the chip gets a run of
+its own: the 200-rule feed behind the sample, one ``128x128`` window, the
+budget patched so that the tier takes row chunks, one chunk of tiles, row
+chunks of tiles and the long scan in turn. One JSON line a plan; exit 1
+if any differs from the direct conv."""
+import importlib.util
+import json
+import sys
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(REPO))
+
+import jax
+import numpy as np
+
+from coraza_kubernetes_operator_tpu.engine import WafEngine
+from coraza_kubernetes_operator_tpu.models import waf_model
+from coraza_kubernetes_operator_tpu.ops.segment import conv_n2_cols, widest_group_cols
+from wafbench.tools import freeze_custom
+
+spec = importlib.util.spec_from_file_location("tiles_tests", REPO / "tests/test_segment_column_tiles.py")
+T = importlib.util.module_from_spec(spec)
+spec.loader.exec_module(T)
+
+dev = jax.devices()[0]
+print(json.dumps({"device": dev.device_kind, "platform": dev.platform,
+                  "scan_past_one_chunk": waf_model._scan_past_one_chunk()}), flush=True)
+feed = freeze_custom.feed_rules(T.N_FEED, T.SEED)
+engine = WafEngine(freeze_custom.feed_text(feed) + T.SAMPLE)
+tiers, _n, _m, _c, _k, lease = engine._batch_tensors(T._uri_requests(feed))
+tier = max(tiers, key=lambda t: t[0].shape[0] * t[0].shape[1])
+tier = tuple(np.array(tier[k]) for k in (0, 1, 6, 7))
+if lease is not None:
+    lease.release()
+t, width = tier[0].shape
+q = width + 2
+n2 = sum(conv_n2_cols(s.spec) for s in engine.model.segs)
+widest = max(widest_group_cols(s.spec) for s in engine.model.segs)
+model = jax.device_put(engine.model)
+
+
+def hits(budget, scan=None):
+    waf_model._SEG_CHUNK_ELEMS = budget
+    if scan is not None:
+        waf_model._scan_past_one_chunk = lambda: scan
+    out = jax.jit(lambda m, *a: waf_model.match_tier(m, *a))(model, *tier)
+    return np.asarray(out), waf_model.tier_seg_plan(engine.model, t, width).summary()
+
+
+direct, plan = hits(2**40)
+print(json.dumps({"shape": [t, width], "columns": n2, "widest": widest, "direct": plan,
+                  "hits": int(direct.sum()), "cells": int(direct.size)}), flush=True)
+ok = True
+for name, budget, scan in (("rows", 16 * q * n2, None), ("tiles", 8 * q * n2 - 1, None),
+                           ("tiles_x_rows_as_this_backend_plans", 8 * q * widest, None),
+                           ("tiles_x_rows", 8 * q * widest, False), ("long", 8 * q * widest, True)):
+    got, plan = hits(budget, scan)
+    same = bool((got == direct).all())
+    ok &= same
+    print(json.dumps({"case": name, "plan": plan, "equal_to_direct": same,
+                      "differing_cells": int((got != direct).sum())}), flush=True)
+print(json.dumps({"ok": ok}))
+sys.exit(0 if ok else 1)

@@ -31,8 +31,12 @@ tier's hit rows (the CRS cells): at most 2 x tiers + 1, where it read
 last warm round, the engine's matcher
 layout from ``automata`` (``rules``, ``segment_columns`` and
 ``segment_splits`` / ``segment_split_groups``: the model
-the table was read on; ``flat_bins``, ``flat_slots``, ``flat_groups``,
-``per_bank_kernels``) and, from the ``frontend`` counters' growth over
+the table was read on; ``segment_long_groups``: the groups a tier scans
+as DFAs where its plan says ``long``; ``flat_bins``, ``flat_slots``,
+``flat_groups``, ``per_bank_kernels``), ``seg_plans`` (how each resident
+matcher's conv tier was cut to its budget: ``compile_cache.executables[]
+.seg_plan``; ``tiering.long_scan_launches`` beside it counts the launches
+that took the long scan) and, from the ``frontend`` counters' growth over
 the last warm round, ``tenant_blob_path_share`` and
 ``engine_windows_per_read`` (also on every window's line, with the
 counters themselves under ``frontend``). What
@@ -68,14 +72,15 @@ LAUNCH_COUNTERS = ("launch_plan_hits", "launch_plan_misses", "device_windows",
 # where the engine scans its dense-DFA blocks: fused flat bins, and the
 # blocks left on one kernel a bank.
 MATCHER_LAYOUT = ("rules", "segment_columns", "segment_splits", "segment_split_groups",
-                  "flat_bins", "flat_slots", "flat_groups", "per_bank_kernels")
+                  "segment_long_groups", "flat_bins", "flat_slots", "flat_groups",
+                  "per_bank_kernels")
 # Growth of these says whether tenant requests rode the blob windows and
 # how many windows one socket read closed (sidecar/ingest.py); the two
 # benchmark metrics that read them give the ratios.
 FRONTEND_COUNTERS = ("window_reads_total", "blob_windows_total", "tenant_requests_total",
                      "tenant_blob_requests_total", "python_path_requests_total")
 FRONTEND_METRICS = ("tenant_blob_path_share", "engine_windows_per_read")
-TIERING_COUNTERS = ("windows", "tiers", "host_operands")
+TIERING_COUNTERS = ("windows", "tiers", "host_operands", "long_scan_launches")
 
 
 def tiering_growth(before: dict, after: dict) -> dict:
@@ -180,6 +185,9 @@ def main() -> int:
                       "instances": len(cell.instances()),
                       "resident_engines": after["resident_engines"],
                       "tiering": tiering_growth(before, after),
+                      "seg_plans": {e["name"]: e.get("seg_plan")
+                                    for e in after["compile_cache"]["executables"]
+                                    if e.get("seg_plan")},
                       **frontend_ratios(cell, before, after)})
         for k, mode in enumerate(args.windows.split(",")):
             trace_dir = work / f"trace{k}"

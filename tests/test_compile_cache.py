@@ -513,8 +513,10 @@ def test_table_hit_keeps_the_aot_rejection_fallback(monkeypatch):
         raise TypeError("compiled for another signature")
 
     _generation, table = eng._launch_table
-    (sig, (match_stages, post_stage)), = table.items()
-    table[sig] = ([(k, fn, rejecting, st) for k, fn, _c, st in match_stages], post_stage)
+    (sig, (match_stages, post_stage, long_scans)), = table.items()
+    assert long_scans == (False,) * len(match_stages)
+    table[sig] = ([(k, fn, rejecting, st) for k, fn, _c, st in match_stages], post_stage,
+                  long_scans)
 
     before = EXEC_CACHE.stats()
     assert [(v.interrupted, v.rule_id) for v in eng.evaluate(reqs)] == want

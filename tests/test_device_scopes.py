@@ -555,7 +555,8 @@ def test_a_text_that_raises_is_a_boundary_and_never_an_exception_into_a_compile(
     stats = cache.stats()
     assert stats["scope_table_errors"] == 1 and stats["misses"] == 1
     assert stats["executables"] == [
-        {"name": "cko_match_4x1", "model": stats["executables"][0]["model"], "device_ops": None}]
+        {"name": "cko_match_4x1", "model": stats["executables"][0]["model"], "device_ops": None,
+         "seg_plan": None}]  # no conv tier was traced under it
     assert cache.scope_tables()[0]["table"] is None
     # a text the walker cannot read counts the same
     assert cache.warm(_jitted("cko_match_8x1", lambda c: type(

@@ -15,13 +15,25 @@ files are the benchmark's and stay where they are; their JAX-free tests
 are imported here, case by case, so that every PR runs them (as
 ``tests/test_wafbench_deployment.py`` does). The whole runs on the CPU
 (each starts a sidecar) stay with ``pytest wafbench/tests``.
+
+``test_the_three_are_listed_for_the_five_crs_cells_and_no_other`` is
+imported as it stands and FAILS since PR 43, visibly (strict ``xfail``):
+it counts the cells of the configurations named ``crs-lite-pl2*`` (five)
+and takes the three metrics from the END of ``per_layer``, and ISSUE 43
+adds a sixth such cell and two metrics behind them. The file is the
+benchmark's, which only a ``benchmark`` issue may edit: that issue appends
+``crs-custom5k-bodies.api-2k-c1`` to the accepted metrics' ``workloads``
+lists, restates the test, and takes the mark away here (``strict``: the
+day the test passes, this one fails until it does). ``PERF.md`` section 7.
 """
 
+import pytest
+
+from wafbench.tests import test_device_ops_readers as _device_ops_readers
 from wafbench.tests.test_device_ops_readers import (  # noqa: F401
     test_a_program_without_the_block_gives_nothing,
     test_an_executable_that_was_not_counted_is_left_out_and_none_counted_gives_nothing,
     test_the_chain_scopes_are_names_the_program_registers,
-    test_the_three_are_listed_for_the_five_crs_cells_and_no_other,
     test_two_matcher_shapes_are_weighed_by_their_runs_and_the_post_stage_is_left_out,
 )
 from wafbench.tests.test_off_path import (  # noqa: F401
@@ -48,3 +60,10 @@ from wafbench.tests.test_trace_windows import (  # noqa: F401
     test_two_windows_in_flight_one_run_off_a_whole_number_is_scaled_to_it,
     test_without_the_order_of_runs_the_whole_capture_is_read,
 )
+
+
+
+@pytest.mark.xfail(strict=True, reason="ISSUE 43 adds a sixth crs-lite-pl2* cell and two per_layer "
+                   "entries; the benchmark's test waits for a benchmark issue (PERF.md section 7)")
+def test_the_three_are_listed_for_the_five_crs_cells_and_no_other():
+    _device_ops_readers.test_the_three_are_listed_for_the_five_crs_cells_and_no_other()
