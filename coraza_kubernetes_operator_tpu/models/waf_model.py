@@ -709,13 +709,18 @@ class SegTierPlan:
     (``lax.map`` over row chunks), ``tiles`` (column tiles inside each row
     chunk) or ``long`` (the DFA long-bank scan). ``tiles`` lists the
     column tiles of a chunk, ``(block, g0, g1, conv columns)``, in the
-    order they run; the other paths run whole blocks."""
+    order they run; the other paths run whole blocks. ``reach_gaps``:
+    the unbounded class gaps of those tiles' suffix structures that
+    ``ops/segment.py`` runs as reachability matmuls, by the size of a
+    structure's block over a chunk's rows (a chunk's worth: a ``lax.map``
+    body counts once, as in ``device_ops``)."""
 
     path: str
     row_chunks: int
     rows_per_chunk: int
     tiles: tuple[tuple[int, int, int, int], ...]
     columns: int
+    reach_gaps: int = 0
 
     def summary(self) -> dict:
         """What ``compile_cache.executables[].seg_plan`` shows."""
@@ -726,6 +731,7 @@ class SegTierPlan:
             "column_tiles": len(self.tiles),
             "columns_per_tile_max": max((c for *_, c in self.tiles), default=0),
             "columns": self.columns,
+            "reach_gaps": self.reach_gaps,
         }
 
 
@@ -754,7 +760,10 @@ def plan_segment_tier(
     output actually allocates), not the deduped ``kernel.shape[2]``; the
     gapcls NCE tables are O(T·Q) a class plus constant O(B²) triangular
     tables at every width (``ops/segment.py:_excl_prefix_sum``) and need
-    no budget term.
+    no budget term; nor do the reachability tables of a large structure's
+    unbounded gaps (``_reach_tables``: 256 bytes a row position a class,
+    what ONE pass of the latch they replace moved for 32 columns, under a
+    structure whose own block is 24 MiB or more).
 
     Tiles: the most rows a chunk (all of them, then every multiple of
     eight downwards, then 4, 2, 1: fewest passes of the chains' many
@@ -765,7 +774,17 @@ def plan_segment_tier(
     another path. ``scan_past_one_chunk`` (``_scan_past_one_chunk``: any
     backend but a TPU) sends a tier whose rows do not fit ONE chunk of
     tiles to the long scan instead of row chunks of tiles."""
-    from ..ops.segment import conv_n2_cols, cut_column_tiles, widest_group_cols
+    from ..ops.segment import (
+        conv_n2_cols,
+        cut_column_tiles,
+        reach_gap_count,
+        tile_spec,
+        widest_group_cols,
+    )
+
+    def planned(path: str, nc: int, rows: int, tiles: tuple) -> SegTierPlan:
+        reach = sum(reach_gap_count(tile_spec(specs[i], g0, g1), rows, q) for i, g0, g1, _ in tiles)
+        return SegTierPlan(path, nc, rows, tiles, columns, reach)
 
     q = width + 2
     cols = {i: conv_n2_cols(specs[i]) for i in keep}
@@ -773,10 +792,10 @@ def plan_segment_tier(
     whole = tuple((i, 0, specs[i].n_groups, cols[i]) for i in keep)
     per_row = q * max(1, columns)
     if t * per_row <= _SEG_CHUNK_ELEMS or not keep:
-        return SegTierPlan("direct", 1, t, whole, columns)
+        return planned("direct", 1, t, whole)
     rows_fit = _SEG_CHUNK_ELEMS // per_row // 8 * 8
     if rows_fit >= 8:
-        return SegTierPlan("rows", *_equal_chunks(t, rows_fit), whole, columns)
+        return planned("rows", *_equal_chunks(t, rows_fit), whole)
     widest = max(widest_group_cols(specs[i]) for i in keep)
     t8 = -(-t // 8) * 8
     one_chunk_only = long_ok and scan_past_one_chunk
@@ -792,11 +811,11 @@ def plan_segment_tier(
                 tiles.append((i, 0, specs[i].n_groups, cols[i]))
             else:
                 tiles += [(i, *tile) for tile in cut_column_tiles(specs[i], max_cols)]
-        return SegTierPlan("tiles", nc, rows, tuple(tiles), columns)
+        return planned("tiles", nc, rows, tuple(tiles))
     if long_ok:
-        return SegTierPlan("long", 1, t, (), columns)
+        return planned("long", 1, t, ())
     # Fallback disabled (or no long banks): direct conv regardless.
-    return SegTierPlan("direct", 1, t, whole, columns)
+    return planned("direct", 1, t, whole)
 
 
 def _block_on(mask: int | None, i: int) -> bool:

@@ -12,7 +12,10 @@ names entered where the matcher and the post stage are traced
 (``models/waf_model.py``, ``ops/segment.py``). A scope is metadata
 (``op_name``): it changes no HLO instruction. Two scopes carry one more
 level (``SUBSCOPED``): ``cko.seg.suffix/b<block>.st<structure>`` and
-``cko.transform/<transforms joined by +>``. Scopes nest (the row-chunked
+``cko.transform/<transforms joined by +>``; beneath a structure, the
+operations of a class gap run as a reachability matmul stand under
+``.../reach`` (``REACH``), priced apart from the structure's passes
+and counted with them. Scopes nest (the row-chunked
 conv tier runs whole segment blocks inside ``cko.seg.chunk``'s
 ``lax.map``): an operation stands under the INNERMOST registered scope
 of its ``op_name``.
@@ -51,10 +54,10 @@ SCOPES = {
     "cko.slab": "match_tier_packed: the static slices and bitcasts of the tier's one operand",
     "cko.transform": "match_tier: a device transform pipeline (beneath: its transforms joined by +)",
     "cko.seg.embed": "match_segment_block: dpad, channel planes, the bf16 stack; position iotas, gap-class membership planes",
-    "cko.seg.nce": "match_segment_block: the gap classes' exclusive prefix counts (NCE), blocked triangular matmuls",
+    "cko.seg.nce": "match_segment_block: the gap classes' exclusive prefix counts (NCE), blocked triangular matmuls; the reachability tables made of them for a wide structure's unbounded gaps",
     "cko.seg.conv": "match_segment_block: conv_general_dilated and the compare that gives m_all",
     "cko.seg.bucket": "match_segment_block tier (b): signature-bucketed chains and their lax.cond gate",
-    "cko.seg.suffix": "match_segment_block tier (a): right-to-left passes of a suffix structure (beneath: b<n>.st<i>)",
+    "cko.seg.suffix": "match_segment_block tier (a): right-to-left passes of a suffix structure (beneath: b<n>.st<i>, and reach beneath that: a class gap as matmuls)",
     "cko.seg.final": "match_segment_block: gates g3 / gj3, AND-any reductions and their lax.cond",
     "cko.seg.fold": "match_segment_block: concatenation of columns, the b2g matmul, always",
     "cko.seg.chunk": "segment_tier_hits, row-chunked: pad / stack / reshape into chunks, the lax.map, reassembly",
@@ -68,6 +71,8 @@ SCOPES = {
     "cko.post.pack": "eval_post_tiered: _pack_verdicts",
 }
 SUBSCOPED = frozenset({"cko.seg.suffix", "cko.transform"})
+# The one third level kept: beneath a suffix structure (ops/segment.py:_REACH_SCOPE).
+REACH = "reach"
 UNSCOPED = "unscoped"
 EXECUTABLE_PREFIX = "cko_"  # stage_executable's names: cko_<role>_<shape>
 
@@ -95,7 +100,8 @@ def scope_path(op_name: str | None) -> str:
         name = parts[i]
         if name in SCOPES:
             if name in SUBSCOPED and i + 1 < len(parts):
-                return f"{name}/{parts[i + 1]}"
+                deeper = f"/{REACH}" if parts[i + 2 : i + 3] == [REACH] else ""
+                return f"{name}/{parts[i + 1]}{deeper}"
             return name
     return UNSCOPED
 

@@ -21,13 +21,52 @@ at once**:
    shape: literal token, then gaps/segments) are SUFFIX-DEDUPED: the
    ops after the first segment evaluate right-to-left once per distinct
    suffix, and each branch collapses to one AND-any against its first
-   segment's conv column. Cumulative ops (window-ORs for any-gaps, the
-   NCE latch for unbounded class gaps) run as log-shift passes —
-   ``jnp.cumsum``/``lax.cummax`` lower to reduce-window on TPU, which
-   profiled at a quarter of the block's runtime; log2(Q) elementwise
-   passes on a 66-long axis are ~free. The one prefix SUM left, the
-   gap classes' NCE counts, rides the MXU at every width: blocked
-   triangular matmuls (``_excl_prefix_sum``, scope ``cko.seg.nce``).
+   segment's conv column. No cumulative op is a scan primitive
+   (``jnp.cumsum``/``lax.cummax`` lower to reduce-window on TPU, which
+   profiled at a quarter of the block's runtime). Which form a gap
+   takes, by what the trace can see:
+
+   - any-gaps (``.{lo,hi}``, ``.*``): window-ORs as doubling shifts
+     (``_spread_or``), at every width;
+   - bounded class gaps (``[^>]{0,60}``): shift-unrolled ORs up to a
+     window of 8, a windowed ``min`` of the NCE counts past that
+     (``_window_min``), at every width, both directions;
+   - unbounded class gaps (``\\s*``, ``[^&]*``), backward (tier b) and
+     forward in a structure whose ``[T, Q, ns]`` block is under
+     ``_REACH_MIN_ELEMS`` elements: the NCE latch, a running ``min`` in
+     log2(Q) shifted passes (``_latch_min``: 10 at Q = 514, 12 at
+     2,050) over an ``int32`` block. While that block is small the
+     compiler keeps it in fast memory and a pass is next to nothing
+     (CRS's structures, ns ≤ 16 and mostly 1–3: 4 MB at ``32x2048``);
+     a block of tens of MB is slower there in proportion, and one that
+     does not stay there goes through HBM every pass: 0.34 ms a pass on
+     a site feed's 1,500 suffixes under one structure at ``32x512``
+     (49 MB: 8 of its 14 fused passes write to HBM in the optimized HLO);
+   - unbounded FORWARD class gaps of a structure whose block holds
+     ``_REACH_MIN_ELEMS`` elements or more: reachability matmuls
+     (``_reach_gap``). A row's class runs do not depend on the column,
+     so one ``[B, B]`` 0/1 table a block of B = 128 positions says which
+     positions reach which, a batched matmul ORs the block's hits along
+     the runs, and a second, tiny one carries a run from block to block:
+     one pass of the MXU and about three of a ``bool`` block where the
+     latch makes ten to twelve of an ``int32`` one, and no step with
+     size. Measured on a v5e (PR 44, ``hack/reach_gap_probe.py``, an
+     application alone, latch / matmuls in ms): 4.2 M elements
+     (``[32, 2050, 64]``, ``[16, 514, 500]``) 0.13 / 0.13; 6.6 M
+     (``[32, 2050, 100]``) 0.18 / 0.21; 8.4 M (``[32, 2050, 128]``)
+     0.30 / 0.13; 12.3 M (``[16, 514, 1500]``) 0.42 / 0.28; 19.7 M
+     (``[32, 2050, 300]``) 2.04 / 0.45; 98 M (``[32, 2050, 1500]``)
+     20.1 / 1.9; at crs-lite's 16 columns the latch wins by 1.2–1.5x
+     (0.07 / 0.10 at 2,050 positions). In a feed's matcher, where other
+     blocks compete for the fast memory: ``[16, 514, 500]`` 0.1 / 0.19
+     (the latch stays); ``[16, 514, 1500]`` 1.2 / 0.28; the fourteen gaps
+     of a ``32x2048`` window's tiles (``[32, 2050, 113..342]``) 0.32
+     each as matmuls, where the three structures they stand in cost 21.0
+     ms with latches and 6.5 without.
+
+   The prefix SUM under all of the class gaps, the NCE counts, rides the
+   MXU at every width too: blocked triangular matmuls
+   (``_excl_prefix_sum``, scope ``cko.seg.nce``).
 
 Conv output columns are PERMUTED (and duplicated when shared) at trace
 time so every chain/final/solo consumer reads a contiguous slice of
@@ -58,6 +97,34 @@ from ..compiler.segments import Branch, Gap, Seg, SegmentPlan
 # total (≤ 256) is a whole number in bf16, and the table is O(B²)
 # whatever Q a request's body gives the tier.
 _PREFIX_BLOCK = 256
+
+# Positions a block of the class-gap reachability matmul spans
+# (``_reach_tables`` / ``_reach_gap``): the MXU's own edge on a v5e, so a
+# block's [B, B] table is one pass deep, Q = L + 2 pads by at most 127
+# positions and the tables are half of what 256 would hold.
+_REACH_BLOCK = 128
+# Elements of a suffix structure's ``[T, Q, ns]`` block (rows and
+# positions as ``match_segment_block`` is traced with them, a chunk's
+# under row chunks; ns the structure's columns, a tile's share under
+# column tiles) from which its unbounded forward class gaps take the
+# reachability matmul instead of ``_latch_min``'s log2(Q) passes. Both
+# forms' cost follows the block's BYTES, not its columns: the latch moves
+# ten to twelve times eight bytes an element, through fast memory while
+# the compiler keeps the ``int32`` block there (memory space ``S(1)`` on
+# its layout in the optimized HLO) and through HBM past that; the matmul
+# form moves about four times one byte and pays a pad, a table and a copy
+# whatever the size. On a v5e (PR 44; the module docstring has the
+# readings) the two tie from 4.2 M to 6.6 M elements alone, the latch is
+# the cheaper at 4.1 M in a matcher, the matmuls are from 8.4 M on, by
+# 2x, and by 4x to 10x once the block has left fast memory (12 M elements
+# in a feed's matcher, 20 M alone). This is the middle of the tie, not a
+# measured point: no structure of a benchmarked matcher lies between 4.2 M
+# and 7.4 M. crs-lite's widest structure is 16 columns: 1.0 M elements at
+# ``32x2048``.
+_REACH_MIN_ELEMS = 6 << 20
+# The level beneath a structure's scope that the matmul form's operations
+# stand under: ``cko.seg.suffix/b<block>.st<i>/reach``.
+_REACH_SCOPE = "reach"
 
 
 # ---------------------------------------------------------------------------
@@ -396,6 +463,53 @@ def _excl_prefix_count(mask: jnp.ndarray) -> jnp.ndarray:
     return _excl_prefix_sum(mask.astype(jnp.bfloat16)).astype(jnp.int32)
 
 
+def _reach_tables(nce: jnp.ndarray, big) -> tuple[jnp.ndarray, ...]:
+    """What every unbounded forward gap of one class reads, from the
+    class's NCE table [T, Q] alone (a row's class runs do not depend on
+    the column): ``within`` [T, NB, B, B] bf16, 1 where j ≥ i and the
+    bytes [i, j) of the block are all in the class; ``cross`` [T, NB, B]
+    bool, the run from p reaches the start of the next block; ``beyond``
+    [T, NB, NB] bf16, 1 at (b, b') where b' > b and the run from the
+    start of block b + 1 reaches the start of block b'. Positions past Q
+    count ``big``, which equals no real count: no run enters them."""
+    t, q = nce.shape
+    b = _REACH_BLOCK
+    nb = -(-q // b)
+    padded = jnp.pad(nce, ((0, 0), (0, (nb + 1) * b - q)), constant_values=big)
+    blocks = padded[:, : nb * b].reshape(t, nb, b)
+    upper = jnp.asarray(np.triu(np.ones((b, b), dtype=bool)))  # [i, j]: j ≥ i
+    within = ((blocks[:, :, :, None] == blocks[:, :, None, :]) & upper).astype(jnp.bfloat16)
+    starts = padded[:, ::b]  # [T, NB + 1]: the count at each block's start
+    cross = blocks == starts[:, 1:, None]
+    later = jnp.asarray(np.triu(np.ones((nb, nb), dtype=bool), 1))  # [b, b']: b' > b
+    beyond = ((starts[:, 1:, None] == starts[:, None, :nb]) & later).astype(jnp.bfloat16)
+    return within, cross, beyond
+
+
+def _reach_gap(x: jnp.ndarray, tables: tuple[jnp.ndarray, ...]) -> jnp.ndarray:
+    """out[t, p, n] = ∃ p' ≥ p: bytes [p, p') ∈ class ∧ x[t, p', n]: the
+    NCE latch (``_latch_min(where(x, nce, big)) == nce``) bit for bit, as
+    0/1 matmuls of ``_reach_tables``. Inside a block one batched
+    [B, B] x [B, ns] product; across blocks, a block's first row says
+    whether a run that enters it from the left finds a hit, and one
+    [NB, NB] x [NB, ns] product carries that to every block before it.
+    Sums are at most B and only their sign is read: exact in bf16 x bf16
+    -> f32, which is one pass of the MXU."""
+    within, cross, beyond = tables
+    t, q, ns = x.shape
+    nb, b = cross.shape[1:]
+    xb = jnp.pad(x, ((0, 0), (0, nb * b - q), (0, 0))).reshape(t, nb, b, ns)
+    local = jnp.einsum(
+        "tbij,tbjn->tbin", within, xb.astype(jnp.bfloat16), preferred_element_type=jnp.float32
+    ) > 0
+    entered = local[:, :, 0].astype(jnp.bfloat16)  # [T, NB, ns]: from a block's first position
+    ahead = jnp.einsum(
+        "tbc,tcn->tbn", beyond, entered, preferred_element_type=jnp.float32
+    ) > 0  # a run that leaves block b to the right finds a hit
+    out = local | (cross[..., None] & ahead[:, :, None, :])
+    return out.reshape(t, nb * b, ns)[:, :q]
+
+
 def _branch_signature(spec: SegmentSpec, prog: tuple, a_start: bool, a_end: bool):
     """Branches with identical signatures run as one batched chain: the op
     sequence with all *static shift amounts* (n_lead/n_real/gap bounds and
@@ -408,6 +522,45 @@ def _branch_signature(spec: SegmentSpec, prog: tuple, a_start: bool, a_end: bool
         else:
             sig.append(el)  # gap params are the signature
     return (tuple(sig), a_start, a_end)
+
+
+def _suffix_structures(spec: SegmentSpec) -> tuple[dict, dict]:
+    """Tier (a)'s distinct suffixes and the structures they stand under:
+    ``suffix_ids`` (the ops after a branch's first segment, its end
+    anchor) -> id in branch order, and ``struct``, the suffixes' op
+    sequence with channel ids dropped (static shifts and gap classes
+    kept) -> its members ``(suffix key, id)``: one [T, Q, len(members)]
+    bitmap and one right-to-left pass a structure."""
+    suffix_ids: dict[tuple, int] = {}
+    for _gid, prog, _a_start, a_end in spec.branches:
+        if len(prog) >= 2 and prog[0][0] == "seg":
+            suffix_ids.setdefault((prog[1:], a_end), len(suffix_ids))
+    struct: dict[tuple, list[tuple[tuple, int]]] = {}
+    for skey, sid in suffix_ids.items():
+        ops, a_end = skey
+        sig = tuple(("seg", *spec.seg_meta[el[1]]) if el[0] == "seg" else el for el in ops)
+        struct.setdefault((sig, a_end), []).append((skey, sid))
+    return suffix_ids, struct
+
+
+def _reach_classes(sig_ops: tuple, elems: int) -> list[tuple]:
+    """The classes of the unbounded forward class gaps that a suffix
+    structure whose ``[T, Q, ns]`` block holds ``elems`` elements runs as
+    reachability matmuls, one entry a gap: none under ``_REACH_MIN_ELEMS``."""
+    if elems < _REACH_MIN_ELEMS:
+        return []
+    return [op[1] for op in sig_ops if op[0] == "gapcls" and op[3] < 0]
+
+
+def reach_gap_count(spec: SegmentSpec, rows: int, positions: int) -> int:
+    """Class-gap operations ``match_segment_block`` runs as matmuls for
+    this spec over ``rows`` rows of ``positions`` = L + 2 chain positions
+    (``seg_plan.reach_gaps``)."""
+    _ids, struct = _suffix_structures(spec)
+    return sum(
+        len(_reach_classes(sig[0], rows * positions * len(members)))
+        for sig, members in struct.items()
+    )
 
 
 class _ConvColumns:
@@ -574,30 +727,14 @@ def match_segment_block(
         gid, prog, a_start, a_end = spec.branches[bi]
         buckets.setdefault(_branch_signature(spec, prog, a_start, a_end), []).append(bi)
 
-    suffix_ids: dict[tuple, int] = {}
+    suffix_ids, struct = _suffix_structures(spec)
     finals: dict[tuple, list[tuple[int, int]]] = {}
     for bi in chain_first:
         gid, prog, a_start, a_end = spec.branches[bi]
-        skey = (prog[1:], a_end)
-        sid = suffix_ids.setdefault(skey, len(suffix_ids))
+        sid = suffix_ids[(prog[1:], a_end)]
         seg_chan = prog[0][1]
         n_lead, n_real = spec.seg_meta[seg_chan]
         finals.setdefault((sid, n_lead, n_real, a_start), []).append((bi, seg_chan))
-
-    def _suffix_sig(skey: tuple) -> tuple:
-        ops, a_end = skey
-        sig: list[tuple] = []
-        for el in ops:
-            if el[0] == "seg":
-                nl, nr = spec.seg_meta[el[1]]
-                sig.append(("seg", nl, nr))
-            else:
-                sig.append(el)
-        return (tuple(sig), a_end)
-
-    struct: dict[tuple, list[tuple[tuple, int]]] = {}
-    for skey, sid in suffix_ids.items():
-        struct.setdefault(_suffix_sig(skey), []).append((skey, sid))
 
     # --- conv column layout ---
     # Every consumer below reads a CONTIGUOUS slice of the conv output:
@@ -752,13 +889,26 @@ def match_segment_block(
     with jax.named_scope("cko.seg.embed"):
         big = jnp.int32(1 << 20)
 
+    # The reachability tables of the classes whose unbounded gaps a WIDE
+    # suffix structure crosses (``_reach_classes``): one set a class, out
+    # here with the counts they are made of. A block whose structures are
+    # all narrow builds none and traces as it always did.
+    reach_of: dict[tuple, tuple] = {}
+    for (sig_ops, _a_end), members in struct.items():
+        for ivs in _reach_classes(sig_ops, t * q * len(members)):
+            if ivs not in reach_of:
+                with jax.named_scope("cko.seg.nce"):
+                    reach_of[ivs] = _reach_tables(nce_of[ivs], big)
+
     def gap_cls(x: jnp.ndarray, ivs: tuple, lo: int, hi: int, forward: bool):
         """Class-gap op along axis 1 of [T, Q, NB]. Forward (suffix/RTL):
         out[p] = ∃d ∈ [lo, hi]: bytes [p, p+d) ∈ C ∧ x[p+d]. Backward
         (bucket/LTR): out[p'] = ∃d: bytes [p'-d, p') ∈ C ∧ x[p'-d].
         Unbounded gaps use the NCE latch (monotone non-class counts) as a
         log-shift running min — lax.cummax/cummin lower to reduce-window
-        on TPU, which profiled at ~1/4 of this block's runtime."""
+        on TPU, which profiled at ~1/4 of this block's runtime — except
+        forward over a block of ``_REACH_MIN_ELEMS`` elements or more, where
+        the same bits come from ``_reach_gap``'s matmuls (module docstring)."""
         nce3 = nce_of[ivs][..., None]
 
         def clean(d: int) -> jnp.ndarray:
@@ -791,6 +941,9 @@ def match_segment_block(
             return m == nce3
         if forward:
             x1 = _lshift3(x, lo) & clean(lo) if lo else x
+            if x.size >= _REACH_MIN_ELEMS:
+                with jax.named_scope(_REACH_SCOPE):
+                    return _reach_gap(x1, reach_of[ivs])
             h = _latch_min(jnp.where(x1, nce3, big), big, forward=True)
             return h == nce3
         x1 = _rshift3(x & clean(lo), lo) if lo else x

@@ -8,7 +8,12 @@ a tier past one chunk of tiles otherwise than the CPU does
 its own: the 200-rule feed behind the sample, one ``128x128`` window, the
 budget patched so that the tier takes row chunks, one chunk of tiles, row
 chunks of tiles and the long scan in turn. One JSON line a plan; exit 1
-if any differs from the direct conv."""
+if any differs from the direct conv.
+
+PR 44: the same window with the unbounded class gaps as reachability
+matmuls (``ops/segment.py:_REACH_MIN_ELEMS`` patched to 1: every structure)
+against the latch's log-shift passes (patched out of reach), direct and in
+one chunk of tiles: ``latch_against_matmul``, ``differing_cells`` 0."""
 import importlib.util
 import json
 import sys
@@ -22,6 +27,7 @@ import numpy as np
 
 from coraza_kubernetes_operator_tpu.engine import WafEngine
 from coraza_kubernetes_operator_tpu.models import waf_model
+from coraza_kubernetes_operator_tpu.ops import segment
 from coraza_kubernetes_operator_tpu.ops.segment import conv_n2_cols, widest_group_cols
 from wafbench.tools import freeze_custom
 
@@ -66,5 +72,31 @@ for name, budget, scan in (("rows", 16 * q * n2, None), ("tiles", 8 * q * n2 - 1
     ok &= same
     print(json.dumps({"case": name, "plan": plan, "equal_to_direct": same,
                       "differing_cells": int((got != direct).sum())}), flush=True)
+for name, budget in (("direct", 2**40), ("tiles", 8 * q * n2 - 1)):
+    got = {}
+    for form, threshold in (("latch", 2**30), ("matmul", 1)):
+        segment._REACH_MIN_ELEMS = threshold
+        jax.clear_caches()  # match_segment_block's traces do not see the constant
+        got[form] = hits(budget)
+    same = bool((got["latch"][0] == got["matmul"][0]).all() and (got["latch"][0] == direct).all())
+    ok &= same and got["latch"][1]["reach_gaps"] == 0 < got["matmul"][1]["reach_gaps"]
+    print(json.dumps({"case": "latch_against_matmul", "plan": got["matmul"][1],
+                      "reach_gaps": {f: g[1]["reach_gaps"] for f, g in got.items()},
+                      "equal": same, "hits": int(got["matmul"][0].sum()),
+                      "differing_cells": int((got["latch"][0] != got["matmul"][0]).sum())}), flush=True)
+# ... and the two forms alone at the feed's real sizes: rows of class runs of every length, a
+# tile's 300 columns, the positions of a 512 and of a 2,048 wide window.
+rng = np.random.default_rng(44)
+big = jax.numpy.int32(1 << 20)
+for rows, q, ns in ((16, 514, 1500), (32, 2050, 300)):
+    outside = rng.random((rows, q)) > rng.choice([0.5, 0.9, 0.99, 0.999], (rows, 1))
+    x = jax.numpy.asarray(rng.random((rows, q, ns)) < 0.002)
+    nce = segment._excl_prefix_count(jax.numpy.asarray(outside))
+    latch = segment._latch_min(jax.numpy.where(x, nce[..., None], big), big, forward=True) == nce[..., None]
+    matmul = jax.jit(lambda x, nce: segment._reach_gap(x, segment._reach_tables(nce, big)))(x, nce)
+    differing = int((np.asarray(latch) != np.asarray(matmul)).sum())
+    ok &= differing == 0
+    print(json.dumps({"case": "reach_gap_alone", "shape": [rows, q, ns], "set": int(np.asarray(latch).sum()),
+                      "differing_cells": differing}), flush=True)
 print(json.dumps({"ok": ok}))
 sys.exit(0 if ok else 1)

@@ -193,6 +193,10 @@ def test_the_six_opcodes_that_are_no_operation_are_left_out(opcode):
 
 @pytest.mark.parametrize("op_name,path", [
     (f"{_MATCH}/jit(match_segment_block)/cko.seg.suffix/b0.st017/jit(_pad)/pad", "cko.seg.suffix/b0.st017"),
+    (f"{_MATCH}/jit(match_segment_block)/cko.seg.suffix/b0.st001/reach/tbij,tbjn->tbin/dot_general",
+     "cko.seg.suffix/b0.st001/reach"),  # a class gap as matmuls, priced apart from the structure's passes
+    (f"{_MATCH}/cko.seg.suffix/b0.st001/jit(_pad)/reach", "cko.seg.suffix/b0.st001"),  # only right beneath
+    (f"{_MATCH}/cko.transform/reach/and", "cko.transform/reach"),  # a transform's name is the level itself
     (f"{_MATCH}/cko.seg.suffix", "cko.seg.suffix"),  # nothing beneath it
     (f"{_MATCH}/cko.flat/jit(_scan_flat_pallas)/pallas_call", "cko.flat"),  # no level beneath: cut at the name
     (f"{_MATCH}/cko.seg.chunk/while/body/jit(match_segment_block)/cko.seg.conv/ge", "cko.seg.conv"),
@@ -316,9 +320,13 @@ def test_every_named_scope_literal_in_the_package_is_in_the_registry():
     strangers = [(str(f), line, name) for f, line, name in literals if name not in ds.SCOPES]
     assert not strangers
     # What is no literal is the level beneath a scope that carries one: the
-    # suffix structure's index, the pipeline's transforms.
+    # suffix structure's index, the pipeline's transforms; and the one name
+    # kept beneath a structure (``ops/segment.py:_REACH_SCOPE``).
     computed = [(str(f), line) for f, line, a in calls if not isinstance(a, ast.Constant)]
-    assert len(computed) == len(ds.SUBSCOPED), computed
+    assert len(computed) == len(ds.SUBSCOPED) + 1, computed
+    from coraza_kubernetes_operator_tpu.ops import segment
+
+    assert segment._REACH_SCOPE == ds.REACH
     assert all(name.startswith("cko.") for name in ds.SCOPES)
     assert ds.SUBSCOPED <= set(ds.SCOPES)
 

@@ -50,10 +50,21 @@ REQUESTS = [
         pytest.param((2, 4), marks=pytest.mark.slow),
     ],
 )
-def test_sharded_matches_single(shape):
+@pytest.mark.parametrize("class_gaps", ["latch", "matmul"])
+def test_sharded_matches_single(shape, class_gaps, monkeypatch, request):
     n_data, n_rule = shape
     if len(jax.devices()) < n_data * n_rule:
         pytest.skip("not enough devices")
+    if class_gaps == "matmul":
+        if shape != (2, 1):
+            pytest.skip("the matmul form's varying axes are the same on every mesh")
+        # `<script[^>]*>` under shard_map with its gap as reachability matmuls
+        # (ops/segment.py; no structure of these rules is large enough by itself)
+        from coraza_kubernetes_operator_tpu.ops import segment
+
+        monkeypatch.setattr(segment, "_REACH_MIN_ELEMS", 1)
+        jax.clear_caches()  # match_segment_block's traces do not see the constant
+        request.addfinalizer(jax.clear_caches)
     compiled = compile_rules(RULES)
     single = WafEngine(compiled)
     expected = single.evaluate(REQUESTS)

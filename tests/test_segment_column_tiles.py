@@ -15,6 +15,12 @@ level: JSON, urlencoded and multipart bodies whose fields trip templates
 b and d get the host evaluator's verdicts, rule id included, on the tiled
 plan, and the executable cache says which plan each matcher was traced
 with.
+
+ISSUE 44: ``seg_plan.reach_gaps`` counts the unbounded class gaps a
+launch runs as reachability matmuls (``ops/segment.py:_reach_gap``): 0
+for crs-lite at every shape, the hand count on a spec with wide
+structures, direct and tiled; and with the threshold patched to 1 the
+feed's window gives the latch's group hits and verdicts.
 """
 
 from __future__ import annotations
@@ -28,6 +34,7 @@ import numpy as np
 import pytest
 
 from coraza_kubernetes_operator_tpu.models import waf_model
+from coraza_kubernetes_operator_tpu.ops import segment as seg_mod
 from coraza_kubernetes_operator_tpu.ops.segment import (
     conv_n2_cols,
     cut_column_tiles,
@@ -250,7 +257,8 @@ def test_the_plan_follows_from_shapes(engine, monkeypatch):
     assert plan(t * q * n2 - 1).path == "rows"
     assert plan(8 * q * n2).summary() == {
         "path": "rows", "row_chunks": 4, "rows_per_chunk": 8, "column_tiles": len(keep),
-        "columns_per_tile_max": max(conv_n2_cols(s) for s in specs), "columns": n2}
+        "columns_per_tile_max": max(conv_n2_cols(s) for s in specs), "columns": n2,
+        "reach_gaps": 0}
     # one element short of eight rows of everything: tiles, all rows in one chunk,
     # down to the budget that holds all rows of the widest group and no more
     for budget in (8 * q * n2 - 1, t * q * widest):
@@ -370,7 +378,8 @@ def test_what_is_counted_says_which_plan_ran(engine, feed, tier, monkeypatch):
         for e in listed:
             if e["name"].startswith("cko_match_"):
                 assert set(e["seg_plan"]) == {"path", "row_chunks", "rows_per_chunk",
-                                              "column_tiles", "columns_per_tile_max", "columns"}
+                                              "column_tiles", "columns_per_tile_max", "columns",
+                                              "reach_gaps"}
                 assert e["seg_plan"]["path"] == "tiles" and e["seg_plan"]["columns"] == n2
                 assert set(e["device_ops"]["by_scope"]) <= set(device_scopes.SCOPES)
             else:
@@ -385,3 +394,116 @@ def test_what_is_counted_says_which_plan_ran(engine, feed, tier, monkeypatch):
         EXEC_CACHE.clear()
         jax.clear_caches()
     assert tiled == long and any(status == 403 for status, _rule in tiled)
+
+
+# -- the class gaps that ride the MXU (ISSUE 44) ----------------------------------------------
+
+
+def _wide_spec(n_rules: int):
+    """``n_rules`` parameter signatures (two unbounded class gaps, one
+    structure of ``n_rules`` columns) and as many spaced pairs (one, under
+    another), group by group in turn: a column tile of k rules holds k
+    columns of each structure."""
+    from coraza_kubernetes_operator_tpu.compiler.re_parser import parse_regex
+    from coraza_kubernetes_operator_tpu.compiler.segments import plan_segments
+
+    pats = [p for i in range(n_rules)
+            for p in (rf"zq{i:03d}k\s*\(\s*['\"]?wv{i:03d}j", rf"zq{i:03d}kx\s*wv{i:03d}j")]
+    return seg_mod.build_segment_block(
+        [plan_segments(parse_regex(p, case_insensitive=False)) for p in pats]).spec
+
+
+def test_reach_gaps_is_the_hand_count_direct_and_tiled(monkeypatch):
+    """The threshold patched to a block of 8 rows x 64 positions x 32
+    columns: a structure counts by the elements of its block as the plan's
+    chunk traces it, rows x positions x its columns in the tile."""
+    monkeypatch.setattr(seg_mod, "_REACH_MIN_ELEMS", 8 * 64 * 32)
+    spec = _wide_spec(80)
+    n2, width = conv_n2_cols(spec), 62
+    assert seg_mod.reach_gap_count(spec, 8, 64) == 3
+    assert seg_mod.reach_gap_count(_wide_spec(31), 8, 64) == 0
+    assert seg_mod.reach_gap_count(_wide_spec(31), 16, 64) == 3  # twice the rows: half the columns do
+    assert seg_mod.reach_gap_count(spec, 8, 24) == 0
+
+    def plan(budget, t):
+        monkeypatch.setattr(waf_model, "_SEG_CHUNK_ELEMS", budget)
+        return waf_model.plan_segment_tier([spec], (0,), t, width, long_ok=False)
+
+    for budget, path in ((2**40, "direct"), (16 * 64 * n2, "rows")):
+        got = plan(budget, 32)
+        assert (got.path, got.summary()["reach_gaps"]) == (path, 3)  # a lax.map's body counts once
+    halves = plan(8 * 64 * (n2 // 2 + 4), 8)  # two tiles of 40 rules: both structures wide in each
+    assert halves.path == "tiles" and len(halves.tiles) == 2 and halves.summary()["reach_gaps"] == 6
+    thirds = plan(8 * 64 * (n2 // 3 + 4), 8)  # 27 rules a tile: no block reaches 8 x 64 x 32
+    assert len(thirds.tiles) == 3 and thirds.summary()["reach_gaps"] == 0
+    uneven = plan(8 * 64 * (n2 * 9 // 20), 8)  # 36 + 36 + 8 rules
+    assert [g1 - g0 for _i, g0, g1, _c in uneven.tiles] == [72, 72, 16]
+    assert uneven.summary()["reach_gaps"] == 6
+    # the count is the sum over the tiles of what each tile's own spec traces
+    assert uneven.reach_gaps == sum(
+        seg_mod.reach_gap_count(tile_spec(spec, g0, g1), 8, 64) for _i, g0, g1, _c in uneven.tiles)
+    monkeypatch.setattr(seg_mod, "_REACH_MIN_ELEMS", 32 * 64 * 81)
+    assert plan(2**40, 32).reach_gaps == 0
+
+
+def test_reach_gaps_is_zero_for_crs_lite_at_every_served_shape():
+    """crs-lite's widest suffix structure is 16 columns (ISSUE 44 read it
+    from the model): its block is a sixth of the threshold at ``32x2048``,
+    so every crs-lite and crs-bodies matcher that is served keeps the
+    latch under every plan; only a chunk of 64 rows or more at 8,192 bytes
+    (no window of any cell) holds a block of theirs that large."""
+    from coraza_kubernetes_operator_tpu.compiler.ruleset import compile_rules
+    from coraza_kubernetes_operator_tpu.models.waf_model import build_model
+    from wafbench.harness import read_rules
+
+    text = read_rules(REPO / "wafbench" / "configs" / "crs-lite-pl2" / "rules")
+    specs = [s.spec for s in build_model(compile_rules(text)).segs]
+    widest = max(len(members) for spec in specs
+                 for members in seg_mod._suffix_structures(spec)[1].values())
+    assert widest == 16 and 32 * 2050 * widest < seg_mod._REACH_MIN_ELEMS
+    keep = tuple(range(len(specs)))
+    plans = {(rows, width, scan): waf_model.plan_segment_tier(specs, keep, rows, width, True, scan)
+             for rows in (8, 16, 32, 64, 256) for width in (32, 128, 512, 2048, 8192)
+             for scan in (False, True)}
+    assert {p.path for p in plans.values()} == {"direct", "rows", "tiles", "long"}
+    for (rows, width, scan), p in plans.items():
+        large = p.rows_per_chunk * (width + 2) * widest >= seg_mod._REACH_MIN_ELEMS
+        assert p.summary()["reach_gaps"] == 0 or large, (rows, width, scan)
+        assert not large or (width == 8192 and p.rows_per_chunk >= 64)
+    assert plans[64, 8192, False].reach_gaps == 2  # the 16- and a 12-column structure, one gap each
+
+
+def test_the_feeds_window_with_every_gap_on_the_mxu_gives_the_latchs_hits_and_verdicts(
+        engine, feed, tier, monkeypatch):
+    """Threshold patched to 1: every unbounded forward class gap of every
+    structure of the 200-rule feed and the sample takes the matmul form
+    (XLA:CPU would never choose it: it is slower there), direct and in
+    column tiles; group hits and verdicts are the unpatched run's."""
+    from coraza_kubernetes_operator_tpu.engine.compile_cache import EXEC_CACHE
+
+    t, q, n2, _widest = _shape(engine, tier)
+    reqs = _uri_requests(feed)
+
+    def run(threshold):
+        monkeypatch.setattr(seg_mod, "_REACH_MIN_ELEMS", threshold)
+        EXEC_CACHE.clear()
+        jax.clear_caches()  # match_segment_block's traces do not see the constant
+        direct, plan = _hits(engine, tier, monkeypatch, 2**40)
+        tiled, tiles = _hits(engine, tier, monkeypatch, 8 * q * n2 - 1)
+        monkeypatch.setattr(waf_model, "_SEG_CHUNK_ELEMS", 2**40)
+        verdicts = [(v.status if v.interrupted else 200, v.rule_id) for v in engine.evaluate(reqs)]
+        counted = [e["seg_plan"]["reach_gaps"] for e in _executables(engine)
+                   if e["name"].startswith("cko_match_")]
+        return direct, tiled, verdicts, (plan.reach_gaps, tiles.reach_gaps, counted)
+
+    try:
+        latch = run(seg_mod._REACH_MIN_ELEMS)
+        reach = run(1)
+    finally:
+        EXEC_CACHE.clear()
+        jax.clear_caches()
+    assert latch[3][:2] == (0, 0) and latch[3][2] and not any(latch[3][2])
+    assert reach[3][0] >= 3 and reach[3][1] >= reach[3][0] and all(reach[3][2])
+    assert (reach[0] == latch[0]).all() and (reach[1] == latch[1]).all()
+    assert (latch[0] == latch[1]).all() and latch[0].any()
+    assert reach[2] == latch[2] and any(status == 403 for status, _rule in reach[2])

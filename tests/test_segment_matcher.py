@@ -316,6 +316,150 @@ def test_no_scan_primitive_and_no_table_that_grows_with_the_width(width):
         assert eqn.outvars[0].aval.dtype == jnp.float32
 
 
+# -- the unbounded forward class gap as reachability matmuls (ISSUE 44) ---------------------
+
+SPACE = ((9, 13), (32, 32))  # \\s: a NUL is outside it
+NOT_AMP = ((0, 37), (39, 255))  # [^&]: a NUL is inside it, so a run enters the zero tail
+
+
+def _reach_rows(kind: str, q: int, rng) -> tuple[np.ndarray, np.ndarray]:
+    """(bytes [T, q] as ``dpad[:, :q]`` holds them: NUL, the row, NULs;
+    lengths [T]) for one kind of row."""
+    body = q - 2
+    rows = []
+    if kind == "one_boundary":  # a class run across position 128 (where q has one)
+        lo, hi = max(1, min(100, body - 8)), max(2, min(140, body))
+        rows = [b"&" * (lo - 1) + b" " * (hi - lo) + b"&x", b"x" * (lo - 1) + b"\t" * (hi - lo)]
+    elif kind == "several_boundaries":  # one run over nearly the whole row, and one broken in the middle
+        rows = [b"&&" + b" " * (body - 5) + b"&a", b" " * (body // 2) + b"&" + b" " * (body - body // 2 - 1)]
+    elif kind == "to_the_end_and_the_tail":  # runs that end with the row; NULs follow
+        rows = [b"&&&" + b"a" * (body - 3), b"&" + b" " * (body - 1), b"a" * (body // 3)]
+    elif kind == "empty_rows":
+        rows = [b"", b"", b" "]
+    else:
+        for density in (0.3, 0.7, 0.9, 0.97):
+            n = int(rng.integers(body // 2, body + 1))
+            rows.append(bytes(np.where(rng.random(n) < density, 32, 38).astype(np.uint8)))
+    rows = [r[:body] for r in rows]
+    data = np.zeros((len(rows), q), dtype=np.int32)
+    for i, r in enumerate(rows):
+        data[i, 1 : 1 + len(r)] = np.frombuffer(r, dtype=np.uint8)
+    return data, np.array([len(r) for r in rows], dtype=np.int32)
+
+
+@pytest.mark.parametrize("ivs", [SPACE, NOT_AMP], ids=["space", "not_amp"])
+@pytest.mark.parametrize("lo", [0, 1, 3])
+@pytest.mark.parametrize("q", [34, 258, 514, 2050])
+@pytest.mark.parametrize(
+    "kind", ["one_boundary", "several_boundaries", "to_the_end_and_the_tail", "empty_rows", "random"])
+def test_reach_gap_is_the_latch_bit_for_bit(kind, q, lo, ivs):
+    """``_reach_gap`` over ``_reach_tables`` against ``_latch_min(...) ==
+    nce`` under the same ``lo`` preamble as ``gap_cls``: class runs that
+    cross one and several blocks of ``_REACH_BLOCK`` positions, that end
+    with the row and travel the zero tail, rows of length 0, Q no multiple
+    of the block; sparse, dense and planted ``x``."""
+    import jax.numpy as jnp
+
+    from coraza_kubernetes_operator_tpu.ops import segment as seg_mod
+
+    rng = np.random.default_rng(q * 7 + lo)
+    dpad, lengths = _reach_rows(kind, q, rng)
+    t = dpad.shape[0]
+    nce = seg_mod._excl_prefix_count(~seg_mod._in_class(ivs, jnp.asarray(dpad)))
+    nce3, big = nce[..., None], jnp.int32(1 << 20)
+    ns = 5
+    x = rng.random((t, q, ns)) < np.array([0.0, 0.004, 0.05, 0.6, 0.0])
+    for i in range(t):  # planted: one hit just past the row's last byte, as a suffix's base has it
+        x[i, 1 + lengths[i], 4] = True
+    x = jnp.asarray(x)
+    if lo:
+        clean = (jnp.pad(nce3, ((0, 0), (0, lo), (0, 0)), constant_values=big)[:, lo:] - nce3) == 0
+        x = seg_mod._lshift3(x, lo) & clean
+    want = seg_mod._latch_min(jnp.where(x, nce3, big), big, forward=True) == nce3
+    got = seg_mod._reach_gap(x, seg_mod._reach_tables(nce, big))
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert not np.asarray(got)[:, :, 0].any()
+    if kind != "empty_rows" and lo == 0:
+        assert np.asarray(got)[:, :, 4].sum() > t  # the planted hit is seen from before it
+
+
+def _signature_block(n_rules: int, lo: int):
+    """``n_rules`` parameter signatures ``tok\\s*\\(\\s*['"]?tok`` (two
+    unbounded class gaps, every rule a suffix of its own under ONE
+    structure) and ``n_rules`` spaced pairs ``tokx\\s{lo,}tok`` under
+    another. The planner peels a repetition's minimum into the segment
+    before it, so the pairs are planned as ``\\s*`` and their gap's ``lo``
+    is written into the spec here: (patterns as Python re reads them, block)."""
+    import dataclasses
+
+    names = [(f"zq{i:03d}k", f"wv{i:03d}j") for i in range(n_rules)]
+    planned = [p for a, b in names for p in (rf"{a}\s*\(\s*['\"]?{b}", rf"{a}x\s*{b}")]
+    plans = [plan_segments(parse_regex(p, case_insensitive=False)) for p in planned]
+    assert all(p is not None for p in plans)
+    block = build_segment_block(plans)
+    branches = tuple(
+        (gid, tuple(("gapcls", el[1], lo, el[3]) if gid % 2 and el[0] == "gapcls" else el for el in prog),
+         a_start, a_end)
+        for gid, prog, a_start, a_end in block.spec.branches)
+    pats = [p for a, b in names for p in (rf"{a}\s*\(\s*['\"]?{b}", rf"{a}x\s{{{lo},}}{b}")]
+    return pats, dataclasses.replace(block, spec=dataclasses.replace(block.spec, branches=branches))
+
+
+@pytest.mark.parametrize("lo", [1, 3])
+@pytest.mark.parametrize("n_rules", [15, 16, 17])
+def test_a_structure_takes_the_matmul_from_the_threshold_on(n_rules, lo, monkeypatch):
+    """Structures of 15, 16 and 17 columns around a threshold patched to
+    the elements of a 16-column block over these rows: ``reach_gap_count``
+    follows the block's size alone, the trace holds the latch's ``min``
+    passes below it and the blocked matmuls from it on, and both forms give
+    Python re's answers on rows whose gaps cross a block, with a gap's
+    ``lo`` at 0, 1 and 3."""
+    import jax
+
+    from coraza_kubernetes_operator_tpu.ops import segment as seg_mod
+
+    pats, block = _signature_block(n_rules, lo)
+    wide = 3 if n_rules >= 16 else 0  # two gaps in the signatures' structure, one in the pairs'
+    max_len = 300
+    rng = random.Random(n_rules)
+    rows = [b"zq003k" + b" " * 140 + b"(" + b"\t" * 120 + b"'wv003j",  # both gaps cross position 128
+            b"zq003k" + b" " * 140 + b"(" + b" " * 60 + b"x" + b" " * 60 + b"'wv003j",  # broken
+            b"zq004k('wv004j", b"zq004k(''wv004j", b"zq005kx wv005j", b"zq004kx   wv004j",
+            b"zq004kx  wv004j", b"zq004kxwv004j", b"a" * 120 + b"zq001kx" + b" " * 150 + b"wv001j",
+            b"zq002k (" + b" " * 280, b"zq002kx" + b" " * 293, b""]
+    rows += [bytes(rng.choice(b"zqwvkj0123( '\"x") for _ in range(rng.randrange(max_len))) for _ in range(6)]
+    data = np.zeros((len(rows), max_len), dtype=np.uint8)
+    for i, r in enumerate(rows):
+        data[i, : len(r)] = np.frombuffer(r, dtype=np.uint8)
+    lengths = np.array([len(r) for r in rows], dtype=np.int32)
+    sixteen = len(rows) * (max_len + 2) * 16  # a 16-column structure's [T, Q, ns] block
+    monkeypatch.setattr(seg_mod, "_REACH_MIN_ELEMS", sixteen)
+    assert seg_mod.reach_gap_count(block.spec, len(rows), max_len + 2) == wide
+    assert seg_mod.reach_gap_count(block.spec, len(rows) - 1, max_len + 2) == (3 if n_rules > 16 else 0)
+
+    def traced(threshold):
+        monkeypatch.setattr(seg_mod, "_REACH_MIN_ELEMS", threshold)
+        fn = jax.jit(lambda k, d, ln: match_segment_block.__wrapped__(k, block.spec, d, ln))
+        closed = jax.make_jaxpr(fn)(block.kernel, data, lengths)
+        eqns = [e for jaxpr, _ in _jaxprs(closed) for e in jaxpr.eqns]
+        blocked = sum(1 for e in eqns
+                      if e.primitive.name == "dot_general" and all(v.aval.ndim == 4 for v in e.invars))
+        mins = sum(1 for e in eqns if e.primitive.name == "min")
+        return np.asarray(fn(block.kernel, data, lengths)), mins, blocked
+
+    latch, latch_mins, latch_dots = traced(2**40)
+    reach, reach_mins, reach_dots = traced(sixteen)
+    assert latch_dots == 0 and latch_mins > 0
+    assert reach_dots == wide and reach_mins == (0 if wide else latch_mins)
+    np.testing.assert_array_equal(reach, latch)
+    for gi, pat in enumerate(pats):
+        oracle = re.compile(pat.encode())
+        for i, r in enumerate(rows):
+            assert bool(reach[i, gi]) == (oracle.search(r) is not None), (pat, i)
+    assert reach[0, 6] and not reach[1, 6] and reach[8, 3] and reach[5, 9] and not reach[7, 9]
+
+
 def test_conv_n2_cols_matches_trace_allocation():
     """conv_n2_cols must equal len(col_order) as match_segment_block
     builds it — the HBM budget in segment_tier_hits depends on it."""
