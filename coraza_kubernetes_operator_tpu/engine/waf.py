@@ -50,7 +50,9 @@ def _bucket_rows(n: int) -> int:
     outlast any budget.
     Two sizes per octave bounds padding waste at ~33% while the distinct
     shape count grows logarithmically, so similar-size batches collapse
-    onto the same executables (EXEC_CACHE hits instead of compiles)."""
+    onto the same executables (EXEC_CACHE hits instead of compiles).
+    wafbench's ``crs-ingress.wide-u512-c1`` (PR 45) is the one cell that
+    runs a row count past 32: 456 unique rows a window, bucket 512."""
     if n <= 2048:
         return _bucket(n)
     size = 2048
@@ -68,7 +70,12 @@ def _bucket_rows(n: int) -> int:
 # matcher executable to compile cold, and the matcher cost is linear in
 # width, so halving the bound count halves the cold executables at a
 # bounded (≤4x-width worst case, same as the old lattice's widest gaps)
-# per-row padding cost.
+# per-row padding cost. The minimum is held to a tier's PAIR rows, before
+# dedup and the value cache: ``crs-ingress.wide-u512-c1`` (PR 45, 112
+# requests a window) is the one cell whose 64-byte tier passes it, 1,900
+# pair rows that the value cache answers to the last (that tier's launch
+# is then one padding row, ``1x64``); every other cell's window merges
+# into one tier.
 _TIER_BOUNDS = (64, 256, 1024, 4096, 16384)
 _MIN_TIER_ROWS = 256
 
@@ -92,8 +99,10 @@ _TIER_PARTS = int(_os.environ.get("CKO_TIER_PARTS", "3"))
 # Partitions below this row count merge into the largest partition: every
 # extra partition is another full matcher trace (compile time) and
 # another set of per-stage fixed costs (the flat fused scans made stages
-# cheaper but not free — round-5 profiling: 11 partitions cost more in
-# stage overhead than their block-skipping saved).
+# cheaper but not free — round-5 profiling, which PERF.md calls no
+# evidence: 11 partitions cost more in stage overhead than their
+# block-skipping saved). No cell reaches it: ``crs-ingress.wide-u512-c1``
+# (PR 45), the widest, is 456 unique rows a window.
 _MIN_PART_ROWS = int(_os.environ.get("CKO_MIN_PART_ROWS", "1024"))
 
 
@@ -565,7 +574,7 @@ class WafEngine:
         # requests the Python extractor read (``body_summary``).
         self._tiering = {
             "windows": 0, "tiers": 0, "cells": 0, "real_bytes": 0, "host_operands": 0,
-            "long_scan_launches": 0,
+            "long_scan_launches": 0, "rows": 0, "rows_padded": 0,
         }
         self._bodies = np.zeros(len(BODY_COUNTERS), dtype=np.int64)
         # Host-tier-path helpers: _dev_col_of[orig_gid] = device hit
@@ -1210,10 +1219,13 @@ class WafEngine:
                 tiers, numvals, cached = staged.tiers, staged.numvals, staged.cached
             counts = self._tiering
             counts["windows"] += 1
-            for tier in tiers:
+            rows = self._tier_rows(tiers, numvals, miss_keys)
+            for tier, n in zip(tiers, rows):
                 counts["tiers"] += 1
                 counts["cells"] += tier[0].shape[0] * tier[0].shape[1]
                 counts["real_bytes"] += int(np.sum(tier[1]))
+                counts["rows"] += n
+                counts["rows_padded"] += tier[0].shape[0]
             match_stages, post_stage, long_scans = self._resolve_launch(
                 tiers, numvals, max_phase, masks, cached
             )
@@ -1269,6 +1281,20 @@ class WafEngine:
             stages=rec,
             arena_lease=staged,
         )
+
+    @staticmethod
+    def _tier_rows(tiers, numvals, miss_keys) -> list[int]:
+        """Per tier the unique rows its matcher has to match, before
+        padding (``tiering.rows``): the value cache's misses, or without
+        a cache the unique rows the tier's real pairs read (the
+        tensorizers number them from 0)."""
+        if miss_keys is not None:
+            return [len(keys) for keys in miss_keys]
+        out = []
+        for t in tiers:
+            real = t[8][t[5] < numvals.shape[0]]
+            out.append(int(real.max()) + 1 if real.size else 0)
+        return out
 
     def _describe_matchers(self, tiers, masks, keys) -> tuple[bool, ...]:
         """Tell the executable cache how each tier's matcher cuts its
@@ -1508,7 +1534,10 @@ class WafEngine:
         the post slab), and one more for a tier whose hit rows the
         prefilter confirm repacked. ``long_scan_launches`` counts the
         launches of a matcher whose conv tier was traced onto the long
-        DFA scan: 0 while every tier rides the MXU."""
+        DFA scan: 0 while every tier rides the MXU. ``rows`` are the
+        unique rows the tiers' matchers had to match and ``rows_padded`` the
+        same as bucketed (1 - rows / rows_padded is the share of matcher
+        rows that was padding)."""
         return dict(self._tiering)
 
     def body_summary(self) -> dict:

@@ -1486,6 +1486,14 @@ class TpuEngineSidecar:
             "Matcher launches whose conv tier was traced onto the long DFA scan",
         ).set_function(lambda: self._engine_stat("tiering_summary", "long_scan_launches"))
         self.metrics.gauge(
+            "cko_tiering_rows_total",
+            "Unique rows the tiers' matchers had to match, before padding",
+        ).set_function(lambda: self._engine_stat("tiering_summary", "rows"))
+        self.metrics.gauge(
+            "cko_tiering_rows_padded_total",
+            "Matcher rows launched, as bucketed: the tiers' row counts",
+        ).set_function(lambda: self._engine_stat("tiering_summary", "rows_padded"))
+        self.metrics.gauge(
             "cko_bodies_json_total",
             "Bodied requests read by the JSON body processor",
         ).set_function(lambda: self._engine_stat("body_summary", "json_total"))

@@ -80,7 +80,8 @@ MATCHER_LAYOUT = ("rules", "segment_columns", "segment_splits", "segment_split_g
 FRONTEND_COUNTERS = ("window_reads_total", "blob_windows_total", "tenant_requests_total",
                      "tenant_blob_requests_total", "python_path_requests_total")
 FRONTEND_METRICS = ("tenant_blob_path_share", "engine_windows_per_read")
-TIERING_COUNTERS = ("windows", "tiers", "host_operands", "long_scan_launches")
+TIERING_COUNTERS = ("windows", "tiers", "host_operands", "long_scan_launches", "rows",
+                    "rows_padded")
 
 
 def tiering_growth(before: dict, after: dict) -> dict:
