@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import contextlib
 from dataclasses import dataclass, field
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -53,31 +53,72 @@ from .slab import unpack_match_slab, unpack_post_slab
 
 _BIG = np.int32(2**31 - 1)
 
-# Conv-tier match-bitmap element budget (rows * (L+2) * N2 conv columns
-# alive at once). ``segment_tier_hits`` cuts a tier to it per traced
-# shape, from shapes alone (``plan_segment_tier``): the whole bitmap
-# under it runs direct; over it the rows are CHUNKED (the conv matchers
-# inside a ``lax.map`` over row blocks: the round-4 trace showed the
-# 19k-row short tier falling off the conv tier into 26 serializing
-# long-bank DFA scans, ~60% of the whole CRS-scale step, because the only
-# options were one giant bitmap or the scan fallback); where not even
-# eight rows of all columns fit, the columns are TILED as well, along
-# group boundaries, so a site's feed of thousands of rules under a
-# 2048-wide window still rides the MXU (PR 43: 12,498 columns at 2,050
-# positions are 25.6 M elements a row, and the window went to the long
-# scan at 763 ms a call): all the rows in one chunk of tiles while they
-# fit, then row chunks of tiles. The DFA long-bank fallback remains for
-# the case ONE row of ONE tile exceeds the budget, and, on a backend
-# that is no TPU, for a tier whose rows do not fit one chunk of tiles
-# (``_scan_past_one_chunk``: the CPU pays the conv's arithmetic in full,
-# the chip the scan's serial steps). CKO_SEG_BITMAP_ELEMENTS=0 builds no
-# long banks (saves their HBM if length buckets are known-small): such a
-# tier runs direct only where one row of the widest group is over the
-# budget.
+# The conv tier's element budget: how many match-bitmap elements (rows *
+# (L+2) positions * N2 conv columns: the bf16 conv output of one launch)
+# may be alive at once. ``segment_tier_hits`` cuts a tier to it per
+# traced shape, from shapes alone (``plan_segment_tier``), in as few
+# pieces as it allows: the whole bitmap under it runs direct (one conv a
+# block and ONE pass of the chains over all rows: a chain operation costs
+# by the rows it reads, so passes over row chunks move the same bytes and
+# add the loop's own slices and copies, 10.9 of the 60.9 ms of crs-lite's
+# ``512x512`` launch under the 2^27 this budget used to be, PR 46); over
+# it the rows are CHUNKED (the conv matchers inside a ``lax.map`` over
+# row blocks: the round-4 trace showed the 19k-row short tier falling off
+# the conv tier into 26 serializing long-bank DFA scans, ~60% of the
+# whole CRS-scale step, because the only options were one giant bitmap or
+# the scan fallback); where not even eight rows of all columns fit, the
+# columns are TILED as well, along group boundaries (PR 43): all the rows
+# in one chunk of tiles while they fit, then row chunks of tiles. The DFA
+# long-bank fallback remains for the case ONE row of ONE tile exceeds the
+# budget, and, on a backend that is no TPU, for a tier whose rows do not
+# fit one chunk of tiles (``_scan_past_one_chunk``: the CPU pays the
+# conv's arithmetic in full, the chip the scan's serial steps).
+#
+# The budget follows what the code can observe, the device's memory
+# (``seg_chunk_budget``): a launch's bf16 conv output may take one part in
+# ``_SEG_HBM_SHARE`` of the device's ``memory_stats()["bytes_limit"]``
+# (a v5e says 16,909,336,064 bytes: 1,056,833,504 elements; every shape
+# a benchmark cell serves is direct there, and two such launches in
+# flight, one a lane, stay under half the device by the compiler's own
+# count of their temporaries: PERF.md §6, PR 46). A device that reports
+# no memory (XLA:CPU: tier-1) keeps ``_SEG_CHUNK_ELEMS_NO_STATS``, the
+# 2^27 every plan was cut to until PR 46, so no CPU plan moved.
+# ``_SEG_CHUNK_ELEMS`` is the override the tests and
+# ``hack/seg_plan_equality.py`` patch; nothing reads the environment.
+#
+# CKO_SEG_BITMAP_ELEMENTS is NOT a budget of the bitmap: it only decides
+# whether the long banks are built (``build_model``) and used
+# (``long_ok``). 0 builds none (saves their HBM if length buckets are
+# known-small): a tier then runs direct where one row of the widest group
+# is over the budget.
 import os as _os
 
 _SEG_BITMAP_ELEMS = int(_os.environ.get("CKO_SEG_BITMAP_ELEMENTS", str(2**30)))
-_SEG_CHUNK_ELEMS = int(_os.environ.get("CKO_SEG_CHUNK_ELEMENTS", str(2**27)))
+_SEG_CHUNK_ELEMS_NO_STATS = 2**27
+_SEG_HBM_SHARE = 8
+_SEG_CHUNK_ELEMS: int | None = None
+
+
+def seg_chunk_budget(bytes_limit: int | None) -> int:
+    """The conv tier's element budget on a device of ``bytes_limit`` bytes
+    (None or 0: the device reports no memory)."""
+    if not bytes_limit:
+        return _SEG_CHUNK_ELEMS_NO_STATS
+    return bytes_limit // _SEG_HBM_SHARE // 2  # bf16: two bytes an element
+
+
+@lru_cache(maxsize=None)
+def _device_bytes_limit() -> int | None:
+    """``bytes_limit`` of the device a launch runs on (every local device
+    of a host is the same chip), None where it reports no memory. Asked
+    once a process: at the first plan, never at import."""
+    return (jax.local_devices()[0].memory_stats() or {}).get("bytes_limit")
+
+
+def _seg_chunk_elems() -> int:
+    if _SEG_CHUNK_ELEMS is not None:
+        return _SEG_CHUNK_ELEMS
+    return seg_chunk_budget(_device_bytes_limit())
 
 
 def _state_bucket(n_states: int) -> int:
@@ -703,7 +744,8 @@ def build_model(crs: CompiledRuleSet, automata=None) -> WafModel:
 
 @dataclass(frozen=True)
 class SegTierPlan:
-    """How one traced shape's conv tier is cut to ``_SEG_CHUNK_ELEMS``.
+    """How one traced shape's conv tier is cut to ``budget`` elements
+    (``seg_chunk_budget`` of the device's memory, or the override).
 
     ``path``: ``direct`` (one conv a block over all rows), ``rows``
     (``lax.map`` over row chunks), ``tiles`` (column tiles inside each row
@@ -720,6 +762,7 @@ class SegTierPlan:
     rows_per_chunk: int
     tiles: tuple[tuple[int, int, int, int], ...]
     columns: int
+    budget: int
     reach_gaps: int = 0
 
     def summary(self) -> dict:
@@ -731,6 +774,7 @@ class SegTierPlan:
             "column_tiles": len(self.tiles),
             "columns_per_tile_max": max((c for *_, c in self.tiles), default=0),
             "columns": self.columns,
+            "budget_elements": self.budget,
             "reach_gaps": self.reach_gaps,
         }
 
@@ -755,7 +799,8 @@ def plan_segment_tier(
     scan_past_one_chunk: bool = False,
 ) -> SegTierPlan:
     """The conv tier's plan for ``t`` rows of ``width`` bytes over the
-    kept blocks' ``specs``, from shapes alone. The budget counts the
+    kept blocks' ``specs``, from shapes alone, under the device's budget
+    (``_seg_chunk_elems``). The budget counts the
     DUPLICATED column count (``conv_n2_cols`` — what the [T, Q, N2] conv
     output actually allocates), not the deduped ``kernel.shape[2]``; the
     gapcls NCE tables are O(T·Q) a class plus constant O(B²) triangular
@@ -782,18 +827,20 @@ def plan_segment_tier(
         widest_group_cols,
     )
 
+    budget = _seg_chunk_elems()
+
     def planned(path: str, nc: int, rows: int, tiles: tuple) -> SegTierPlan:
         reach = sum(reach_gap_count(tile_spec(specs[i], g0, g1), rows, q) for i, g0, g1, _ in tiles)
-        return SegTierPlan(path, nc, rows, tiles, columns, reach)
+        return SegTierPlan(path, nc, rows, tiles, columns, budget, reach)
 
     q = width + 2
     cols = {i: conv_n2_cols(specs[i]) for i in keep}
     columns = sum(cols.values())
     whole = tuple((i, 0, specs[i].n_groups, cols[i]) for i in keep)
     per_row = q * max(1, columns)
-    if t * per_row <= _SEG_CHUNK_ELEMS or not keep:
+    if t * per_row <= budget or not keep:
         return planned("direct", 1, t, whole)
-    rows_fit = _SEG_CHUNK_ELEMS // per_row // 8 * 8
+    rows_fit = budget // per_row // 8 * 8
     if rows_fit >= 8:
         return planned("rows", *_equal_chunks(t, rows_fit), whole)
     widest = max(widest_group_cols(specs[i]) for i in keep)
@@ -801,7 +848,7 @@ def plan_segment_tier(
     one_chunk_only = long_ok and scan_past_one_chunk
     for rows_fit in [t8] if one_chunk_only else [*range(t8, 0, -8), 4, 2, 1]:
         nc, rows = _equal_chunks(t, rows_fit)
-        max_cols = _SEG_CHUNK_ELEMS // (rows * q)
+        max_cols = budget // (rows * q)
         if max_cols < widest:
             continue
         # A block that fits is a tile; a wider one is dealt group by group.

@@ -7,7 +7,9 @@ cannot compile full crs-lite): builds the engine, compiles
 ``jit_cko_match_<rows>x<width>`` for each shape (in parallel: XLA
 releases the interpreter lock), then calls each 20 times with the
 model's tables resident and waits for the result, twice: every row as
-long as the tier is wide, and every row 32 bytes long in the same tier.
+long as the tier is wide, and every row 32 bytes long in the same tier;
+``memory`` is what the executable holds beside its operands
+(``memory_analysis``) and the device's peak with two launches in flight.
 Wall time per call is device time here: nothing else runs, and a call
 is tens of milliseconds against tens of microseconds of dispatch. The
 second line says what a call launches (``automata_summary()``: flat
@@ -213,6 +215,17 @@ def main(argv=None) -> int:
                     ms.append(1e3 * (time.perf_counter() - t0))
                 line[name + "_ms"] = {"min": min(ms), "median": statistics.median(ms),
                                       "max": max(ms)}
+            # What a launch holds on the device: the executable's own temporaries
+            # (static), and the process's peak after two launches were in flight at
+            # once, as two lanes hold them (it only grows: put the small shape first).
+            pair = [compiled(model, *ops) for _ in range(2)]
+            jax.block_until_ready(pair)
+            del pair
+            held, stats = compiled.memory_analysis(), dev.memory_stats() or {}
+            line["memory"] = {
+                "temp_bytes": held.temp_size_in_bytes, "argument_bytes": held.argument_size_in_bytes,
+                "output_bytes": held.output_size_in_bytes,
+                **{k: stats.get(k) for k in ("bytes_limit", "peak_bytes_in_use", "bytes_in_use")}}
             if args.scopes:
                 line.update(priced(shape, compiled, operands(rows, width, width)))
             out.append(line)

@@ -58,7 +58,7 @@ def engine(feed):
     from coraza_kubernetes_operator_tpu.engine import WafEngine
 
     with pytest.MonkeyPatch.context() as mp:
-        for k in ("CKO_FLAT", "CKO_AUTOMATA", "CKO_NATIVE", "CKO_SEG_CHUNK_ELEMENTS"):
+        for k in ("CKO_FLAT", "CKO_AUTOMATA", "CKO_NATIVE"):
             mp.delenv(k, raising=False)
         return WafEngine(freeze_custom.feed_text(feed) + SAMPLE)
 
@@ -258,7 +258,7 @@ def test_the_plan_follows_from_shapes(engine, monkeypatch):
     assert plan(8 * q * n2).summary() == {
         "path": "rows", "row_chunks": 4, "rows_per_chunk": 8, "column_tiles": len(keep),
         "columns_per_tile_max": max(conv_n2_cols(s) for s in specs), "columns": n2,
-        "reach_gaps": 0}
+        "budget_elements": 8 * q * n2, "reach_gaps": 0}
     # one element short of eight rows of everything: tiles, all rows in one chunk,
     # down to the budget that holds all rows of the widest group and no more
     for budget in (8 * q * n2 - 1, t * q * widest):
@@ -379,8 +379,9 @@ def test_what_is_counted_says_which_plan_ran(engine, feed, tier, monkeypatch):
             if e["name"].startswith("cko_match_"):
                 assert set(e["seg_plan"]) == {"path", "row_chunks", "rows_per_chunk",
                                               "column_tiles", "columns_per_tile_max", "columns",
-                                              "reach_gaps"}
+                                              "budget_elements", "reach_gaps"}
                 assert e["seg_plan"]["path"] == "tiles" and e["seg_plan"]["columns"] == n2
+                assert e["seg_plan"]["budget_elements"] == 32 * 66 * (n2 // 6)  # what it was cut to
                 assert set(e["device_ops"]["by_scope"]) <= set(device_scopes.SCOPES)
             else:
                 assert e["seg_plan"] is None  # the post stage traces no conv tier

@@ -2,8 +2,13 @@
 ``crs-ingress.wide-u512-c1``).
 
 No cell before it served more than 32 rows a launch. Here, small and on
-the CPU: the plan crs-lite's conv tier takes at ``512x512`` (row chunks,
-5 x 104) beside the ``32x512`` one every other CRS cell rides (direct); an
+the CPU: the conv tier's budget as a function of the device's memory
+(``models/waf_model.py:seg_chunk_budget``, PR 46: an eighth of a v5e's
+memory in bf16, 2^27 where the device reports none, as this CPU) and the
+plans it gives crs-lite's and the 5,000-rule feed's conv tiers at every
+shape a benchmark cell serves (``512x512``: one conv on a v5e, row chunks
+of 5 x 104 without memory stats), with shapes past the v5e's budget that
+still go ``rows`` / ``tiles``; an
 engine on the bundled CRS-shaped rule set given one window of 400 unique
 rows on a row-chunked plan, held request for request to the host
 evaluator; what a tier whose every row the value cache answered launches
@@ -80,33 +85,106 @@ def _matchers() -> list[dict]:
 @pytest.fixture(scope="module")
 def crs_lite():
     with pytest.MonkeyPatch.context() as mp:
-        for k in ("CKO_FLAT", "CKO_AUTOMATA", "CKO_SEG_CHUNK_ELEMENTS"):
+        for k in ("CKO_FLAT", "CKO_AUTOMATA"):
             mp.delenv(k, raising=False)
         return WafEngine(read_rules(REPO / "wafbench/configs/crs-lite-pl2/rules"))
 
 
-@pytest.mark.parametrize("rows,width,path,chunks,per_chunk", [
-    (512, 512, "rows", 5, 104),   # crs-ingress.wide-u512-c1
-    (32, 512, "direct", 1, 32),   # every other crs-lite cell
+# ``memory_stats()["bytes_limit"]`` of one v5e chip (my chip run, PR 46).
+V5E = 16_909_336_064
+
+
+@pytest.mark.parametrize("bytes_limit,elements", [
+    (None, 2**27),          # XLA:CPU reports no memory: tier-1, every plan as before PR 46
+    (0, 2**27),
+    (16 * 2**30, 2**30),    # 16 GiB: an eighth of it, in bf16
+    (V5E, 1_056_833_504),   # what a v5e says it has
 ])
-def test_the_plan_crs_lite_rides_at_each_cell_s_shape(crs_lite, rows, width, path, chunks,
-                                                      per_chunk):
-    plan = waf_model.tier_seg_plan(crs_lite.model, rows, width)
+def test_the_conv_tiers_budget_follows_the_devices_memory(monkeypatch, bytes_limit, elements):
+    assert waf_model.seg_chunk_budget(bytes_limit) == elements
+    monkeypatch.setattr(waf_model, "_device_bytes_limit", lambda: bytes_limit)
+    assert waf_model._seg_chunk_elems() == elements
+    monkeypatch.setattr(waf_model, "_SEG_CHUNK_ELEMS", 12345)  # the tests' override wins
+    assert waf_model._seg_chunk_elems() == 12345
+
+
+def test_this_cpu_reports_no_memory_and_nothing_reads_the_environment():
+    assert waf_model._device_bytes_limit.__wrapped__() is None
+    assert waf_model._SEG_CHUNK_ELEMS is None and waf_model._seg_chunk_elems() == 2**27
+    package = Path(waf_model.__file__).parents[1]
+    assert not [f for f in package.rglob("*.py") if "CKO_SEG_CHUNK_ELEMENTS" in f.read_text()]
+
+
+@pytest.fixture(scope="module")
+def conv_specs(crs_lite):
+    """The conv tier's specs of the two rule texts the CRS cells serve:
+    crs-lite (2,496 columns) and crs-lite behind the 5,000-rule feed
+    (12,498), built as ``build_model`` does, no engine for the feed."""
+    from coraza_kubernetes_operator_tpu.compiler.ruleset import compile_rules
+
+    feed = read_rules(REPO / "wafbench/configs/crs-lite-pl2-custom5k/rules")
+    return {2496: [sb.spec for sb in crs_lite.model.segs],
+            12498: [sb.spec for sb in waf_model.build_model(compile_rules(feed)).segs]}
+
+
+@pytest.mark.parametrize("bytes_limit,columns,rows,width,path,chunks,per_chunk,tiles", [
+    # without memory stats (2^27): the plans every cell rode until PR 46
+    (None, 2496, 512, 512, "rows", 5, 104, 8),     # crs-ingress.wide-u512-c1
+    (None, 2496, 32, 512, "direct", 1, 32, 8),     # the three crs-lite cells
+    (None, 2496, 32, 2048, "rows", 2, 16, 8),      # crs-bodies.api-2k-c1
+    (None, 12498, 32, 512, "rows", 2, 16, 8),      # crs-custom5k.ftw-salted-c1
+    (None, 12498, 32, 2048, "tiles", 1, 32, 12),   # crs-custom5k-bodies.api-2k-c1
+    # on a v5e: every shape a cell serves is one conv and one pass of the chains
+    (V5E, 2496, 512, 512, "direct", 1, 512, 8),
+    (V5E, 2496, 32, 512, "direct", 1, 32, 8),
+    (V5E, 2496, 32, 2048, "direct", 1, 32, 8),
+    (V5E, 12498, 32, 512, "direct", 1, 32, 8),
+    (V5E, 12498, 32, 2048, "direct", 1, 32, 8),
+    # ... and past its budget the rows are chunked and the columns tiled as before
+    (V5E, 2496, 256, 8192, "rows", 6, 48, 8),
+    (V5E, 12498, 512, 512, "rows", 4, 128, 8),
+    (V5E, 12498, 64, 32768, "tiles", 1, 64, 29),
+])
+def test_the_plan_at_each_cell_s_shape_follows_the_budget(monkeypatch, conv_specs, bytes_limit,
+                                                          columns, rows, width, path, chunks,
+                                                          per_chunk, tiles):
+    monkeypatch.setattr(waf_model, "_device_bytes_limit", lambda: bytes_limit)
+    specs = conv_specs[columns]
+    plan = waf_model.plan_segment_tier(specs, tuple(range(len(specs))), rows, width, long_ok=True)
     assert (plan.path, plan.row_chunks, plan.rows_per_chunk) == (path, chunks, per_chunk)
     said = plan.summary()
-    assert said["columns"] == sum(conv_n2_cols(sb.spec) for sb in crs_lite.model.segs) == 2496
-    assert said["column_tiles"] == len(crs_lite.model.segs) and said["reach_gaps"] == 0
-    # what the budget holds: 514 bitmap positions a row over every column
-    assert per_chunk * (width + 2) * 2496 <= waf_model._SEG_CHUNK_ELEMS
+    assert said["columns"] == sum(conv_n2_cols(s) for s in specs) == columns
+    assert said["column_tiles"] == tiles
+    # which rule engaged: the budget the plan was cut to, and what it holds
+    budget = said["budget_elements"]
+    assert budget == waf_model.seg_chunk_budget(bytes_limit)
+    positions = width + 2
+    assert per_chunk * positions * said["columns_per_tile_max"] <= budget
+    if path == "direct":
+        assert rows * positions * columns <= budget
+    else:  # the fewest chunks the budget allows: eight rows more would not fit
+        assert rows * positions * columns > budget
     if path == "rows":
-        assert (per_chunk + 8) * (width + 2) * 2496 > waf_model._SEG_CHUNK_ELEMS
+        rows_fit = budget // (positions * columns) // 8 * 8
+        assert per_chunk <= rows_fit and chunks == -(-rows // rows_fit)
+
+
+def test_the_engine_says_the_plan_and_its_budget_of_the_wide_window(crs_lite, monkeypatch):
+    """``tier_seg_plan`` is what ``compile_cache.executables[].seg_plan``
+    shows of a matcher: on a v5e the ingress cell's ``512x512`` is direct."""
+    assert waf_model.tier_seg_plan(crs_lite.model, 512, 512).summary()["path"] == "rows"
+    monkeypatch.setattr(waf_model, "_device_bytes_limit", lambda: V5E)
+    said = waf_model.tier_seg_plan(crs_lite.model, 512, 512).summary()
+    assert (said["path"], said["row_chunks"], said["rows_per_chunk"]) == ("direct", 1, 512)
+    assert said["budget_elements"] == V5E // 16 and said["reach_gaps"] == 0
 
 
 @pytest.fixture(scope="module")
 def chunked():
     """The bundled rule set's engine with the conv budget cut so that a
     window of 512 rows of 512 bytes goes through in four chunks of 128
-    rows, as crs-lite's goes through in five on a v5e."""
+    rows, as crs-lite's goes through in five under the 2^27 of a device
+    that reports no memory (and went on a v5e until PR 46)."""
     engine = native_engine(MINI, None)
     n2 = sum(conv_n2_cols(sb.spec) for sb in engine.model.segs)
     with pytest.MonkeyPatch.context() as mp:
