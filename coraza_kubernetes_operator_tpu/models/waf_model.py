@@ -755,7 +755,13 @@ class SegTierPlan:
     the unbounded class gaps of those tiles' suffix structures that
     ``ops/segment.py`` runs as reachability matmuls, by the size of a
     structure's block over a chunk's rows (a chunk's worth: a ``lax.map``
-    body counts once, as in ``device_ops``)."""
+    body counts once, as in ``device_ops``). ``conv_passes`` and
+    ``conv_fill`` say how far the tiles' convolutions fill the MXU's
+    depth (``ops/segment.py:conv_tap_packing``): the passes of the array's
+    depth the tiles' convs make an output tile, summed (taps x 128-deep
+    slices of a tap's contraction: W a block while a tap contracts over its
+    C channels alone, ``ceil(W / (128 // C))`` with the taps packed), and
+    the share of the depth a pass fills, weighted by the tiles' columns."""
 
     path: str
     row_chunks: int
@@ -764,6 +770,8 @@ class SegTierPlan:
     columns: int
     budget: int
     reach_gaps: int = 0
+    conv_passes: int = 0
+    conv_fill: float = 0.0
 
     def summary(self) -> dict:
         """What ``compile_cache.executables[].seg_plan`` shows."""
@@ -776,6 +784,8 @@ class SegTierPlan:
             "columns": self.columns,
             "budget_elements": self.budget,
             "reach_gaps": self.reach_gaps,
+            "conv_passes": self.conv_passes,
+            "conv_fill": round(self.conv_fill, 4),
         }
 
 
@@ -820,7 +830,9 @@ def plan_segment_tier(
     backend but a TPU) sends a tier whose rows do not fit ONE chunk of
     tiles to the long scan instead of row chunks of tiles."""
     from ..ops.segment import (
+        conv_fill,
         conv_n2_cols,
+        conv_passes,
         cut_column_tiles,
         reach_gap_count,
         tile_spec,
@@ -831,7 +843,10 @@ def plan_segment_tier(
 
     def planned(path: str, nc: int, rows: int, tiles: tuple) -> SegTierPlan:
         reach = sum(reach_gap_count(tile_spec(specs[i], g0, g1), rows, q) for i, g0, g1, _ in tiles)
-        return SegTierPlan(path, nc, rows, tiles, columns, budget, reach)
+        # A tile's conv has its block's taps and channels: ``tile_spec`` cuts columns.
+        passes = sum(conv_passes(specs[i]) for i, *_ in tiles)
+        fill = sum(c * conv_fill(specs[i]) for i, _g0, _g1, c in tiles) / max(1, sum(c for *_, c in tiles))
+        return SegTierPlan(path, nc, rows, tiles, columns, budget, reach, passes, fill)
 
     q = width + 2
     cols = {i: conv_n2_cols(specs[i]) for i in keep}

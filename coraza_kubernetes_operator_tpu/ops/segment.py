@@ -6,16 +6,29 @@ byte* (a sequential ``lax.scan``), this tier matches all fixed-length
 byte-class segments (``compiler/segments.py``) for **all start positions
 at once**:
 
-1. **embed**: bytes → ``[T, Lp, C]`` channel planes built from pure VPU
+1. **embed**: bytes → ``[T, Lp, k·C]`` channel planes built from pure VPU
    comparisons (nibble one-hots, class-interval tests, a constant ones
-   plane) — no gathers, no 256-wide one-hot.
-2. **conv**: one ``conv_general_dilated`` with kernel ``[W, C, N]``. Each
-   segment position contributes exactly 2 when its byte matches (hi+lo
+   plane) — no gathers, no 256-wide one-hot. Each byte stands with its
+   k − 1 right-hand neighbours, so the C planes are compared once over
+   ``[T, Lp, k]`` (step 2 says what k is).
+2. **conv**: one ``conv_general_dilated``. Each segment position
+   contributes exactly 2 when its byte matches (hi+lo
    nibble hits for product classes, weight-2 indicator otherwise, the
    ones plane for padding), so ``out == 2W`` ⇔ the segment matches at
    that window start. This is the classic exact-match-as-threshold
    formulation: a DFA transition needs a table lookup; an equality test
    is just arithmetic, and arithmetic is what the systolic array does.
+   The kernel is ``[W, C, N]`` and a tap contracts over the block's C
+   embed channels alone (16 to 42 for CRS and a site's feed) of the
+   MXU's 128-deep contraction, so ``k = 128 // C`` taps ride one
+   contraction (``conv_tap_packing``): the embed holds positions
+   p .. p + k − 1 side by side on the channel axis, the kernel takes zero
+   taps up to ``k·J``, ``J = ceil(W / k)``, and is reshaped to
+   ``[J, k·C, N]``, and the conv is dilated by k, so tap j reads
+   positions p + k·j .. p + k·j + k − 1. The sums are the same whole
+   numbers (a zero tap adds nothing). A block of more than 64 channels
+   has k = 1 and traces the plain conv, operation for operation.
+   PERF.md §5–§6 (PR 47) have the blocks' statics and the readings.
 3. **chain**: gap constraints as bitmap algebra on ``[T, Q, ·]``
    blocks. Multi-element branches starting with a segment (the common
    shape: literal token, then gaps/segments) are SUFFIX-DEDUPED: the
@@ -125,6 +138,12 @@ _REACH_MIN_ELEMS = 6 << 20
 # The level beneath a structure's scope that the matmul form's operations
 # stand under: ``cko.seg.suffix/b<block>.st<i>/reach``.
 _REACH_SCOPE = "reach"
+
+# Depth of the MXU's contraction: what one pass of the systolic array
+# multiplies at once. 128 on a v5e, the one generation this repo has
+# measured; a 256-deep array (v6e) would pack twice the taps, and the
+# constant would then follow the backend's device kind.
+_MXU_DEPTH = 128
 
 
 # ---------------------------------------------------------------------------
@@ -309,6 +328,30 @@ def build_segment_block(plans: list[SegmentPlan]) -> SegmentBlock:
 # ---------------------------------------------------------------------------
 
 
+def conv_tap_packing(spec: SegmentSpec) -> tuple[int, int]:
+    """``(k, J)``: the taps of the block's kernel that ride one contraction
+    of the conv, and the taps the conv is left with. ``k`` is as many as
+    fill the MXU's depth with the block's C channels (at most the W there
+    are, 1 for a block of more than half the depth: the plain conv);
+    ``J = ceil(W / k)``."""
+    k = max(1, min(spec.w, _MXU_DEPTH // len(spec.channels)))
+    return k, -(-spec.w // k)
+
+
+def conv_passes(spec: SegmentSpec) -> int:
+    """Passes of the MXU's depth a conv of this block makes an output tile:
+    its taps times the ``_MXU_DEPTH``-deep slices of a tap's contraction."""
+    k, taps = conv_tap_packing(spec)
+    return taps * -(-k * len(spec.channels) // _MXU_DEPTH)
+
+
+def conv_fill(spec: SegmentSpec) -> float:
+    """The share of the MXU's depth that the block's conv fills a pass."""
+    k, _taps = conv_tap_packing(spec)
+    depth = k * len(spec.channels)
+    return depth / (_MXU_DEPTH * -(-depth // _MXU_DEPTH))
+
+
 def _channel_plane(chan: tuple, dpad: jnp.ndarray) -> jnp.ndarray:
     kind = chan[0]
     if kind == "hi":
@@ -322,6 +365,46 @@ def _channel_plane(chan: tuple, dpad: jnp.ndarray) -> jnp.ndarray:
     for lo, hi in ivs:
         acc = acc | ((dpad >= lo) & (dpad <= hi)) if lo != hi else acc | (dpad == lo)
     return acc
+
+
+def _embed_taps(spec: SegmentSpec, data: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Step 1: the padded bytes ``[T, 1+L+W']`` and the channel planes
+    ``[T, span, k·C]`` bf16 the conv reads, each byte beside its k − 1
+    right-hand neighbours (``conv_tap_packing``). The neighbours are stacked
+    as BYTES and the C planes compared once over ``[T, span, k]``: k shifted
+    slices of the planes' stack cost a v5e's compiler a relayout copy a
+    plane (PERF.md §6, PR 47)."""
+    pack, taps = conv_tap_packing(spec)
+    span = data.shape[1] + 2 + pack * (taps - 1)  # positions a packed tap starts at
+    # Front NUL pad (position 0) + right slack so every window is full:
+    # W, up to ``pack * taps`` with the kernel's zero taps.
+    dpad = jnp.pad(data, ((0, 0), (1, pack * taps))).astype(jnp.int32)
+    near = dpad if pack == 1 else jnp.stack([dpad[:, r : r + span] for r in range(pack)], axis=-1)
+    planes = [_channel_plane(c, near) for c in spec.channels]
+    embed = jnp.stack(planes, axis=-1).astype(jnp.bfloat16).reshape(data.shape[0], span, -1)
+    return dpad, embed
+
+
+def _conv_taps(spec: SegmentSpec, embed: jnp.ndarray, kernel_p: jnp.ndarray) -> jnp.ndarray:
+    """Step 2: ``[T, Q, N2]`` bf16 sums of ``_embed_taps``' planes under the
+    ``[W, C, N2]`` kernel, ``pack`` taps a contraction."""
+    pack, taps = conv_tap_packing(spec)
+    if pack > 1:  # zero taps up to pack·taps, then ``pack`` taps a contraction: [J, k·C, N2]
+        kernel_p = jnp.pad(kernel_p, ((0, pack * taps - spec.w), (0, 0), (0, 0)))
+        kernel_p = kernel_p.reshape(taps, pack * len(spec.channels), -1)
+    # bf16 accumulation is exact here (integer partial sums ≤ 2W = 52
+    # ≪ 256) and halves the conv-output HBM traffic — the threshold is
+    # fused into each consumer, so every chain stage reads `out`, not a
+    # materialized bool.
+    return jax.lax.conv_general_dilated(
+        embed,
+        kernel_p,
+        window_strides=(1,),
+        padding="VALID",
+        rhs_dilation=(pack,),
+        dimension_numbers=("NWC", "WIO", "NWC"),
+        preferred_element_type=jnp.bfloat16,
+    )
 
 
 def _in_class(ivs: tuple, dpad: jnp.ndarray) -> jnp.ndarray:
@@ -692,12 +775,8 @@ def match_segment_block(
     w = spec.w
     q = ln + 2  # chain positions: window starts 0 .. L+1
     with jax.named_scope("cko.seg.embed"):
-        # Front NUL pad (position 0) + right slack so every window is full.
-        dpad = jnp.pad(data, ((0, 0), (1, w))).astype(jnp.int32)  # [T, 1+L+W]
-
         # 1. embed: channel planes from comparisons only.
-        planes = [_channel_plane(c, dpad) for c in spec.channels]
-        embed = jnp.stack(planes, axis=-1).astype(jnp.bfloat16)  # [T, 1+L+W, C]
+        dpad, embed = _embed_taps(spec, data)
 
     # --- static chain program (pure Python at trace time) ---
     # Two tiers:
@@ -830,27 +909,22 @@ def match_segment_block(
         col_order = [0]
 
     # 2. conv: all segments, all start positions. out[t, p, n] == 2W ⇔
-    # segment n matches the window starting at padded position p. (An
-    # im2col-matmul formulation was measured 1.6x SLOWER here at XLA
-    # level — the [T·Q, W·C] window materialization's HBM traffic
-    # exceeds the conv's MXU inefficiency. A fused Pallas finals tier
+    # segment n matches the window starting at padded position p, with
+    # ``128 // C`` taps a contraction (module docstring). The other end was
+    # tried first: a full im2col matmul "was measured 1.6x SLOWER here at
+    # XLA level" (commit 88b9050; the record names no shape and no chip):
+    # all W·C channels a position, a [T·Q, W·C] window whose HBM traffic
+    # exceeded the conv's MXU inefficiency. A fused Pallas finals tier
     # that built the windows in VMEM read 11.1–11.6 ms/step against
-    # this conv's 6.9 on a v5e and was removed in PR 30; git history
-    # has ops/segment_pallas.py.)
+    # the plain conv's 6.9 on a v5e and was removed in PR 30; git history
+    # has ops/segment_pallas.py. In the feed's ``32x2048`` matcher on a v5e
+    # this form's block-0 conv reads 8.6 ms against 23.0 with one tap a
+    # contraction, the launch 37.8 against 55.9; space-to-depth (no
+    # dilation, k phase kernels, the output reshaped back) 87.8
+    # (PERF.md §6, PR 47).
     with jax.named_scope("cko.seg.conv"):
         kernel_p = kernel[:, :, np.asarray(col_order)]  # [W, C, N2] tiny gather
-        # bf16 accumulation is exact here (integer partial sums ≤ 2W = 34
-        # ≪ 256) and halves the conv-output HBM traffic — the threshold is
-        # fused into each consumer, so every chain stage reads `out`, not a
-        # materialized bool.
-        out = jax.lax.conv_general_dilated(
-            embed,
-            kernel_p,
-            window_strides=(1,),
-            padding="VALID",
-            dimension_numbers=("NWC", "WIO", "NWC"),
-            preferred_element_type=jnp.bfloat16,
-        )  # [T, Q, N2]
+        out = _conv_taps(spec, embed, kernel_p)  # [T, Q, N2]
         m_all = out >= jnp.bfloat16(2.0 * w)  # equality; >= is safe (2W is the max)
 
     def mslice(a0: int, a1: int) -> jnp.ndarray:

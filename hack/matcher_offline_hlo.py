@@ -49,6 +49,8 @@ def main(argv=None) -> int:
     ap.add_argument("--opcodes", default="", help="scopes to split by opcode, comma-separated")
     ap.add_argument("--bytes-limit", type=int, default=V5E_BYTES_LIMIT,
                     help="the described device's memory, which the conv tier's plan follows")
+    ap.add_argument("--plain-conv", action="store_true",
+                    help="one tap a contraction (ops/segment.py:conv_tap_packing patched to 1), as until PR 47")
     args = ap.parse_args(argv)
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -62,8 +64,11 @@ def main(argv=None) -> int:
     from coraza_kubernetes_operator_tpu.models import waf_model
     from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
     from coraza_kubernetes_operator_tpu.observability import device_scopes
+    from coraza_kubernetes_operator_tpu.ops import segment
     from wafbench.harness import read_rules
 
+    if args.plain_conv:
+        segment.conv_tap_packing = lambda spec: (1, spec.w)
     engine = WafEngine(read_rules(Path(args.rules)))
     one_chip = SingleDeviceSharding(
         topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2").devices[0])

@@ -40,6 +40,18 @@ comparisons and selects). Per kind the static count, and from the capture
 ms a call and operations run a call; ``reduce_window_instructions``
 counts every ``reduce-window`` of the optimized HLO by scope, inside
 fusions too.
+
+With ``--scopes`` every line also carries ``convolutions``: for each
+convolution of ``cko.seg.conv`` (an instruction of the optimized HLO, or
+the fusion that holds it) the block it belongs to, its rows x positions x
+columns, the taps it was traced with beside the block's W and C, ms a call
+from the capture, and its useful flops (2·T·Q·W·C·N2: what the plain conv
+needs, zero taps and all) over that time as a share of
+``wafbench/peaks.json``'s ``bf16_flops_per_s``; ``seg_plan`` beside them
+has the static reading (``conv_passes``, ``conv_fill``:
+``models/waf_model.py:SegTierPlan``). ``--plain-conv`` traces the conv one
+tap a contraction (``ops/segment.py:conv_tap_packing`` patched to 1: the
+program until PR 47), to price the packing against it in one call.
 """
 
 from __future__ import annotations
@@ -47,6 +59,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import statistics
 import sys
 import time
@@ -65,6 +78,7 @@ def main(argv=None) -> int:
     ap.add_argument("--scopes", action="store_true", help="price each shape by device scope")
     ap.add_argument("--scope-calls", type=int, default=8, help="calls in the --scopes capture")
     ap.add_argument("--opcodes", default="", help="scopes to split by opcode, comma-separated")
+    ap.add_argument("--plain-conv", action="store_true", help="one tap a contraction, as until PR 47")
     args = ap.parse_args(argv)
 
     import jax
@@ -73,14 +87,19 @@ def main(argv=None) -> int:
     from coraza_kubernetes_operator_tpu.engine.compile_cache import configure_persistent_cache
     from coraza_kubernetes_operator_tpu.engine.waf import WafEngine
     from coraza_kubernetes_operator_tpu.models.slab import match_slab_shape, match_views
-    from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable
+    from coraza_kubernetes_operator_tpu.models.waf_model import stage_executable, tier_seg_plan
     from coraza_kubernetes_operator_tpu.observability import device_scopes
+    from coraza_kubernetes_operator_tpu.ops import segment
     from wafbench.harness import read_rules
+
+    if args.plain_conv:
+        segment.conv_tap_packing = lambda spec: (1, spec.w)
 
     # Where JAX_COMPILATION_CACHE_DIR holds a cache, its keys carry the scopes' salt:
     # an executable another build cached would come back with that build's names.
     configure_persistent_cache()
     dev = jax.devices()[0]
+    peaks = json.loads((REPO / "wafbench/peaks.json").read_text())["peaks"].get(dev.device_kind, {})
     print(json.dumps({"device": dev.device_kind, "platform": dev.platform,
                       "JAX_COMPILATION_CACHE_DIR": os.environ.get("JAX_COMPILATION_CACHE_DIR")}),
           flush=True)
@@ -154,6 +173,48 @@ def main(argv=None) -> int:
                           for k, (n, sec, ran) in sorted(rows.items(), key=lambda kv: -kv[1][1])}
         return out
 
+    convolution = re.compile(r"=\s+\w+\[([\d,]+)\]\S*\s+convolution\(.*window=\{size=(\d+).*dim_labels=\w+_\w+->(\w+)")
+
+    def convolutions(text: str, events: dict, names: dict, calls: int, plan) -> list[dict]:
+        """``cko.seg.conv``'s convolutions against the chip's bf16 peak."""
+        _entry, comps = device_scopes._parse(text)
+        held, comp = {}, None  # computation or instruction -> (T, Q, N2, taps)
+        for line in text.splitlines():
+            started = device_scopes._COMPUTATION.match(line)
+            if started:
+                comp = started.group(2)
+            found = convolution.search(line)
+            if found:
+                dims = dict(zip(found.group(3), map(int, found.group(1).split(","))))
+                held[comp] = held[device_scopes._INSTRUCTION.match(line).group(2)] = (
+                    dims["b"], dims["0"], dims["f"], int(found.group(2)))
+        convs = {i.name: held.get(i.fused) or held[i.name] for body in comps.values() for i in body
+                 if (i.fused in held or i.opcode == "convolution")
+                 and device_scopes.scope_of(names.get(i.name, "")) == "cko.seg.conv"}
+        seconds = dict.fromkeys(convs, 0.0)
+        for dev_events in events["devices"]:
+            run = sorted(dev_events["ops"], key=lambda e: (e[1], -e[2]))
+            for e, self_ns in zip(run, device_scopes._self_ns(run)):
+                instr = device_scopes.instruction_name(e[0])
+                if instr in seconds:
+                    seconds[instr] += self_ns / 1e9
+        specs = [sb.spec for sb in engine.model.segs]
+        out = []
+        for instr, (t, q, n2, taps) in convs.items():
+            # A tile's conv has its columns; two blocks of as many columns differ by their taps.
+            block = next((i for i, _g0, _g1, c in plan.tiles
+                          if c == n2 and taps in (specs[i].w, segment.conv_tap_packing(specs[i])[1])), None)
+            line = {"instruction": instr, "block": block, "rows": t, "positions": q, "columns": n2,
+                    "taps": taps, "ms_per_call": 1e3 * seconds[instr] / calls}
+            if block is not None:
+                w, c = specs[block].w, len(specs[block].channels)
+                line.update(w=w, c=c, useful_flops=2 * t * q * w * c * n2)
+                if seconds[instr] and peaks:
+                    line["share_of_bf16_peak"] = (line["useful_flops"] * calls / seconds[instr]
+                                                  / peaks["bf16_flops_per_s"])
+            out.append(line)
+        return sorted(out, key=lambda line: -line["ms_per_call"])
+
     def priced(shape: str, compiled, ops) -> dict:
         """One capture of ``--scope-calls`` calls, reduced by scope."""
         t0 = time.perf_counter()
@@ -180,6 +241,11 @@ def main(argv=None) -> int:
         gained = {"device_ops": static, "as_text_s": t1 - t0, "walk_s": walk_s, "text_bytes": len(text)}
         if args.opcodes:
             gained["opcodes"] = by_opcode(text, events, names, reduced["runs"] if reduced else 1)
+        rows, width = map(int, shape.split("x"))
+        plan = tier_seg_plan(engine.model, rows, width)
+        if plan:
+            gained["seg_plan"] = plan.summary()
+            gained["convolutions"] = convolutions(text, events, names, reduced["runs"] if reduced else 1, plan)
         if not reduced:  # the CPU has no device plane
             return dict(gained, capture={"calls": 0, "stop_s": stop_s})
         calls = reduced["runs"]

@@ -22,7 +22,14 @@ tier (cut to the rows of the cell's steady window, the longest first) handed to 
 sidecar's own program, ``mask`` None as ``hack/matcher_shape_probe.py``
 compiles it, so the two share a persistent compile cache) under each of
 ``--budgets`` in turn; every plan's group hits against the last budget's.
-``--budgets device`` is the budget the device's memory gives."""
+``--budgets device`` is the budget the device's memory gives.
+
+PR 47: the conv with its taps packed into the MXU's depth
+(``ops/segment.py:conv_tap_packing``) against one tap a contraction (the
+packing patched to 1): ``packed_against_plain`` on the feed window, direct
+and in one chunk of tiles, and ``--conv plain packed`` on a cell's served
+matcher (each form under each budget, all held to the last form under the
+last budget; every line says its ``conv``)."""
 import argparse
 import base64
 import importlib.util
@@ -92,6 +99,19 @@ def feed_window() -> bool:
                           "reach_gaps": {f: g[1]["reach_gaps"] for f, g in got.items()},
                           "equal": same, "hits": int(got["matmul"][0].sum()),
                           "differing_cells": int((got["latch"][0] != got["matmul"][0]).sum())}), flush=True)
+    packing = segment.conv_tap_packing
+    for name, budget in (("direct", 2**40), ("tiles", 8 * q * n2 - 1)):
+        got = {}
+        for form, fn in (("plain", lambda spec: (1, spec.w)), ("packed", packing)):
+            segment.conv_tap_packing = fn
+            jax.clear_caches()  # match_segment_block's traces do not see the patch
+            got[form] = hits(budget)
+        same = bool((got["plain"][0] == got["packed"][0]).all() and (got["packed"][0] == direct).all())
+        ok &= same and got["packed"][1]["conv_passes"] < got["plain"][1]["conv_passes"]
+        print(json.dumps({"case": "packed_against_plain", "plan": got["packed"][1],
+                          "conv_passes": {f: g[1]["conv_passes"] for f, g in got.items()},
+                          "equal": same, "hits": int(got["packed"][0].sum()),
+                          "differing_cells": int((got["plain"][0] != got["packed"][0]).sum())}), flush=True)
     # ... and the two forms alone at the feed's real sizes: rows of class runs of every length, a
     # tile's 300 columns, the positions of a 512 and of a 2,048 wide window.
     rng = np.random.default_rng(44)
@@ -117,7 +137,7 @@ def _budget(word: str) -> int | None:
     return int(base) ** int(exp) if exp else int(base)
 
 
-def served_cells(cells: list[str], budgets: list[int | None]) -> bool:
+def served_cells(cells: list[str], budgets: list[int | None], convs: list[str]) -> bool:
     from concurrent.futures import ThreadPoolExecutor
 
     from coraza_kubernetes_operator_tpu.engine.compile_cache import configure_persistent_cache
@@ -128,7 +148,9 @@ def served_cells(cells: list[str], budgets: list[int | None]) -> bool:
 
     configure_persistent_cache()
     engines: dict[str, tuple] = {}
-    lowered = []  # (cell, budget, plan, operands, the lowering): traced one by one, under its budget
+    packing = {"packed": segment.conv_tap_packing, "plain": lambda spec: (1, spec.w)}
+    forms = [(conv, budget) for conv in convs for budget in budgets]
+    lowered = []  # (cell, form, plan, operands, the lowering): traced one by one, under its form
     for cell in cells:
         config, plan = cell.split(":")
         cdir = REPO / "wafbench" / "configs" / config
@@ -161,23 +183,25 @@ def served_cells(cells: list[str], budgets: list[int | None]) -> bool:
             lease.release()
         said = {"cell": cell, "shape": [rows, width], "requests": len(reqs),
                 "rows_with_bytes": int((tier[1][kept] > 0).sum())}
-        for budget in budgets:
-            waf_model._SEG_CHUNK_ELEMS = budget
-            jax.clear_caches()  # a trace does not see the budget
+        for conv, budget in forms:
+            waf_model._SEG_CHUNK_ELEMS, segment.conv_tap_packing = budget, packing[conv]
+            jax.clear_caches()  # a trace sees neither
             lowering = waf_model.stage_executable("match", f"{rows}x{width}").lower(model, slab, mask=None)
-            lowered.append((said, budget, waf_model.tier_seg_plan(engine.model, rows, width).summary(),
+            lowered.append((said, (conv, budget), waf_model.tier_seg_plan(engine.model, rows, width).summary(),
                             (model, slab), lowering))
-    with ThreadPoolExecutor(max_workers=max(1, len(lowered))) as workers:  # XLA releases the lock
+    # XLA releases the lock; two at a time: six compiles at once (three cells, two forms) met a one-chip
+    # machine's 40 GiB (PR 47)
+    with ThreadPoolExecutor(max_workers=2) as workers:
         compiled = list(workers.map(lambda job: job[4].compile(), lowered))
     ok = True
     # [U, PB] uint8, a bit a group (``np.packbits``): compared bit for bit, each plan of a
     # cell against the cell's last
     got = [np.unpackbits(np.asarray(run(*job[3])), axis=1) for job, run in zip(lowered, compiled)]
-    for k, ((said, budget, plan, _operands, _lowering), out) in enumerate(zip(lowered, got)):
-        want = got[k - k % len(budgets) + len(budgets) - 1]
+    for k, ((said, (conv, budget), plan, _operands, _lowering), out) in enumerate(zip(lowered, got)):
+        want = got[k - k % len(forms) + len(forms) - 1]
         differing = int((out != want).sum())
         ok &= differing == 0
-        print(json.dumps({**said, "budget": budget if budget is not None else "device", "plan": plan,
+        print(json.dumps({**said, "conv": conv, "budget": budget if budget is not None else "device", "plan": plan,
                           "hits": int(out.sum()), "cells": int(out.size),
                           "differing_cells": differing}), flush=True)
     return ok
@@ -188,12 +212,14 @@ def main(argv=None) -> int:
     ap.add_argument("--cell", action="append", default=[], help="<config>:<plan> of wafbench/configs")
     ap.add_argument("--budgets", nargs="+", type=_budget, default=[2**27, None],
                     help="conv-tier budgets to compare, the last the one the others are held to")
+    ap.add_argument("--conv", nargs="+", choices=["plain", "packed"], default=["packed"],
+                    help="the conv's forms to compare (--cell), the last the one the others are held to")
     args = ap.parse_args(argv)
     dev = jax.devices()[0]
     print(json.dumps({"device": dev.device_kind, "platform": dev.platform,
                       "memory_bytes_limit": (dev.memory_stats() or {}).get("bytes_limit"),
                       "scan_past_one_chunk": waf_model._scan_past_one_chunk()}), flush=True)
-    ok = served_cells(args.cell, args.budgets) if args.cell else feed_window()
+    ok = served_cells(args.cell, args.budgets, args.conv) if args.cell else feed_window()
     print(json.dumps({"ok": ok}))
     return 0 if ok else 1
 

@@ -79,6 +79,29 @@ def test_sharded_matches_single(shape, class_gaps, monkeypatch, request):
         assert g.rule_id == e.rule_id, (i, REQUESTS[i].uri)
 
 
+def test_sharded_packed_taps_give_the_plain_convs_verdicts(monkeypatch, request):
+    """The rule-sharded path traces the conv tier under ``shard_map`` with
+    ``128 // C`` taps a contraction (``ops/segment.py:conv_tap_packing``):
+    its verdicts are the same path's with one tap a contraction (the
+    packing patched to 1), and both hold the attacks."""
+    from coraza_kubernetes_operator_tpu.ops import segment
+
+    if len(jax.devices()) < 2:
+        pytest.skip("not enough devices")
+    sharded = ShardedWafEngine(compiled=compile_rules(RULES), mesh=make_mesh(2, 1))
+    assert sharded.model.segs and all(segment.conv_tap_packing(b.spec)[0] > 1 for b in sharded.model.segs)
+    packed = sharded.evaluate(REQUESTS)
+
+    monkeypatch.setattr(segment, "conv_tap_packing", lambda spec: (1, spec.w))
+    jax.clear_caches()  # match_segment_block's traces do not see the patch
+    request.addfinalizer(jax.clear_caches)
+    plain = sharded.evaluate(REQUESTS)
+
+    assert [(v.interrupted, v.status, v.rule_id) for v in packed] == [
+        (v.interrupted, v.status, v.rule_id) for v in plain]
+    assert [v.rule_id for v in packed[1:6]] == [942100, 941100, 3001, 44, 45]
+
+
 def test_mesh_device_requirements():
     with pytest.raises(ValueError):
         make_mesh(1000, 1000)

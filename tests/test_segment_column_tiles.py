@@ -258,7 +258,9 @@ def test_the_plan_follows_from_shapes(engine, monkeypatch):
     assert plan(8 * q * n2).summary() == {
         "path": "rows", "row_chunks": 4, "rows_per_chunk": 8, "column_tiles": len(keep),
         "columns_per_tile_max": max(conv_n2_cols(s) for s in specs), "columns": n2,
-        "budget_elements": 8 * q * n2, "reach_gaps": 0}
+        "budget_elements": 8 * q * n2, "reach_gaps": 0,
+        "conv_passes": sum(seg_mod.conv_passes(s) for s in specs),
+        "conv_fill": round(sum(conv_n2_cols(s) * seg_mod.conv_fill(s) for s in specs) / n2, 4)}
     # one element short of eight rows of everything: tiles, all rows in one chunk,
     # down to the budget that holds all rows of the widest group and no more
     for budget in (8 * q * n2 - 1, t * q * widest):
@@ -379,7 +381,8 @@ def test_what_is_counted_says_which_plan_ran(engine, feed, tier, monkeypatch):
             if e["name"].startswith("cko_match_"):
                 assert set(e["seg_plan"]) == {"path", "row_chunks", "rows_per_chunk",
                                               "column_tiles", "columns_per_tile_max", "columns",
-                                              "budget_elements", "reach_gaps"}
+                                              "budget_elements", "reach_gaps", "conv_passes",
+                                              "conv_fill"}
                 assert e["seg_plan"]["path"] == "tiles" and e["seg_plan"]["columns"] == n2
                 assert e["seg_plan"]["budget_elements"] == 32 * 66 * (n2 // 6)  # what it was cut to
                 assert set(e["device_ops"]["by_scope"]) <= set(device_scopes.SCOPES)
@@ -508,3 +511,60 @@ def test_the_feeds_window_with_every_gap_on_the_mxu_gives_the_latchs_hits_and_ve
     assert (reach[0] == latch[0]).all() and (reach[1] == latch[1]).all()
     assert (latch[0] == latch[1]).all() and latch[0].any()
     assert reach[2] == latch[2] and any(status == 403 for status, _rule in reach[2])
+
+
+# -- the taps that fill the MXU's depth (ISSUE 47) ---------------------------------------------
+
+
+def test_conv_passes_and_fill_are_the_hand_count_direct_and_tiled(monkeypatch):
+    """``seg_plan.conv_passes`` / ``conv_fill``: every tile's conv makes its
+    block's taps (W a block with one tap a contraction, ``ceil(W / (128 //
+    C))`` packed), each one 128-deep slice while k·C fits the depth, and
+    fills k·C of the 128, weighted by the tiles' columns; the long scan
+    makes no conv."""
+    spec = _wide_spec(80)
+    w, c, n2, width = spec.w, len(spec.channels), conv_n2_cols(spec), 62
+    k = 128 // c
+    assert k > 1 and seg_mod.conv_tap_packing(spec) == (k, -(-w // k))
+
+    def plan(budget, t=8, long_ok=False):
+        monkeypatch.setattr(waf_model, "_SEG_CHUNK_ELEMS", budget)
+        return waf_model.plan_segment_tier([spec], (0,), t, width, long_ok=long_ok)
+
+    for budget, tiles in ((2**40, 1), (8 * 64 * (n2 // 2 + 4), 2), (8 * 64 * (n2 // 3 + 4), 3)):
+        said = plan(budget).summary()
+        assert said["column_tiles"] == tiles
+        assert said["conv_passes"] == tiles * -(-w // k) and said["conv_fill"] == round(k * c / 128, 4)
+    long = plan(1, t=1, long_ok=True)
+    assert long.path == "long" and (long.conv_passes, long.conv_fill) == (0, 0.0)
+    monkeypatch.setattr(seg_mod, "conv_tap_packing", lambda s: (1, s.w))  # one tap a contraction
+    said = plan(8 * 64 * (n2 // 2 + 4)).summary()
+    assert said["conv_passes"] == 2 * w and said["conv_fill"] == round(c / 128, 4)
+
+
+def test_crs_lites_taps_fill_the_depth_at_every_served_shape(monkeypatch):
+    """What ISSUE 47 read from the model by hand: crs-lite's eight blocks
+    make 170 passes of the MXU's depth an output tile with one tap a
+    contraction, at 22 to 42 of its 128 rows, and 47 with the taps packed,
+    at 104 to 128; a plan changes neither, rows or tiles."""
+    from coraza_kubernetes_operator_tpu.compiler.ruleset import compile_rules
+    from coraza_kubernetes_operator_tpu.models.waf_model import build_model
+    from wafbench.harness import read_rules
+
+    text = read_rules(REPO / "wafbench" / "configs" / "crs-lite-pl2" / "rules")
+    specs = [s.spec for s in build_model(compile_rules(text)).segs]
+    keep = tuple(range(len(specs)))
+    assert sorted((s.w, len(s.channels)) for s in specs) == [
+        (11, 16), (15, 22), (19, 27), (24, 26), (24, 42), (25, 27), (26, 27), (26, 36)]
+    assert [seg_mod.conv_tap_packing(s) for s in sorted(specs, key=lambda s: (s.w, len(s.channels)))] == [
+        (8, 2), (5, 3), (4, 5), (4, 6), (3, 8), (4, 7), (4, 7), (3, 9)]
+
+    def said(rows, width):
+        return waf_model.plan_segment_tier(specs, keep, rows, width, True, False).summary()
+
+    for rows, width in ((32, 512), (512, 512), (32, 2048), (1, 64)):
+        got = said(rows, width)
+        assert got["path"] in ("direct", "rows") and (got["conv_passes"], got["conv_fill"]) == (47, 0.8715)
+    monkeypatch.setattr(seg_mod, "conv_tap_packing", lambda s: (1, s.w))
+    got = said(32, 512)
+    assert (got["conv_passes"], got["conv_fill"]) == (170, 0.2617)

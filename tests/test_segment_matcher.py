@@ -460,6 +460,103 @@ def test_a_structure_takes_the_matmul_from_the_threshold_on(n_rules, lo, monkeyp
     assert reach[0, 6] and not reach[1, 6] and reach[8, 3] and reach[5, 9] and not reach[7, 9]
 
 
+# -- the conv's taps packed into the MXU's depth (ISSUE 47) ---------------------------------
+
+# One more embed channel each: a class that is no product of nibble sets.
+_CLASS_FILL = [rf"q[{chr(a)}-{chr(b)}]" for a in range(ord("g"), ord("p")) for b in range(ord("p"), ord("x"))]
+_W26 = [r"\babcdefghijklmnopqrstuvwx\b", r"union\s+select", r"^/admin", r"\.php$"]
+# id, base patterns, a match of the first, C channels, (k, taps), width (Q = width + 2), one row alone
+PACK_CASES = [
+    ("w26_c36_k3_w_no_multiple", _W26, b"abcdefghijklmnopqrstuvwx", 36, (3, 9), 97, False),
+    ("w26_c36_k3_q_no_multiple", _W26, b"abcdefghijklmnopqrstuvwx", 36, (3, 9), 98, False),
+    ("w24_c26_k4", [r"abcdefghijklmnopqrstuvwx", r"^pq", r"rs$"], b"abcdefghijklmnopqrstuvwx", 26, (4, 6), 100, False),
+    ("w11_c16_k8_two_taps", [r"abcdefghijk", r"cab$", r"^bad"], b"abcdefghijk", 16, (8, 2), 64, False),
+    ("c65_k1_the_plain_program", [r"hello\s+world", r"^he", r"ld$"], b"hello \tworld", 65, (1, 6), 64, False),
+    ("one_row", _W26, b"abcdefghijklmnopqrstuvwx", 36, (3, 9), 40, True),
+    ("w8_c14_k_no_more_than_w", [r"evilmonk", r"^bad"], b"evilmonk", 14, (8, 1), 33, False),
+]
+
+
+def _block_of_channels(base: list[str], channels: int):
+    """``base`` and as many one-class fillers as bring the block's embed to
+    ``channels`` planes: (patterns, block)."""
+    pats, fill = list(base), iter(_CLASS_FILL)
+    while True:
+        block = build_segment_block([plan_segments(parse_regex(p)) for p in pats])
+        if len(block.spec.channels) >= channels:
+            return pats, block
+        pats.append(next(fill))
+
+
+@pytest.mark.parametrize("case", PACK_CASES, ids=[c[0] for c in PACK_CASES])
+def test_packed_taps_give_the_plain_convs_hits_bit_for_bit(case, monkeypatch):
+    """``128 // C`` taps of a block's kernel ride one contraction
+    (``conv_tap_packing``): the group hits are the plain conv's (one tap a
+    contraction: the packing patched to 1) bit for bit and Python re's, on
+    rows whose matches start on the first byte and end on the last of a row
+    as wide as the tier, with W and Q multiples of k and not; the traced
+    conv is ``ceil(W / k)`` taps of ``k·C`` channels dilated by k (one tap
+    of ``W·C`` where the kernel has fewer taps than fit); and a block of
+    more than 64 channels traces the plain program."""
+    import jax
+
+    from coraza_kubernetes_operator_tpu.ops import segment as seg_mod
+
+    _id, base, first, channels, (k, taps), width, one_row = case
+    pats, block = _block_of_channels(base, channels)
+    spec = block.spec
+    assert len(spec.channels) == channels and seg_mod.conv_tap_packing(spec) == (k, taps)
+    assert taps == -(-spec.w // k) and k == max(1, min(spec.w, 128 // channels))
+    assert seg_mod.conv_passes(spec) == taps and seg_mod.conv_fill(spec) == k * channels / 128
+
+    rng = random.Random(width)
+    words = [w.encode() for w in ("abcdefghijklmnopqrstuvwx", "abcdefghijk", "union", " select", "/admin",
+                                  "a.php", "pq", "rs", "cab", "bad", "hello ", "\tworld", "he", "ld", "qk", "evilmonk",
+                                  "qp", "qs", " ", "-", "x")]
+    noise = bytes(rng.choice(b" -.;=") for _ in range(width))
+    rows = [first + noise[: width - len(first) - 3],  # starts on the first byte
+            noise[: width - len(first)] + first,  # ends on the last byte of a row as wide as the tier
+            noise[: width - len(first) - 1] + first + b" ",  # ... and one byte short of it
+            b"/admin" + noise[:20] + b"a.php", b"x/admin a.phpx", b"pq" + noise[:9] + b"rs", b"bad cab", b"he ld",
+            first[:-1], first[1:], b""]
+    rows += [b"".join(rng.choice(words) for _ in range(rng.randrange(12)))[:width] for _ in range(24)]
+    if one_row:
+        rows = rows[1:2]
+    data = np.zeros((len(rows), width), dtype=np.uint8)
+    for i, r in enumerate(rows):
+        data[i, : len(r)] = np.frombuffer(r, dtype=np.uint8)
+    lengths = np.array([len(r) for r in rows], dtype=np.int32)
+
+    def traced():
+        fn = jax.jit(lambda kern, d, ln: match_segment_block.__wrapped__(kern, spec, d, ln))
+        closed = jax.make_jaxpr(fn)(block.kernel, data, lengths)
+        (conv,) = [e for jaxpr, _ in _jaxprs(closed) for e in jaxpr.eqns
+                   if e.primitive.name == "conv_general_dilated"]
+        return np.asarray(fn(block.kernel, data, lengths)), conv, str(closed)
+
+    packed, conv, packed_program = traced()
+    monkeypatch.setattr(seg_mod, "conv_tap_packing", lambda s: (1, s.w))
+    plain, plain_conv, plain_program = traced()
+
+    n2 = seg_mod.conv_n2_cols(spec)
+    q = width + 2
+    assert plain_conv.params["rhs_dilation"] == (1,)
+    assert [tuple(v.aval.shape) for v in plain_conv.invars] == [(len(rows), q + spec.w - 1, channels),
+                                                                (spec.w, channels, n2)]
+    assert conv.params["rhs_dilation"] == (k,)
+    assert [tuple(v.aval.shape) for v in conv.invars] == [(len(rows), q + k * (taps - 1), k * channels),
+                                                          (taps, k * channels, n2)]
+    assert tuple(conv.outvars[0].aval.shape) == tuple(plain_conv.outvars[0].aval.shape) == (len(rows), q, n2)
+    assert (packed_program == plain_program) == (k == 1)
+
+    np.testing.assert_array_equal(packed, plain)
+    for gi, pat in enumerate(pats):
+        oracle = re.compile(pat.encode())
+        for i, r in enumerate(rows):
+            assert bool(packed[i, gi]) == (oracle.search(r) is not None), (pat, r)
+    assert packed[0, 0] and (one_row or (packed[1, 0] and packed[2, 0] and not packed[8:11, 0].any()))
+
+
 def test_conv_n2_cols_matches_trace_allocation():
     """conv_n2_cols must equal len(col_order) as match_segment_block
     builds it — the HBM budget in segment_tier_hits depends on it."""
