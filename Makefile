@@ -93,7 +93,7 @@ extproc.smoke:  ## Envoy e2e gate: ftw corpus through a real Envoy -> ext_proc, 
 	$(PYTHON) hack/extproc_smoke.py
 
 .PHONY: automata.smoke
-automata.smoke:  ## Two-level automata gate: ftw+crs-lite replay on vs off, byte-identical verdicts, dfa-hot + prefiltered tiers exercised, Pallas interpret parity on CPU.
+automata.smoke:  ## Two-level automata gate: ftw+crs-lite replay on vs off, byte-identical verdicts, dfa-hot + prefiltered tiers exercised in the flat bins alone, the bins' Pallas interpret parity on CPU.
 	$(PYTHON) hack/automata_smoke.py
 
 .PHONY: metrics.lint

@@ -677,7 +677,7 @@ def main(argv: list[str] | None = None) -> int:
             "rules_skipped": s["cko_rules_skipped_total"],
             "rules_approximated": s["cko_rules_approximated_total"],
             "automata": {k: s["automata"].get(k) for k in (
-                "enabled", "tiers", "gather_banks", "pre_banks",
+                "enabled", "tiers", "dfa_hot_blocks", "prefilter_blocks",
                 "flat_bins", "flat_slots", "flat_groups", "per_bank_kernels")},
             "dfa_states": [s["compile_cache"]["dfa_states_pre_min"],
                            s["compile_cache"]["dfa_states_post_min"]],

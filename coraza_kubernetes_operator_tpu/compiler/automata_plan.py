@@ -3,8 +3,8 @@
 One planner, consumed from three places so they can never disagree:
 
 - ``models/waf_model.build_model`` routes groups into segment blocks,
-  DFA hot-tier gather banks, prefiltered banks, or exact NFA banks
-  according to the plan it's handed;
+  dfa-hot blocks (cut by ``cut_hot_blocks`` below), prefilter blocks,
+  or exact nfa blocks according to the plan it's handed;
 - ``engine/waf.WafEngine`` computes the plan (env knobs below), passes
   it to ``build_model``, and keeps it for prefilter confirmation and
   stats;
@@ -16,14 +16,15 @@ One planner, consumed from three places so they can never disagree:
 Tier kinds per rule group:
 
 - ``segment``     — conv/segment plan exists (cheapest path, unchanged);
-- ``dfa-hot``     — small exact minimized DFA, evaluated through the
-                    byte-class-packed gather banks (``ops/dfa_gather``);
+- ``dfa-hot``     — small exact minimized DFA: slots of a fused flat
+                    bin (``ops/dfa_flat``), in blocks of its own;
 - ``prefiltered`` — expensive group fronted by a sound over-approximate
                     automaton (``re_approx``); device clears the
                     no-match case, positive rows are confirmed exactly
                     on the host so verdicts never change;
-- ``nfa``         — everything else: the existing vectorized-NFA bank
-                    path.
+- ``nfa``         — everything else: an exact DFA in a state-bucket
+                    block (a flat bin's slots too, unless one DFA of
+                    the block is past the bins' VMEM plan).
 
 Env knobs (CKO_* convention, all read at plan time):
 
@@ -33,7 +34,7 @@ Env knobs (CKO_* convention, all read at plan time):
 - ``CKO_DFA_HOT=0``              — disable only the hot tier;
 - ``CKO_PREFILTER=0``            — disable only the prefilter;
 - ``CKO_DFA_HOT_MAX_STATES``     — hot-tier ceiling (default 64: packed
-  transition values stay int8 so the gather kernel rides the int8 MXU);
+  transition values ``next + S*emit`` stay below 128, one int8);
 - ``CKO_PREFILTER_MIN_STATES``   — minimum exact-state count before a
   group is worth prefiltering (default 129 = just past the dense-table
   ceiling, i.e. exactly the groups on the serializing scan path);
@@ -46,6 +47,8 @@ from __future__ import annotations
 import os
 from dataclasses import dataclass, field
 
+import numpy as np
+
 from .re_approx import DEFAULT_WIDTH, approx_dfa
 from .re_dfa import DFA
 from .segments import plan_segments
@@ -53,8 +56,7 @@ from .segments import plan_segments
 KINDS = ("segment", "dfa-hot", "prefiltered", "nfa")
 
 # Hot-tier default ceiling: 2*S-1 <= 127 keeps packed next|emit values
-# int8 (ops/dfa.py _dense_dtype), so the gather kernel's two matmuls run
-# on the int8 MXU path.
+# int8 (ops/dfa.py _dense_dtype).
 DEFAULT_HOT_MAX_STATES = 64
 
 # Past the dense-table ceiling (ops/dfa.py _DENSE_MAX_STATES == 128) a
@@ -220,3 +222,66 @@ def plan_automata(
                 )
             )
     return plan
+
+
+# The two caps ``cut_hot_blocks`` cuts by. They were the limits of a
+# kernel that scanned one dfa-hot block alone (a joint byte-class table
+# resident in VMEM; deleted in PR 48: the flat bins scan every dense
+# block) and are kept as plain arithmetic FOR THE LAYOUT'S SAKE: where a
+# block ends decides ``block_kinds``, the kind-partition masks and the
+# row partitions of ``tier_tensors``, and wafbench's frozen plans rest
+# on them. Merging the hot blocks into the state buckets is a layout
+# change of its own (ROADMAP Queue 3).
+_HOT_MAX_JOINT_CLASSES = 120
+_HOT_BYTES_CAP = 11 * 2**20
+_HOT_ROW_BYTES = 512
+_LANE = 128
+
+
+def _hot_block_bytes(s: int, g: int, c: int) -> int:
+    """The byte estimate of a block of ``g`` DFAs padded to ``s`` states
+    over ``c`` joint byte classes, rows of ``_HOT_ROW_BYTES`` bytes."""
+    itemsize = 1 if 2 * s - 1 <= 127 else 4  # ops/dfa.py:_dense_dtype
+    gp = -(-g // _LANE) * _LANE
+    cp = -(-c // _LANE) * _LANE
+    tables = 256 * cp * itemsize + cp * s * gp * itemsize
+    work = _LANE * s * gp * 4 * 2 + _LANE * cp * 4
+    rows = _HOT_ROW_BYTES * _LANE * 4 * 2
+    return tables + work + rows
+
+
+def cut_hot_blocks(dfas: list[DFA]) -> list[list[int]]:
+    """Cut one (pipeline, state bucket) population of dfa-hot DFAs into
+    blocks: index lists into ``dfas``, each one maskable block of the
+    model. Greedy first fit by state count under ``_HOT_MAX_JOINT_CLASSES``
+    and ``_HOT_BYTES_CAP``."""
+    order = sorted(range(len(dfas)), key=lambda i: (dfas[i].n_states, i))
+    blocks: list[list[int]] = []
+    # Per block its joint byte classes so far ([256] ids) and widest DFA:
+    # a candidate's joint class count is the distinct (block class, own
+    # class) pairs over the 256 bytes, what ``re_dfa.joint_class_count``
+    # of the whole block would say, without restacking the block per
+    # candidate (that was 56 s of a 5,000-rule feed's install, PR 37).
+    joint: list[np.ndarray] = []
+    widest: list[int] = []
+    for idx in order:
+        d = dfas[idx]
+        own = d.classmap.astype(np.int64)
+        for k, block in enumerate(blocks):
+            pairs, inv = np.unique(joint[k] * 256 + own, return_inverse=True)
+            c = int(pairs.shape[0])
+            s = max(widest[k], d.n_states)
+            if c > _HOT_MAX_JOINT_CLASSES or _hot_block_bytes(s, len(block) + 1, c) > _HOT_BYTES_CAP:
+                continue
+            block.append(idx)
+            joint[k], widest[k] = inv.reshape(-1).astype(np.int64), s
+            break
+        else:
+            blocks.append([idx])
+            joint.append(np.unique(own, return_inverse=True)[1].reshape(-1).astype(np.int64))
+            widest.append(d.n_states)
+    # Deterministic model layout: blocks ordered by first member.
+    for block in blocks:
+        block.sort()
+    blocks.sort(key=lambda b: b[0])
+    return blocks

@@ -462,38 +462,11 @@ def literal_dfa(
     return compile_nfa_dfa(nfa, ast=ast)
 
 
-def joint_classmap(dfas: list[DFA]) -> tuple[np.ndarray, list[np.ndarray]]:
-    """Joint byte-class partition across a bank of DFAs.
-
-    Two bytes share a joint class iff every member DFA maps them to the
-    same per-DFA class — the coarsest common refinement of the members'
-    classmaps. Returns ``(classmap, remaps)``: ``classmap[256]`` int32
-    with classes numbered by first byte occurrence (deterministic for
-    the compile cache), and per member a ``remaps[i][joint_class] →
-    member class`` vector so packed transition tables can be re-indexed
-    by joint class. The gather hot tier keys its dense tables by joint
-    class: table height drops from 256 to C (typically ≲64 for banks of
-    similar CRS patterns), shrinking both VMEM residency and the
-    per-step matmul by 256/C.
-    """
-    if not dfas:
-        return np.zeros(256, dtype=np.int32), []
-    stacked = np.stack([d.classmap for d in dfas], axis=1)  # [256, N]
-    _, inv = np.unique(stacked, axis=0, return_inverse=True)
-    n_cls = int(inv.max()) + 1
-    uniq, first = np.unique(inv, return_index=True)
-    order = np.argsort(first, kind="stable")
-    rank = np.empty(n_cls, dtype=np.int64)
-    rank[uniq[order]] = np.arange(n_cls)
-    classmap = rank[inv].astype(np.int32)
-    reps = first[order]  # representative byte per joint class
-    remaps = [d.classmap[reps].astype(np.int32) for d in dfas]
-    return classmap, remaps
-
-
 def joint_class_count(dfas: list[DFA]) -> int:
-    """Number of joint byte classes ``joint_classmap`` would produce —
-    cheap enough for greedy bank packing to call per candidate."""
+    """Number of joint byte classes of ``dfas``: two bytes share a joint
+    class iff every member maps them to the same class of its own (the
+    coarsest common refinement of the members' classmaps). The reference
+    the tests hold ``automata_plan.cut_hot_blocks``'s class cap to."""
     if not dfas:
         return 0
     stacked = np.stack([d.classmap for d in dfas], axis=1)

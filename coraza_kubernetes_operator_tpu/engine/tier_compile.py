@@ -73,7 +73,7 @@ class TierCompiler:
         # label -> free-form metadata annotated at tier-selection time
         # (engine startup stamps the automata composition here so stats
         # can say WHAT each compiled stage contains — e.g. how
-        # many dfa-hot gather banks rode into the matcher trace).
+        # many dfa-hot blocks rode into the matcher trace).
         self.meta: dict[str, dict] = {}
 
     def annotate(self, label: str, **meta) -> None:

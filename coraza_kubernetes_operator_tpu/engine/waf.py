@@ -544,12 +544,14 @@ class WafEngine:
         # disables).
         from .value_cache import ValueHitCache
 
-        g_total = (
-            sum(s.n_groups for s in self.model.segs)
-            + sum(b.n_groups for b in self.model.banks)
-            + sum(b.n_groups for b in self.model.gather_banks)
-            + sum(b.n_groups for b in self.model.pre_banks)
+        # Per matcher block (segs, then the dense blocks: match_tier's
+        # column order) its group count: the hit columns in all, and what
+        # _host_tier_hits zeroes for a mask-off block.
+        self._block_group_counts = tuple(
+            [s.n_groups for s in self.model.segs]
+            + [b.groups for b in self.model.dense_blocks]
         )
+        g_total = sum(self._block_group_counts)
         cache_mb = int(_os.environ.get("CKO_VALUE_CACHE_MB", "256"))
         self.value_cache = (
             ValueHitCache((max(1, g_total) + 7) // 8, cache_mb * 2**20)
@@ -577,21 +579,13 @@ class WafEngine:
             "long_scan_launches": 0, "rows": 0, "rows_padded": 0,
         }
         self._bodies = np.zeros(len(BODY_COUNTERS), dtype=np.int64)
-        # Host-tier-path helpers: _dev_col_of[orig_gid] = device hit
-        # column (inverse of model.group_order), and per matcher block
-        # (segs then banks — match_tier's column order) its group count,
-        # for mask-off zeroing in _host_tier_hits.
+        # Host-tier-path helper: _dev_col_of[orig_gid] = device hit
+        # column (inverse of model.group_order).
         order = self.model.group_order
         col_of = np.zeros(max(1, len(order)), dtype=np.int64)
         for col, gid in enumerate(order):
             col_of[gid] = col
         self._dev_col_of = col_of
-        self._block_group_counts = tuple(
-            [s.n_groups for s in self.model.segs]
-            + [b.n_groups for b in self.model.banks]
-            + [b.n_groups for b in self.model.gather_banks]
-            + [b.n_groups for b in self.model.pre_banks]
-        )
         # Prefilter confirmation counters (metrics/stats): hits = device
         # prefilter positives seen, confirms = positives the exact DFA
         # upheld, false_positives = positives it cleared. Guarded by a
@@ -630,8 +624,7 @@ class WafEngine:
             dfa_hot_groups=_counts["dfa-hot"],
             prefiltered_groups=_counts["prefiltered"],
             nfa_groups=_counts["nfa"],
-            gather_banks=len(self.model.gather_banks),
-            pre_banks=len(self.model.pre_banks),
+            **self._dense_block_counts(),
         )
         # Host fallback evaluator (degraded-mode serving): built lazily on
         # first use — pure NumPy over the same compiled tables, so it can
@@ -1484,12 +1477,21 @@ class WafEngine:
             ok[j] = self.compiled.groups[gid].dfa.search(val)
         return ok
 
+    def _dense_block_counts(self) -> dict:
+        """The plan's dfa-hot and prefilter blocks of the model, counted."""
+        kinds = [b.kind for b in self.model.dense_blocks]
+        return {
+            "dfa_hot_blocks": kinds.count("dfa-hot"),
+            "prefilter_blocks": kinds.count("prefilter"),
+        }
+
     def automata_summary(self) -> dict:
         """Automata-tier composition + prefilter counters for stats
         and metrics: which groups run where (the plan's verdict),
-        how many device banks each tier produced, where the dense-DFA
-        blocks are scanned (fused flat bins, or one kernel per bank for
-        the blocks no bin covers), the size of the model they serve
+        how many dense blocks the dfa-hot and prefilter tiers produced,
+        where the dense-DFA blocks are scanned (fused flat bins, or the
+        plain bank scan, ``per_bank_kernels``, for a block no bin
+        covers), the size of the model they serve
         (compiled rules; the conv tier's output columns, summed over
         its blocks; the runs longer than one conv piece that were split
         into chained pieces, and the groups that hold one; the groups the
@@ -1511,15 +1513,11 @@ class WafEngine:
             "segment_splits": sum(t.splits for t in plan.tiers),
             "segment_split_groups": sum(1 for t in plan.tiers if t.splits),
             "segment_long_groups": sum(b.n_groups for b in model.long_banks),
-            "gather_banks": len(model.gather_banks),
-            "pre_banks": len(model.pre_banks),
+            **self._dense_block_counts(),
             "flat_bins": len(model.flat_banks),
             "flat_slots": sum(fb.n_slots for fb in model.flat_banks),
             "flat_groups": sum(fb.n_groups for fb in model.flat_banks),
-            "per_bank_kernels": len(model.banks)
-            + len(model.gather_banks)
-            + len(model.pre_banks)
-            - len(model.flat_covered),
+            "per_bank_kernels": len(model.banks),
             "prefilter": pstats,
         }
 

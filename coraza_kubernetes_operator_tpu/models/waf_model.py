@@ -39,14 +39,10 @@ from ..compiler.ruleset import (
     LINK_NUMERIC,
     LINK_STRING,
 )
+from ..compiler.automata_plan import cut_hot_blocks
 from ..compiler.segments import plan_segments
-from ..ops.dfa import DFABank, stack_dfas
-from ..ops.dfa_gather import (
-    GatherBank,
-    plan_gather_bins,
-    scan_gather_bank,
-    stack_gather_bank,
-)
+from ..ops.dfa import DFABank, scan_dfa_bank, stack_dfas
+from ..ops.dfa_flat import build_flat_bank, plan_flat_bins, scan_flat_bank
 from ..ops.segment import SegmentBlock, build_segment_block, match_segment_block
 from ..ops.transforms import apply_device_pipeline
 from .slab import unpack_match_slab, unpack_post_slab
@@ -138,11 +134,33 @@ def _state_bucket(n_states: int) -> int:
 _STATE_BUCKETS = (32, 256, 2048, 16384, 65536)
 
 
+@dataclass(frozen=True)
+class DenseBlock:
+    """One dense-DFA block of the model, a static record: the pipeline
+    its DFAs read and how many they are (what a trace needs: a skipped
+    block's zero columns, an uncovered block's buffer), and, host-side
+    only, its kind (``nfa``: an exact state bucket; ``dfa-hot``;
+    ``prefilter``: over-approximations the engine confirms on the host)
+    and the state count of its widest DFA (``block_cost``). The
+    host-side two stay out of equality and hash, and so out of the
+    executable cache's key (``WafModel.tree_flatten``'s aux): no traced
+    code reads them."""
+
+    pipeline: int
+    groups: int
+    kind: str = field(default="", compare=False)
+    states: int = field(default=0, compare=False)
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class WafModel:
     """Pytree of device arrays + static metadata (hashable aux)."""
 
+    # The stacked DFAs of the dense blocks NO flat bin covers (one DFA of
+    # such a block is past the bins' VMEM plan, ``plan_flat_bins``);
+    # ``bank_blocks`` names each one's block. A covered block has no bank:
+    # its tables live in its bins alone.
     banks: list[DFABank]
     # Conv-segment tier: groups whose regex decomposes exactly into
     # fixed-length segments + gaps match here (one MXU conv for all
@@ -186,26 +204,21 @@ class WafModel:
     # so long buckets stream through the constant-memory scan carry.
     long_banks: list = field(default_factory=list)
     seg_perm: jnp.ndarray | None = None  # [Gs, Gs] one-hot: long order → seg order
-    # Flat-slot fused bins (ops/dfa_flat.py): cover the dense-DFA blocks
-    # (banks, gather_banks, pre_banks) with a few fused VMEM-resident
-    # scans; a covered block's own scan is skipped in match_tier. Empty
-    # when fusion is disabled.
+    # Flat-slot fused bins (ops/dfa_flat.py): the dense blocks' DFAs as
+    # a few fused VMEM-resident scans; match_tier takes a covered block's
+    # columns from its bins.
     flat_banks: list = field(default_factory=list)
-    # Two-level automata (ops/dfa_gather.py, compiler/automata_plan.py).
-    # DFA hot tier: joint-byte-class packed gather banks for the plan's
-    # "dfa-hot" groups — one block each, scanned by its own kernel only
-    # where no flat bin covers it. Empty unless build_model was handed a
-    # plan.
-    gather_banks: list = field(default_factory=list)
-    # Approximate prefilter: stacked OVER-APPROXIMATING automata fronting
-    # the plan's "prefiltered" groups. Their hit columns may over-match
-    # by design — the engine's dispatch confirms positive rows against
-    # the exact automata on the host (prefilter_cols below) before the
-    # post stage, so verdicts never change. A model with non-empty
-    # pre_banks must only be evaluated through that confirm path.
-    pre_banks: list = field(default_factory=list)
     # static metadata
-    bank_pipelines: tuple = field(default_factory=tuple)  # pipeline id per bank
+    # The dense-DFA blocks, in the column order (after the seg blocks):
+    # the exact nfa buckets, then the plan's dfa-hot blocks, then its
+    # prefilter blocks. A prefilter block's columns may over-match by
+    # design: the engine's dispatch confirms positive rows against the
+    # exact automata on the host (prefilter_cols below) before the post
+    # stage, so verdicts never change, and a model that holds one must
+    # only be evaluated through that confirm path. No dfa-hot or
+    # prefilter block unless build_model was handed a plan.
+    dense_blocks: tuple = ()
+    bank_blocks: tuple = ()  # block index (segs first) per entry of banks
     seg_pipelines: tuple = field(default_factory=tuple)  # pipeline id per seg block
     long_bank_pipelines: tuple = field(default_factory=tuple)
     pipelines: tuple = field(default_factory=tuple)  # names per pipeline id
@@ -219,7 +232,7 @@ class WafModel:
     # ctl never applies its own removals (Coraza in-order semantics).
     removal_rows: tuple = ()
     # Kind-partitioned matching (static): per matcher block (segs first,
-    # then banks — match_tier's concat order), the tuple of kind ids
+    # then the dense blocks — match_tier's concat order), the tuple of kind ids
     # that can reach any of the block's groups, and a rough relative
     # per-row cost. tier_tensors partitions rows by the set of blocks
     # their kinds can reach; a tier whose mask excludes a block skips
@@ -231,8 +244,8 @@ class WafModel:
     # ctl:ruleRemoveTargetById variants) — post_match then runs a second
     # counter pass so counter-gated rules' own setvars still accumulate.
     two_pass_counters: bool = False
-    # Static: block indexes (segs, banks, gather_banks, pre_banks — the
-    # column order) whose hit columns come from flat_banks.
+    # Static: block indexes (segs first, then dense_blocks: the column
+    # order) whose hit columns come from flat_banks.
     flat_covered: tuple = ()
     # Host-side only: ORIGINAL group id held by each device hit column
     # (the inverse of build_model's remap). The lazy per-tier dispatch
@@ -240,10 +253,6 @@ class WafModel:
     # to permute them back for the host post-match. Canonicalized out of
     # the aux like block_kinds/block_cost — never read in a trace.
     group_order: tuple = ()
-    # Pipeline id per gather / prefilter bank (trace statics, mirror
-    # bank_pipelines).
-    gather_bank_pipelines: tuple = field(default_factory=tuple)
-    pre_bank_pipelines: tuple = field(default_factory=tuple)
     # Host-side only: (device hit column, original group id) per
     # prefiltered group — the engine's confirm step re-checks positive
     # rows of these columns against the exact DFA. Canonicalized out of
@@ -280,8 +289,6 @@ class WafModel:
             self.long_banks,
             self.seg_perm,
             self.flat_banks,
-            self.gather_banks,
-            self.pre_banks,
         )
         # CANONICAL aux (shape-canonical executable reuse): the aux tuple
         # is the jit/AOT cache key's treedef component, so it must contain
@@ -291,9 +298,11 @@ class WafModel:
         # two same-layout rulesets hash to different executables. They
         # flatten as () placeholders; unflattened copies (the jit-internal
         # reconstruction, device_put round trips) see empty tuples, which
-        # no traced code reads.
+        # no traced code reads. (A dense block's kind and states stay out
+        # of the key by ``DenseBlock``'s own equality.)
         aux = (
-            self.bank_pipelines,
+            self.dense_blocks,
+            self.bank_blocks,
             self.seg_pipelines,
             self.long_bank_pipelines,
             self.pipelines,
@@ -308,8 +317,6 @@ class WafModel:
             self.two_pass_counters,
             self.flat_covered,
             (),  # group_order: host-side only, canonicalized out
-            self.gather_bank_pipelines,
-            self.pre_bank_pipelines,
             (),  # prefilter_cols: host-side only, canonicalized out
         )
         return leaves, aux
@@ -338,20 +345,20 @@ def lgroup_onehot(lgroup: np.ndarray, n_groups: int) -> np.ndarray:
 
 def build_model(crs: CompiledRuleSet, automata=None) -> WafModel:
     """Lay out a CompiledRuleSet as device arrays. Groups are re-ordered so
-    each bank's groups are contiguous; links are rewritten accordingly.
+    each block's groups are contiguous; links are rewritten accordingly.
 
     Routing: each group first tries the exact conv-segment decomposition
     (``compiler/segments.py``) — those match on the MXU conv tier; the
-    rest bucket into DFA banks by state count. Global group order (and the
-    lgroup remap) is: segment blocks sorted by pipeline id, then DFA
-    buckets sorted by (pipeline, bucket), then gather banks, then
-    prefilter banks.
+    rest bucket into dense-DFA blocks by state count. Global group order
+    (and the lgroup remap) is: segment blocks sorted by pipeline id, then
+    the exact DFA buckets sorted by (pipeline, bucket), then the dfa-hot
+    blocks, then the prefilter buckets.
 
     ``automata`` (``compiler/automata_plan.AutomataPlan`` or None) turns
     on the two-level automata layout: the plan's "dfa-hot" groups leave
-    the generic banks for joint-byte-class ``GatherBank``s and its
-    "prefiltered" groups are REPLACED on device by their small
-    over-approximating automata (``pre_banks`` + ``prefilter_cols``).
+    the state buckets for blocks of their own and its "prefiltered"
+    groups are REPLACED on device by their small over-approximating
+    automata (the "prefilter" blocks + ``prefilter_cols``).
     The default (None) keeps every group exact — direct ``eval_waf*``
     callers and the sharded path (``parallel/mesh.py``) never see an
     approximate column; only ``engine.waf.WafEngine`` passes a plan, and
@@ -396,86 +403,58 @@ def build_model(crs: CompiledRuleSet, automata=None) -> WafModel:
             remap[g] = next_new
             next_new += 1
 
-    banks: list[DFABank] = []
-    bank_pipelines: list[int] = []
-    bank_gids: list[list[int]] = []
+    # The dense-DFA blocks, listed once, in the column order: the exact
+    # nfa buckets, the dfa-hot blocks (a (pipeline, bucket) population cut
+    # by ``cut_hot_blocks``; one piece == one maskable block), the
+    # prefilter buckets (the plan's over-approximating automata; their
+    # columns over-match by design, and prefilter_cols records which
+    # device columns need the engine's exact host confirm). Each is its
+    # kind, its pipeline, its members' original group ids and their DFAs.
+    blocks: list[tuple[str, int, list[int], list]] = []
     for (pid, _bucket), gids in sorted(buckets.items()):
-        banks.append(stack_dfas([crs.groups[g].dfa for g in gids]))
-        bank_pipelines.append(pid)
-        bank_gids.append(list(gids))
-        for g in gids:
-            remap[g] = next_new
-            next_new += 1
-
-    # DFA hot tier: joint-byte-class gather banks. Within a (pipeline,
-    # bucket) population the greedy packer splits members into bins so
-    # each bank's joint class count and VMEM working set stay under the
-    # kernel caps; one bin == one GatherBank == one maskable block.
-    gather_banks: list[GatherBank] = []
-    gather_bank_pipelines: list[int] = []
-    gather_bank_gids: list[list[int]] = []
+        blocks.append(("nfa", pid, gids, [crs.groups[g].dfa for g in gids]))
     for (pid, _bucket), gids in sorted(hot_buckets.items()):
-        dfas = [crs.groups[g].dfa for g in gids]
-        for bin_ in plan_gather_bins(dfas):
-            members = [gids[i] for i in bin_]
-            gather_banks.append(stack_gather_bank([crs.groups[g].dfa for g in members]))
-            gather_bank_pipelines.append(pid)
-            gather_bank_gids.append(members)
-            for g in members:
-                remap[g] = next_new
-                next_new += 1
-
-    # Approximate prefilter banks: the plan's over-approximating automata
-    # stacked like ordinary (small => dense fast path) banks. Their
-    # columns over-match by design; prefilter_cols records which device
-    # columns need the engine's exact host confirm.
-    pre_banks: list[DFABank] = []
-    pre_bank_pipelines: list[int] = []
-    pre_bank_gids: list[list[int]] = []
-    prefilter_cols: list[tuple[int, int]] = []
+        for cut in cut_hot_blocks([crs.groups[g].dfa for g in gids]):
+            members = [gids[i] for i in cut]
+            blocks.append(("dfa-hot", pid, members, [crs.groups[g].dfa for g in members]))
     for (pid, _bucket), gids in sorted(pre_buckets.items()):
-        pre_banks.append(stack_dfas([approx_of[g] for g in gids]))
-        pre_bank_pipelines.append(pid)
-        pre_bank_gids.append(list(gids))
+        blocks.append(("prefilter", pid, gids, [approx_of[g] for g in gids]))
+    prefilter_cols: list[tuple[int, int]] = []
+    for kind, _pid, gids, _dfas in blocks:
         for g in gids:
-            prefilter_cols.append((next_new, g))
+            if kind == "prefilter":
+                prefilter_cols.append((next_new, g))
             remap[g] = next_new
             next_new += 1
+    dense_blocks = tuple(
+        DenseBlock(pid, len(dfas), kind, max(d.n_states for d in dfas))
+        for kind, pid, _gids, dfas in blocks
+    )
 
-    # Flat-slot fused bins (ops/dfa_flat.py): every dense-DFA block —
-    # the generic banks, the dfa-hot gather banks and the prefilter's
-    # approximations — is offered to the flat planner, and the blocks it
-    # accepts collapse into a few VMEM-resident fused kernels that cost
-    # their real states (a per-bank kernel pads 1-7 groups to 128
-    # lanes). The per-bank scans remain for a block the planner rejects,
-    # for CKO_FLAT=0 and for the sharded path. Column order is
-    # untouched: a bin's pieces carry (block, g_lo, g_hi) and match_tier
-    # stitches by block.
+    # Flat-slot fused bins (ops/dfa_flat.py): every dense block is offered
+    # to the flat planner, and the blocks it accepts collapse into a few
+    # VMEM-resident fused kernels that cost their real states. It rejects
+    # a block only when ONE exact DFA of it overflows the bins' VMEM plan
+    # (thousands of states that the prefilter could not front): such a
+    # block alone gets a ``DFABank``, which has no dense table at that
+    # size, and match_tier scans it with the gather scan of ops/dfa.py.
+    # Column order is untouched: a bin's pieces carry (block, g_lo, g_hi)
+    # and match_tier stitches by block.
     n_segs_blocks = len(segs)
-    flat_banks_built: list = []
-    flat_covered: set[int] = set()
-    dense_blocks = [
-        (pid, [crs.groups[g].dfa for g in gids])
-        for pid, gids in zip(
-            bank_pipelines + gather_bank_pipelines, bank_gids + gather_bank_gids
-        )
-    ] + [
-        (pid, [approx_of[g] for g in gids])
-        for pid, gids in zip(pre_bank_pipelines, pre_bank_gids)
-    ]
-    if _os.environ.get("CKO_FLAT", "1") != "0" and dense_blocks:
-        from ..ops.dfa_flat import build_flat_bank, plan_flat_bins
-
-        bins, _rejected = plan_flat_bins(
-            [
-                (n_segs_blocks + i, pid, dfas)
-                for i, (pid, dfas) in enumerate(dense_blocks)
-            ]
-        )
-        for bn in bins:
-            flat_banks_built.append(build_flat_bank(bn))
-            for block_idx, _pid, _glo, _ghi, _ds in bn:
-                flat_covered.add(block_idx)
+    bins, rejected = plan_flat_bins(
+        [
+            (n_segs_blocks + i, pid, dfas)
+            for i, (_kind, pid, _gids, dfas) in enumerate(blocks)
+        ]
+    )
+    flat_banks = [build_flat_bank(bn) for bn in bins]
+    bank_blocks = tuple(sorted(rejected))
+    banks = [stack_dfas(blocks[blk - n_segs_blocks][3]) for blk in bank_blocks]
+    flat_covered = tuple(
+        blk
+        for blk in range(n_segs_blocks, n_segs_blocks + len(blocks))
+        if blk not in rejected
+    )
 
     # Long-buffer fallback banks: every segment-routed group's DFA,
     # bucketed by state count like the normal banks. Their concatenated
@@ -610,70 +589,28 @@ def build_model(crs: CompiledRuleSet, automata=None) -> WafModel:
     # Kind-partitioned matching constants: which kinds can reach each
     # matcher block (union of the include sets of every string link on
     # any of the block's groups), and a rough relative per-row cost by
-    # formulation (conv / Pallas VMEM / HBM take-scan / serializing
-    # gather-scan). Only the RANKING matters — tier_tensors uses the
+    # formulation (conv / fused flat scan / serializing gather scan).
+    # Only the RANKING matters — tier_tensors uses the
     # costs to cluster row partitions, never as absolute time.
-    from ..ops.dfa import _PALLAS_VMEM_BUDGET, _pallas_vmem_bytes
     from ..ops.segment import conv_n2_cols
 
     gkind_sets: list[set[int]] = [set() for _ in range(max(1, len(crs.groups)))]
     for link in crs.links:
         if link.link_type == LINK_STRING and link.group >= 0:
             gkind_sets[link.group].update(link.include_kinds)
-    block_kinds: list[tuple[int, ...]] = []
-    block_cost: list[float] = []
-    for pid in sorted(seg_groups):
-        ks: set[int] = set()
-        for gid, _plan in seg_groups[pid]:
-            ks |= gkind_sets[gid]
-        block_kinds.append(tuple(sorted(ks)))
-    for seg in segs:
-        block_cost.append(float(conv_n2_cols(seg.spec)))
-    for (_pid, _bucket), gids in sorted(buckets.items()):
-        ks = set()
-        for gid in gids:
-            ks |= gkind_sets[gid]
-        block_kinds.append(tuple(sorted(ks)))
-    for bi, bank in enumerate(banks):
-        s, g = bank.n_states, bank.n_groups
-        if n_segs_blocks + bi in flat_covered:
-            block_cost.append(0.5 * s * g)  # fused flat scan, no lane padding
-        elif bank.t256.size == 0:
-            block_cost.append(1000.0 * g)  # gather path serializes
-        elif (
-            _pallas_vmem_bytes(s, g, bank.t256.dtype.itemsize, 64)
-            <= _PALLAS_VMEM_BUDGET
-        ):
-            block_cost.append(0.5 * s * max(g, 128))  # VMEM-resident MXU scan
+
+    def kinds_of(gids) -> tuple[int, ...]:
+        return tuple(sorted(set().union(*(gkind_sets[g] for g in gids))))
+
+    block_kinds = [
+        kinds_of(gid for gid, _plan in seg_groups[pid]) for pid in sorted(seg_groups)
+    ] + [kinds_of(gids) for _kind, _pid, gids, _dfas in blocks]
+    block_cost = [float(conv_n2_cols(seg.spec)) for seg in segs]
+    for blk, db in enumerate(dense_blocks, start=n_segs_blocks):
+        if blk in rejected:
+            block_cost.append(1000.0 * db.groups)  # the gather scan serializes
         else:
-            block_cost.append(8.0 * s * g)  # HBM take-scan
-    for members in gather_bank_gids:
-        ks = set()
-        for gid in members:
-            ks |= gkind_sets[gid]
-        block_kinds.append(tuple(sorted(ks)))
-    n_banks_blocks = n_segs_blocks + len(banks)
-    for gi, gb in enumerate(gather_banks):
-        if n_banks_blocks + gi in flat_covered:
-            block_cost.append(0.5 * gb.n_states * gb.n_groups)
-        else:
-            # Joint-class packing shrinks the resident table and the
-            # dominant per-step contraction by 256/C vs the byte-indexed
-            # dense scan.
-            factor = max(0.1, gb.n_classes / 256.0)
-            block_cost.append(0.5 * factor * gb.n_states * max(gb.n_groups, 128))
-    for members in pre_bank_gids:
-        ks = set()
-        for gid in members:
-            ks |= gkind_sets[gid]
-        block_kinds.append(tuple(sorted(ks)))
-    n_gather_blocks = n_banks_blocks + len(gather_banks)
-    for pi, pb in enumerate(pre_banks):
-        s, g = pb.n_states, pb.n_groups
-        if n_gather_blocks + pi in flat_covered:
-            block_cost.append(0.5 * s * g)
-        else:
-            block_cost.append(0.5 * s * max(g, 128))  # small dense approx bank
+            block_cost.append(0.5 * db.states * db.groups)  # fused flat scan
     # Inverse of remap: original group id per device hit column (host
     # metadata for the lazy host-tier path — see WafModel.group_order).
     n_g = len(crs.groups)
@@ -718,10 +655,9 @@ def build_model(crs: CompiledRuleSet, automata=None) -> WafModel:
         ),
         long_banks=long_banks,
         seg_perm=seg_perm,
-        flat_banks=flat_banks_built,
-        gather_banks=gather_banks,
-        pre_banks=pre_banks,
-        bank_pipelines=tuple(bank_pipelines),
+        flat_banks=flat_banks,
+        dense_blocks=dense_blocks,
+        bank_blocks=bank_blocks,
         seg_pipelines=tuple(seg_pipelines),
         long_bank_pipelines=tuple(long_bank_pipelines),
         pipelines=tuple(tuple(p) for p in crs.pipelines),
@@ -734,10 +670,8 @@ def build_model(crs: CompiledRuleSet, automata=None) -> WafModel:
         block_kinds=tuple(block_kinds),
         block_cost=tuple(block_cost),
         two_pass_counters=two_pass_counters,
-        flat_covered=tuple(sorted(flat_covered)),
+        flat_covered=flat_covered,
         group_order=group_order,
-        gather_bank_pipelines=tuple(gather_bank_pipelines),
-        pre_bank_pipelines=tuple(pre_bank_pipelines),
         prefilter_cols=tuple(prefilter_cols),
     )
 
@@ -950,8 +884,7 @@ def segment_tier_hits(
     hit columns (exact: post_match's ``rel`` gate resolves their links
     False for such rows). The long-bank fallback ignores ``keep`` — it
     is the rare giant-buffer path and scans everything."""
-    from ..ops.dfa import scan_dfa_bank
-    from ..ops.segment import match_segment_block, tile_spec
+    from ..ops.segment import tile_spec
 
     if not segs:
         return []
@@ -1102,7 +1035,7 @@ def match_tier(
     mask: int | None = None,
 ) -> jnp.ndarray:
     """Stages 1+2 for ONE length tier: transforms + matchers → per-target
-    group hits [T, G]. Segment blocks first, DFA banks after — the same
+    group hits [T, G]. Segment blocks first, dense-DFA blocks after — the same
     global order build_model's remap assigned. Tiers are independent
     until post_match (rows only meet at the req_id reduction), which is
     what makes row-level length tiering (``engine.waf.tier_tensors``)
@@ -1111,7 +1044,7 @@ def match_tier(
     the body's width.
 
     ``mask`` (static int) is the kind-partition block bitmask: bit i set
-    = scan block i (segs first, then banks — build_model order). Bits
+    = scan block i (segs first, then the dense blocks — build_model order). Bits
     0-61 are usable; blocks at index >= 62 are always scanned
     (saturation for huge models). A
     skipped block contributes all-False hits, which is exact for rows
@@ -1119,8 +1052,6 @@ def match_tier(
     gates those links off regardless of the hit bit)."""
     per_block: list[jnp.ndarray] = []
     transformed: dict[int, tuple[jnp.ndarray, jnp.ndarray]] = {}
-    from ..ops.dfa import scan_dfa_bank
-
     n_segs = len(model.segs)
 
     def block_on(i: int) -> bool:
@@ -1151,64 +1082,40 @@ def match_tier(
             keep=tuple(i for i in range(n_segs) if block_on(i)),
         )
     )
-    # Flat-slot fused bins: one fused scan covers many banks. A bin runs
+    # Flat-slot fused bins: one fused scan covers many blocks. A bin runs
     # when ANY of its blocks is mask-on; mask-off blocks' columns are
     # discarded (the stitcher emits zeros for them below, which is exact
     # — post_match's rel gate resolves those links False regardless).
     flat_cols: dict[int, dict[int, jnp.ndarray]] = {}
-    if model.flat_banks:
-        from ..ops.dfa_flat import scan_flat_bank
-
-        for fi, fb in enumerate(model.flat_banks):
-            if not any(block_on(p[0]) for p in fb.pieces):
-                continue
-            sub = {p: transformed_for(p) for p in sorted(set(fb.seg_pipes))}
-            with jax.named_scope("cko.flat"):
-                out = scan_flat_bank(fb, sub, name=f"cko_flat_bin{fi}")
-                col = 0
-                for blk, g_lo, g_hi in fb.pieces:
-                    w = g_hi - g_lo
-                    flat_cols.setdefault(blk, {})[g_lo] = out[:, col : col + w]
-                    col += w
-    # Dense-DFA blocks in the global column order: the generic banks,
-    # then the two-level automata's dfa-hot gather banks, then its
-    # approximate prefilter banks (whose columns the engine confirms on
-    # the host). A flat-covered block takes its columns from its bins;
-    # the per-bank kernel is for a block the flat planner left out.
-    dense_blocks = (
-        [
-            (bank, pid, scan_dfa_bank, f"cko_dfa_bank{i}")
-            for i, (bank, pid) in enumerate(zip(model.banks, model.bank_pipelines))
-        ]
-        + [
-            (bank, pid, scan_gather_bank, f"cko_gather_bank{i}")
-            for i, (bank, pid) in enumerate(
-                zip(model.gather_banks, model.gather_bank_pipelines)
-            )
-        ]
-        + [
-            (bank, pid, scan_dfa_bank, f"cko_prefilter_bank{i}")
-            for i, (bank, pid) in enumerate(
-                zip(model.pre_banks, model.pre_bank_pipelines)
-            )
-        ]
-    )
-    for blk, (bank, pid, scan, name) in enumerate(dense_blocks, start=n_segs):
+    for fi, fb in enumerate(model.flat_banks):
+        if not any(block_on(p[0]) for p in fb.pieces):
+            continue
+        sub = {p: transformed_for(p) for p in sorted(set(fb.seg_pipes))}
+        with jax.named_scope("cko.flat"):
+            out = scan_flat_bank(fb, sub, name=f"cko_flat_bin{fi}")
+            col = 0
+            for blk, g_lo, g_hi in fb.pieces:
+                w = g_hi - g_lo
+                flat_cols.setdefault(blk, {})[g_lo] = out[:, col : col + w]
+                col += w
+    # The dense-DFA blocks in the global column order. A flat-covered
+    # block takes its columns from its bins; the plain bank scan is for a
+    # block the flat planner left out.
+    bank_of = dict(zip(model.bank_blocks, model.banks))
+    for blk, db in enumerate(model.dense_blocks, start=n_segs):
         if not block_on(blk):
             with jax.named_scope("cko.stitch"):
-                per_block.append(
-                    jnp.zeros((data.shape[0], bank.n_groups), dtype=bool)
-                )
-        elif blk in model.flat_covered:
+                per_block.append(jnp.zeros((data.shape[0], db.groups), dtype=bool))
+        elif blk in bank_of:
+            td = transformed_for(db.pipeline)
+            with jax.named_scope("cko.dense"):
+                per_block.append(scan_dfa_bank(bank_of[blk], *td))
+        else:
             pieces = flat_cols[blk]
             with jax.named_scope("cko.stitch"):
                 per_block.append(
                     jnp.concatenate([pieces[k] for k in sorted(pieces)], axis=1)
                 )
-        else:
-            td = transformed_for(pid)
-            with jax.named_scope("cko.dense"):
-                per_block.append(scan(bank, *td, name=name))
     with jax.named_scope("cko.stitch"):
         if per_block:
             return jnp.concatenate(per_block, axis=1)  # [T, G]

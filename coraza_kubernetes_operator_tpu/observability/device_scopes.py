@@ -64,7 +64,7 @@ SCOPES = {
     "cko.seg.tile": "segment_tier_hits, column-tiled: the barrier that runs a chunk's column tiles one after another, their concatenation",
     "cko.seg.long": "segment_tier_hits, long-bank fallback: scan_dfa_bank over the long banks, the seg_perm matmul",
     "cko.flat": "scan_flat_bank: class maps, slot layout, the Pallas call cko_flat_bin<i>, unpacking columns",
-    "cko.dense": "match_tier: a per-bank kernel for a dense-DFA block no bin covers (cko_dfa_bank<i> etc.)",
+    "cko.dense": "match_tier: scan_dfa_bank, the XLA scan of a dense-DFA block no bin covers ",
     "cko.stitch": "match_tier, match_tier_packed: concatenation of the blocks' columns, packbits",
     "cko.post.unpack": "eval_post_tiered: slab views, hit rows unpacked and taken by uid",
     "cko.post.match": "eval_post_tiered: post_match",

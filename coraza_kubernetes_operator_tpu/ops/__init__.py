@@ -1,21 +1,18 @@
 """JAX/Pallas device kernels for the TPU data plane.
 
-- ``transforms`` — vectorized byte-level Seclang transformations over
+- ``transforms``: vectorized byte-level Seclang transformations over
   ``[batch, len]`` uint8 tensors.
-- ``dfa`` — the core matcher: blockwise ``lax.scan`` over stacked
-  byte-class DFA tables (two gathers per byte per rule-group).
-- ``dfa_gather`` — the DFA hot tier: joint-byte-class packed
-  transition-gather banks for small/safe groups (docs/AUTOMATA.md).
-- ``pallas`` — hand-written TPU kernels for the hot paths.
+- ``segment``: the conv tier, every position of a segment plan in one MXU
+  convolution and the chains over its match bitmap.
+- ``dfa_flat``: the dense-DFA blocks (exact nfa buckets, dfa-hot blocks,
+  prefilter approximations) as fused flat-slot bins, one Pallas kernel a
+  bin on a TPU (docs/AUTOMATA.md).
+- ``dfa``: the plain bank scan, a ``lax.scan`` over stacked DFA tables:
+  a block no bin holds, the conv tier's long banks, the bins' oracle.
+- ``dfa_host``: the same automata in NumPy, for the host fallback.
 
 All kernels are shape-static and jit-safe: control flow is ``lax.scan``/
 ``jnp.where`` only, per the XLA compilation model.
 """
 
 from .dfa import DFABank, scan_dfa_bank, stack_dfas  # noqa: F401
-from .dfa_gather import (  # noqa: F401
-    GatherBank,
-    plan_gather_bins,
-    scan_gather_bank,
-    stack_gather_bank,
-)

@@ -1,28 +1,31 @@
-"""Stacked-DFA batch scanner — the core matcher kernel.
+"""Stacked-DFA batch scanner: the plain bank scan.
 
-A bank stacks G compiled DFAs (``compiler/re_dfa.py``) into device tables and
-scans a ``[B, L]`` byte batch. Two formulations:
+A bank stacks G compiled DFAs (``compiler/re_dfa.py``) into device tables
+and scans a ``[B, L]`` byte batch in one ``lax.scan``. The served path
+matches its dense-DFA blocks in the fused flat bins (``ops/dfa_flat.py``);
+this module stays as (a) the scan of a block no bin holds and of the conv
+tier's long banks (``models/waf_model.py``), (b) the oracle
+``tests/test_dfa_flat.py`` holds the bins to, and (c) what
+``parallel/mesh.py`` shards. Two formulations, picked by ``scan_dfa_bank``
+from the bank alone, the same on every backend:
 
-- ``scan_dfa_bank`` (default): **gather-free matmul scan**. Per byte step the
-  byte one-hot ``[B, 256]`` is contracted with a dense per-slot transition
-  table ``[256, S*G]`` on the MXU, and the current-state one-hot selects the
-  per-group next state with a VPU reduce. XLA's gather lowering serializes on
-  TPU (~100M elem/s measured), while this rides the systolic array — the
-  difference is ~100x end-to-end. Entries pack ``next + S*emit`` so one
-  matmul yields both transition and match bit; dtype is int8 when the packed
-  values fit (S <= 64, int8 MXU), else bf16 (S <= 128, integers exact to
-  256), else f32.
-- ``scan_dfa_bank_gather``: the original two-gathers-per-byte formulation,
-  kept as the semantic oracle for differential tests and as the CPU path of
-  last resort.
+- ``scan_dfa_bank_take``: a bank of at most ``_DENSE_MAX_STATES`` padded
+  states carries a dense per-slot table ``[256, S*G]`` whose entries pack
+  ``next + S*emit`` (int8 when the packed values fit, S <= 64; else f32,
+  cast to bf16 on a TPU while S <= 128: integers exact to 256). A byte
+  step is one row ``take`` and a VPU select by the current state.
+- ``scan_dfa_bank_gather``: two gathers a byte (classmap, then the packed
+  ``[G, S, C]`` table). The semantic oracle of the differential tests and
+  the scan of a bank too wide for a dense table, which on a TPU
+  serializes: the prefilter (``compiler/re_approx.py``) exists to keep
+  such groups off it.
 
-Long bodies stream through the same scan — DFA state is the natural carry,
-which is the blockwise "long context" decomposition (SURVEY §5): no
-cross-chip sequence parallelism is needed at WAF body sizes, the scan carry
+Long bodies stream through the same scan: DFA state is the natural carry,
+which is the blockwise "long context" decomposition (SURVEY §5); the carry
 crosses block boundaries exactly.
 
 Groups are bucketed by table size before stacking (``stack_dfas`` callers
-pad to the bank max), trading padding waste for a single fused kernel.
+pad to the bank max), trading padding waste for a single fused scan.
 """
 
 from __future__ import annotations
@@ -100,7 +103,7 @@ def stack_dfas(dfas: list[DFA], min_states: int = 1) -> DFABank:
     match_end = np.zeros((g, s_max), dtype=bool)
     always = np.zeros(g, dtype=bool)
     build_dense = s_max <= _DENSE_MAX_STATES
-    # Dense byte-indexed table for the matmul/Pallas scan: for every byte
+    # Dense byte-indexed table for the take-scan: for every byte
     # value and (state, group) slot, the packed next-state + S*emit. Padded
     # states (s >= d.n_states) self-loop to 0 and never activate (state
     # one-hot starts at local state 0 and transitions stay in range).
@@ -133,64 +136,15 @@ def stack_dfas(dfas: list[DFA], min_states: int = 1) -> DFABank:
     )
 
 
-# VMEM budget for the Pallas kernel's resident working set (table + per-step
-# accumulator tiles at block_b=128). Banks above it run the XLA take-scan.
-# KNOWN-GOOD at 11MB: raising it to 40MB (to move the S=104 x G=84 header
-# bank onto the Pallas path, ~20% off the matcher pass in isolated
-# profiling) made the kernel pass standalone differential tests but
-# FAULT the device inside the big-model serve loops on real v5e hardware
-# (config 4 'TPU device error — kernel fault'; config 3's compile
-# crashed) — the larger resident set plus the serve program's own
-# VMEM demand oversubscribes what the estimate models. Do not raise this
-# again without exercising the full serve loop on hardware. block_b
-# stays 128: it is the lane (minormost) dimension of the dataT BlockSpec
-# and sub-128 lane tiles are unexercised on Mosaic.
-_PALLAS_VMEM_BUDGET = 11 * 2**20
-_PALLAS_BLOCK_B = 128
-
-
-def _pallas_vmem_bytes(s: int, g: int, itemsize: int, length: int) -> int:
-    gp = (g + 127) // 128 * 128
-    table = 256 * s * gp * itemsize
-    # per-step [block_b, S*Gp] accumulator + one fused select intermediate
-    work = _PALLAS_BLOCK_B * s * gp * 4 * 2
-    # dataT tile is lane-padded to 128 and double-buffered by Pallas
-    data_tile = length * _PALLAS_BLOCK_B * 4 * 2
-    return table + work + data_tile
-
-
-def scan_dfa_bank(
-    bank: DFABank, data: jnp.ndarray, lengths: jnp.ndarray, name: str | None = None
-) -> jnp.ndarray:
+def scan_dfa_bank(bank: DFABank, data: jnp.ndarray, lengths: jnp.ndarray) -> jnp.ndarray:
     """Scan ``data`` [B, L] uint8 (zero-padded past ``lengths`` [B]) against
     every DFA in the bank. Returns ``matched`` [B, G] bool.
 
-    Dispatch: Pallas VMEM-resident kernel on TPU when the dense table and
-    working set fit VMEM (``ops/dfa_pallas.py``); XLA dense-row take-scan
-    when a dense table exists; classmap gather scan for huge-state banks
-    (no dense table — it would be a (256/C)x memory blow-up)."""
+    The dense-row take-scan where the bank has a dense table; the classmap
+    gather scan for a huge-state bank (no dense table: it would be a
+    (256/C)x memory blow-up)."""
     if bank.t256.size == 0:
         return scan_dfa_bank_gather(bank, data, lengths)
-    fits = (
-        _pallas_vmem_bytes(
-            bank.n_states, bank.n_groups, bank.t256.dtype.itemsize, data.shape[1]
-        )
-        <= _PALLAS_VMEM_BUDGET
-    )
-    if jax.default_backend() == "tpu" and fits:
-        from .dfa_pallas import scan_dfa_bank_pallas
-
-        return scan_dfa_bank_pallas(
-            bank.t256,
-            bank.match_end.T,
-            bank.always,
-            data,
-            lengths,
-            s=bank.n_states,
-            g=bank.n_groups,
-            block_b=_PALLAS_BLOCK_B,
-            name=name,
-        )
     return scan_dfa_bank_take(bank, data, lengths)
 
 
@@ -199,9 +153,9 @@ def scan_dfa_bank_take(
     bank: DFABank, data: jnp.ndarray, lengths: jnp.ndarray
 ) -> jnp.ndarray:
     """XLA formulation: per byte step a row-gather from the dense table
-    (``take``) and a VPU state-select. Correct everywhere, but materializes
-    a [B, S*G] intermediate in HBM per step — the Pallas kernel exists to
-    keep that tile in VMEM. (A one-hot @ table matmul inside ``lax.scan``
+    (``take``) and a VPU state-select. Materializes a [B, S*G]
+    intermediate in HBM per step, which the flat bins keep in VMEM for the
+    blocks they hold. (A one-hot @ table matmul inside ``lax.scan``
     is NOT used: XLA miscompiles it at batch ~4096-5000, identically on CPU
     and TPU; see tests/test_dfa_kernel.py.)"""
     b, length = data.shape

@@ -72,11 +72,9 @@ _LANE = 128
 # COMPILE time (observed: a 3584-slot bin over two pipelines at L=2048
 # rejected at 16.09M/16.00M with a clean compile error — not the
 # round-4 style runtime fault). The estimator below is calibrated
-# against that measurement; the default budget keeps ~1MB of margin
-# under the real limit. Env-tunable for validation runs.
-import os as _os
-
-_FLAT_VMEM_BUDGET = int(_os.environ.get("CKO_FLAT_VMEM_MB", "15")) * 2**20
+# against that measurement; the budget keeps ~1MB of margin under the
+# real limit.
+_FLAT_VMEM_BUDGET = 15 * 2**20
 CHIP_SCOPED_VMEM_BYTES = 16 * 2**20  # what a v5e's compiler gives one kernel
 MAX_BIN_SLOTS = 6144  # a bin's slot digits are base 256, two of them: far below 65536
 _BLOCK_B = 128
@@ -216,9 +214,8 @@ def _layout_stats(pieces) -> tuple[int, int, int, int]:
 # and 2048 (chip calls and tests/test_tpu_compile.py); the one refusal
 # on record, a 3,584-slot bin over two pipelines at 2048 (16.09M of
 # 16.00M), reads 16.7MB on the estimator, so the planner never makes it
-# (tests/test_custom_feed.py). CKO_FLAT_MAX_LEN below is for validation
-# runs; no deployment has to set it.
-_PALLAS_MAX_LEN = int(_os.environ.get("CKO_FLAT_MAX_LEN", "2048"))
+# (tests/test_custom_feed.py).
+_PALLAS_MAX_LEN = 2048
 
 
 def plan_flat_bins(
@@ -231,7 +228,8 @@ def plan_flat_bins(
     fused-kernel bins; oversized banks split by group ranges. Returns
     (bins, rejected_blocks): bins of (block_index, pid, g_lo, g_hi,
     dfas-slice) pieces, plus block indexes whose single-DFA working set
-    exceeds the budget (those banks stay on the legacy scan path).
+    exceeds the budget (``build_model`` stacks a ``DFABank`` for those
+    and ``match_tier`` scans it with ``ops/dfa.py:scan_dfa_bank``).
 
     Packing is per pipeline, in block order: kind-partition masks tend
     to exclude whole pipelines, so a mask usually skips or keeps a whole

@@ -8,6 +8,13 @@ per-target hit bits over the rule axis (the only collective — G bits per
 target, riding ICI), rebuilds the global group-hit matrix and runs the
 shared post-match stages. Targets/requests are stacked on a leading data
 axis with ``PartitionSpec('data')``.
+
+A correctness contract, not a served path: no sidecar and no benchmark
+cell runs this module (the served engine is one chip, its dense blocks in
+the fused flat bins of ``ops/dfa_flat.py``), and tier-1 holds its verdicts
+to the single-chip engine's on virtual CPU devices. Its banks take
+``ops/dfa.py:scan_dfa_bank`` on every backend: on a TPU too that is the
+XLA take-scan (the gather scan past 128 states), no Pallas kernel.
 """
 
 from __future__ import annotations
@@ -187,7 +194,6 @@ def build_sharded_model(crs: CompiledRuleSet, n_rule_shards: int) -> ShardedWafM
         phase=base.phase,
         weights=base.weights,
         counter_base=base.counter_base,
-        bank_pipelines=(),
         seg_pipelines=(),
         pipelines=base.pipelines,
         pipeline_device=base.pipeline_device,

@@ -1219,7 +1219,7 @@ class TpuEngineSidecar:
         # engine — and its plan — under us).
         self.metrics.gauge(
             "cko_dfa_hot_groups",
-            "Groups compiled to DFA-hot transition-gather banks"
+            "Groups the plan routed to the dfa-hot tier (flat-bin slots)"
             " (default tenant)",
         ).set_function(lambda: float(self._automata_count("dfa-hot")))
         m_tier_kind = self.metrics.gauge(

@@ -36,7 +36,9 @@ as DFAs where its plan says ``long``; ``flat_bins``, ``flat_slots``,
 ``flat_groups``, ``per_bank_kernels``), ``seg_plans`` (how each resident
 matcher's conv tier was cut to its budget: ``compile_cache.executables[]
 .seg_plan``; ``tiering.long_scan_launches`` beside it counts the launches
-that took the long scan) and, from the ``frontend`` counters' growth over
+that took the long scan), ``device_ops_total`` (what one launch of each
+resident executable is made of: ``executables[].device_ops.total``,
+parameters not counted) and, from the ``frontend`` counters' growth over
 the last warm round, ``tenant_blob_path_share`` and
 ``engine_windows_per_read`` (also on every window's line, with the
 counters themselves under ``frontend``). What
@@ -70,7 +72,7 @@ LAUNCH_COUNTERS = ("launch_plan_hits", "launch_plan_misses", "device_windows",
 # The model the table is read on (``automata_summary``): compiled rules,
 # the conv tier's columns and the long runs cut into chained pieces, and
 # where the engine scans its dense-DFA blocks: fused flat bins, and the
-# blocks left on one kernel a bank.
+# blocks outside every bin.
 MATCHER_LAYOUT = ("rules", "segment_columns", "segment_splits", "segment_split_groups",
                   "segment_long_groups", "flat_bins", "flat_slots", "flat_groups",
                   "per_bank_kernels")
@@ -189,6 +191,9 @@ def main() -> int:
                       "seg_plans": {e["name"]: e.get("seg_plan")
                                     for e in after["compile_cache"]["executables"]
                                     if e.get("seg_plan")},
+                      "device_ops_total": {e["name"]: e["device_ops"]["total"]
+                                           for e in after["compile_cache"]["executables"]
+                                           if e.get("device_ops")},
                       **frontend_ratios(cell, before, after)})
         for k, mode in enumerate(args.windows.split(",")):
             trace_dir = work / f"trace{k}"

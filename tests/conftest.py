@@ -189,3 +189,35 @@ def dfa_witness(dfa) -> bytes:
                     return path
                 todo.append(nxt)
     raise AssertionError("the DFA matches nothing")
+
+
+def layout_pin(model) -> dict:
+    """What a model's layout rests on, as plain JSON: the column order,
+    the blocks' kinds and costs (the masks and row partitions of
+    ``tier_tensors``), which blocks ride flat bins, the columns the host
+    confirms, and every bin's slot layout. ``tests/data/layout_pins.json``
+    holds these as the parent of PR 48 built them."""
+    return {
+        "group_order": [int(g) for g in model.group_order],
+        "block_kinds": [[int(k) for k in ks] for ks in model.block_kinds],
+        "block_cost": [float(c) for c in model.block_cost],
+        "flat_covered": [int(b) for b in model.flat_covered],
+        "prefilter_cols": [[int(c), int(g)] for c, g in model.prefilter_cols],
+        "bins": [
+            {
+                "pieces": [[int(x) for x in p] for p in fb.pieces],
+                "seg_pipes": [int(p) for p in fb.seg_pipes],
+                "seg_slots": [int(n) for n in fb.seg_slots],
+            }
+            for fb in model.flat_banks
+        ],
+    }
+
+
+def layout_pin_sha256(pin: dict) -> str:
+    import hashlib
+    import json
+
+    return hashlib.sha256(
+        json.dumps(pin, sort_keys=True, separators=(",", ":")).encode()
+    ).hexdigest()

@@ -1,8 +1,7 @@
 """Two-level automata routing: verdict parity and the confirm path.
 
-Builds the same compiled ruleset into two engines — automata on (with
-the Pallas interpret kernel forced, so the exact TPU kernel program runs
-on CPU) and automata off — and proves:
+Builds the same compiled ruleset into two engines — automata on and
+automata off — and proves:
 
 - the plan routes groups to all three new tiers (segment stays segment,
   the small regex goes dfa-hot, the big one is prefiltered);
@@ -45,16 +44,11 @@ def _verdict_key(v):
 @pytest.fixture(scope="module")
 def engines():
     crs = compile_rules(RULES)
-    saved = {
-        k: os.environ.get(k)
-        for k in ("CKO_AUTOMATA", "CKO_PALLAS_INTERPRET", "CKO_PALLAS")
-    }
+    saved = {k: os.environ.get(k) for k in ("CKO_AUTOMATA",)}
     try:
         os.environ["CKO_AUTOMATA"] = "0"
         off = WafEngine(crs)
         os.environ["CKO_AUTOMATA"] = "1"
-        os.environ["CKO_PALLAS"] = "1"
-        os.environ["CKO_PALLAS_INTERPRET"] = "1"
         on = WafEngine(crs)
         yield on, off
     finally:
@@ -71,12 +65,11 @@ def test_plan_routes_all_tiers(engines):
     assert counts["dfa-hot"] >= 1
     assert counts["prefiltered"] >= 1
     assert counts["segment"] >= 1
-    assert len(on.model.gather_banks) >= 1
-    assert len(on.model.pre_banks) >= 1
+    assert {b.kind for b in on.model.dense_blocks} == {"dfa-hot", "prefilter"}
     assert len(on.model.prefilter_cols) >= 1
     # The off engine keeps the exact pre-feature layout.
     assert off.automata_plan.counts()["dfa-hot"] == 0
-    assert not off.model.gather_banks and not off.model.pre_banks
+    assert {b.kind for b in off.model.dense_blocks} == {"nfa"}
     assert not off.model.prefilter_cols
 
 
@@ -111,8 +104,8 @@ def test_automata_summary_shape(engines):
     summary = on.automata_summary()
     assert summary["enabled"] is True
     assert set(summary["tiers"]) == {"segment", "dfa-hot", "prefiltered", "nfa"}
-    assert summary["gather_banks"] >= 1
-    assert summary["pre_banks"] >= 1
+    assert summary["dfa_hot_blocks"] >= 1
+    assert summary["prefilter_blocks"] >= 1
     # Where the dense-DFA blocks are scanned: all of them in flat bins.
     assert summary["flat_bins"] >= 1 and summary["per_bank_kernels"] == 0
     assert summary["flat_groups"] == 2 and summary["flat_slots"] % 128 == 0

@@ -69,7 +69,7 @@ def engine(feed, dense):
     from coraza_kubernetes_operator_tpu.engine import WafEngine
 
     with pytest.MonkeyPatch.context() as mp:
-        for k in ("CKO_FLAT", "CKO_AUTOMATA", "CKO_NATIVE"):
+        for k in ("CKO_AUTOMATA", "CKO_NATIVE"):
             mp.delenv(k, raising=False)
         return WafEngine(freeze_custom.feed_text(feed + dense) + SAMPLE)
 
@@ -122,6 +122,16 @@ def test_picks_hold_the_ends_of_the_feed_and_four_of_every_template():
         by = {tpl: sum(freeze_custom.TEMPLATE_OF[i % 10] == tpl for i in got)
               for tpl in TEMPLATES}
         assert min(by.values()) >= 4, by
+
+
+def test_the_feed_layout_is_the_parents(engine):
+    """The layout as the parent of PR 48 built it, before the per-bank
+    matchers went (``tests/data/layout_pins.json``, by its SHA-256)."""
+    from conftest import layout_pin, layout_pin_sha256
+
+    pins = json.loads((REPO / "tests" / "data" / "layout_pins.json").read_text())
+    assert layout_pin_sha256(layout_pin(engine.model)) == pins["custom-feed"]["sha256"]
+    assert engine.model.banks == [] and [b.kind for b in engine.model.dense_blocks] == ["dfa-hot"]
 
 
 def test_the_model_is_past_the_boundaries(engine):
@@ -222,19 +232,19 @@ def test_the_refusal_the_chip_recorded_is_over_the_planners_budget():
 
 
 def test_hot_tier_bank_packing_is_linear_in_the_feed():
-    from coraza_kubernetes_operator_tpu.compiler.re_dfa import joint_class_count
-    from coraza_kubernetes_operator_tpu.ops.dfa_gather import (
-        _MAX_JOINT_CLASSES,
-        plan_gather_bins,
+    from coraza_kubernetes_operator_tpu.compiler.automata_plan import (
+        _HOT_MAX_JOINT_CLASSES,
+        cut_hot_blocks,
     )
+    from coraza_kubernetes_operator_tpu.compiler.re_dfa import joint_class_count
 
     dfas = _dfas_of_27_states(1000)
     t0 = time.monotonic()
-    bins = plan_gather_bins(dfas)
-    assert time.monotonic() - t0 < 4.0  # restacking every bin per candidate: 14 s at 1,000
-    assert sorted(i for b in bins for i in b) == list(range(1000))
-    for b in bins:
-        assert joint_class_count([dfas[i] for i in b]) <= _MAX_JOINT_CLASSES
+    blocks = cut_hot_blocks(dfas)
+    assert time.monotonic() - t0 < 4.0  # restacking every block per candidate: 14 s at 1,000
+    assert sorted(i for b in blocks for i in b) == list(range(1000))
+    for b in blocks:
+        assert joint_class_count([dfas[i] for i in b]) <= _HOT_MAX_JOINT_CLASSES
 
 
 # -- install: what a feed adds to a reload (PR 37) ---------------------------------------------

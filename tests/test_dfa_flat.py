@@ -1,4 +1,4 @@
-"""Flat-slot fused multi-bank scan vs the per-bank gather oracle.
+"""Flat-slot fused multi-bank scan vs the plain bank scan's gather oracle.
 
 The fused kernel (ops/dfa_flat.py) must agree exactly with
 ``scan_dfa_bank_gather`` on every bank it fuses — heterogeneous state
@@ -16,7 +16,11 @@ from coraza_kubernetes_operator_tpu.compiler import (
     literal_dfa,
     pm_dfa,
 )
-from coraza_kubernetes_operator_tpu.ops.dfa import scan_dfa_bank_gather, stack_dfas
+from coraza_kubernetes_operator_tpu.ops.dfa import (
+    scan_dfa_bank_gather,
+    scan_dfa_bank_take,
+    stack_dfas,
+)
 from coraza_kubernetes_operator_tpu.ops.dfa_flat import (
     build_flat_bank,
     plan_flat_bins,
@@ -172,9 +176,9 @@ def test_vmem_planner_respects_budget():
 # --- crs-lite's dfa-hot and prefilter tiers in the flat bins (PR 31) -------
 #
 # build_model plans every dense-DFA block into flat bins. The bins must
-# give, column for column, what the per-bank paths they replace give
-# (``scan_gather_bank_jnp`` for a dfa-hot bank, ``scan_dfa_bank_take``
-# for a prefilter bank) and what the scalar ``DFA.search`` gives.
+# give, column for column, what the plain bank scans of ``ops/dfa.py``
+# give over ``stack_dfas`` of the block's DFAs (``scan_dfa_bank_take``,
+# ``scan_dfa_bank_gather``) and what the scalar ``DFA.search`` gives.
 
 CRS_WIDTH = 64  # small: the interpreted Pallas kernel steps every byte
 
@@ -189,33 +193,27 @@ def crs_lite():
 
     cache = str(Path(__file__).resolve().parent / ".crs_cache")
     with pytest.MonkeyPatch.context() as mp:
-        for k in ("CKO_FLAT", "CKO_AUTOMATA", "CKO_NATIVE"):
+        for k in ("CKO_AUTOMATA", "CKO_NATIVE"):
             mp.delenv(k, raising=False)
         return WafEngine(compile_rules_cached(load_ruleset_text(), cache))
 
 
-def _tier_blocks(eng, tier):
-    """(block index, pipeline, per-bank oracle, bank, the block's DFAs)
-    for each device bank of ``tier``, the DFAs through ``group_order``
-    (device column -> original group), as the engine's confirm does."""
-    from coraza_kubernetes_operator_tpu.ops.dfa import scan_dfa_bank_take
-    from coraza_kubernetes_operator_tpu.ops.dfa_gather import scan_gather_bank_jnp
-
+def _tier_blocks(eng, kind):
+    """(block index, pipeline, the block's DFAs) for each dense block of
+    ``kind``, the DFAs through ``group_order`` (device column -> original
+    group), as the engine's confirm does: the exact DFA of a dfa-hot
+    group, the approximation of a prefiltered one."""
     m = eng.model
     offs = np.concatenate([[0], np.cumsum(eng._block_group_counts)])
-    first = len(m.segs) + len(m.banks)
-    if tier == "dfa-hot":
-        banks, pids, oracle = m.gather_banks, m.gather_bank_pipelines, scan_gather_bank_jnp
+    if kind == "dfa-hot":
         dfa_of = lambda gid: eng.compiled.groups[gid].dfa  # noqa: E731
     else:
-        first += len(m.gather_banks)
-        banks, pids, oracle = m.pre_banks, m.pre_bank_pipelines, scan_dfa_bank_take
         dfa_of = lambda gid: eng.automata_plan.tiers[gid].approx  # noqa: E731
     out = []
-    for i, (bank, pid) in enumerate(zip(banks, pids)):
-        blk = first + i
-        gids = [m.group_order[c] for c in range(offs[blk], offs[blk + 1])]
-        out.append((blk, pid, oracle, bank, [dfa_of(g) for g in gids]))
+    for blk, db in enumerate(m.dense_blocks, start=len(m.segs)):
+        if db.kind == kind:
+            gids = [m.group_order[c] for c in range(offs[blk], offs[blk + 1])]
+            out.append((blk, db.pipeline, [dfa_of(g) for g in gids]))
     return out
 
 
@@ -320,7 +318,7 @@ def test_crs_lite_tier_in_flat_bins_matches_per_bank_oracles(
     # The bins hold slots to 767: past what one bf16 digit holds.
     assert max(fb.n_slots for fb in crs_lite.model.flat_banks) > 512
     blocks = _tier_blocks(crs_lite, tier)
-    dfas = [d for _blk, _pid, _o, _bank, ds in blocks for d in ds]
+    dfas = [d for _blk, _pid, ds in blocks for d in ds]
     assert len(dfas) == n_groups
     assert {blk for blk, *_ in blocks} <= set(crs_lite.model.flat_covered)
     rows = _crs_rows(dfas, CRS_WIDTH, seed=31)
@@ -331,11 +329,13 @@ def test_crs_lite_tier_in_flat_bins_matches_per_bank_oracles(
     data_by_pipe = {p: _as_tensors(rows_of[p], CRS_WIDTH) for p in pids}
     got = _scan_bins(crs_lite.model, data_by_pipe, path)
     last_byte_hits = 0
-    for blk, pid, oracle, bank, ds in blocks:
+    for blk, pid, ds in blocks:
         data, lengths = data_by_pipe[pid]
-        np.testing.assert_array_equal(
-            got[blk], np.asarray(oracle(bank, data, lengths)), err_msg=f"block {blk}"
-        )
+        bank = stack_dfas(ds)
+        for oracle in (scan_dfa_bank_take, scan_dfa_bank_gather):
+            np.testing.assert_array_equal(
+                got[blk], np.asarray(oracle(bank, data, lengths)), err_msg=f"block {blk}"
+            )
         scalar = np.array([[d.search(r) for d in ds] for r in rows_of[pid]])
         np.testing.assert_array_equal(got[blk], scalar, err_msg=f"block {blk}")
         for j, d in enumerate(ds):
@@ -351,7 +351,7 @@ def test_engine_prefilter_columns_stay_approximate_in_flat_bins(crs_lite):
     """The served engine: ``prefilter_cols`` still names columns that
     hold the APPROXIMATION's answer (now out of a flat bin), the host
     confirm refutes a bait that matches the approximation only, and no
-    dense-DFA block is left on a per-bank kernel."""
+    dense-DFA block is left outside the bins."""
     from coraza_kubernetes_operator_tpu.compiler.transforms_host import apply_pipeline
     from coraza_kubernetes_operator_tpu.engine import HttpRequest, WafEngine
     from coraza_kubernetes_operator_tpu.models.waf_model import apply_device_pipeline
@@ -362,7 +362,7 @@ def test_engine_prefilter_columns_stay_approximate_in_flat_bins(crs_lite):
     # the 15 groups whose only fault was a literal past MAX_SEG_LEN ride
     # the conv tier as chained pieces, not these bins
     assert summary["flat_groups"] == 34 and summary["flat_slots"] % 128 == 0
-    assert summary["gather_banks"] == 5 and summary["pre_banks"] == 5
+    assert summary["dfa_hot_blocks"] == 5 and summary["prefilter_blocks"] == 5
     assert summary["segment_split_groups"] == 15 and summary["segment_splits"] == 26
 
     # crs-lite has no device executable on the CPU at a window's shape:
@@ -393,7 +393,7 @@ def test_engine_prefilter_columns_stay_approximate_in_flat_bins(crs_lite):
     n_segs = len(m.segs)
     hits = np.zeros((len(rows), int(m.e_lg.shape[0])), dtype=np.uint8)
     col = sum(s.n_groups for s in m.segs)
-    for blk in range(n_segs, n_segs + len(m.banks) + len(m.gather_banks) + len(m.pre_banks)):
+    for blk in range(n_segs, n_segs + len(m.dense_blocks)):
         w = by_block[blk].shape[1]
         hits[:, col : col + w] = by_block[blk]
         col += w

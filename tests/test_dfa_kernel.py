@@ -117,30 +117,6 @@ def test_take_and_gather_formulations_agree():
     assert (m_take == m_gather).all()
 
 
-def test_pallas_kernel_interpret_matches_oracle():
-    """The TPU kernel, run in interpreter mode, agrees with the scalar DFA."""
-    from coraza_kubernetes_operator_tpu.ops.dfa_pallas import scan_dfa_bank_pallas
-
-    dfas, bank = _bank()
-    data, lengths = _random_batch(16, 32, seed=11)
-    matched = np.asarray(
-        scan_dfa_bank_pallas(
-            bank.t256,
-            bank.match_end.T,
-            bank.always,
-            jnp.asarray(data),
-            jnp.asarray(lengths),
-            s=bank.n_states,
-            g=bank.n_groups,
-            interpret=True,
-        )
-    )
-    for i in range(data.shape[0]):
-        raw = bytes(data[i, : lengths[i]])
-        for g, dfa in enumerate(dfas):
-            assert matched[i, g] == dfa.search(raw), (raw, PATTERNS[g])
-
-
 def test_matmul_scan_xla_miscompile_guard():
     """Regression guard for the XLA bug that forced the `take` formulation.
 
@@ -154,8 +130,6 @@ def test_matmul_scan_xla_miscompile_guard():
 
     dfas, bank = _bank()
     data, lengths = _random_batch(4096, 24, seed=5)
-    # Call the take formulation directly: the dispatcher would route to the
-    # Pallas kernel on TPU and never exercise the path this test guards.
     matched = np.asarray(
         scan_dfa_bank_take(bank, jnp.asarray(data), jnp.asarray(lengths))
     )

@@ -58,7 +58,7 @@ def engine(feed):
     from coraza_kubernetes_operator_tpu.engine import WafEngine
 
     with pytest.MonkeyPatch.context() as mp:
-        for k in ("CKO_FLAT", "CKO_AUTOMATA", "CKO_NATIVE"):
+        for k in ("CKO_AUTOMATA", "CKO_NATIVE"):
             mp.delenv(k, raising=False)
         return WafEngine(freeze_custom.feed_text(feed) + SAMPLE)
 

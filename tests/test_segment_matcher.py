@@ -146,7 +146,7 @@ def test_group_routing_in_model():
     )
     model = build_model(compile_rules(rules))
     assert sum(s.n_groups for s in model.segs) >= 1
-    assert sum(b.n_groups for b in model.banks) >= 1
+    assert sum(b.groups for b in model.dense_blocks) >= 1
 
 
 def test_finals_tier_matches_python_re():

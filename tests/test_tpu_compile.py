@@ -100,25 +100,17 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _widest(banks):
-    return max(banks, key=lambda b: b.n_states * b.n_groups)
-
-
-# (kernel family, rows, width): each bank family of the default crs-lite
-# plan at the sidecar window and at the large batch. The dispatchers must
-# pick the Pallas kernel (one tpu_custom_call), never the XLA fallback.
-# "flat<i>" is the plan's i-th fused bin: since PR 31 every dense-DFA
-# block of crs-lite (nfa banks, dfa-hot gather banks, prefilter
-# approximations) rides one of FLAT_BINS bins, each compiled at both
-# served window shapes; the per-bank kernels stay compiled here as the
-# path of a block the flat planner leaves out.
+# (bin, rows, width): each fused bin of the default crs-lite plan at
+# the sidecar window, the bodies window and the large batch. The
+# dispatcher must pick the Pallas kernel (one tpu_custom_call), never the
+# XLA fallback. "flat<i>" is the plan's i-th fused bin: every dense-DFA
+# block of crs-lite (nfa buckets, dfa-hot blocks, prefilter
+# approximations) rides one of FLAT_BINS bins. A block the flat planner
+# leaves out has no kernel: ``outside`` below compiles its XLA scan.
 FLAT_BINS = 2
 KERNEL_CASES = [
     ("flat0", ROWS_WINDOW, WIDTH_WINDOW),
     ("flat0", ROWS_BATCH, WIDTH_MAX),
-    ("prefilter", ROWS_BATCH, WIDTH_MAX),
-    ("gather", ROWS_WINDOW, WIDTH_WINDOW),
-    ("gather", ROWS_BATCH, WIDTH_MAX),
     ("flat0", ROWS_BODIES, WIDTH_BODIES),
     ("flat1", ROWS_WINDOW, WIDTH_WINDOW),
     ("flat1", ROWS_BODIES, WIDTH_BODIES),
@@ -126,44 +118,21 @@ KERNEL_CASES = [
 ]
 
 
-def _dense_blocks(model) -> int:
-    return len(model.banks) + len(model.gather_banks) + len(model.pre_banks)
-
-
-def _pallas_calls(model) -> int:
-    """Custom calls of the whole matcher: one a flat bin and one a
-    dense-DFA block no bin covers."""
-    return len(model.flat_banks) + _dense_blocks(model) - len(model.flat_covered)
-
-
 @pytest.mark.parametrize("family,rows,width", KERNEL_CASES)
 def test_pallas_kernel_compiles_for_v5e(crs_lite, described, operand, family, rows, width):
-    from coraza_kubernetes_operator_tpu.ops.dfa import scan_dfa_bank
     from coraza_kubernetes_operator_tpu.ops.dfa_flat import scan_flat_bank
-    from coraza_kubernetes_operator_tpu.ops.dfa_gather import scan_gather_bank
 
+    # ops/dfa_flat.py:_scan_flat_pallas — every dense-DFA block of
+    # crs-lite rides one of the fused flat bins.
     model = crs_lite.model
-    data = operand((rows, width), jnp.uint8)
-    lengths = operand((rows,), jnp.int32)
-    if family.startswith("flat"):
-        # ops/dfa_flat.py:_scan_flat_pallas — every dense-DFA block of
-        # crs-lite rides one of the fused flat bins.
-        assert len(model.flat_banks) == FLAT_BINS
-        assert len(model.flat_covered) == _dense_blocks(model)
-        bank = model.flat_banks[int(family[len("flat"):])]
-        pipes = sorted(set(bank.seg_pipes))
-        text = _compile(
-            lambda b, d, n: scan_flat_bank(b, {p: (d, n) for p in pipes}),
-            described(bank), data, lengths,
-        )
-    elif family == "prefilter":
-        # ops/dfa_pallas.py:scan_dfa_bank_pallas — what a prefilter
-        # bank runs when no flat bin covers it.
-        text = _compile(scan_dfa_bank, described(_widest(model.pre_banks)), data, lengths)
-    else:
-        # ops/dfa_gather_pallas.py:scan_gather_bank_pallas — what a
-        # dfa-hot bank runs when no flat bin covers it.
-        text = _compile(scan_gather_bank, described(_widest(model.gather_banks)), data, lengths)
+    assert len(model.flat_banks) == FLAT_BINS and model.banks == []
+    assert len(model.flat_covered) == len(model.dense_blocks)
+    bank = model.flat_banks[int(family[len("flat"):])]
+    pipes = sorted(set(bank.seg_pipes))
+    text = _compile(
+        lambda b, d, n: scan_flat_bank(b, {p: (d, n) for p in pipes}),
+        described(bank), operand((rows, width), jnp.uint8), operand((rows,), jnp.int32),
+    )
     assert text.count("tpu_custom_call") == 1, "dispatch fell back off the Pallas kernel"
 
 
@@ -211,14 +180,13 @@ def test_canary_matcher_compiles_for_v5e(crs_lite, described, operand):
         mask=None,
     ).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == _pallas_calls(model) == FLAT_BINS
+    assert text.count("tpu_custom_call") == len(model.flat_banks) == FLAT_BINS
     # The names a device trace prints: the module by role and window
     # shape (only the post stage's holds "eval_post"), every Pallas
     # kernel by family and bank, not by XLA's running counter.
     assert f"HloModule jit_cko_match_{u}x{width}" in text and "eval_post" not in text
     for i in range(len(model.flat_banks)):
         assert f"%cko_flat_bin{i}" in text, f"cko_flat_bin{i}"
-    assert "%cko_gather_bank" not in text and "%cko_prefilter_bank" not in text
     # It has to fit beside the model's tables in one v5e's 16 GB.
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
 
@@ -241,9 +209,35 @@ def test_long_matcher_compiles_for_v5e(crs_lite, described, operand, rows, width
         mask=None,
     ).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == _pallas_calls(model) == FLAT_BINS
+    assert text.count("tpu_custom_call") == len(model.flat_banks) == FLAT_BINS
     assert f"HloModule jit_cko_match_{rows}x{width}" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**30
+
+
+def test_canary_matcher_with_a_block_outside_every_bin_compiles_for_v5e(on_chip, described, operand):
+    """The model of ``tests/test_dense_blocks.py`` whose wide DFA no bin
+    holds: its canary matcher is one Pallas call a flat bin and, for the
+    block outside them, the XLA gather scan of ``ops/dfa.py`` under
+    ``cko.dense``: no kernel of its own on the chip either."""
+    from test_dense_blocks import OUTSIDE_RULES
+
+    from coraza_kubernetes_operator_tpu.compiler.automata_plan import plan_automata
+    from coraza_kubernetes_operator_tpu.compiler.ruleset import compile_rules
+    from coraza_kubernetes_operator_tpu.models.slab import match_slab_shape
+    from coraza_kubernetes_operator_tpu.models.waf_model import build_model, stage_executable
+
+    crs = compile_rules(OUTSIDE_RULES)
+    model = build_model(crs, plan_automata(crs, prefilter_enabled=False))
+    assert len(model.banks) == 1 and len(model.flat_banks) == 1
+    u, width = ROWS_CANARY, WIDTH_CANARY
+    text = (
+        stage_executable("match", f"{u}x{width}")
+        .lower(described(model), operand(match_slab_shape(u, width, 1), jnp.uint8), mask=None)
+        .compile()
+        .as_text()
+    )
+    assert text.count("tpu_custom_call") == len(model.flat_banks) == 1
+    assert "%cko_flat_bin0" in text and "/cko.dense/" in text
 
 
 # --- the widest bin a custom feed makes (PR 37) ------------------------------

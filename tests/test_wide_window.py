@@ -85,7 +85,7 @@ def _matchers() -> list[dict]:
 @pytest.fixture(scope="module")
 def crs_lite():
     with pytest.MonkeyPatch.context() as mp:
-        for k in ("CKO_FLAT", "CKO_AUTOMATA"):
+        for k in ("CKO_AUTOMATA",):
             mp.delenv(k, raising=False)
         return WafEngine(read_rules(REPO / "wafbench/configs/crs-lite-pl2/rules"))
 
